@@ -108,7 +108,7 @@ def run_microbenchmarks(duration: float = 2.0) -> list[dict]:
     del big_ref
 
     # compiled-DAG per-tick cost: per-call executor vs pre-allocated shm
-    # channel loops (ref: compiled_dag_node.py fast path; VERDICT r3 #3)
+    # channel loops (ref: compiled_dag_node.py fast path)
     @rt.remote
     class Echo:
         def apply(self, x):

@@ -36,9 +36,20 @@ from ray_tpu.core.object_store import make_shm_store
 logger = setup_logger("node_manager")
 
 
+def _holds_tpu(demand: dict[str, float]) -> bool:
+    """True when a lease's demand includes TPU chips (plain, or reserved
+    through a placement-group bundle: "TPU_pg_<id>_<i>")."""
+    return any(amt > 0 and r.split("_pg_", 1)[0] == "TPU"
+               for r, amt in demand.items())
+
+
 class _Worker:
-    def __init__(self, proc: subprocess.Popen):
+    def __init__(self, proc: subprocess.Popen, tpu: bool = False):
         self.proc = proc
+        # started for a lease that holds TPU chips: the only kind of
+        # worker whose jax may leave the CPU (see _spawn_worker). It is
+        # never pooled — it lives exactly as long as its lease
+        self.tpu = tpu
         self.info: WorkerInfo | None = None
         self.conn: Connection | None = None
         self.registered = asyncio.Event()
@@ -748,8 +759,15 @@ class NodeManager:
                     await self._on_worker_death(w)
             self._unregistered = [w for w in self._unregistered
                                   if w.proc.poll() is None]
-            self._doomed = [w for w in self._doomed
-                            if w.proc.poll() is None]
+            still = []
+            for w in self._doomed:
+                if w.proc.poll() is None:
+                    still.append(w)
+                elif w.lease_resources:  # retired by _retire_worker
+                    self._release_resources(w.lease_resources)
+                    w.lease_resources = None
+                    self._maybe_grant_pending()
+            self._doomed = still
             await asyncio.sleep(0.1)
 
     def _on_job_finished(self, job_hex: str):
@@ -829,8 +847,9 @@ class NodeManager:
                        w.info.worker_id if w.info else "?", w.proc.returncode)
 
     # ---------------------------------------------------------- worker pool
-    def _spawn_worker(self) -> _Worker:
-        from ray_tpu._internal.spawn import child_env, fast_python_argv
+    def _spawn_worker(self, tpu: bool = False) -> _Worker:
+        from ray_tpu._internal.spawn import (child_env, fast_python_argv,
+                                             jax_platforms_env)
 
         pkg_root = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
@@ -843,12 +862,17 @@ class NodeManager:
             else "segments")
         env["RAYT_NODE_ADDR"] = f"{self.address.host}:{self.address.port}"
         env["RAYT_GCS_ADDR"] = f"{self.gcs_address.host}:{self.gcs_address.port}"
-        # Workers must not grab the TPU chips unless a task asks for them;
-        # the runtime sets JAX visibility per-lease via env in the future.
+        # One process per chip, decided by the lease: a chip belongs to
+        # one process at a time, and the first process to initialise jax
+        # would take it. So every worker is pinned to the CPU at spawn,
+        # except the one started for a lease that was granted TPU > 0,
+        # which gets this node's own platform setting.
+        env["JAX_PLATFORMS"] = jax_platforms_env(
+            os.environ.get("JAX_PLATFORMS"), tpu)
         proc = subprocess.Popen(
             fast_python_argv("ray_tpu.core.worker_main"),
             env=env, stdin=subprocess.DEVNULL)
-        w = _Worker(proc)
+        w = _Worker(proc, tpu=tpu)
         self._unregistered.append(w)
         return w
 
@@ -889,13 +913,14 @@ class NodeManager:
         self._maybe_grant_pending()
         return True
 
-    def _try_claim_idle(self) -> _Worker | None:
-        """Atomically (no awaits) claim an idle worker. Callers across await
-        points must use this so two concurrent lease grants can't both pick
-        the same worker (which would co-locate a task with an actor and
-        deadlock its executor)."""
+    def _try_claim_idle(self, tpu: bool = False) -> _Worker | None:
+        """Atomically (no awaits) claim an idle worker of the wanted kind.
+        Callers across await points must use this so two concurrent lease
+        grants can't both pick the same worker (which would co-locate a
+        task with an actor and deadlock its executor)."""
         for w in self.workers.values():
-            if not w.busy and w.actor_id is None and w.conn is not None:
+            if not w.busy and w.actor_id is None and w.conn is not None \
+                    and w.tpu == tpu:
                 w.busy = True
                 self._replenish_pool()
                 return w
@@ -908,14 +933,14 @@ class NodeManager:
             return
         target = get_config().idle_worker_pool_size
         idle = sum(1 for w in self.workers.values()
-                   if not w.busy and w.actor_id is None)
-        starting = len(self._unregistered)
+                   if not w.busy and w.actor_id is None and not w.tpu)
+        starting = sum(1 for w in self._unregistered if not w.tpu)
         for _ in range(target - idle - starting):
             self._spawn_worker()
 
-    async def _get_idle_worker(self, timeout_s: float | None = None
-                               ) -> _Worker:
-        w = self._try_claim_idle()
+    async def _get_idle_worker(self, timeout_s: float | None = None,
+                               tpu: bool = False) -> _Worker:
+        w = self._try_claim_idle(tpu)
         if w is not None:
             return w
         cfg = get_config()
@@ -931,10 +956,10 @@ class NodeManager:
             if time.monotonic() >= deadline:
                 raise TimeoutError("worker startup queue timed out")
             await asyncio.sleep(0.05)
-            cand = self._try_claim_idle()
+            cand = self._try_claim_idle(tpu)
             if cand is not None:
                 return cand
-        spawned = self._spawn_worker()
+        spawned = self._spawn_worker(tpu)
         while time.monotonic() < deadline:
             if spawned.info is not None and spawned.conn is not None \
                     and not spawned.busy:
@@ -942,7 +967,7 @@ class NodeManager:
                 return spawned
             # registration may have been matched to another _Worker entry;
             # claim any idle one
-            cand = self._try_claim_idle()
+            cand = self._try_claim_idle(tpu)
             if cand is not None:
                 return cand
             if spawned.proc.poll() is not None:
@@ -1268,7 +1293,7 @@ class NodeManager:
         granted: list = []
         while True:
             try:
-                w = await self._get_idle_worker()
+                w = await self._get_idle_worker(tpu=_holds_tpu(demand))
             except Exception as e:
                 self._release_resources(demand)
                 self._maybe_grant_pending()
@@ -1294,6 +1319,9 @@ class NodeManager:
         w = self.workers.get(wid)
         if w is None:
             return False
+        if w.tpu:
+            self._retire_worker(w)
+            return True
         if w.lease_resources:
             self._release_resources(w.lease_resources)
             w.lease_resources = None
@@ -1302,6 +1330,19 @@ class NodeManager:
         w.last_idle = time.monotonic()
         self._maybe_grant_pending()
         return True
+
+    def _retire_worker(self, w: _Worker):
+        """Terminate a leased worker; the reap loop hands its lease's
+        resources back once the process has exited, not here. A TPU
+        worker may hold the chip until it is gone, and the next lessee
+        must not be started against a chip that is still taken."""
+        if w.info is not None:
+            self.workers.pop(w.info.worker_id, None)
+        try:
+            w.proc.terminate()
+        except Exception:
+            pass
+        self._doomed.append(w)
 
     def _maybe_grant_pending(self):
         """Two-pass FIFO grant: under-share (and unquota'd) waiters
@@ -1362,7 +1403,8 @@ class NodeManager:
                     spec.actor_id, spec.name or "")
         try:
             w = await self._get_idle_worker(
-                timeout_s=budget - time.monotonic())
+                timeout_s=budget - time.monotonic(),
+                tpu=_holds_tpu(demand))
         except Exception as e:
             self._release_resources(demand)
             self._maybe_grant_pending()
@@ -1385,22 +1427,15 @@ class NodeManager:
             # returning it to the idle pool (its state is unknown — the
             # create may still be executing on it).
             w.actor_id = None
-            if w.lease_resources:
-                self._release_resources(w.lease_resources)
-                w.lease_resources = None
-            if w.info is not None:
-                self.workers.pop(w.info.worker_id, None)
-            try:
-                w.proc.terminate()
-            except Exception:
-                pass
-            self._doomed.append(w)  # keep poll()ing it so it gets reaped
-            self._maybe_grant_pending()
+            self._retire_worker(w)
             logger.warning("actor creation push failed, will reschedule: %s", e)
             return None
         if err is not None:
-            w.busy = False
             w.actor_id = None
+            if w.tpu:  # the failed constructor may have taken the chip
+                self._retire_worker(w)
+                return (w.info, err)
+            w.busy = False
             self._release_resources(demand)
             w.lease_resources = None
             self._maybe_grant_pending()
@@ -1429,6 +1464,8 @@ class NodeManager:
                 "actor_id": w.actor_id.hex() if w.actor_id else None,
                 "address": (f"{w.info.address.host}:{w.info.address.port}"
                             if w.info else None),
+                # holds a TPU lease: the only workers not pinned to the CPU
+                "tpu": w.tpu,
             })
         out.extend({"worker_id": None, "pid": w.proc.pid,
                     "busy": False, "actor_id": None, "starting": True}

@@ -31,6 +31,11 @@ class RuntimeContext:
         self.head_node_id: NodeID | None = None
         self.job_id: JobID | None = None
         self.owns_cluster = False
+        # environment variables init() changed in this process, with
+        # the values they had (None = unset): shutdown() puts them back,
+        # so a later cluster started from here inherits the operator's
+        # settings again (see _keep_driver_off_the_chips)
+        self.env_before: dict[str, str | None] = {}
 
 
 def _detect_default_resources(num_cpus, resources):
@@ -52,6 +57,31 @@ def _detect_default_resources(num_cpus, resources):
                 out.setdefault(k, v)
     out.setdefault("memory", float(_system_memory_bytes()))
     return out
+
+
+def _keep_driver_off_the_chips():
+    """A node's chips belong to the workers its manager leases them to
+    (node_manager._spawn_worker), and a chip serves one process at a
+    time. The driver is not such a worker, so when it starts a node
+    that advertises TPU it keeps its own jax on the CPU. Called once the
+    head's environment is made: the head keeps the operator's setting."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        return
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                "this process initialised the "
+                f"{jax.default_backend()!r} backend before ray_tpu.init(): "
+                "it holds the chip that the cluster's TPU workers need. "
+                "Call init() first, and compute on the chip in a task or "
+                "actor that asks for num_tpus")
+    else:
+        jax.config.update("jax_platforms", "cpu")  # env is read at import
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 
 def _system_memory_bytes() -> int:
@@ -91,6 +121,10 @@ def init(address: str | None = None, *, num_cpus: float | None = None,
             os.path.dirname(os.path.abspath(__file__))))
         env = child_env(pkg_root)
         env["RAYT_CONFIG_JSON"] = get_config().to_json()
+        if total.get("TPU"):  # after env is made: the head's is as it was
+            ctx.env_before["JAX_PLATFORMS"] = os.environ.get(
+                "JAX_PLATFORMS")
+            _keep_driver_off_the_chips()
         ctx.head_proc = subprocess.Popen(
             fast_python_argv("ray_tpu.core.head_main")
             + ["--resources", json.dumps(total)],
@@ -142,6 +176,11 @@ def shutdown():
     if ctx is None:
         return
     _global = None
+    for name, before in ctx.env_before.items():
+        if before is None:
+            os.environ.pop(name, None)
+        else:
+            os.environ[name] = before
     try:
         if ctx.core_worker is not None:
             try:

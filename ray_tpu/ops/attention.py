@@ -72,7 +72,24 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if impl == "flash":
         from ray_tpu.ops.pallas.flash_attention import flash_attention
 
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               block_q=block_q, block_k=block_k)
+        def kernel(q, k, v):
+            return flash_attention(q, k, v, causal=causal, scale=scale,
+                                   block_q=block_q, block_k=block_k)
+
+        mesh = jax.sharding.get_abstract_mesh()
+        if mesh.empty or mesh.size == 1:
+            return kernel(q, k, v)
+        # GSPMD cannot partition a Mosaic kernel (the chip's compiler
+        # says so: "wrap the call in a shard_map"), so under a mesh the
+        # kernel runs per shard. Rows and heads attend independently:
+        # splitting batch and heads needs no collective. The sequence
+        # stays whole; a `seq` axis is ring attention's job.
+        from ray_tpu.parallel.mesh import spec_for
+
+        q_spec = spec_for(("batch", None, "heads", None), mesh=mesh)
+        kv_spec = spec_for(("batch", None, "kv_heads", None), mesh=mesh)
+        return jax.shard_map(kernel, mesh=mesh,
+                             in_specs=(q_spec, kv_spec, kv_spec),
+                             out_specs=q_spec, check_vma=False)(q, k, v)
     return xla_attention(q, k, v, causal=causal, segment_ids=segment_ids,
                         scale=scale)

@@ -26,11 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases;
-# accept either so the kernels load on both sides of the rename
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams", None)
-
 NEG_INF = -1e30
 # lse sentinel for fully-masked rows: exp(s - BIG) == 0 for any finite s
 _MASKED_LSE = 1e30
@@ -38,8 +33,12 @@ _LANES = 128
 
 
 def _interpret() -> bool:
-    """Pallas interpret mode off-TPU so CPU CI exercises the kernels."""
-    return jax.default_backend() != "tpu"
+    """Interpret mode only where the CPU was asked for by name
+    (JAX_PLATFORMS=cpu, as the tests set it). It is never inferred from
+    the backend jax happened to end up with: a process that was meant to
+    run on the chip and fell back to the CPU must fail to lower the
+    kernel, not emulate it quietly."""
+    return jax.config.jax_platforms == "cpu"
 
 
 # --------------------------------------------------------------- forward
@@ -156,7 +155,7 @@ def _flash_forward(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=_interpret(),
@@ -316,7 +315,7 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool,
                                lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interp,
@@ -357,7 +356,7 @@ def _flash_backward(q, k, v, out, lse, g, *, causal: bool,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interp,

@@ -24,7 +24,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -90,12 +89,12 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array,
     local = functools.partial(_pipeline_local, fn, axis=axis)
 
     param_specs = jax.tree.map(lambda _: P(axis), stage_params)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda p, mx: local(jax.tree.map(lambda l: l[0], p), mx),
         mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     out = sharded(stage_params, micro_x)
     return out.reshape((b,) + out.shape[2:])
 

@@ -88,6 +88,13 @@ def build_train_step(loss_fn: Callable, optimizer: optax.GradientTransformation,
         is_leaf=lambda x: isinstance(x, jax.Array))
 
     def one_step(state, batch):
+        # traced under the mesh, so model code that must know it (a
+        # Pallas kernel has to be shard_map'ped by hand, ops/attention.py)
+        # finds it with jax.sharding.get_abstract_mesh()
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return _one_step(state, batch)
+
+    def _one_step(state, batch):
         def compute(p, b):
             # params stay an arbitrary pytree unless a frozen split exists
             full = {**state["frozen"], **p} if "frozen" in state else p

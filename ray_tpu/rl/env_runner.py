@@ -14,13 +14,9 @@ class EnvRunner:
     def __init__(self, env_name: str, num_envs: int, seed: int,
                  module_cfg_blob: bytes,
                  connector_blob: bytes | None = None):
-        from ray_tpu._internal.spawn import wait_site_ready
-
-        wait_site_ready()
         import cloudpickle
         import jax
 
-        jax.config.update("jax_platforms", "cpu")  # sampling is host-side
         from ray_tpu.rl.connectors import default_env_to_module
         from ray_tpu.rl.env import make_vector_env
 

@@ -113,13 +113,9 @@ class MultiAgentEnvRunner:
     def __init__(self, env_name: str, num_envs: int, seed: int,
                  module_cfg_blob: bytes, mapping_blob: bytes,
                  env_cfg_blob: bytes | None = None):
-        from ray_tpu._internal.spawn import wait_site_ready
-
-        wait_site_ready()
         import cloudpickle
         import jax
 
-        jax.config.update("jax_platforms", "cpu")  # sampling is host-side
         env_cfg = (cloudpickle.loads(env_cfg_blob)
                    if env_cfg_blob is not None else {})
         self.env = make_multi_agent_env(env_name, num_envs, seed,

@@ -30,12 +30,8 @@ class SACRunner(ReplayRolloutMixin):
 
     def __init__(self, env_name: str, num_envs: int, seed: int,
                  module_cfg_blob: bytes):
-        from ray_tpu._internal.spawn import wait_site_ready
-
-        wait_site_ready()
         import jax
 
-        jax.config.update("jax_platforms", "cpu")
         self.env = make_vector_env(env_name, num_envs, seed)
         self.module_cfg = cloudpickle.loads(module_cfg_blob)
         self._key = jax.random.PRNGKey(seed)

@@ -697,11 +697,6 @@ def cmd_microbenchmark(args):
     import ray_tpu as rt
     from ray_tpu._internal.perf import run_microbenchmarks
 
-    # Substrate benchmark: workers never touch the device backend, and an
-    # eagerly-imported PJRT plugin with an unreachable endpoint can spin
-    # ~half a core per process (see spawn.import_site_background), which
-    # turns the measurement into plugin noise on small hosts.
-    os.environ.setdefault("RAYT_SITE_IMPORT", "lazy")
     rt.init(num_cpus=args.num_cpus or None)
     try:
         rows = run_microbenchmarks(duration=args.duration)
@@ -712,15 +707,11 @@ def cmd_microbenchmark(args):
     if args.json_out:
         import platform
 
-        mode = os.environ.get("RAYT_SITE_IMPORT", "lazy")
         doc = {"suite": "rayt microbenchmark",
                "host": {"cpus": os.cpu_count(),
                         "platform": platform.platform()},
-               "note": (f"measured with RAYT_SITE_IMPORT={mode} (this "
-                        "command defaults to lazy so substrate workers "
-                        "never load a PJRT plugin — an unreachable device "
-                        "endpoint would spin-steal cores from the "
-                        "measurement)"),
+               "note": ("host-side substrate rates (tasks, actors, "
+                        "objects); no worker touches a device"),
                "results": rows}
         with open(args.json_out, "w") as f:
             json.dump(doc, f, indent=1)

@@ -17,6 +17,7 @@ prefill/decode with donated KV cache, greedy/temperature sampling in-jit.
 from __future__ import annotations
 
 import asyncio
+import os
 import threading
 import time
 
@@ -780,6 +781,67 @@ class LLMEngine:
                 "tp": self.mesh.shape.get("tensor", 1)}
 
 
+def greedy_reference_check(engine: "LLMEngine", tokens: list[int],
+                           generated: list[int]) -> dict:
+    """Hold a finished greedy request against the training-path model:
+    one `llama.forward` over prompt + generated with the engine's own
+    params (no KV cache, no left padding, `_block`'s attention instead
+    of `_decode_block`'s). Position by position, the token the engine
+    emitted must be the argmax of the reference's logits given the same
+    prefix — which is greedy decoding with `forward`, since equal
+    prefixes are what it would have fed itself. `max_margin` is how far
+    an emitted token sat below the reference's best logit (0 where they
+    agree): in bf16 two near-tied logits can swap between the two
+    programs, so callers hold it to a tolerance instead of asking for
+    bit-equal arithmetic."""
+    cfg = engine.cfg
+    seq = list(tokens) + list(generated)
+    padded = 128
+    while padded < len(seq):
+        padded *= 2  # right pads sit behind the causal mask
+    toks = np.zeros((1, padded), np.int32)
+    toks[0, :len(seq)] = seq
+
+    def forward(params, toks):
+        # under the engine's mesh: with tp > 1 a flash kernel in the
+        # reference has to be split per shard (ops/attention.py)
+        with jax.sharding.use_abstract_mesh(engine.mesh.abstract_mesh):
+            return llama.forward(params, toks, cfg)
+
+    logits = jax.jit(forward)(engine.params, jnp.asarray(toks))
+    rows = np.asarray(logits[0, len(tokens) - 1:len(seq) - 1], np.float32)
+    ref = rows.argmax(-1)
+    got = np.asarray(generated)
+    margin = rows.max(-1) - rows[np.arange(len(got)), got]
+    return {"reference": [int(t) for t in ref],
+            "equal": bool((ref == got).all()),
+            "mismatches": int((ref != got).sum()),
+            "max_margin": float(margin.max()),
+            "logit_std": float(rows.std()),
+            "forward_len": padded}
+
+
+def _replica_chips(engine_kw: dict) -> dict:
+    """Actor options for a replica that hosts an engine: the chips its
+    tensor axis spans, when the cluster has chips. Only a worker that
+    holds a TPU lease may leave the CPU (node_manager._spawn_worker), so
+    an engine that is to run on the chip has to ask for it. With no `tp`
+    the engine spans every device its process sees, which for a leased
+    worker is every chip of its node; the request pins that count into
+    `engine_kw` so lease and mesh agree."""
+    import ray_tpu as rt
+
+    if not rt.is_initialized():
+        return {}
+    per_node = [int(n.resources_total.get("TPU", 0))
+                for n in rt.nodes() if n.alive]
+    if not any(per_node):
+        return {}
+    if not engine_kw.get("tp"):
+        engine_kw["tp"] = max(per_node)
+    return {"num_tpus": engine_kw["tp"]}
+
+
 class LlamaService:
     """Serve callable hosting one LLMEngine (deploy via serve.deployment).
 
@@ -804,6 +866,23 @@ class LlamaService:
     def stats(self) -> dict:
         return self.engine.stats()
 
+    def device_report(self) -> dict:
+        """Where this replica computes, as jax reports it from inside
+        the replica's process, and what it holds on each device."""
+        devs = jax.devices()
+        return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                "count": len(devs), "pid": os.getpid(),
+                "mesh_devices": [d.id for d in
+                                 self.engine.mesh.devices.flat],
+                # distinct compiled variants of the engine's one jitted
+                # step: a prefill per bucket or chunk shape, one decode
+                "step_programs": self.engine._step_jit._cache_size(),
+                "memory": [d.memory_stats() or {} for d in devs]}
+
+    def reference_check(self, tokens: list[int],
+                        generated: list[int]) -> dict:
+        return greedy_reference_check(self.engine, tokens, generated)
+
 
 def llm_app(preset: str = "debug", *, num_replicas: int = 1,
             max_ongoing_requests: int = 64, **engine_kw):
@@ -814,6 +893,7 @@ def llm_app(preset: str = "debug", *, num_replicas: int = 1,
         LlamaService,
         num_replicas=num_replicas,
         max_ongoing_requests=max_ongoing_requests,
+        ray_actor_options=_replica_chips(engine_kw),
     )
     return dep.bind(preset, **engine_kw)
 
@@ -898,6 +978,7 @@ def lora_llm_app(preset: str = "debug", *, num_replicas: int = 1,
         MultiplexedLoraService,
         num_replicas=num_replicas,
         max_ongoing_requests=max_ongoing_requests,
+        ray_actor_options=_replica_chips(engine_kw),
     )
     return dep.bind(preset,
                     max_adapters_per_replica=max_adapters_per_replica,
@@ -913,8 +994,6 @@ DECODE_REPLICAS_ENV = "RAYT_SERVE_DECODE_REPLICAS"
 
 
 def _pool_size(env: str, default: int) -> int:
-    import os
-
     try:
         return max(1, int(os.environ.get(env, default)))
     except (TypeError, ValueError):
@@ -1092,11 +1171,14 @@ def disagg_llm_app(preset: str = "debug", *,
         prefill_replicas = _pool_size(PREFILL_REPLICAS_ENV, 1)
     if decode_replicas is None:
         decode_replicas = _pool_size(DECODE_REPLICAS_ENV, 1)
+    chips = _replica_chips(engine_kw)  # each pool's replica holds its own
     prefill_dep = deployment(
         PrefillWorker, num_replicas=prefill_replicas,
-        max_ongoing_requests=max_ongoing_requests)
+        max_ongoing_requests=max_ongoing_requests,
+        ray_actor_options=chips)
     decode_dep = deployment(
         DecodeLlamaService, num_replicas=decode_replicas,
-        max_ongoing_requests=max_ongoing_requests)
+        max_ongoing_requests=max_ongoing_requests,
+        ray_actor_options=chips)
     return decode_dep.bind(prefill_dep.bind(preset, **engine_kw),
                            preset, **engine_kw)

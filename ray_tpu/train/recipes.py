@@ -135,41 +135,19 @@ def corpus_pretrain_loop(config: dict):
     return float(loss) if loss is not None else None
 
 
-def lora_finetune_loop(config: dict):
-    """LoRA fine-tune a Llama-family model (BASELINE.json config #3).
-
-    Runs inside each TrainWorker: builds the mesh from ScalingConfig,
-    initializes (or loads) frozen base params + LoRA adapters, and trains
-    ONLY the adapters (build_train_step(trainable_keys=("lora",)) — the
+def build_lora_step(config: dict, mesh):
+    """The LoRA fine-tune step as `lora_finetune_loop` runs it, for the
+    keys of its config that shape the program (preset, model_overrides,
+    lora_*, lr, grad_accum, seed, init_params_fn): frozen base params +
+    fresh adapters placed on `mesh`, and a jitted step that trains ONLY
+    the adapters (build_train_step(trainable_keys=("lora",)) — the
     backward computes no base-weight gradients and the optimizer holds
-    moments only for A/B).
-
-    config keys:
-      preset        — llama preset name (default "debug")
-      model_overrides — dict merged into the preset config
-      lora_rank / lora_alpha / lora_targets
-      lr, steps, batch_size, seq_len, grad_accum
-      report_every  — steps between train.report calls (default 10)
-      batch_fn      — optional callable (step, rank) -> {"tokens","targets"}
-                      (defaults to synthetic LM data)
-      init_params_fn — optional callable (cfg) -> base params (defaults to
-                      random init; real runs pass a checkpoint loader)
-    """
-    import os
-    import pickle
-    import tempfile
-
+    moments only for A/B). Returns (step, state, cfg)."""
     import jax
-    import jax.numpy as jnp
     import optax
 
-    from ray_tpu import train
     from ray_tpu.models import llama, lora
-    from ray_tpu.parallel.spmd import build_train_step, shard_batch
-    from ray_tpu.train.checkpoint import Checkpoint, save_pytree
-
-    ctx = train.get_context()
-    mesh = ctx.get_mesh()
+    from ray_tpu.parallel.spmd import build_train_step
 
     overrides = dict(config.get("model_overrides") or {})
     overrides.setdefault("lora_alpha", config.get("lora_alpha", 16.0))
@@ -195,6 +173,41 @@ def lora_finetune_loop(config: dict):
         loss, optax.adamw(config.get("lr", 1e-3)), params, axes, mesh,
         grad_accum=config.get("grad_accum", 1),
         trainable_keys=("lora",))
+    return step, state, cfg
+
+
+def lora_finetune_loop(config: dict):
+    """LoRA fine-tune a Llama-family model (BASELINE.json config #3).
+
+    Runs inside each TrainWorker: builds the mesh from ScalingConfig,
+    initializes (or loads) frozen base params + LoRA adapters, and trains
+    ONLY the adapters (see `build_lora_step`).
+
+    config keys:
+      preset        — llama preset name (default "debug")
+      model_overrides — dict merged into the preset config
+      lora_rank / lora_alpha / lora_targets
+      lr, steps, batch_size, seq_len, grad_accum
+      report_every  — steps between train.report calls (default 10)
+      batch_fn      — optional callable (step, rank) -> {"tokens","targets"}
+                      (defaults to synthetic LM data)
+      init_params_fn — optional callable (cfg) -> base params (defaults to
+                      random init; real runs pass a checkpoint loader)
+    """
+    import os
+    import pickle
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu import train
+    from ray_tpu.parallel.spmd import shard_batch
+    from ray_tpu.train.checkpoint import Checkpoint, save_pytree
+
+    ctx = train.get_context()
+    mesh = ctx.get_mesh()
+    step, state, cfg = build_lora_step(config, mesh)
 
     rank = ctx.get_world_rank()
     start_step = 0

@@ -33,7 +33,6 @@ def scale_cluster():
     # the conftest SIGALRM budget (180s) is sized for tier-1 tests; this
     # module legitimately runs for tens of minutes on one core
     signal.alarm(0)
-    os.environ.setdefault("RAYT_SITE_IMPORT", "lazy")
     # serialized spawn on 1 core: late members of a 640-actor fleet wait
     # minutes for their turn — measure capacity, not spawn latency
     os.environ.setdefault("RAYT_WORKER_STARTUP_TIMEOUT_S", "1800")

@@ -11,7 +11,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops.attention import xla_attention
@@ -32,10 +31,10 @@ def _make_qkv(b, s, h, hk, d, seed=0):
 
 def _sharded_attn(attn_fn, mesh, causal):
     fn = functools.partial(attn_fn, axis_name="seq", causal=causal)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, "seq"), P(None, "seq"), P(None, "seq")),
-        out_specs=P(None, "seq"), check_rep=False)
+        out_specs=P(None, "seq"), check_vma=False)
 
 
 @pytest.mark.parametrize("causal", [True, False])

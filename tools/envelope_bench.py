@@ -27,10 +27,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# CPU-only workload: never load a PJRT plugin in the fleet (see
-# spawn.import_site_background — a wedged device endpoint spins cores).
-os.environ.setdefault("RAYT_SITE_IMPORT", "lazy")
-
 import numpy as np  # noqa: E402
 
 
