@@ -13,6 +13,11 @@ Two probes, both RPC-triggered against any live worker:
 * :func:`sample_memory` — tracemalloc window: enables tracing for
   `duration_s` and reports the top allocation sites by net new bytes
   (the memray-lite answer to "what is this worker allocating?").
+
+And one name for the JAX profiler's host span, :func:`span_type`: what
+the serve engine and the train StepRecorder open around their units of
+work, so that a `jax.profiler` trace of the process that holds the chip
+shows them on the device operations' own clock.
 """
 
 from __future__ import annotations
@@ -21,6 +26,21 @@ import sys
 import threading
 import time
 import traceback
+
+
+def span_type():
+    """`jax.profiler.TraceAnnotation` itself, imported on first use (a
+    train controller or a driver that never touches jax imports this
+    module too). `with span_type()("rayt.engine.admit", slot=3):` is a
+    TraceMe: with no profiler session active it is a flag check (under a
+    microsecond, no file, no buffer, no thread); under one, the event
+    lands in the `/host:` plane of the same .xplane.pb as the device
+    operations, with the keyword arguments as its stats. Names start
+    with `rayt.`; arguments are plain ints, floats and short strings
+    that the caller already holds."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
 
 
 def sample_cpu(duration_s: float = 5.0, interval_s: float = 0.01,

@@ -8,9 +8,10 @@ site-packages dirs on PYTHONPATH explicitly. jax, jaxlib and libtpu are
 plain packages there, so a worker started this way still finds the TPU
 backend.
 
-`child_env` is also where the two per-process JAX settings are decided:
-the persistent compile cache directory and (through `jax_platforms_env`)
-whether the process may touch the chip.
+`child_env` is also where the per-process JAX settings are decided: the
+persistent compile cache directory, that its key holds the programs'
+metadata, and (through `jax_platforms_env`) whether the process may
+touch the chip.
 """
 
 from __future__ import annotations
@@ -20,6 +21,7 @@ import sys
 import sysconfig
 
 COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+COMPILE_CACHE_METADATA_ENV = "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY"
 
 
 def fast_python_argv(module: str) -> list[str]:
@@ -52,6 +54,13 @@ def child_env(pkg_root: str, base: dict | None = None) -> dict:
     env["PYTHONPATH"] = os.pathsep.join(paths)
     # JAX reads the variable itself; a directory set from outside wins
     env.setdefault(COMPILE_CACHE_ENV, compile_cache_dir(pkg_root))
+    # The program names its device operations with jax.named_scope, and
+    # a profiler trace reads those names from the executable. JAX's cache
+    # key leaves such metadata out by default, so a cache filled by an
+    # older tree would go on serving executables with the old names (seen
+    # on the chip, PERF.md PR 24). With the metadata in the key a tree
+    # whose names or lines moved compiles once more and is cached again.
+    env.setdefault(COMPILE_CACHE_METADATA_ENV, "true")
     return env
 
 

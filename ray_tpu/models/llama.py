@@ -257,8 +257,9 @@ def _proj(cfg: LlamaConfig, layer: dict, name: str, h):
     a = layer.get(name + "_a")
     if a is not None:
         scale = cfg.lora_alpha / a.shape[-1]
-        out = out + ((h @ a.astype(dt)) @ layer[name + "_b"].astype(dt)
-                     ) * jnp.asarray(scale, dt)
+        with jax.named_scope("lora"):
+            out = out + ((h @ a.astype(dt)) @ layer[name + "_b"].astype(dt)
+                         ) * jnp.asarray(scale, dt)
     return out
 
 
@@ -278,31 +279,40 @@ def _block(cfg: LlamaConfig, x, layer, cos, sin, positions):
     def proj(name, h):
         return _proj(cfg, layer, name, h)
 
-    h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q = proj("wq", h).reshape(b, s, nh, hd)
-    kk = proj("wk", h).reshape(b, s, nkv, hd)
-    vv = proj("wv", h).reshape(b, s, nkv, hd)
-    q = apply_rope(q, cos, sin, positions)
-    kk = apply_rope(kk, cos, sin, positions)
-    attn = _attention(cfg, q, kk, vv).reshape(b, s, nh * hd)
+    # The named scopes here and in _decode_block are HLO metadata only:
+    # they name each device operation's part of the block in a profiler
+    # trace (benchmarks/trace_spans.py sums device time by them) and
+    # change no operation, fusion or buffer.
+    with jax.named_scope("attn_qkv"):
+        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        q = proj("wq", h).reshape(b, s, nh, hd)
+        kk = proj("wk", h).reshape(b, s, nkv, hd)
+        vv = proj("wv", h).reshape(b, s, nkv, hd)
+        q = apply_rope(q, cos, sin, positions)
+        kk = apply_rope(kk, cos, sin, positions)
+    with jax.named_scope("attn"):
+        attn = _attention(cfg, q, kk, vv).reshape(b, s, nh * hd)
     # Named so the remat policy can save it: attention outputs are dots
     # WITH batch dims, so dots_with_no_batch_dims_saveable would rerun
     # the whole flash kernel forward inside the backward pass (~+33% on
     # the attention budget) to rebuild this one activation.
     attn = checkpoint_name(attn, "attn_out")
-    x = x + proj("wo", attn)
+    with jax.named_scope("attn_out"):
+        x = x + proj("wo", attn)
 
-    h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    if cfg.moe:
-        from ray_tpu.ops.moe import moe_ffn
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        if cfg.moe:
+            from ray_tpu.ops.moe import moe_ffn
 
-        moe_params = {"router": layer["router"], "w_gate": layer["w_gate"],
-                      "w_up": layer["w_up"], "w_down": layer["w_down"]}
-        out, aux = moe_ffn(moe_params, h, cfg.moe_config())
-        return x + out, aux
-    gate = jax.nn.silu(proj("w_gate", h))
-    up = proj("w_up", h)
-    x = x + proj("w_down", gate * up)
+            moe_params = {"router": layer["router"],
+                          "w_gate": layer["w_gate"],
+                          "w_up": layer["w_up"], "w_down": layer["w_down"]}
+            out, aux = moe_ffn(moe_params, h, cfg.moe_config())
+            return x + out, aux
+        gate = jax.nn.silu(proj("w_gate", h))
+        up = proj("w_up", h)
+        x = x + proj("w_down", gate * up)
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -316,7 +326,8 @@ def backbone(params: dict, tokens: jax.Array, cfg: LlamaConfig,
     optionally rematerialized.
     """
     dt = cfg.dtype
-    x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     scanned_layers = params["layers"]
     if "lora" in params:
@@ -365,7 +376,8 @@ def forward(params: dict, tokens: jax.Array, cfg: LlamaConfig,
             positions: jax.Array | None = None) -> jax.Array:
     """tokens: [b, s] int32 -> logits [b, s, vocab] (f32)."""
     x = backbone(params, tokens, cfg, positions)
-    return (x @ _head_matrix(params, cfg)).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        return (x @ _head_matrix(params, cfg)).astype(jnp.float32)
 
 
 def loss_fn(params: dict, batch: dict, cfg: LlamaConfig):
@@ -377,8 +389,9 @@ def loss_fn(params: dict, batch: dict, cfg: LlamaConfig):
     from ray_tpu.ops.cross_entropy import fused_lm_head_cross_entropy
 
     x, moe_aux = backbone(params, batch["tokens"], cfg, with_aux=True)
-    ce_loss, n_tok = fused_lm_head_cross_entropy(
-        x, _head_matrix(params, cfg), batch["targets"])
+    with jax.named_scope("ce"):   # the head's matmul is fused into it
+        ce_loss, n_tok = fused_lm_head_cross_entropy(
+            x, _head_matrix(params, cfg), batch["targets"])
     loss = ce_loss + moe_aux
     return loss, {"loss": ce_loss, "tokens": n_tok, "moe_aux": moe_aux}
 
@@ -418,44 +431,50 @@ def _decode_block(cfg: LlamaConfig, x, layer, k_cache, v_cache, cos, sin,
     b, s, d = x.shape
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     dt = cfg.dtype
-    h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q = _proj(cfg, layer, "wq", h).reshape(b, s, nh, hd)
-    kk = _proj(cfg, layer, "wk", h).reshape(b, s, nkv, hd)
-    vv = _proj(cfg, layer, "wv", h).reshape(b, s, nkv, hd)
-    q = apply_rope(q, cos, sin, positions)
-    kk = apply_rope(kk, cos, sin, positions)
-    if jnp.ndim(cache_len) == 0:
-        # whole batch advances together (left-padded batched decode)
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, kk, (0, cache_len, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, vv, (0, cache_len, 0, 0))
-    else:
-        # per-row write offsets (continuous-batching slots: each row is
-        # an independent request at its own depth — vLLM-style)
-        def _upd(c, new, off):
-            return jax.lax.dynamic_update_slice(c, new, (off, 0, 0))
-        k_cache = jax.vmap(_upd)(k_cache, kk, cache_len)
-        v_cache = jax.vmap(_upd)(v_cache, vv, cache_len)
-    # mask: key slot j visible iff start <= j <= query slot
-    max_len = k_cache.shape[1]
-    q_pos = positions if abs_positions is None else abs_positions  # [b, s]
-    k_pos = jnp.arange(max_len)[None, :]
-    mask = k_pos[:, None, :] <= q_pos[..., None]          # [b, s, max_len]
-    if start is not None:
-        mask = mask & (k_pos[:, None, :] >= start[:, None, None])
-    kr = _repeat_kv(k_cache, nh // nkv)
-    vr = _repeat_kv(v_cache, nh // nkv)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
-                        preferred_element_type=jnp.float32) * (hd ** -0.5)
-    logits = jnp.where(mask[:, None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(dt)
-    attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vr).reshape(b, s, nh * hd)
-    x = x + _proj(cfg, layer, "wo", attn)
-    h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    x = x + _proj(cfg, layer, "w_down",
-                  jax.nn.silu(_proj(cfg, layer, "w_gate", h))
-                  * _proj(cfg, layer, "w_up", h))
+    with jax.named_scope("attn_qkv"):
+        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        q = _proj(cfg, layer, "wq", h).reshape(b, s, nh, hd)
+        kk = _proj(cfg, layer, "wk", h).reshape(b, s, nkv, hd)
+        vv = _proj(cfg, layer, "wv", h).reshape(b, s, nkv, hd)
+        q = apply_rope(q, cos, sin, positions)
+        kk = apply_rope(kk, cos, sin, positions)
+    with jax.named_scope("kv_update"):
+        if jnp.ndim(cache_len) == 0:
+            # whole batch advances together (left-padded batched decode)
+            k_cache = jax.lax.dynamic_update_slice(
+                k_cache, kk, (0, cache_len, 0, 0))
+            v_cache = jax.lax.dynamic_update_slice(
+                v_cache, vv, (0, cache_len, 0, 0))
+        else:
+            # per-row write offsets (continuous-batching slots: each row
+            # is an independent request at its own depth — vLLM-style)
+            def _upd(c, new, off):
+                return jax.lax.dynamic_update_slice(c, new, (off, 0, 0))
+            k_cache = jax.vmap(_upd)(k_cache, kk, cache_len)
+            v_cache = jax.vmap(_upd)(v_cache, vv, cache_len)
+    with jax.named_scope("attn"):
+        # mask: key slot j visible iff start <= j <= query slot
+        max_len = k_cache.shape[1]
+        q_pos = positions if abs_positions is None else abs_positions
+        k_pos = jnp.arange(max_len)[None, :]
+        mask = k_pos[:, None, :] <= q_pos[..., None]      # [b, s, max_len]
+        if start is not None:
+            mask = mask & (k_pos[:, None, :] >= start[:, None, None])
+        kr = _repeat_kv(k_cache, nh // nkv)
+        vr = _repeat_kv(v_cache, nh // nkv)
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
+                            preferred_element_type=jnp.float32) * (hd ** -0.5)
+        logits = jnp.where(mask[:, None], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(dt)
+        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vr).reshape(
+            b, s, nh * hd)
+    with jax.named_scope("attn_out"):
+        x = x + _proj(cfg, layer, "wo", attn)
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        x = x + _proj(cfg, layer, "w_down",
+                      jax.nn.silu(_proj(cfg, layer, "w_gate", h))
+                      * _proj(cfg, layer, "w_up", h))
     return x, k_cache, v_cache
 
 
@@ -482,7 +501,8 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array,
     else:
         # rope positions are relative to each row's first real token
         positions = jnp.maximum(abs_positions - start[:, None], 0)
-    x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
     scanned_layers = params["layers"]
     if "lora" in params:
@@ -500,10 +520,9 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array,
 
     x, (k_new, v_new) = jax.lax.scan(
         step, x, (scanned_layers, cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(dt)
-    logits = (x[:, -1] @ head).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = (x[:, -1] @ _head_matrix(params, cfg)).astype(jnp.float32)
     new_cache = {"k": k_new, "v": v_new, "length": cache_len + s}
     if start is not None:
         new_cache["start"] = start

@@ -120,14 +120,21 @@ def build_train_step(loss_fn: Callable, optimizer: optax.GradientTransformation,
             grads = jax.tree.map(lambda g: g / grad_accum, grads)
             aux = jax.tree.map(lambda a: a / grad_accum, aux)
         else:
-            (_, aux), grads = jax.value_and_grad(
-                compute, has_aux=True)(state["params"], batch)
-        updates, new_opt = optimizer.update(
-            grads, state["opt_state"], state["params"])
-        new_params = optax.apply_updates(state["params"], updates)
-        # keep param dtype stable (optax promotes on mixed dtypes)
-        new_params = jax.tree.map(
-            lambda new, old: new.astype(old.dtype), new_params, state["params"])
+            # scope names are HLO metadata: a profiler trace of the step
+            # says which device operations are the loss (jax adds jvp,
+            # transpose and checkpoint to the path itself) and which the
+            # optimizer
+            with jax.named_scope("loss"):
+                (_, aux), grads = jax.value_and_grad(
+                    compute, has_aux=True)(state["params"], batch)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, state["opt_state"], state["params"])
+            new_params = optax.apply_updates(state["params"], updates)
+            # keep param dtype stable (optax promotes on mixed dtypes)
+            new_params = jax.tree.map(
+                lambda new, old: new.astype(old.dtype), new_params,
+                state["params"])
         out = {"params": new_params, "opt_state": new_opt,
                "step": state["step"] + 1}
         if "frozen" in state:

@@ -7,7 +7,11 @@ pgs,workers,tasks,objects,dags,events,requests}, dag <id>, why-pending
 {submit,status,logs,stop,list} (ref analog for jobs:
 dashboard/modules/job/cli.py). `list requests` renders per-request
 serve latency waterfalls; `serve status` appends the per-app stage
-p50/p99 table.
+p50/p99 table. In a request's `engine[...]` part, `queue`, `prefill` and
+decode tile the engine's share of the request: `prefill` is admission to
+first token on the host (with the count of prefill calls after the `x`),
+not the prefill's device time, which only a profiler trace gives (scope
+`prefill`; README, "Profiler traces").
 """
 
 from __future__ import annotations
@@ -1030,7 +1034,14 @@ def main(argv=None):
     sp.add_argument("--address")
     sp.set_defaults(fn=cmd_summary)
 
-    sp = sub.add_parser("list", help="list cluster state")
+    sp = sub.add_parser(
+        "list", help="list cluster state",
+        description="list cluster state. `requests` prints one line per "
+        "serve request: the proxy's stages, then replica[queue service] "
+        "and engine[queue, prefill = admission to first token x prefill "
+        "calls, ttft, tpot, occupancy]; the record also holds the "
+        "engine's four stamps t_enqueue, t_admit, t_first, t_last "
+        "(state_api.get_serve_request).")
     sp.add_argument("kind", choices=["nodes", "actors", "jobs", "pgs",
                                      "workers", "tasks", "objects",
                                      "dags", "events", "requests",

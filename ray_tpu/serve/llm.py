@@ -28,9 +28,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._internal.profiler import span_type
 from ray_tpu.models import llama
 from ray_tpu.parallel.mesh import build_mesh, shard_params, spec_for
 from ray_tpu.serve.multiplex import multiplexed
+
+# Host spans of the engine's units of work (`rayt.engine.*`), written
+# into the JAX profiler's trace when one is being taken of this process
+# and a flag check otherwise (_internal/profiler.py).
+_span = span_type()
 
 
 def _bucket(n: int, buckets: tuple[int, ...]) -> int:
@@ -58,6 +64,11 @@ class _Request:
     # prefill-pool side (prefill_only): deliver the finished small
     # cache as the result instead of decoding from it
     handoff_out: bool = False
+
+    @property
+    def request_id(self) -> str:
+        """The id the replica put into obs; joins a span to its record."""
+        return self.obs.get("request_id", "") if self.obs else ""
 
 
 @dataclass
@@ -148,13 +159,19 @@ class LLMEngine:
         def step(params, cache, tokens, key, temperature):
             if tokens.ndim == 1:  # decode path: device-resident [b]
                 tokens = tokens[:, None]
-            logits, cache = llama.decode_step(params, cache, tokens, cfg)
-            key, sub = jax.random.split(key)
-            greedy = jnp.argmax(logits, axis=-1)
-            sampled = jax.random.categorical(
-                sub, logits / jnp.maximum(temperature, 1e-4))
-            nxt = jnp.where(temperature[:, 0] > 0, sampled, greedy)
-            return nxt.astype(jnp.int32), cache, key
+            # the phase, known from the static shape, names every device
+            # operation of this trace in the profiler (metadata only)
+            with jax.named_scope("prefill" if tokens.shape[1] > 1
+                                 else "decode"):
+                logits, cache = llama.decode_step(params, cache, tokens,
+                                                  cfg)
+                with jax.named_scope("sample"):
+                    key, sub = jax.random.split(key)
+                    greedy = jnp.argmax(logits, axis=-1)
+                    sampled = jax.random.categorical(
+                        sub, logits / jnp.maximum(temperature, 1e-4))
+                    nxt = jnp.where(temperature[:, 0] > 0, sampled, greedy)
+                    return nxt.astype(jnp.int32), cache, key
 
         # one jit; prefill (s=bucket) and decode (s=1) are separate traces
         # of the same function, cached per shape. Donation keeps the
@@ -448,94 +465,90 @@ class LLMEngine:
             self._admit_locked(req)
 
     def _admit_locked(self, req: _Request):
-        cfg = self.cfg
         obs = req.obs
         if obs is not None and "gen_start" in obs:
-            obs["queue_s"] = time.perf_counter() - obs["gen_start"]
-        try:
-            self._ensure_decode_cache()
-        except Exception:
-            self._decode_cache = None
-            raise
+            obs["admit"] = time.perf_counter()
         slot = next(i for i, s in enumerate(self._slots) if s is None)
-        if req.prefilled is not None:
-            # disaggregated handoff: the prefill pool already produced
-            # these KV rows — graft them and go straight to decode
-            self._admit_prefilled_locked(req, slot)
-            return
+        bucket = (int(req.prefilled["bucket"]) if req.prefilled is not None
+                  else _bucket(len(req.tokens), self.prompt_buckets))
+        with _span("rayt.engine.admit", request_id=req.request_id,
+                   prompt_len=len(req.tokens), bucket=bucket, slot=slot):
+            try:
+                self._ensure_decode_cache()
+            except Exception:
+                self._decode_cache = None
+                raise
+            if req.prefilled is not None:
+                # disaggregated handoff: the prefill pool already produced
+                # these KV rows — graft them and go straight to decode
+                self._admit_prefilled_locked(req, slot)
+                return
+            self._admit_prompt_locked(req, slot, bucket)
+
+    def _admit_prompt_locked(self, req: _Request, slot: int, bucket: int):
+        obs = req.obs
         toks = req.tokens  # generate() enforces len <= max bucket
-        bucket = _bucket(len(toks), self.prompt_buckets)
         start = bucket - len(toks)
         prompts = np.zeros((1, bucket), np.int32)
         prompts[0, start:] = toks
 
-        small = llama.init_kv_cache(cfg, 1, max_len=bucket)
+        small = llama.init_kv_cache(self.cfg, 1, max_len=bucket)
         small["start"] = jnp.asarray([start], jnp.int32)
         small = jax.device_put(small, self._cache_sharding)
         entry, matched = self._prefix_lookup(toks)
+        pos = 0
         if matched:
             # prefix hit: graft the stored rows at this prompt's start
             # offset (KV content is start-RELATIVE — models/llama.py
             # rope positions — so rows are reusable across layouts) and
             # resume the prefill at the first un-cached token
-            pos0 = start + matched
-            small = self._graft_prefix(small, entry, pos0 - matched,
-                                       matched)
+            pos = start + matched
+            small = self._graft_prefix(small, entry, start, matched)
             self.prefix_hits += 1
             self.prefix_hit_tokens += matched
             if obs is not None:
                 obs["prefix_cache"] = "hit"
                 obs["prefix_hit_tokens"] = matched
-            if self.prefill_chunk and \
-                    bucket - pos0 > self.prefill_chunk:
-                self._slots[slot] = _Slot(req, emitted=-1, length=0)
-                self._pending_prefills.append(_PendingPrefill(
-                    req=req, slot=slot, prompts=prompts, small=small,
-                    bucket=bucket, pos=pos0))
-                return
-            temps1 = jnp.asarray([[req.temperature]], np.float32)
-            t_pf = time.perf_counter()
-            nxt, small, self._key = self._step(
-                self.params, small, jnp.asarray(prompts[:, pos0:]),
-                self._key, temps1)
-            self.prefills += 1
-            if obs is not None:
-                obs["prefill_s"] = obs.get("prefill_s", 0.0) + (
-                    time.perf_counter() - t_pf)
-                obs["prefill_chunks"] = obs.get("prefill_chunks", 0) + 1
-            self._finish_prefill(req, slot, small,
-                                 int(np.asarray(nxt)[0]), bucket, start)
-            return
-        if (self.prefix_cache_entries and self._prefix_block
+        elif (self.prefix_cache_entries and self._prefix_block
                 and len(toks) > self._prefix_block):
             self.prefix_misses += 1
             if obs is not None:
                 obs["prefix_cache"] = "cold"
-        if self.prefill_chunk and bucket > self.prefill_chunk:
+        if self.prefill_chunk and bucket - pos > self.prefill_chunk:
             # long prompt: reserve the slot, prefill chunk-by-chunk
             # between decode steps (engine loop drives _advance_prefill).
             # Left-pad chunks are skipped entirely: they carry no
             # information (masked by `start`), so begin at the last
             # chunk boundary before the first real token.
-            skip = (start // self.prefill_chunk) * self.prefill_chunk
-            if skip:
-                small["length"] = jnp.int32(skip)
+            if not matched:
+                pos = (start // self.prefill_chunk) * self.prefill_chunk
+                if pos:
+                    small["length"] = jnp.int32(pos)
             self._slots[slot] = _Slot(req, emitted=-1, length=0)
             self._pending_prefills.append(_PendingPrefill(
                 req=req, slot=slot, prompts=prompts, small=small,
-                bucket=bucket, pos=skip))
+                bucket=bucket, pos=pos))
             return
-        temps1 = jnp.asarray([[req.temperature]], np.float32)
-        t_pf = time.perf_counter()
-        nxt, small, self._key = self._step(
-            self.params, small, jnp.asarray(prompts), self._key, temps1)
+        nxt, small = self._prefill_dispatch(req, small, prompts, pos,
+                                            bucket - pos)
         self.prefills += 1
-        if obs is not None:
-            obs["prefill_s"] = obs.get("prefill_s", 0.0) + (
-                time.perf_counter() - t_pf)
-            obs["prefill_chunks"] = obs.get("prefill_chunks", 0) + 1
-        self._finish_prefill(req, slot, small, int(np.asarray(nxt)[0]),
-                             bucket, start)
+        self._finish_prefill(req, slot, small, nxt, bucket, start)
+
+    def _prefill_dispatch(self, req: _Request, small, prompts, pos: int,
+                          chunk: int):
+        """Launch one prefill call over prompts[:, pos:pos + chunk] (the
+        whole rest of a short prompt, or one chunk of a long one). The
+        call is asynchronous: its device time is scope `prefill` in a
+        profiler trace, not this span. Returns (sampled token, cache)."""
+        with _span("rayt.engine.prefill_chunk", request_id=req.request_id,
+                   pos=pos, chunk=chunk,
+                   last=int(pos + chunk >= prompts.shape[1])):
+            nxt, small, self._key = self._step(
+                self.params, small, jnp.asarray(prompts[:, pos:pos + chunk]),
+                self._key, jnp.asarray([[req.temperature]], np.float32))
+        if req.obs is not None:
+            req.obs["prefill_chunks"] = req.obs.get("prefill_chunks", 0) + 1
+        return nxt, small
 
     # ----------------------------------------------- prefix KV reuse
     def _prefix_lookup(self, toks: list) -> tuple[Optional[dict], int]:
@@ -611,25 +624,17 @@ class LLMEngine:
             pf = self._pending_prefills[0]
             try:
                 chunk = min(self.prefill_chunk, pf.bucket - pf.pos)
-                tokens = jnp.asarray(pf.prompts[:, pf.pos:pf.pos + chunk])
-                temps1 = jnp.asarray([[pf.req.temperature]], np.float32)
-                t_pf = time.perf_counter()
-                nxt, pf.small, self._key = self._step(
-                    self.params, pf.small, tokens, self._key, temps1)
+                nxt, pf.small = self._prefill_dispatch(
+                    pf.req, pf.small, pf.prompts, pf.pos, chunk)
                 pf.pos += chunk
                 self.prefill_chunks += 1
-                obs = pf.req.obs
-                if obs is not None:
-                    obs["prefill_s"] = obs.get("prefill_s", 0.0) + (
-                        time.perf_counter() - t_pf)
-                    obs["prefill_chunks"] = obs.get("prefill_chunks", 0) + 1
                 if pf.pos < pf.bucket:
                     return
                 self._pending_prefills.pop(0)
                 self.prefills += 1
                 self._slots[pf.slot] = None  # release the reservation
                 self._finish_prefill(
-                    pf.req, pf.slot, pf.small, int(np.asarray(nxt)[0]),
+                    pf.req, pf.slot, pf.small, nxt,
                     pf.bucket, pf.bucket - len(pf.req.tokens))
             except BaseException as e:
                 # a failed chunk step donated pf.small's buffers, and a
@@ -648,51 +653,57 @@ class LLMEngine:
                     else RuntimeError(repr(e)))
                 raise
 
-    def _finish_prefill(self, req: _Request, slot: int, small, first: int,
+    def _finish_prefill(self, req: _Request, slot: int, small, first,
                         bucket: int, start: int, store: bool = True):
         """Deliver the prefill's sampled token and graft the KV rows
-        into the slot (callers hold _mutex)."""
-        if store:
-            # park the rows for prefix reuse BEFORE any donation can
-            # touch them (insert_row leaves small's arrays alive; the
-            # store holds its own refs)
-            self._prefix_put(req.tokens, small, bucket)
-        if req.handoff_out:
-            # prefill-pool side of a disaggregated deployment: the
-            # result IS the KV handoff payload — the decode pool grafts
-            # it via generate_prefilled. No slot, no insert, no decode.
-            req.loop.call_soon_threadsafe(
-                req.out.put_nowait,
-                {"k": small["k"], "v": small["v"], "first": int(first),
-                 "bucket": int(bucket), "start": int(start)})
-            req.loop.call_soon_threadsafe(req.out.put_nowait, None)
-            return
-        if self.eos_token_id is not None and first == self.eos_token_id:
-            req.loop.call_soon_threadsafe(req.out.put_nowait, None)
-            return
-        self.generated_tokens += 1
-        if req.obs is not None:
-            now = time.perf_counter()
-            req.obs["first_token"] = now
-            req.obs["last_token"] = now
-            req.obs["tokens"] = req.obs.get("tokens", 0) + 1
-        req.loop.call_soon_threadsafe(req.out.put_nowait, first)
-        if req.max_new_tokens <= 1:
-            req.loop.call_soon_threadsafe(req.out.put_nowait, None)
-            return
-        try:
-            self._decode_cache = self._insert_row(
-                self._decode_cache, small["k"], small["v"],
-                jnp.int32(slot), jnp.int32(bucket), jnp.int32(start))
-        except BaseException:
-            # insert_row donates the shared cache: a failure here loses
-            # every active slot's KV, not just the new request's
-            self._poison_recover()
-            raise
-        self._slots[slot] = _Slot(req, emitted=1, length=bucket)
-        self._cur, self._temps = self._set_slot(
-            self._cur, self._temps, jnp.int32(slot), jnp.int32(first),
-            jnp.float32(req.temperature))
+        into the slot (callers hold _mutex). `first` is the step's
+        sampled-token array, read here (the host waits for the prefill),
+        or the int a prefill pool already read."""
+        with _span("rayt.engine.finish_prefill", request_id=req.request_id,
+                   slot=slot):
+            if not isinstance(first, int):
+                first = int(np.asarray(first)[0])
+            if store:
+                # park the rows for prefix reuse BEFORE any donation can
+                # touch them (insert_row leaves small's arrays alive; the
+                # store holds its own refs)
+                self._prefix_put(req.tokens, small, bucket)
+            if req.handoff_out:
+                # prefill-pool side of a disaggregated deployment: the
+                # result IS the KV handoff payload — the decode pool grafts
+                # it via generate_prefilled. No slot, no insert, no decode.
+                req.loop.call_soon_threadsafe(
+                    req.out.put_nowait,
+                    {"k": small["k"], "v": small["v"], "first": int(first),
+                     "bucket": int(bucket), "start": int(start)})
+                req.loop.call_soon_threadsafe(req.out.put_nowait, None)
+                return
+            if self.eos_token_id is not None and first == self.eos_token_id:
+                req.loop.call_soon_threadsafe(req.out.put_nowait, None)
+                return
+            self.generated_tokens += 1
+            if req.obs is not None:
+                now = time.perf_counter()
+                req.obs["first_token"] = now
+                req.obs["last_token"] = now
+                req.obs["tokens"] = req.obs.get("tokens", 0) + 1
+            req.loop.call_soon_threadsafe(req.out.put_nowait, first)
+            if req.max_new_tokens <= 1:
+                req.loop.call_soon_threadsafe(req.out.put_nowait, None)
+                return
+            try:
+                self._decode_cache = self._insert_row(
+                    self._decode_cache, small["k"], small["v"],
+                    jnp.int32(slot), jnp.int32(bucket), jnp.int32(start))
+            except BaseException:
+                # insert_row donates the shared cache: a failure here loses
+                # every active slot's KV, not just the new request's
+                self._poison_recover()
+                raise
+            self._slots[slot] = _Slot(req, emitted=1, length=bucket)
+            self._cur, self._temps = self._set_slot(
+                self._cur, self._temps, jnp.int32(slot), jnp.int32(first),
+                jnp.float32(req.temperature))
 
     def _reseed_key(self):
         """Rebuild the PRNG key after a failed (donating) step consumed
@@ -729,42 +740,55 @@ class LLMEngine:
     def _decode_step_locked(self):
         """One decode step across all slots (free rows compute masked
         garbage — the price of a single static-shape trace)."""
+        active = sum(1 for s in self._slots
+                     if s is not None and s.emitted >= 0)
         try:
-            nxt, self._decode_cache, self._key = self._step(
-                self.params, self._decode_cache, self._cur,
-                self._key, self._temps)
+            # t_host is this process's perf_counter read as the span
+            # opens: the one event that carries both clocks, so request
+            # records and a client's stamps (CLOCK_MONOTONIC, one clock
+            # for the host) can be laid on the profiler's time axis
+            with _span("rayt.engine.decode_dispatch", active=active,
+                       t_host=time.perf_counter()):
+                nxt, self._decode_cache, self._key = self._step(
+                    self.params, self._decode_cache, self._cur,
+                    self._key, self._temps)
         except BaseException:
             self._poison_recover()
             raise
-        toks = np.asarray(nxt)  # host sync: this step's sampled tokens
+        with _span("rayt.engine.token_sync", active=active):
+            toks = np.asarray(nxt)  # host sync: this step's sampled tokens
         self._cur = nxt  # stays on device for the next step
         self.batches += 1
         # occupancy of THIS step, stamped into each participant's obs:
         # mean over a request's steps = how full its decode batches ran
-        active = sum(1 for s in self._slots
-                     if s is not None and s.emitted >= 0)
         occupancy = active / self.max_batch
-        now = time.perf_counter()
-        for i, s in enumerate(self._slots):
-            if s is None or s.emitted < 0:  # free or mid-prefill
-                continue
-            t = int(toks[i])
-            s.length += 1
-            if self.eos_token_id is not None and t == self.eos_token_id:
-                self._finish(i)
-                continue
-            s.emitted += 1
-            self.generated_tokens += 1
-            if s.req.obs is not None:
-                o = s.req.obs
-                o["tokens"] = o.get("tokens", 0) + 1
-                o["decode_steps"] = o.get("decode_steps", 0) + 1
-                o["occupancy_sum"] = o.get("occupancy_sum", 0.0) + occupancy
-                o["last_token"] = now
-            s.req.loop.call_soon_threadsafe(s.req.out.put_nowait, t)
-            if (s.emitted >= s.req.max_new_tokens
-                    or s.length >= self.cfg.max_seq_len - 1):
-                self._finish(i)
+        with _span("rayt.engine.emit", active=active) as span:
+            now = time.perf_counter()
+            finished = 0
+            for i, s in enumerate(self._slots):
+                if s is None or s.emitted < 0:  # free or mid-prefill
+                    continue
+                t = int(toks[i])
+                s.length += 1
+                if self.eos_token_id is not None and t == self.eos_token_id:
+                    self._finish(i)
+                    finished += 1
+                    continue
+                s.emitted += 1
+                self.generated_tokens += 1
+                if s.req.obs is not None:
+                    o = s.req.obs
+                    o["tokens"] = o.get("tokens", 0) + 1
+                    o["decode_steps"] = o.get("decode_steps", 0) + 1
+                    o["occupancy_sum"] = (o.get("occupancy_sum", 0.0)
+                                          + occupancy)
+                    o["last_token"] = now
+                s.req.loop.call_soon_threadsafe(s.req.out.put_nowait, t)
+                if (s.emitted >= s.req.max_new_tokens
+                        or s.length >= self.cfg.max_seq_len - 1):
+                    self._finish(i)
+                    finished += 1
+            span.set_metadata(finished=finished)
 
     def stats(self) -> dict:
         return {"generated_tokens": self.generated_tokens,

@@ -169,23 +169,41 @@ def _reset_request_obs(token):
 def engine_section(obs: Optional[dict]) -> Optional[dict]:
     """Fold an engine-stamped observation dict into the record's
     ``engine`` section (replica side, after the handler returns).
-    Returns None when the engine never touched the request."""
+    Returns None when the engine never touched the request.
+
+    The engine stamps four instants on this process's
+    ``time.perf_counter()``; they are kept as ``t_enqueue`` (generate()
+    saw the request), ``t_admit`` (a slot was free and admission began),
+    ``t_first`` (the first token was read on the host) and ``t_last``.
+    The three durations tile the engine's share of the request:
+    ``queue_s + prefill_s + decode_s == t_last - t_enqueue``.
+    ``prefill_s`` is admission to first token: everything the engine
+    did for the prompt, the decode steps of other requests that ran
+    between its chunks included. The prefill's device time is scope
+    ``prefill`` in a profiler trace, onto whose time axis the
+    ``t_host`` field of a ``rayt.engine.decode_dispatch`` span places
+    these stamps."""
     if not obs or "gen_start" not in obs:
         return None
+    enqueue, admit = obs["gen_start"], obs.get("admit")
     first = obs.get("first_token")
     last = obs.get("last_token", first)
     tokens = int(obs.get("tokens", 0))
     out = {
-        "queue_s": obs.get("queue_s"),
-        "prefill_s": obs.get("prefill_s"),
+        "t_enqueue": enqueue, "t_admit": admit, "t_first": first,
+        "t_last": last,
+        "queue_s": None if admit is None else admit - enqueue,
+        "prefill_s": None,
         "prefill_chunks": int(obs.get("prefill_chunks", 0)),
         "tokens": tokens,
         "decode_steps": int(obs.get("decode_steps", 0)),
     }
     if first is not None:
-        out["ttft_s"] = first - obs["gen_start"]
-        if last is not None and last > first and tokens > 1:
-            out["decode_s"] = last - first
+        out["ttft_s"] = first - enqueue
+        if admit is not None:
+            out["prefill_s"] = first - admit
+        out["decode_s"] = last - first
+        if last > first and tokens > 1:
             out["tpot_s"] = (last - first) / (tokens - 1)
     steps = out["decode_steps"]
     if steps:
@@ -199,6 +217,12 @@ def engine_section(obs: Optional[dict]) -> Optional[dict]:
         # structural zeros (decode counters on the prefill record,
         # chunk counts on the decode record) would clobber the other
         # half's real values at GCS coalesce time — merge order is
-        # flush-cadence luck, so ship only the phases this pool ran
-        out = {k: v for k, v in out.items() if v not in (None, 0)}
+        # flush-cadence luck, so ship only the phases this pool ran.
+        # The stamps are one engine's and the record holds two: the
+        # decode pool's graft is no prefill, and neither side's
+        # instants tile the pair's time.
+        if obs["pool"] == "decode":
+            out["prefill_s"] = None
+        out = {k: v for k, v in out.items()
+               if v not in (None, 0) and not k.startswith("t_")}
     return out

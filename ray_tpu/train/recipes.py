@@ -202,9 +202,11 @@ def lora_finetune_loop(config: dict):
     import jax.numpy as jnp
 
     from ray_tpu import train
+    from ray_tpu._internal.profiler import span_type
     from ray_tpu.parallel.spmd import shard_batch
     from ray_tpu.train.checkpoint import Checkpoint, save_pytree
 
+    report_span = span_type()
     ctx = train.get_context()
     mesh = ctx.get_mesh()
     step, state, cfg = build_lora_step(config, mesh)
@@ -274,7 +276,10 @@ def lora_finetune_loop(config: dict):
             last_loss = float(aux["loss"])
             if first_loss is None:
                 first_loss = last_loss
-            with tempfile.TemporaryDirectory() as d:
+            # one host span over everything a report blocks the loop for:
+            # the adapter and moment copies to the host, then the report
+            with report_span("rayt.train.report", step=i + 1), \
+                    tempfile.TemporaryDirectory() as d:
                 # adapters-only checkpoint: the LoRA artifact is the
                 # deliverable (base stays wherever it was loaded from);
                 # optimizer moments ride along so restarts resume the

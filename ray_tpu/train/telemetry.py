@@ -24,6 +24,11 @@ The same flush cycle carries two sidecars:
   the recorder falls back to process RSS so the
   ``rayt_device_memory_*`` gauges stay live on the host mesh).
 
+Each phase is also a host span ``rayt.train.<phase>`` in the JAX
+profiler's trace when one is being taken of this worker
+(``_internal/profiler.span_type``; a flag check otherwise), so the
+waterfall's stages sit on the device operations' clock.
+
 XLA compile accounting rides :meth:`wrap_jit`: the first call per
 argument-shape signature is timed as the compile (first-trace) event;
 a NEW signature after the first is a retrace, published with the shape
@@ -39,10 +44,13 @@ import uuid
 import weakref
 from typing import Optional
 
+from ray_tpu._internal.profiler import span_type
 from ray_tpu.core.gcs_train_manager import CH_TRAIN
 
 # phase name -> waterfall stage key (manager TRAIN_STAGES order)
 _PHASES = ("data_wait", "h2d", "step", "ckpt_block")
+# a phase is also a host span of this name in a profiler trace
+_SPAN_PREFIX = "rayt.train."
 # device-memory snapshot cadence (rides the flush cycle, rate-limited)
 _MEMORY_INTERVAL_S = 1.0
 
@@ -232,7 +240,8 @@ class StepRecorder:
         self.rank = rank
         self.node_id = node_id
         self._pub = _TrainPublisher(owner=self)
-        self._phase: Optional[tuple] = None  # (name, t0, step)
+        self._phase: Optional[tuple] = None  # (name, t0, step, span)
+        self._span_type = span_type()
         self._acc = dict.fromkeys(_PHASES, 0.0)
         self._step = 0
         self._last_step_end: Optional[float] = None
@@ -245,7 +254,9 @@ class StepRecorder:
         return _PhaseCtx(self, name)
 
     def begin_phase(self, name: str):
-        self._phase = (name, time.perf_counter(), self._step)
+        span = self._span_type(_SPAN_PREFIX + name, step=self._step)
+        span.__enter__()
+        self._phase = (name, time.perf_counter(), self._step, span)
         if name in ("data_wait", "ckpt_block"):
             # the block-prone phases arm the heartbeat chain; compute
             # phases ride the chain steps already keep alive
@@ -256,9 +267,10 @@ class StepRecorder:
         if ph is None:
             return
         self._phase = None
-        name, t0, _ = ph
+        name, t0, _, span = ph
         if name in self._acc:
             self._acc[name] += time.perf_counter() - t0
+        span.__exit__(None, None, None)
 
     def add_stage(self, name: str, seconds: float):
         """Fold an externally-measured duration into the current step's
@@ -337,7 +349,7 @@ class StepRecorder:
         ph = self._phase
         if ph is not None and not self._closed:
             keep = True
-            name, t0, step = ph
+            name, t0, step, _ = ph
             blocked = time.perf_counter() - t0
             if blocked >= _stall_grace_s():
                 recs.append({"kind": "phase", "run_id": self.run_id,
@@ -366,7 +378,7 @@ class StepRecorder:
         """Worker teardown: stop sidecars and drain the buffer
         synchronously so the run's final records survive the actor."""
         self._closed = True
-        self._phase = None
+        self.end_phase()
         self._pub.flush_now()
 
 
@@ -416,10 +428,15 @@ def device_memory_snapshot() -> list[dict]:
             if not ms:
                 continue
             used = int(ms.get("bytes_in_use") or 0)
+            # on this runtime the allocator's reserved pool is not in
+            # `peak_bytes_in_use`: the peak a program needed is the sum
+            # (what the benchmark's *_peak_hbm_gb reports)
+            peak = (int(ms.get("peak_bytes_in_use") or used)
+                    + int(ms.get("peak_bytes_reserved") or 0))
             devices.append({
                 "device": f"{d.platform}:{d.id}",
                 "bytes_in_use": used,
-                "peak_bytes": int(ms.get("peak_bytes_in_use") or used)})
+                "peak_bytes": peak})
     except Exception:
         pass
     if devices:

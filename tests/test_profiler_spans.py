@@ -1,0 +1,283 @@
+"""The program's own names in a JAX profiler trace: `rayt.*` host spans
+of the serve engine and the train StepRecorder, named scopes in the
+device programs, and the request record's stamps. All on the CPU, and
+nothing here times anything: what is checked is that the names and
+fields are written, and nest as the readers (benchmarks/trace_spans.py)
+expect."""
+
+import asyncio
+import os
+import re
+import threading
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmarks import trace_spans
+from ray_tpu._internal import spawn
+from ray_tpu._internal.profiler import span_type
+from ray_tpu.models import llama
+from ray_tpu.serve import request_context
+from ray_tpu.serve.llm import LLMEngine
+from ray_tpu.train import telemetry
+
+ENGINE_SPANS = ("admit", "prefill_chunk", "finish_prefill",
+                "decode_dispatch", "token_sync", "emit")
+
+
+def _host_spans(trace_dir) -> list:
+    """[(line, name, start, end, stats)] of every rayt.* span written."""
+    path = trace_spans.newest_xplane(str(trace_dir))
+    assert path, "the profiler wrote no .xplane.pb"
+    return [(i, ev[0], ev[1], ev[1] + ev[2], ev[3])
+            for p in trace_spans.events_from_xplane(path)["planes"]
+            for i, ln in enumerate(p["lines"]) for ev in ln["events"]]
+
+
+def _trace_options():
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
+
+
+def test_helper_is_the_profilers_own_span_and_nothing_else(tmp_path):
+    """(e) no span store of ours: the helper is TraceAnnotation itself,
+    and opening spans with no session leaves no thread and no file."""
+    assert span_type() is jax.profiler.TraceAnnotation
+    os.chdir(tmp_path)
+    threads = threading.active_count()
+    for i in range(100):
+        with span_type()("rayt.engine.emit", active=i) as span:
+            span.set_metadata(finished=0)
+    assert threading.active_count() == threads
+    assert os.listdir(tmp_path) == []
+    rec = telemetry.StepRecorder("run", "exp")
+    with rec.phase("step"):
+        pass
+    assert os.listdir(tmp_path) == []
+
+
+def test_engine_writes_every_span_into_the_profilers_trace(tmp_path):
+    """(a) a debug engine run under a profiler session: a short prompt
+    (one-shot prefill inside admit) and a chunked one (prefill_chunk
+    from the engine loop), a few decode steps each."""
+    eng = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=256,
+                    prompt_buckets=(16, 64), prefill_chunk=16,
+                    prefix_cache_entries=0)
+
+    async def one(rid, tokens):
+        token = request_context._set_request_obs({"request_id": rid})
+        try:
+            return [t async for t in eng.generate(tokens, max_new_tokens=4)]
+        finally:
+            request_context._reset_request_obs(token)
+
+    async def run():
+        return await asyncio.gather(one("short", [5, 9, 11]),
+                                    one("long", list(range(1, 41))))
+
+    asyncio.run(run())          # compile outside the session
+    jax.profiler.start_trace(str(tmp_path), profiler_options=_trace_options())
+    try:
+        outs = asyncio.run(run())
+    finally:
+        jax.profiler.stop_trace()
+    assert [len(o) for o in outs] == [4, 4]
+
+    spans = _host_spans(tmp_path)
+    by_name: dict = {}
+    for line, name, start, end, stats in spans:
+        by_name.setdefault(name, []).append((line, start, end, stats))
+    for short in ENGINE_SPANS:
+        assert "rayt.engine." + short in by_name, (short, sorted(by_name))
+    for short in ("admit", "prefill_chunk", "finish_prefill"):
+        ids = {s[3]["request_id"] for s in by_name["rayt.engine." + short]}
+        assert ids == {"short", "long"}, (short, ids)
+    admit = {s[3]["request_id"]: s[3] for s in by_name["rayt.engine.admit"]}
+    assert admit["short"]["prompt_len"] == 3
+    assert admit["short"]["bucket"] == 16
+    assert admit["long"]["prompt_len"] == 40 and admit["long"]["bucket"] == 64
+    chunks = [s[3] for s in by_name["rayt.engine.prefill_chunk"]
+              if s[3]["request_id"] == "long"]
+    # 24 pad slots: the first 16-token chunk is skipped, three are run
+    assert [(c["pos"], c["chunk"], c["last"]) for c in chunks] == \
+        [(16, 16, 0), (32, 16, 0), (48, 16, 1)]
+    for s in by_name["rayt.engine.decode_dispatch"]:
+        assert isinstance(s[3]["t_host"], float) and s[3]["active"] >= 1
+    assert {s[3]["finished"] for s in by_name["rayt.engine.emit"]} >= {0, 1}
+    # token_sync is a unit of its own: inside no other engine span
+    for line, start, end, _ in by_name["rayt.engine.token_sync"]:
+        around = [n for ln, n, s, e, _ in spans
+                  if ln == line and n != "rayt.engine.token_sync"
+                  and s <= start and end <= e]
+        assert around == [], around
+    # a one-shot prefill and its finish are parts of the admission
+    a_line, a_start, a_end, _ = next(
+        s for s in by_name["rayt.engine.admit"]
+        if s[3]["request_id"] == "short")
+    inner = [n for ln, n, s, e, st in spans if ln == a_line
+             and a_start <= s and e <= a_end
+             and st.get("request_id") == "short"]
+    assert set(inner) == {"rayt.engine.admit", "rayt.engine.prefill_chunk",
+                          "rayt.engine.finish_prefill"}
+
+
+def test_step_recorder_phases_and_report_are_spans(tmp_path):
+    rec = telemetry.StepRecorder("run", "exp")
+    jax.profiler.start_trace(str(tmp_path), profiler_options=_trace_options())
+    try:
+        for step in range(3):
+            for phase in telemetry._PHASES:
+                with rec.phase(phase):
+                    pass
+            with span_type()("rayt.train.report", step=step + 1):
+                pass
+            rec.end_step()
+    finally:
+        jax.profiler.stop_trace()
+    seen: dict = {}
+    for _, name, _, _, stats in _host_spans(tmp_path):
+        seen.setdefault(name, []).append(stats["step"])
+    assert seen == {
+        **{"rayt.train." + p: [0, 1, 2] for p in telemetry._PHASES},
+        "rayt.train.report": [1, 2, 3]}
+
+
+def _scope_paths(lowered) -> set:
+    """Every name path of the lowered module, without its last component
+    (the primitive), jax's transformation wrappers taken off."""
+    text = lowered.as_text(debug_info=True)
+    paths = set()
+    for m in re.finditer(r'loc\("([^"]+)"', text):
+        parts = [trace_spans._core(c) for c in m.group(1).split("/")[:-1]]
+        paths.add("/".join(parts))
+    return paths
+
+
+@pytest.mark.parametrize("phase,tokens", [("decode", None),
+                                          ("prefill", (1, 16))])
+def test_engine_step_names_its_phase_and_parts(phase, tokens):
+    """(b) lowered, not compiled: the engine's step for a decode and for
+    a prefill shape carries the phase and every part of the block."""
+    eng = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=64,
+                    prompt_buckets=(16,), prefill_chunk=0)
+    eng._ensure_decode_cache()
+    if tokens is None:
+        cache, toks, temps = eng._decode_cache, eng._cur, eng._temps
+    else:
+        cache = llama.init_kv_cache(eng.cfg, 1, max_len=16)
+        toks = jnp.zeros(tokens, jnp.int32)
+        temps = jnp.zeros((1, 1), jnp.float32)
+    paths = _scope_paths(eng._step_jit.lower(
+        eng.params, cache, toks, eng._key, temps))
+    other = "prefill" if phase == "decode" else "decode"
+    assert not [p for p in paths if other in p.split("/")]
+    for part in ("embed", "lm_head", "sample"):
+        assert any(p.endswith(f"{phase}/{part}") or f"{phase}/{part}/" in p
+                   for p in paths), part
+    # the block is a scan body, lowered as a function of its own: its
+    # paths are relative there and whole in the compiled module
+    for part in ("attn_qkv", "kv_update", "attn", "attn_out", "mlp"):
+        assert any(part in p.split("/") for p in paths), part
+    assert trace_spans.scope_of(
+        f"jit(step)/{phase}/while/body/closed_call/attn/mul") == \
+        (phase, "attn", False)
+
+
+def test_lora_train_step_names_loss_optimizer_and_kernels():
+    """(b) the LoRA step with the flash kernels (interpret mode here) and
+    full remat: loss, optimizer, the parts, the three kernels, the
+    adapters' branch; jax itself marks the recomputed forward."""
+    from ray_tpu.parallel.mesh import build_mesh
+    from ray_tpu.train.recipes import build_lora_step
+
+    mesh = build_mesh({"data": 1, "fsdp": 1, "tensor": 1}, jax.devices()[:1])
+    step, state, _ = build_lora_step({
+        "preset": "debug", "lora_rank": 4, "model_overrides": {
+            "attn_impl": "flash", "remat_policy": "nothing",
+            "max_seq_len": 128, "attn_block_q": 64, "attn_block_k": 64}},
+        mesh)
+    batch = {"tokens": jnp.zeros((2, 128), jnp.int32),
+             "targets": jnp.zeros((2, 128), jnp.int32)}
+    paths = _scope_paths(step.lower(state, batch))
+    for name in ("loss", "optimizer", "embed", "ce", "attn_qkv", "attn",
+                 "attn_out", "mlp", "lora", "flash_fwd", "flash_bwd_dq",
+                 "flash_bwd_dkv", trace_spans.RECOMPUTE):
+        assert any(name in p.split("/") for p in paths), name
+    assert trace_spans.scope_of(
+        "jit(one_step)/loss/transpose(jvp())/while/body/closed_call/"
+        "checkpoint/rematted_computation/attn_qkv/lora/dot_general") == \
+        ("loss", "lora", True)
+
+
+def test_engine_section_tiles_the_request_and_keeps_the_stamps():
+    """(d) queue + prefill + decode is the engine's whole share."""
+    obs = {"request_id": "r", "gen_start": 10.0, "admit": 10.25,
+           "first_token": 11.0, "last_token": 13.5, "tokens": 6,
+           "decode_steps": 5, "occupancy_sum": 2.5, "prefill_chunks": 3}
+    eng = request_context.engine_section(obs)
+    assert (eng["t_enqueue"], eng["t_admit"], eng["t_first"],
+            eng["t_last"]) == (10.0, 10.25, 11.0, 13.5)
+    assert eng["queue_s"] + eng["prefill_s"] + eng["decode_s"] == \
+        eng["t_last"] - eng["t_enqueue"]
+    assert eng["prefill_s"] == 0.75 and eng["ttft_s"] == 1.0
+    assert eng["tpot_s"] == 0.5 and eng["prefill_chunks"] == 3
+    assert "request_id" not in eng
+    # not yet admitted, and never seen by the engine
+    queued = request_context.engine_section({"gen_start": 1.0})
+    assert queued["queue_s"] is None and queued["prefill_s"] is None
+    assert request_context.engine_section({"request_id": "r"}) is None
+    # a disaggregated pair's halves ship durations only, and the decode
+    # pool's graft is no prefill
+    half = request_context.engine_section({**obs, "pool": "decode"})
+    assert "prefill_s" not in half and half["decode_s"] == 2.5
+    assert not [k for k in half if k.startswith("t_")]
+
+
+def test_records_of_a_real_engine_run_carry_the_stamps():
+    eng = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=128,
+                    prompt_buckets=(16, 64), prefill_chunk=16)
+    obs = {"request_id": "abc"}
+
+    async def run():
+        token = request_context._set_request_obs(obs)
+        try:
+            return [t async for t in eng.generate(list(range(1, 41)),
+                                                  max_new_tokens=3)]
+        finally:
+            request_context._reset_request_obs(token)
+
+    assert len(asyncio.run(run())) == 3
+    sec = request_context.engine_section(obs)
+    assert sec["t_enqueue"] <= sec["t_admit"] <= sec["t_first"] <= \
+        sec["t_last"]
+    assert sec["prefill_chunks"] == 3 and sec["tokens"] == 3
+    assert abs(sec["queue_s"] + sec["prefill_s"] + sec["decode_s"]
+               - (sec["t_last"] - sec["t_enqueue"])) < 1e-9
+
+
+def test_memory_gauge_adds_the_reserved_peak(monkeypatch):
+    """The rayt_device_memory_* gauges' peak is what *_peak_hbm_gb
+    reports: peak_bytes_in_use + peak_bytes_reserved."""
+    class Dev:
+        platform, id = "tpu", 0
+
+        def memory_stats(self):
+            return {"bytes_in_use": 5, "peak_bytes_in_use": 7,
+                    "peak_bytes_reserved": 11}
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [Dev()])
+    assert telemetry.device_memory_snapshot() == [
+        {"device": "tpu:0", "bytes_in_use": 5, "peak_bytes": 18}]
+
+
+def test_spawned_processes_key_their_compile_cache_on_metadata():
+    """A cache filled before the scopes existed must not serve their
+    programs: the names a trace shows come from the executable."""
+    env = spawn.child_env("/pkg", base={})
+    assert env[spawn.COMPILE_CACHE_METADATA_ENV] == "true"
+    assert env[spawn.COMPILE_CACHE_ENV] == "/pkg/.jax_cache"
+    kept = spawn.child_env("/pkg", base={
+        spawn.COMPILE_CACHE_METADATA_ENV: "false"})
+    assert kept[spawn.COMPILE_CACHE_METADATA_ENV] == "false"
