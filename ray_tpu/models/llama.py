@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops.attention import _repeat_kv, dot_product_attention
+from ray_tpu.ops.attention import dot_product_attention
 from ray_tpu.ops.cross_entropy import softmax_cross_entropy
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention
@@ -397,13 +397,24 @@ def loss_fn(params: dict, batch: dict, cfg: LlamaConfig):
 
 
 # ----------------------------------------------------------------- decoding
+# Axis of the cache positions in cache["k"] and cache["v"]: whoever cuts
+# or grafts a stretch of positions (serve/llm.py) reads it from here.
+KV_LEN_AXIS = {"k": 4, "v": 3}
+
+
 def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int | None = None
                   ) -> dict:
+    """An empty cache, stacked over layers, in the axis order attention
+    reads: K transposed, ``[layers, b, kv_heads, hd, len]``, and V
+    ``[layers, b, kv_heads, len, hd]``, so that per kv head the scores are
+    ``q @ K`` and the output ``probs @ V`` with both operands as they lie
+    (any other order costs a layout copy of each layer, or of the whole
+    stack, per step: PERF.md, PR 25)."""
     max_len = max_len or cfg.max_seq_len
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
+    lead = (cfg.n_layers, batch, cfg.n_kv_heads)
     return {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
+        "k": jnp.zeros(lead + (cfg.head_dim, max_len), cfg.dtype),
+        "v": jnp.zeros(lead + (max_len, cfg.head_dim), cfg.dtype),
         "length": jnp.zeros((), jnp.int32),
         # per-row first REAL slot: left-padded batched serving writes pad
         # tokens into cache slots [0, start); they are masked out and rope
@@ -413,23 +424,27 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int | None = None
 
 
 def kv_cache_logical_axes() -> dict:
-    return {"k": ("layers", "batch", None, "kv_heads", "head_dim"),
-            "v": ("layers", "batch", None, "kv_heads", "head_dim"),
+    return {"k": ("layers", "batch", "kv_heads", "head_dim", None),
+            "v": ("layers", "batch", "kv_heads", None, "head_dim"),
             "length": (), "start": ("batch",)}
 
 
-def _decode_block(cfg: LlamaConfig, x, layer, k_cache, v_cache, cos, sin,
-                  positions, cache_len, start=None, abs_positions=None):
-    """Single-step (or chunked prefill) block with KV cache.
+def _decode_block(cfg: LlamaConfig, x, layer, li, k_cache, v_cache, cos, sin,
+                  positions, cache_len, start, abs_positions):
+    """Single-step (or chunked prefill) block `li` against the KV cache.
 
-    x: [b, s, d]; k_cache/v_cache: [b, max_len, nkv, hd]. Writes new K/V at
-    [cache_len, cache_len+s) via dynamic_update_slice (static shapes).
-    `positions` are rope positions (start-relative for left-padded rows);
-    `abs_positions` are cache-slot positions used for masking; `start` [b]
-    hides the left-pad slots of each row.
+    x: [b, s, d]; k_cache/v_cache: the STACKED cache (`init_kv_cache`),
+    carried through the layer loop. The block writes its s new rows at
+    positions [cache_len[row], cache_len[row] + s) of layer `li` in place
+    and reads that layer once, for attention; nothing else of the cache
+    is read, written or copied. `positions` are rope positions
+    (start-relative for left-padded rows); `abs_positions` [b, s] are the
+    cache slots the new rows land in, used for masking; `start` [b] (or
+    None) hides the left-pad slots of each row.
     """
     b, s, d = x.shape
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
+    group = nh // nkv
     dt = cfg.dtype
     with jax.named_scope("attn_qkv"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
@@ -439,34 +454,50 @@ def _decode_block(cfg: LlamaConfig, x, layer, k_cache, v_cache, cos, sin,
         q = apply_rope(q, cos, sin, positions)
         kk = apply_rope(kk, cos, sin, positions)
     with jax.named_scope("kv_update"):
-        if jnp.ndim(cache_len) == 0:
-            # whole batch advances together (left-padded batched decode)
+        def write(k_cache, v_cache, kk, vv, row, at):
+            # kk, vv [rows, s, nkv, hd] -> the cache's orders, at position
+            # `at` of rows [row, row + rows) of layer li
             k_cache = jax.lax.dynamic_update_slice(
-                k_cache, kk, (0, cache_len, 0, 0))
+                k_cache, kk.transpose(0, 2, 3, 1)[None], (li, row, 0, 0, at))
             v_cache = jax.lax.dynamic_update_slice(
-                v_cache, vv, (0, cache_len, 0, 0))
+                v_cache, vv.transpose(0, 2, 1, 3)[None], (li, row, 0, at, 0))
+            return k_cache, v_cache
+
+        if jnp.ndim(cache_len) == 0:
+            # whole batch advances together (left-padded batched decode,
+            # batch-1 prefill): one block of the carry
+            k_cache, v_cache = write(k_cache, v_cache, kk, vv, 0, cache_len)
         else:
             # per-row write offsets (continuous-batching slots: each row
-            # is an independent request at its own depth — vLLM-style)
-            def _upd(c, new, off):
-                return jax.lax.dynamic_update_slice(c, new, (off, 0, 0))
-            k_cache = jax.vmap(_upd)(k_cache, kk, cache_len)
-            v_cache = jax.vmap(_upd)(v_cache, vv, cache_len)
+            # is an independent request at its own depth, vLLM-style).
+            # One small in-place write per row, the row cut out BEFORE it
+            # is transposed: as one scatter, as a vmap of
+            # dynamic_update_slice over the batch axis, or cut from the
+            # transposed batch, the compiler re-lays the carry out for
+            # the update's layout and copies the whole cache into and
+            # out of the loop (PERF.md, PR 25; tests/test_chip_compile.py
+            # holds the step to it).
+            for r in range(b):
+                k_cache, v_cache = write(k_cache, v_cache, kk[r:r + 1],
+                                         vv[r:r + 1], r, cache_len[r])
     with jax.named_scope("attn"):
+        # Over kv-head groups, K and V as they lie in the cache: the
+        # group's query heads are rows of one matmul per kv head, so no
+        # GQA repeat of K or V exists anywhere.
+        k_l = jax.lax.dynamic_index_in_dim(k_cache, li, 0, keepdims=False)
+        v_l = jax.lax.dynamic_index_in_dim(v_cache, li, 0, keepdims=False)
+        max_len = v_l.shape[2]
+        qg = q.reshape(b, s, nkv, group, hd).transpose(0, 2, 1, 3, 4)
+        logits = jnp.einsum("bnqgd,bndk->bnqgk", qg, k_l,
+                            preferred_element_type=jnp.float32) * (hd ** -0.5)
         # mask: key slot j visible iff start <= j <= query slot
-        max_len = k_cache.shape[1]
-        q_pos = positions if abs_positions is None else abs_positions
         k_pos = jnp.arange(max_len)[None, :]
-        mask = k_pos[:, None, :] <= q_pos[..., None]      # [b, s, max_len]
+        mask = k_pos[:, None, :] <= abs_positions[..., None]  # [b, s, max_len]
         if start is not None:
             mask = mask & (k_pos[:, None, :] >= start[:, None, None])
-        kr = _repeat_kv(k_cache, nh // nkv)
-        vr = _repeat_kv(v_cache, nh // nkv)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
-                            preferred_element_type=jnp.float32) * (hd ** -0.5)
-        logits = jnp.where(mask[:, None], logits, -1e30)
+        logits = jnp.where(mask[:, None, :, None], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(dt)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vr).reshape(
+        attn = jnp.einsum("bnqgk,bnkd->bqngd", probs, v_l).reshape(
             b, s, nh * hd)
     with jax.named_scope("attn_out"):
         x = x + _proj(cfg, layer, "wo", attn)
@@ -487,7 +518,12 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array,
     cache["length"] may be a scalar (whole batch in lock-step, the
     left-padded batched path) or shape [b] (per-row depths: the
     continuous-batching slot path, where each row is an independent
-    request and writes at its own cache offset)."""
+    request and writes at its own cache offset).
+
+    The stacked cache rides the layer loop as carried state: per step
+    every cache byte is read at most once, by attention, and only the new
+    rows are written. With the cache donated (as the engine does) the
+    writes land in the caller's buffers and no copy of a stack is made."""
     b, s = tokens.shape
     dt = cfg.dtype
     cache_len = cache["length"]
@@ -504,22 +540,23 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-    scanned_layers = params["layers"]
+    layers = params["layers"]
     if "lora" in params:
         # serve-time adapters: stacked on the same [n_layers] axis, they
-        # ride the decode scan exactly like the training path's (the
-        # _proj low-rank branch fires per layer; models/lora.py)
-        scanned_layers = {**scanned_layers, **params["lora"]["layers"]}
+        # are taken per layer exactly like the base weights (the _proj
+        # low-rank branch fires per layer; models/lora.py)
+        layers = {**layers, **params["lora"]["layers"]}
 
-    def step(x, inputs):
-        layer, kc, vc = inputs
-        x, kc, vc = _decode_block(cfg, x, layer, kc, vc, cos, sin,
-                                  positions, cache_len, start=start,
-                                  abs_positions=abs_positions)
-        return x, (kc, vc)
+    def step(li, carry):
+        x, kc, vc = carry
+        layer = jax.tree.map(
+            lambda w: jax.lax.dynamic_index_in_dim(w, li, 0, keepdims=False),
+            layers)
+        return _decode_block(cfg, x, layer, li, kc, vc, cos, sin,
+                             positions, cache_len, start, abs_positions)
 
-    x, (k_new, v_new) = jax.lax.scan(
-        step, x, (scanned_layers, cache["k"], cache["v"]))
+    x, k_new, v_new = jax.lax.fori_loop(
+        0, cfg.n_layers, step, (x, cache["k"], cache["v"]))
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = (x[:, -1] @ _head_matrix(params, cfg)).astype(jnp.float32)

@@ -175,10 +175,13 @@ class LLMEngine:
 
         # one jit; prefill (s=bucket) and decode (s=1) are separate traces
         # of the same function, cached per shape. Donation keeps the
-        # decode state ON-CHIP between ticks with in-place buffer reuse:
-        # cache (1), tokens (2) and PRNG key (3) are all rebound from
-        # the return at every call site, so XLA may overwrite them —
-        # temps (4) is NOT donated: decode reuses it across steps.
+        # decode state ON-CHIP between ticks: cache (1), tokens (2) and
+        # PRNG key (3) are all rebound from the return at every call
+        # site, and the step overwrites them — decode_step writes the
+        # new rows into the donated cache's own buffers and makes no
+        # copy of it, so a cache handed to a step is gone, whole, even
+        # when the step fails. temps (4) is NOT donated: decode reuses
+        # it across steps.
         self._step_jit = jax.jit(step, donate_argnums=(1, 2, 3))
         self._key_seed = seed ^ 0x5EED
         self._key_reseeds = 0
@@ -580,13 +583,11 @@ class LLMEngine:
         Runs op-by-op outside jit (concrete sizes; one dispatch pair per
         distinct (bucket, matched) — bounded by the block grid)."""
         e_off = int(entry["start"])
-        for key_ in ("k", "v"):
-            src = entry[key_]
-            seg = jax.lax.dynamic_slice(
-                src, (0, 0, e_off, 0, 0),
-                (src.shape[0], 1, matched, src.shape[3], src.shape[4]))
-            small[key_] = jax.lax.dynamic_update_slice(
-                small[key_], seg, (0, 0, off, 0, 0))
+        for key_, axis in llama.KV_LEN_AXIS.items():
+            seg = jax.lax.dynamic_slice_in_dim(
+                entry[key_], e_off, matched, axis)
+            small[key_] = jax.lax.dynamic_update_slice_in_dim(
+                small[key_], seg, off, axis)
         small["length"] = jnp.int32(off + matched)
         return small
 

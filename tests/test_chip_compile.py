@@ -9,7 +9,9 @@ compile takes a second or two. Skipped where the topology cannot be
 described.
 """
 
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -100,10 +102,10 @@ def test_flash_kernel_compiles_for_the_chip(chip, compiled_not_interpreted,
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
-def test_1b_decode_step_compiles_for_the_chip(chip):
-    """The serving engine's steady-state program: one token for each of 4
-    slots against a 2048-deep cache with per-row depths."""
-    cfg = llama.config_for("1b", max_seq_len=2048)
+def _compiled_decode_step(chip, cfg, batch, max_len, s, per_row):
+    """llama.decode_step for `batch` rows x `s` tokens against a cache
+    `max_len` deep, cache donated, compiled for the described chip.
+    Returns (compiled, the cache's shapes)."""
     on = SingleDeviceSharding(chip)
 
     def place(tree):
@@ -112,12 +114,66 @@ def test_1b_decode_step_compiles_for_the_chip(chip):
 
     params = place(jax.eval_shape(
         lambda key: llama.init_params(cfg, key), jax.random.PRNGKey(0)))
-    cache = dict(jax.eval_shape(lambda: llama.init_kv_cache(cfg, 4)))
-    cache["length"] = jax.ShapeDtypeStruct((4,), jnp.int32)
-    tokens = jax.ShapeDtypeStruct((4, 1), jnp.int32, sharding=on)
+    cache = dict(jax.eval_shape(
+        lambda: llama.init_kv_cache(cfg, batch, max_len)))
+    if per_row:
+        cache["length"] = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((batch, s), jnp.int32, sharding=on)
     compiled = jax.jit(
         lambda p, c, t: llama.decode_step(p, c, t, cfg),
         donate_argnums=(1,)).lower(params, place(cache), tokens).compile()
+    return compiled, cache
+
+
+def test_1b_decode_step_compiles_for_the_chip(chip):
+    """The serving engine's steady-state program: one token for each of 4
+    slots against a 2048-deep cache with per-row depths."""
+    cfg = llama.config_for("1b", max_seq_len=2048)
+    compiled, _ = _compiled_decode_step(chip, cfg, 4, 2048, 1, True)
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < HBM_BYTES)
+
+
+# InternLM2-1.8B widths, as the benchmark's serve cells hold them (bf16)
+_SERVE_CFG = dict(vocab_size=92544, dim=2048, n_layers=24, n_heads=16,
+                  n_kv_heads=8, hidden_dim=8192, rope_theta=1e6,
+                  dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+# result of an HLO instruction: "%name = dtype[dims]{layout} opcode("
+_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = ([a-z0-9]+)\[([0-9,]*)\][^ ]* ([\w\-]+)\(")
+
+
+@pytest.mark.parametrize("batch,max_len,s,per_row", [
+    (8, 4096, 1, True),       # the engine's decode step, 8 slots
+    (1, 3584, 256, False),    # one chunk of a long prompt's prefill
+], ids=["decode-8x4096", "chunk-1x3584-s256"])
+def test_decode_step_moves_no_cache(chip, batch, max_len, s, per_row):
+    """Per step every cache byte is read at most once, by attention, and
+    only the new rows are written (PERF.md, PR 25): with the cache
+    donated the step's temporaries hold less than ONE layer of it (seed:
+    3.63 GB for the decode shape, two whole copies among them), the cache
+    is updated in its own buffers, nothing but the in-place row writes
+    produces an array of a whole stack's shape, and no array of positions
+    x head_dim is as large as a layer's K repeated over its group's query
+    heads."""
+    cfg = llama.LlamaConfig(max_seq_len=max_len, **_SERVE_CFG)
+    compiled, cache = _compiled_decode_step(chip, cfg, batch, max_len, s,
+                                            per_row)
+
+    stacks = {tuple(cache[key].shape) for key in ("k", "v")}
+    layer_bytes = 2 * math.prod(cache["k"].shape[1:])
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < min(0.5e9, 2 * layer_bytes)
+    assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * layer_bytes
+    repeated = batch * max_len * cfg.n_heads * cfg.head_dim
+    for line in compiled.as_text().splitlines():
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if dims in stacks:
+            assert m.group(3) in ("parameter", "get-tuple-element",
+                                  "dynamic-update-slice"), line[:200]
+        elif max_len in dims and cfg.head_dim in dims:
+            assert math.prod(dims) < repeated, line[:200]

@@ -57,19 +57,93 @@ def test_loss_decreases_under_sgd(cfg, params):
     assert losses[-1] < losses[0] * 0.9, losses
 
 
-def test_decode_matches_forward(cfg, params):
-    """KV-cache decode must agree with the dense forward pass."""
-    tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 12), 0,
-                                cfg.vocab_size)
-    dense = llama.forward(params, tokens, cfg)  # [1, 12, vocab]
-    cache = llama.init_kv_cache(cfg, 1, max_len=32)
-    # prefill first 8, then decode 4 one at a time
-    logits, cache = llama.decode_step(params, cache, tokens[:, :8], cfg)
-    np.testing.assert_allclose(logits, dense[:, 7], rtol=2e-2, atol=2e-2)
-    for i in range(8, 12):
-        logits, cache = llama.decode_step(params, cache, tokens[:, i:i + 1],
-                                          cfg)
-        np.testing.assert_allclose(logits, dense[:, i], rtol=2e-2, atol=2e-2)
+def _positions_last(cache):
+    """cache["k"], cache["v"] as numpy with the cache positions last."""
+    return {key: np.moveaxis(np.asarray(cache[key]), axis, -1)
+            for key, axis in llama.KV_LEN_AXIS.items()}
+
+
+_GQA = [(4, 4), (4, 2), (8, 2)]
+_DECODE_CASES = [
+    pytest.param(h, kv, lengths, s, False, jnp.float32,
+                 id=f"h{h}kv{kv}-{lengths}-s{s}")
+    for h, kv in _GQA for lengths in ("scalar", "per_row") for s in (1, 8)
+] + [
+    pytest.param(4, 2, "per_row", 1, True, jnp.float32, id="lora"),
+    pytest.param(4, 2, "scalar", 1, False, jnp.bfloat16, id="bf16"),
+]
+
+
+@pytest.mark.parametrize("heads,kv_heads,lengths,s,lora,dtype",
+                         _DECODE_CASES)
+def test_decode_matches_forward(heads, kv_heads, lengths, s, lora, dtype):
+    """KV-cache decode must agree with the dense forward pass: for every
+    GQA group size, with the cache length a scalar (lock-step rows, left
+    padded) or per row (slots at their own depths), appending one token
+    at a time or a chunk of 8 to a partly filled cache, adapters in the
+    loop or not. And a step writes the new rows and nothing else: every
+    other position of every layer is bit-equal before and after it."""
+    cfg = llama.config_for("debug", remat=False, attn_impl="xla",
+                           n_heads=heads, n_kv_heads=kv_heads, dtype=dtype)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    if lora:
+        from ray_tpu.models import lora as lora_mod
+
+        ad = lora_mod.init_lora_params(cfg, lora_mod.LoraConfig(rank=4),
+                                       jax.random.PRNGKey(5))["layers"]
+        params["lora"] = {"layers": {
+            k: (0.3 * jax.random.normal(jax.random.PRNGKey(i), v.shape,
+                                        v.dtype) if k.endswith("_b") else v)
+            for i, (k, v) in enumerate(sorted(ad.items()))}}
+    b, first, more, max_len = 3, 8, 8, 32
+    starts = np.array([0, 2, 3], np.int32)      # first real slot per row
+    real = np.asarray(jax.random.randint(
+        jax.random.PRNGKey(2), (b, first + more), 1, cfg.vocab_size))
+    dense = [np.asarray(llama.forward(params, jnp.asarray(real[r:r + 1]),
+                                      cfg)[0]) for r in range(b)]
+    step = jax.jit(lambda p, c, t: llama.decode_step(p, c, t, cfg))
+
+    cache = llama.init_kv_cache(cfg, b, max_len=max_len)
+    cache["start"] = jnp.asarray(starts)
+    fed = np.zeros((b, first), np.int32)
+    if lengths == "scalar":
+        # rows in lock-step: the first call holds each row's pad slots
+        seen = first - starts
+        for r in range(b):
+            fed[r, starts[r]:] = real[r, :seen[r]]
+    else:
+        # each row at its own depth: row r's first token lands in slot
+        # starts[r], a chunk of 8 real tokens per row
+        cache["length"] = jnp.asarray(starts)
+        seen = np.full((b,), first)
+        fed[:] = real[:, :first]
+
+    def check(logits):
+        for r in range(b):
+            np.testing.assert_allclose(
+                np.asarray(logits[r]), dense[r][seen[r] - 1],
+                rtol=tol, atol=tol, err_msg=f"row {r} after {seen[r]}")
+
+    logits, cache = step(params, cache, jnp.asarray(fed))
+    check(logits)
+    for _ in range(more // s):
+        nxt = np.stack([real[r, seen[r]:seen[r] + s] for r in range(b)])
+        before = _positions_last(cache)
+        at = np.broadcast_to(np.asarray(cache["length"]), (b,))
+        logits, cache = step(params, cache, jnp.asarray(nxt))
+        seen = seen + s
+        check(logits)
+        after = _positions_last(cache)
+        for key in before:
+            for r in range(b):
+                new = np.zeros(max_len, bool)
+                new[at[r]:at[r] + s] = True
+                np.testing.assert_array_equal(
+                    after[key][:, r][..., ~new], before[key][:, r][..., ~new])
+                assert (after[key][:, r][..., new] != 0).any(axis=(1, 2)).all()
+    assert np.array_equal(np.broadcast_to(cache["length"], (b,)),
+                          at + s)
 
 
 def test_remat_matches(cfg, params):
