@@ -5,6 +5,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from benchmarks.serve_cell import judged_ttft_s
+
 
 def _first_in_window(obs):
     t0, t1 = obs["window"]
@@ -23,6 +25,24 @@ def ttft_p50_ms(obs: dict):
     """Median time to first token, from when the request was due."""
     ttft = [s.t[0] - s.due for s in _first_in_window(obs)]
     return float(np.median(ttft) * 1e3) if ttft else None
+
+
+def ttft_percentile_ms(obs: dict, q: float):
+    """The q-th percentile of the time to first token, from when each
+    request was due, over the requests due inside the window. One that
+    erred or missed ttft_limit_s counts as slower than any other
+    (`serve_cell.judged_ttft_s`); None where the percentile falls among
+    those."""
+    ttft = sorted(judged_ttft_s(obs))
+    if not ttft:
+        return None
+    # numpy's linear rule, by hand: it gives nan beside an inf
+    k = q / 100.0 * (len(ttft) - 1)
+    lo = int(k)
+    value = ttft[lo]
+    if k > lo:
+        value += (ttft[lo + 1] - ttft[lo]) * (k - lo)
+    return value * 1e3 if np.isfinite(value) else None
 
 
 def proxy_overhead_p50_ms(obs: dict):

@@ -228,33 +228,39 @@ def serve_tokens_per_s(obs: dict) -> float:
             + generated) / (t1 - t0)
 
 
+def judged_ttft_s(obs: dict) -> list:
+    """Open loop: the time to first token, from when it was due, of each
+    request due inside the window; inf for one that erred or had no
+    first token within ttft_limit_s of being due (one still inside its
+    limit when the run stopped is not judged)."""
+    t0, t1 = obs["window"]
+    limit = float(obs["traffic"]["ttft_limit_s"])
+    stop = max((s.t[-1] for s in obs["streams"] if s.t), default=t1)
+    out = []
+    for s in obs["streams"]:
+        if not t0 <= s.due <= t1:
+            continue
+        if s.error is not None:
+            out.append(np.inf)
+        elif s.t:
+            ttft = s.t[0] - s.due
+            out.append(ttft if ttft <= limit else np.inf)
+        elif stop - s.due > limit:
+            out.append(np.inf)
+    return out
+
+
 def attempted_failed(obs: dict) -> tuple:
     """Open loop: requests due inside the window; failed if they erred
-    or had no first token within ttft_limit_s of being due (one still
-    inside its limit when the run stopped is not judged). Closed loop:
-    requests sent inside the window; failed if they erred."""
+    or missed ttft_limit_s (`judged_ttft_s`). Closed loop: requests sent
+    inside the window; failed if they erred."""
+    if obs["traffic"]["kind"] == "serve_open":
+        ttft = judged_ttft_s(obs)
+        return len(ttft), ttft.count(np.inf)
     t0, t1 = obs["window"]
-    tr = obs["traffic"]
-    attempted = failed = 0
-    if tr["kind"] == "serve_open":
-        limit = float(tr["ttft_limit_s"])
-        stop = max((s.t[-1] for s in obs["streams"] if s.t), default=t1)
-        for s in obs["streams"]:
-            if not t0 <= s.due <= t1:
-                continue
-            if s.error is not None:
-                attempted, failed = attempted + 1, failed + 1
-            elif s.t:
-                attempted += 1
-                failed += (s.t[0] - s.due) > limit
-            elif stop - s.due > limit:
-                attempted, failed = attempted + 1, failed + 1
-    else:
-        for s in obs["streams"]:
-            if s.sent is not None and t0 <= s.sent <= t1:
-                attempted += 1
-                failed += s.error is not None
-    return attempted, int(failed)
+    sent = [s for s in obs["streams"]
+            if s.sent is not None and t0 <= s.sent <= t1]
+    return len(sent), sum(1 for s in sent if s.error is not None)
 
 
 def correct(obs: dict, tol: dict) -> bool:
@@ -302,6 +308,9 @@ def info(obs: dict) -> dict:
         out["gap_ms"] = {"median": bare, "p90": float(np.percentile(
             gaps, 90)), "p99": float(np.percentile(gaps, 99)),
             "max": float(gaps.max()), "by_mode": hist}
+        if len(hist) > 1:   # the share of gaps that are not bare steps
+            out["gap_ms"]["beyond_first_mode_share"] = 100.0 * (
+                1.0 - next(iter(hist.values())) / len(gaps))
     for s in obs["streams"]:   # one request's durations, to check by hand
         eng = ((obs.get("records") or {}).get(s.request_id) or {}
                ).get("engine") or {}
@@ -317,4 +326,7 @@ def info(obs: dict) -> dict:
     if ttft:
         out["ttft_s"] = {"p50": float(np.median(ttft)),
                          "max": float(max(ttft))}
+    if tr["kind"] == "serve_open":   # every request due in the window
+        out["ttft_due_s"] = [round(x, 5) if x < np.inf else None
+                             for x in sorted(judged_ttft_s(obs))]
     return out

@@ -5,12 +5,20 @@ What repeats from seed to seed, by construction:
 
 * the multiset of shapes. Request i of a stratum takes its prompt and
   output lengths at the evenly spaced quantiles (i + 1/2) / n of the
-  file's distributions; the seed only shuffles the order (prompts and
-  outputs apart) and draws the token ids. Every seed offers the same
-  total of prompt tokens, of output tokens and the same count per bucket.
-* the count of arrivals. An open loop sends exactly round(rate x length)
-  requests in the lead-in and in the window, each at sorted uniform
-  draws: a Poisson process given its count.
+  file's distributions, and a shuffle pairs them (prompts and outputs
+  apart). Every seed offers the same total of prompt tokens, of output
+  tokens and the same count per bucket.
+* an open loop's whole schedule. `round(rate x seconds)` arrivals at
+  sorted uniform draws (a Poisson process given its count) on a ring
+  `seconds` long, each with its shapes, are drawn once from the file's
+  `schedule_seed`. The seed picks the point of the ring at which the
+  window starts, and draws the token ids. The window is one whole turn,
+  so every seed's window holds the same requests at the same distances
+  from each other, and what runs over its end is what ran into its
+  start; the lead-in plays the arc that precedes the starting point.
+  With a schedule of its own for every seed the window's work swung
+  with the draw (PERF.md, PR 27): how many requests overlapped decided
+  the gaps' tail more than the program did.
 
 A closed loop hands its clients the requests of a cycle of `cycle`
 shapes, shuffled anew each cycle, so any `cycle` consecutive requests
@@ -51,8 +59,10 @@ def quantile_lengths(spec: dict, n: int) -> list:
     return [int(min(hi, max(lo, round(v)))) for v in vals]
 
 
-def _stratum(rng, spec: dict, vocab: int, n: int, t0: float, t1: float,
-             name: str, first_index: int, partway: bool = False) -> list:
+def _shapes_and_dues(rng, spec: dict, n: int, t0: float, t1: float,
+                     partway: bool = False):
+    """n prompt lengths, n output lengths, each shuffled apart, and n
+    sorted uniform arrival times in [t0, t1)."""
     prompts = quantile_lengths(spec["prompt_len"], n)
     outputs = quantile_lengths(spec["output_len"], n)
     if partway:  # request i is the fraction (i + 1/2) / n through its output
@@ -60,7 +70,12 @@ def _stratum(rng, spec: dict, vocab: int, n: int, t0: float, t1: float,
                    for i, o in enumerate(outputs)]
     rng.shuffle(prompts)
     rng.shuffle(outputs)
-    dues = np.sort(rng.uniform(t0, t1, size=n))
+    return prompts, outputs, np.sort(rng.uniform(t0, t1, size=n))
+
+
+def _stratum(rng, spec: dict, vocab: int, n: int, t0: float, t1: float,
+             name: str, first_index: int, partway: bool = False) -> list:
+    prompts, outputs, dues = _shapes_and_dues(rng, spec, n, t0, t1, partway)
     return [Request(index=first_index + i, due_s=float(dues[i]),
                     tokens=rng.integers(1, vocab, size=prompts[i]).tolist(),
                     max_new_tokens=outputs[i], stratum=name)
@@ -69,21 +84,31 @@ def _stratum(rng, spec: dict, vocab: int, n: int, t0: float, t1: float,
 
 def open_loop(spec: dict, vocab: int, seed: int, seconds: float) -> list:
     """Requests for lead_s of lead-in and `seconds` of window, sorted by
-    due time. Counts are fixed by the rate; see the module docstring."""
+    due time: one turn of the file's ring and the arc that precedes it;
+    see the module docstring."""
     rng = np.random.default_rng([int(seed), 1])
     lead = float(spec["lead_s"])
-    n_lead = round(spec["rate_per_s"] * lead)
-    n_win = round(spec["rate_per_s"] * seconds)
     # the engine starts full: `inflight_at_start` requests at time 0,
     # each partway through its output (evenly spaced fractions), stand
     # for those a steady state would already hold, so the lead-in need
     # not last a whole request lifetime
-    n0 = int(spec.get("inflight_at_start", 0))
-    reqs = _stratum(rng, spec, vocab, n0, 0.0, 0.0, "lead", 0,
-                    partway=True)
-    reqs += _stratum(rng, spec, vocab, n_lead, 0.0, lead, "lead", n0)
-    reqs += _stratum(rng, spec, vocab, n_win, lead, lead + seconds,
-                     "window", n0 + n_lead)
+    reqs = _stratum(rng, spec, vocab, int(spec.get("inflight_at_start", 0)),
+                    0.0, 0.0, "lead", 0, partway=True)
+    ring = _shapes_and_dues(
+        np.random.default_rng([int(spec["schedule_seed"]), 4]), spec,
+        round(spec["rate_per_s"] * seconds), 0.0, seconds)
+    phase = rng.uniform(0.0, seconds)
+    for prompt_len, max_new_tokens, at in zip(*ring):
+        due = lead + (float(at) - phase) % seconds    # inside the window
+        while due >= 0.0:                  # and each turn before it
+            reqs.append(Request(
+                index=0, due_s=due, max_new_tokens=max_new_tokens,
+                tokens=rng.integers(1, vocab, size=prompt_len).tolist(),
+                stratum="window" if due >= lead else "lead"))
+            due -= seconds
+    reqs.sort(key=lambda r: r.due_s)
+    for i, r in enumerate(reqs):
+        r.index = i
     return reqs
 
 

@@ -227,6 +227,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, work: str,
         "batch_size": job["batch_size"], "lora_rank": job["lora_rank"],
         "lora_targets": job["lora_targets"],
         "report_every": job["report_every"],
+        "grad_accum": job.get("grad_accum", 1),
         "seed": model.fold_seed(seed),
         "model_overrides": {"max_seq_len": job["seq_len"],
                             **job.get("model_overrides", {})},

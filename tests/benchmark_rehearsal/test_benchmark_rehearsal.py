@@ -27,6 +27,8 @@ CONFIGS = [c["name"] for c in MANIFEST["configs"]]
 SERVE_TRAFFIC = sorted({w["traffic"] for w in MANIFEST["workloads"]
                         if manifest_mod.resolve(MANIFEST, w["name"])
                         .traffic["driver"] == "serve_cell"})
+TRAIN_CELLS = [n for n in CELLS if manifest_mod.resolve(MANIFEST, n)
+               .traffic["driver"] == "train_cell"]
 # what the contract calls a width: `reduced` may never name one
 WIDTH_RE = re.compile(r"(hidden|intermediate|latent|state|proj).*size|"
                       r"_dim$|_rank$|head_size|expansion|experts_per_tok")
@@ -116,7 +118,11 @@ def test_generator_offers_the_same_work_for_every_seed(traffic_name):
         return [next(it) for _ in range(3 * spec["cycle"])]
 
     a, b = requests(seeds[0]), requests(seeds[1])
-    for stratum in {r.stratum for r in a}:
+    open_loop = spec["kind"] == "serve_open"
+    # an open loop's lead-in is the arc of the ring that precedes the
+    # seed's own starting point: other requests for another seed
+    for stratum in {r.stratum for r in a} - ({"lead"} if open_loop
+                                             else set()):
         sa = [r for r in a if r.stratum == stratum]
         sb = [r for r in b if r.stratum == stratum]
         assert traffic_mod.shape_summary(sa, buckets) == \
@@ -135,19 +141,48 @@ def test_generator_offers_the_same_work_for_every_seed(traffic_name):
         assert len(r.tokens) <= max(buckets)
         assert max(buckets) + r.max_new_tokens < \
             spec["engine"]["max_seq_len"]
-    if spec["kind"] == "serve_open":
+    if open_loop:
         n_win = sum(1 for r in a if r.stratum == "window")
         assert n_win == round(spec["rate_per_s"] * 30.0)
         assert all(spec["lead_s"] <= r.due_s <= spec["lead_s"] + 30.0
                    for r in a if r.stratum == "window")
         assert a == sorted(a, key=lambda r: r.due_s)
+        assert [r.index for r in a] == list(range(len(a)))
         assert set(traffic_mod.shape_summary(
             [r for r in a if r.stratum == "window"],
             buckets)["per_bucket"]) == set(buckets)
+        windows_are_one_ring(spec, a, b)
     else:
         cyc = spec["cycle"]
         assert traffic_mod.shape_summary(a[:cyc], buckets) == \
             traffic_mod.shape_summary(a[cyc:2 * cyc], buckets)
+
+
+def windows_are_one_ring(spec, a, b):
+    """Two seeds' windows are one turn of the same ring: the same
+    requests at the same distances, started at another point; each
+    lead-in repeats the end of its own window one turn earlier."""
+    lead, turn = spec["lead_s"], 30.0
+
+    def on_ring(reqs, stratum):
+        return sorted(((r.due_s - lead) % turn, len(r.tokens),
+                       r.max_new_tokens) for r in reqs
+                      if r.stratum == stratum and r.due_s > 0)
+
+    wa, wb = on_ring(a, "window"), on_ring(b, "window")
+    shapes = lambda win: [x[1:] for x in win]
+    k = next(k for k in range(len(wa))      # b's window is a's, turned
+             if shapes(wb) == shapes(wa[k:] + wa[:k]))
+    turned = wa[k:] + wa[:k]
+    shift = (wb[0][0] - turned[0][0]) % turn
+    assert 0.01 < shift < turn - 0.01       # and started elsewhere
+    for x, y in zip(wb, turned):
+        off = (x[0] - y[0] - shift) % turn
+        assert min(off, turn - off) < 1e-6
+    for reqs, win in ((a, wa), (b, wb)):
+        tail = [x for x in win if x[0] >= turn - lead]
+        assert [(round(t, 6), p, o) for t, p, o in on_ring(reqs, "lead")] \
+            == [(round(t, 6), p, o) for t, p, o in tail]
 
 
 def test_quantile_lengths_stay_inside_their_limits():
@@ -281,6 +316,98 @@ def test_window_metrics_are_taken_over_every_stamp_in_the_window():
 
     assert client_readers.tokens_per_s_at_first_token(obs) == \
         pytest.approx((100 + 1000 + 200 + arrived) / 10.0)
+
+
+def test_ttft_percentile_is_from_when_due_and_counts_the_missing():
+    from benchmarks.readers import client as client_readers
+
+    # due at 10.0 .. 18.0, first token 0.10 .. 0.90 s after being due;
+    # each was sent 1 ms late, which the time from when due contains
+    streams = [_stream(10.0 + i, 10.0 + i + 0.1 * (i + 1), [0.05] * 3, 20)
+               for i in range(9)]
+    obs = {"window": (10.0, 20.0), "streams": streams,
+           "traffic": {"kind": "serve_open", "ttft_limit_s": 2.0}}
+    ttft = [0.1 * (i + 1) for i in range(9)]
+    for q in (50, 75, 90):
+        assert client_readers.ttft_percentile_ms(obs, q) == pytest.approx(
+            np.percentile(ttft, q) * 1e3)
+    assert client_readers.ttft_percentile_ms(obs, 50) == pytest.approx(
+        client_readers.ttft_p50_ms(obs))
+    assert streams[0].sent > streams[0].due
+    # outside the window's dues: not this window's requests
+    obs["streams"] = streams + [_stream(5.0, 9.0, [0.1] * 20, 20),
+                                _stream(20.5, 20.6, [0.1], 20)]
+    assert client_readers.ttft_percentile_ms(obs, 75) == pytest.approx(
+        np.percentile(ttft, 75) * 1e3)
+    # one that erred and one past the limit count as slower than all
+    # the others: eleven requests, the two slowest missing
+    missing = [_stream(15.5, None, [], 20, error="boom"),
+               _stream(16.5, 19.0, [0.1], 20)]
+    obs["streams"] = streams + missing
+    assert serve_cell.attempted_failed(obs) == (11, 2)
+    assert serve_cell.judged_ttft_s(obs).count(float("inf")) == 2
+    assert client_readers.ttft_percentile_ms(obs, 50) == pytest.approx(
+        np.percentile(ttft + [9.0, 9.0], 50) * 1e3)
+    assert client_readers.ttft_percentile_ms(obs, 80) == pytest.approx(900.0)
+    # a percentile that falls among the missing is no number
+    assert client_readers.ttft_percentile_ms(obs, 90) is None
+    # no first token yet, still inside its limit when the run stopped
+    # (the last stamp of any stream, 19.1): not judged either way
+    obs["streams"] = streams + [_stream(19.0, None, [], 20)]
+    assert serve_cell.attempted_failed(obs) == (9, 0)
+    assert client_readers.ttft_percentile_ms(obs, 50) == pytest.approx(500.0)
+    obs["streams"] = []
+    assert client_readers.ttft_percentile_ms(obs, 50) is None
+
+
+def test_ttft_metrics_of_the_manifest_are_held_to_their_reader():
+    """Every `chat_ttft_p<nn>_ms` of BENCHMARK.json takes the nn-th
+    percentile, through the reader its metric file names."""
+    from benchmarks.readers import client as client_readers
+
+    ttft = [0.01 * (i + 1) for i in range(40)]
+    obs = {"window": (10.0, 20.0), "streams": [
+        _stream(10.0 + 0.1 * i, 10.0 + 0.1 * i + x, [0.05], 20)
+        for i, x in enumerate(ttft)],
+        "traffic": {"kind": "serve_open", "ttft_limit_s": 2.0}}
+    cell = manifest_mod.resolve(MANIFEST, "internlm2-1.8b.chat")
+    seen = set()
+    for m in cell.per_layer:
+        found = re.fullmatch(r"chat_ttft_p(\d+)_ms", m["name"])
+        if not found:
+            continue
+        q = int(found.group(1))
+        assert (m["unit"], m["better"], m["source"]) == \
+            ("ms", "lower", "host_clock")
+        assert m["workloads"] == [cell.name]
+        assert m["moves"] in {e["name"] for e in cell.end_to_end}
+        reader = getattr(client_readers,
+                         m["file"]["reader"].split(".", 1)[1])
+        assert reader(obs, **m["file"]["args"]) == pytest.approx(
+            np.percentile(ttft, q) * 1e3), m["name"]
+        seen.add(q)
+    assert seen == {50, 75}
+
+
+@pytest.mark.parametrize("cell_name", TRAIN_CELLS)
+def test_train_job_is_whole_micro_batches_and_leaves_steps_to_measure(
+        cell_name, rehearsal_manifest):
+    """A step of `batch_size` rows in `grad_accum` micro-batches (the
+    recipe's own knob, 1 where the mix does not name it), at full size
+    and on the twin; a traced run keeps two whole steps ahead of the
+    profiler, which `train_tokens_per_s` needs; a window of run_seconds
+    holds as many at the step time the mix's file states."""
+    for manifest in (MANIFEST, rehearsal_manifest):
+        tr = manifest_mod.resolve(manifest, cell_name).traffic
+        job = tr["job"]
+        accum = job.get("grad_accum", 1)
+        assert accum >= 1 and job["batch_size"] % accum == 0
+        assert tr["warmup_steps"] >= 1 and tr["trace_steps"] >= 1
+        assert tr["trace_after_steps"] >= 2
+    obs = {"window_stamps": [0.0, 5.25, 10.5],
+           "job": manifest_mod.resolve(MANIFEST, cell_name).traffic["job"]}
+    assert train_cell.train_tokens_per_s(obs) == pytest.approx(
+        obs["job"]["batch_size"] * obs["job"]["seq_len"] / 5.25)
 
 
 def test_train_rate_counts_whole_steps_between_stamps():

@@ -295,6 +295,48 @@ def test_scope_readers_on_the_hand_made_trace(reduced):
     assert spans.ttft_decode_interleave_share({"records": {}}, "c") is None
 
 
+@pytest.mark.parametrize("shape", [
+    "24,8,8,128,4096", "24,8,8,4096,128",     # K and V since PR 25
+    "8,8,128,4096", "8,8,4096,128",           # one layer of each
+    "1,8,8,128,4096", "1,8,8,4096,128",
+    "24,8,4096,8,128", "8,4096,8,128"])       # the seed's axis order
+def test_kv_update_share_counts_a_whole_cache_move_by_shape(reduced, shape):
+    """chat_decode_kv_update_share's own patterns, on a hand-encoded
+    move of a whole cache (or a layer of it) in phase `decode` under no
+    part and under no scope at all. The patterns of PR 24 named the
+    seed's axis order only, and miss the others."""
+    trace = _hand_made()
+    ops = trace["planes"][0]["lines"][0]["events"]
+    # the 100 ns of decode/attn now move a cache inside the layer loop,
+    # and the unscoped copy takes the shape too
+    ops[1] = [f"%copy.2 = bf16[{shape}]{{4,3,2,1,0}} copy(y)", 10, 100,
+              {"path": "jit(step)/decode/while/body/dynamic_slice:"}]
+    ops[3][0] = f"%copy.4 = bf16[{shape}]{{4,3,2,1,0}} copy(c)"
+    reduced(trace)
+    with open(os.path.join(ROOT, "benchmarks", "metrics",
+                           "chat_decode_kv_update_share.json")) as f:
+        args = json.load(f)["args"]
+    share = lambda like: spans.scope_share({}, "c", args["scopes"],
+                                           ops_like=like)
+    rows = 10.0            # decode/kv_update itself: 50 of 500 ns
+    assert share(None) == pytest.approx(rows)
+    assert share(args["ops_like"]) == pytest.approx(rows + 20.0 + 20.0)
+    old = {"decode": r"bf16\[(24,)?8,4096,8,128\]",
+           "unscoped": r"bf16\[24,8,4096,8,128\]"}
+    seeds_order = {"24,8,4096,8,128": 40.0, "8,4096,8,128": 20.0}
+    assert share(old) == pytest.approx(rows + seeds_order.get(shape, 0.0))
+    # an in-place write of new rows names the whole cache as its output
+    # and moves none of it: under our part it is counted once, as rows
+    ops[1] = [f"%dynamic-update-slice.2 = bf16[{shape}]{{4,3,2,1,0}} "
+              "dynamic-update-slice(y)", 10, 100,
+              {"path": BODY + "kv_update/dynamic_update_slice:"}]
+    # ... and insert_row's, outside every scope, is no move either
+    ops[3][0] = (f"%constant_dynamic-update-slice_fusion.1 = bf16[{shape}]"
+                 "{4,3,2,1,0} fusion(c)")
+    reduced(trace)
+    assert share(args["ops_like"]) == share(None) == pytest.approx(30.0)
+
+
 def test_train_readers_and_the_flash_yardstick(reduced):
     reduced(_train())
     assert spans.recompute_share({}, "c") == pytest.approx(100 * 150 / 660)
