@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops.attention import dot_product_attention
+from ray_tpu.ops.attention import cached_attention, dot_product_attention
 from ray_tpu.ops.cross_entropy import softmax_cross_entropy
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention
@@ -429,6 +429,17 @@ def kv_cache_logical_axes() -> dict:
             "length": (), "start": ("batch",)}
 
 
+# The names serve/llm.py's engine calls on whichever model module serves
+# its config (models/granite_hybrid.py has the same set).
+TENSOR_PARALLEL = True
+CACHE_LEN_AXIS = KV_LEN_AXIS
+init_cache = init_kv_cache
+
+
+def cache_logical_axes(cfg: LlamaConfig) -> dict:
+    return kv_cache_logical_axes()
+
+
 def _decode_block(cfg: LlamaConfig, x, layer, li, k_cache, v_cache, cos, sin,
                   positions, cache_len, start, abs_positions):
     """Single-step (or chunked prefill) block `li` against the KV cache.
@@ -444,61 +455,14 @@ def _decode_block(cfg: LlamaConfig, x, layer, li, k_cache, v_cache, cos, sin,
     """
     b, s, d = x.shape
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    group = nh // nkv
-    dt = cfg.dtype
     with jax.named_scope("attn_qkv"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q = _proj(cfg, layer, "wq", h).reshape(b, s, nh, hd)
         kk = _proj(cfg, layer, "wk", h).reshape(b, s, nkv, hd)
         vv = _proj(cfg, layer, "wv", h).reshape(b, s, nkv, hd)
-        q = apply_rope(q, cos, sin, positions)
-        kk = apply_rope(kk, cos, sin, positions)
-    with jax.named_scope("kv_update"):
-        def write(k_cache, v_cache, kk, vv, row, at):
-            # kk, vv [rows, s, nkv, hd] -> the cache's orders, at position
-            # `at` of rows [row, row + rows) of layer li
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, kk.transpose(0, 2, 3, 1)[None], (li, row, 0, 0, at))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, vv.transpose(0, 2, 1, 3)[None], (li, row, 0, at, 0))
-            return k_cache, v_cache
-
-        if jnp.ndim(cache_len) == 0:
-            # whole batch advances together (left-padded batched decode,
-            # batch-1 prefill): one block of the carry
-            k_cache, v_cache = write(k_cache, v_cache, kk, vv, 0, cache_len)
-        else:
-            # per-row write offsets (continuous-batching slots: each row
-            # is an independent request at its own depth, vLLM-style).
-            # One small in-place write per row, the row cut out BEFORE it
-            # is transposed: as one scatter, as a vmap of
-            # dynamic_update_slice over the batch axis, or cut from the
-            # transposed batch, the compiler re-lays the carry out for
-            # the update's layout and copies the whole cache into and
-            # out of the loop (PERF.md, PR 25; tests/test_chip_compile.py
-            # holds the step to it).
-            for r in range(b):
-                k_cache, v_cache = write(k_cache, v_cache, kk[r:r + 1],
-                                         vv[r:r + 1], r, cache_len[r])
-    with jax.named_scope("attn"):
-        # Over kv-head groups, K and V as they lie in the cache: the
-        # group's query heads are rows of one matmul per kv head, so no
-        # GQA repeat of K or V exists anywhere.
-        k_l = jax.lax.dynamic_index_in_dim(k_cache, li, 0, keepdims=False)
-        v_l = jax.lax.dynamic_index_in_dim(v_cache, li, 0, keepdims=False)
-        max_len = v_l.shape[2]
-        qg = q.reshape(b, s, nkv, group, hd).transpose(0, 2, 1, 3, 4)
-        logits = jnp.einsum("bnqgd,bndk->bnqgk", qg, k_l,
-                            preferred_element_type=jnp.float32) * (hd ** -0.5)
-        # mask: key slot j visible iff start <= j <= query slot
-        k_pos = jnp.arange(max_len)[None, :]
-        mask = k_pos[:, None, :] <= abs_positions[..., None]  # [b, s, max_len]
-        if start is not None:
-            mask = mask & (k_pos[:, None, :] >= start[:, None, None])
-        logits = jnp.where(mask[:, None, :, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(dt)
-        attn = jnp.einsum("bnqgk,bnkd->bqngd", probs, v_l).reshape(
-            b, s, nh * hd)
+    attn, k_cache, v_cache = cached_attention(
+        q, kk, vv, k_cache, v_cache, li, cache_len, abs_positions, start,
+        scale=hd ** -0.5, rope=(cos, sin, positions))
     with jax.named_scope("attn_out"):
         x = x + _proj(cfg, layer, "wo", attn)
     with jax.named_scope("mlp"):

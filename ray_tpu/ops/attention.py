@@ -19,6 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.rope import apply_rope
+
 NEG_INF = -1e30
 
 
@@ -93,3 +95,81 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              out_specs=q_spec, check_vma=False)(q, k, v)
     return xla_attention(q, k, v, causal=causal, segment_ids=segment_ids,
                         scale=scale)
+
+
+def cached_attention(q: jax.Array, kk: jax.Array, vv: jax.Array,
+                     k_cache: jax.Array, v_cache: jax.Array, li,
+                     cache_len, abs_positions: jax.Array, start, *,
+                     scale: float, rope: tuple | None = None
+                     ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Attention of s new tokens against layer `li` of a stacked KV
+    cache, for a decode step (s = 1) or a prefill chunk (s > 1).
+
+    q: [b, s, heads, hd]; kk, vv: [b, s, kv_heads, hd], as projected.
+    k_cache ``[layers, b, kv_heads, hd, len]`` and v_cache ``[layers, b,
+    kv_heads, len, hd]`` are the STACKED cache, carried through the
+    caller's layer loop: the s new rows are written at positions
+    [cache_len[row], cache_len[row] + s) of layer `li` in place and that
+    layer is read once; nothing else of the cache is read, written or
+    copied. `cache_len` is a scalar (the batch in lock-step) or [b]
+    (per-row depths). `abs_positions` [b, s] are the slots the new rows
+    land in, used for masking; `start` [b] (or None) hides the left-pad
+    slots of each row. `scale` multiplies the scores. `rope` is (cos,
+    sin, positions) for rotary embeddings on q and k, or None for a
+    model without position embeddings.
+    Returns (attn [b, s, heads * hd], k_cache, v_cache)."""
+    b, s, nh, hd = q.shape
+    nkv = kk.shape[2]
+    group = nh // nkv
+    if rope is not None:
+        with jax.named_scope("attn_qkv"):
+            cos, sin, positions = rope
+            q = apply_rope(q, cos, sin, positions)
+            kk = apply_rope(kk, cos, sin, positions)
+    with jax.named_scope("kv_update"):
+        def write(k_cache, v_cache, kk, vv, row, at):
+            # kk, vv [rows, s, nkv, hd] -> the cache's orders, at position
+            # `at` of rows [row, row + rows) of layer li
+            k_cache = jax.lax.dynamic_update_slice(
+                k_cache, kk.transpose(0, 2, 3, 1)[None], (li, row, 0, 0, at))
+            v_cache = jax.lax.dynamic_update_slice(
+                v_cache, vv.transpose(0, 2, 1, 3)[None], (li, row, 0, at, 0))
+            return k_cache, v_cache
+
+        if jnp.ndim(cache_len) == 0:
+            # whole batch advances together (left-padded batched decode,
+            # batch-1 prefill): one block of the carry
+            k_cache, v_cache = write(k_cache, v_cache, kk, vv, 0, cache_len)
+        else:
+            # per-row write offsets (continuous-batching slots: each row
+            # is an independent request at its own depth, vLLM-style).
+            # One small in-place write per row, the row cut out BEFORE it
+            # is transposed: as one scatter, as a vmap of
+            # dynamic_update_slice over the batch axis, or cut from the
+            # transposed batch, the compiler re-lays the carry out for
+            # the update's layout and copies the whole cache into and
+            # out of the loop (PERF.md, PR 25; tests/test_chip_compile.py
+            # holds the step to it).
+            for r in range(b):
+                k_cache, v_cache = write(k_cache, v_cache, kk[r:r + 1],
+                                         vv[r:r + 1], r, cache_len[r])
+    with jax.named_scope("attn"):
+        # Over kv-head groups, K and V as they lie in the cache: the
+        # group's query heads are rows of one matmul per kv head, so no
+        # GQA repeat of K or V exists anywhere.
+        k_l = jax.lax.dynamic_index_in_dim(k_cache, li, 0, keepdims=False)
+        v_l = jax.lax.dynamic_index_in_dim(v_cache, li, 0, keepdims=False)
+        max_len = v_l.shape[2]
+        qg = q.reshape(b, s, nkv, group, hd).transpose(0, 2, 1, 3, 4)
+        logits = jnp.einsum("bnqgd,bndk->bnqgk", qg, k_l,
+                            preferred_element_type=jnp.float32) * scale
+        # mask: key slot j visible iff start <= j <= query slot
+        k_pos = jnp.arange(max_len)[None, :]
+        mask = k_pos[:, None, :] <= abs_positions[..., None]  # [b, s, max_len]
+        if start is not None:
+            mask = mask & (k_pos[:, None, :] >= start[:, None, None])
+        logits = jnp.where(mask[:, None, :, None], logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(v_l.dtype)
+        attn = jnp.einsum("bnqgk,bnkd->bqngd", probs, v_l).reshape(
+            b, s, nh * hd)
+    return attn, k_cache, v_cache

@@ -17,6 +17,7 @@ prefill/decode with donated KV cache, greedy/temperature sampling in-jit.
 from __future__ import annotations
 
 import asyncio
+import dataclasses
 import os
 import threading
 import time
@@ -29,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu._internal.profiler import span_type
-from ray_tpu.models import llama
+from ray_tpu.models import llama, module_for
 from ray_tpu.parallel.mesh import build_mesh, shard_params, spec_for
 from ray_tpu.serve.multiplex import multiplexed
 
@@ -90,7 +91,7 @@ class _PendingPrefill:
     req: _Request
     slot: int
     prompts: Any            # np [1, bucket]
-    small: Any              # per-request prefill cache
+    small: Any              # per-request prefill cache (the model's pytree)
     bucket: int
     pos: int = 0            # tokens already prefilled
 
@@ -107,9 +108,27 @@ class LLMEngine:
     batch to drain. Finished slots free immediately and refill from the
     queue between steps. Static shapes throughout: one decode trace
     ever, one prefill + insert trace per prompt bucket.
+
+    `model` is a model config (a `llama.LlamaConfig`, a
+    `granite_hybrid.GraniteHybridConfig`) or the name of a llama preset;
+    the module that serves it (`models.module_for`) is asked for
+    everything the engine needs to know about the model. "The cache" is
+    that module's pytree: besides the bookkeeping leaves `length` and
+    `start`, every leaf has a batch axis (where its logical axes say
+    "batch"), so a request's ROW is that leaf at one batch index, and
+    `CACHE_LEN_AXIS` names the leaves that also have a position axis
+    (K, V). A leaf without one is recurrent state: what the whole prefix
+    left behind, valid at the one position it was computed to.
+
+    The prefix store grafts a block-aligned PREFIX of a stored row's
+    positions into a new request's cache. Recurrent state has no such
+    prefix to cut: grafting K and V beside a state that saw other
+    tokens would be wrong, so for a model with recurrent state the
+    store holds nothing (`prefix_cache_entries` is 0 in `stats()`),
+    whatever was asked for.
     """
 
-    def __init__(self, preset: str = "debug", *, tp: int | None = None,
+    def __init__(self, model: Any = "debug", *, tp: int | None = None,
                  max_batch: int = 4, max_seq_len: int | None = None,
                  prompt_buckets: tuple[int, ...] = (32, 128, 512, 1024),
                  prefill_chunk: int = 256,
@@ -119,10 +138,14 @@ class LLMEngine:
         devices = jax.devices()
         tp = tp or len(devices)
         self.mesh = build_mesh({"data": 1, "tensor": tp}, devices[:tp])
-        cfg = llama.config_for(preset)
+        cfg = llama.config_for(model) if isinstance(model, str) else model
         if max_seq_len is not None:
-            cfg = llama.config_for(preset, max_seq_len=max_seq_len)
+            cfg = dataclasses.replace(cfg, max_seq_len=max_seq_len)
         self.cfg = cfg
+        self._model = mod = module_for(cfg)
+        if tp > 1 and not mod.TENSOR_PARALLEL:
+            raise ValueError(f"{mod.__name__} has no sharding over a "
+                             f"tensor axis; tp={tp}")
         self.max_batch = max_batch
         # chunked prefill: prompts longer than this prefill one chunk
         # per engine round instead of stalling decode for the whole
@@ -132,9 +155,9 @@ class LLMEngine:
             b for b in prompt_buckets if b < cfg.max_seq_len) or (
                 cfg.max_seq_len // 2,)
         self.eos_token_id = eos_token_id
-        logical = llama.param_logical_axes(cfg)
+        logical = mod.param_logical_axes(cfg)
         if params is None:
-            params = llama.init_params(cfg, jax.random.PRNGKey(seed))
+            params = mod.init_params(cfg, jax.random.PRNGKey(seed))
         if "lora" in params:
             # adapter-bearing params: the decode path applies the
             # low-rank delta in-scan (models/llama.py), so the engine
@@ -150,11 +173,24 @@ class LLMEngine:
                                          targets=targets))}
         shardings = shard_params(params, logical, self.mesh)
         self.params = jax.device_put(params, shardings)
+        cache_axes = mod.cache_logical_axes(cfg)
         self._cache_sharding = jax.tree.map(
             lambda ax: jax.sharding.NamedSharding(
                 self.mesh, spec_for(ax, mesh=self.mesh)),
-            llama.kv_cache_logical_axes(),
-            is_leaf=lambda x: isinstance(x, tuple))
+            cache_axes, is_leaf=lambda x: isinstance(x, tuple))
+        # a request's row: every leaf but the bookkeeping, by batch axis
+        self._batch_axis = {name: ax.index("batch")
+                            for name, ax in cache_axes.items()
+                            if name not in ("length", "start")}
+        self._len_axis = mod.CACHE_LEN_AXIS
+        recurrent = set(self._batch_axis) - set(self._len_axis)
+        shapes = jax.eval_shape(lambda: mod.init_cache(
+            cfg, max_batch, max_len=cfg.max_seq_len))
+        self._cache_bytes = {
+            kind: sum(shapes[n].size * shapes[n].dtype.itemsize
+                      for n in names)
+            for kind, names in (("kv", self._len_axis),
+                                ("state", recurrent))}
 
         def step(params, cache, tokens, key, temperature):
             if tokens.ndim == 1:  # decode path: device-resident [b]
@@ -163,8 +199,7 @@ class LLMEngine:
             # operation of this trace in the profiler (metadata only)
             with jax.named_scope("prefill" if tokens.shape[1] > 1
                                  else "decode"):
-                logits, cache = llama.decode_step(params, cache, tokens,
-                                                  cfg)
+                logits, cache = mod.decode_step(params, cache, tokens, cfg)
                 with jax.named_scope("sample"):
                     key, sub = jax.random.split(key)
                     greedy = jnp.argmax(logits, axis=-1)
@@ -200,17 +235,17 @@ class LLMEngine:
 
         self._step = _step_guarded
 
-        def insert_row(cache, row_k, row_v, slot, length, start):
-            """Graft a freshly prefilled request's KV rows into `slot` of
-            the persistent cache and reset that row's depth/start."""
-            return {
-                "k": jax.lax.dynamic_update_slice(
-                    cache["k"], row_k, (0, slot, 0, 0, 0)),
-                "v": jax.lax.dynamic_update_slice(
-                    cache["v"], row_v, (0, slot, 0, 0, 0)),
-                "length": cache["length"].at[slot].set(length),
-                "start": cache["start"].at[slot].set(start),
-            }
+        def insert_row(cache, row, slot, length, start):
+            """Graft a freshly prefilled request's row (each leaf along
+            its own batch axis; K and V as deep as the bucket, recurrent
+            leaves whole) into `slot` of the persistent cache and reset
+            that row's depth/start."""
+            out = {name: jax.lax.dynamic_update_slice_in_dim(
+                cache[name], leaf, slot, self._batch_axis[name])
+                for name, leaf in row.items()}
+            out["length"] = cache["length"].at[slot].set(length)
+            out["start"] = cache["start"].at[slot].set(start)
+            return out
 
         self._insert_row = jax.jit(insert_row, donate_argnums=(0,))
 
@@ -246,7 +281,8 @@ class LLMEngine:
 
         from ray_tpu.serve.handle import prefix_block_tokens
 
-        self.prefix_cache_entries = int(prefix_cache_entries)
+        self.prefix_cache_entries = 0 if recurrent else int(
+            prefix_cache_entries)
         self._prefix_block = prefix_block_tokens()
         self._prefix_store: "OrderedDict[tuple, dict]" = OrderedDict()
         # perf counters (for the serve bench)
@@ -342,7 +378,8 @@ class LLMEngine:
                            temperature: float = 0.0) -> dict:
         """Run ONLY the prefill (chunked as configured, prefix reuse
         included) and return the KV handoff payload instead of decoding:
-        ``{"k", "v", "first", "bucket", "start"}``. This is the
+        ``{"row", "first", "bucket", "start"}``, `row` the request's
+        leaves of the model's cache. This is the
         prefill-pool half of a disaggregated deployment — feed the
         payload to a decode pool's `generate_prefilled`."""
         limit = max(self.prompt_buckets)
@@ -448,8 +485,8 @@ class LLMEngine:
     # ------------------------------------------------------- the hot path
     def _ensure_decode_cache(self):
         if self._decode_cache is None:
-            cache = llama.init_kv_cache(self.cfg, self.max_batch,
-                                        max_len=self.cfg.max_seq_len)
+            cache = self._model.init_cache(self.cfg, self.max_batch,
+                                           max_len=self.cfg.max_seq_len)
             # per-row depths: each slot is an independent request
             cache["length"] = jnp.zeros((self.max_batch,), jnp.int32)
             self._decode_cache = jax.device_put(cache, self._cache_sharding)
@@ -495,7 +532,7 @@ class LLMEngine:
         prompts = np.zeros((1, bucket), np.int32)
         prompts[0, start:] = toks
 
-        small = llama.init_kv_cache(self.cfg, 1, max_len=bucket)
+        small = self._model.init_cache(self.cfg, 1, max_len=bucket)
         small["start"] = jnp.asarray([start], jnp.int32)
         small = jax.device_put(small, self._cache_sharding)
         entry, matched = self._prefix_lookup(toks)
@@ -521,7 +558,8 @@ class LLMEngine:
             # long prompt: reserve the slot, prefill chunk-by-chunk
             # between decode steps (engine loop drives _advance_prefill).
             # Left-pad chunks are skipped entirely: they carry no
-            # information (masked by `start`), so begin at the last
+            # information (masked by `start`, and they leave a recurrent
+            # state at the zero it starts from), so begin at the last
             # chunk boundary before the first real token.
             if not matched:
                 pos = (start // self.prefill_chunk) * self.prefill_chunk
@@ -583,9 +621,9 @@ class LLMEngine:
         Runs op-by-op outside jit (concrete sizes; one dispatch pair per
         distinct (bucket, matched) — bounded by the block grid)."""
         e_off = int(entry["start"])
-        for key_, axis in llama.KV_LEN_AXIS.items():
+        for key_, axis in self._len_axis.items():
             seg = jax.lax.dynamic_slice_in_dim(
-                entry[key_], e_off, matched, axis)
+                entry["row"][key_], e_off, matched, axis)
             small[key_] = jax.lax.dynamic_update_slice_in_dim(
                 small[key_], seg, off, axis)
         small["length"] = jnp.int32(off + matched)
@@ -601,7 +639,7 @@ class LLMEngine:
             return
         key = tuple(tokens[:block])
         self._prefix_store[key] = {
-            "tokens": list(tokens), "k": small["k"], "v": small["v"],
+            "tokens": list(tokens), "row": self._row(small),
             "start": bucket - len(tokens), "bucket": bucket}
         self._prefix_store.move_to_end(key)
         while len(self._prefix_store) > self.prefix_cache_entries:
@@ -609,12 +647,10 @@ class LLMEngine:
 
     def _admit_prefilled_locked(self, req: _Request, slot: int):
         h = req.prefilled
-        kv = jax.device_put(
-            {"k": h["k"], "v": h["v"]},
-            {"k": self._cache_sharding["k"],
-             "v": self._cache_sharding["v"]})
+        row = jax.device_put(
+            dict(h["row"]), {k: self._cache_sharding[k] for k in h["row"]})
         self.kv_handoffs += 1
-        self._finish_prefill(req, slot, kv, int(h["first"]),
+        self._finish_prefill(req, slot, row, int(h["first"]),
                              int(h["bucket"]), int(h["start"]),
                              store=False)
 
@@ -654,14 +690,19 @@ class LLMEngine:
                     else RuntimeError(repr(e)))
                 raise
 
+    def _row(self, small: dict) -> dict:
+        """A batch-1 cache's leaves without the bookkeeping."""
+        return {k: small[k] for k in self._batch_axis}
+
     def _finish_prefill(self, req: _Request, slot: int, small, first,
                         bucket: int, start: int, store: bool = True):
-        """Deliver the prefill's sampled token and graft the KV rows
-        into the slot (callers hold _mutex). `first` is the step's
+        """Deliver the prefill's sampled token and graft the request's
+        row into the slot (callers hold _mutex). `first` is the step's
         sampled-token array, read here (the host waits for the prefill),
         or the int a prefill pool already read."""
+        row = self._row(small)
         with _span("rayt.engine.finish_prefill", request_id=req.request_id,
-                   slot=slot):
+                   slot=slot, row_bytes=sum(a.nbytes for a in row.values())):
             if not isinstance(first, int):
                 first = int(np.asarray(first)[0])
             if store:
@@ -675,7 +716,7 @@ class LLMEngine:
                 # it via generate_prefilled. No slot, no insert, no decode.
                 req.loop.call_soon_threadsafe(
                     req.out.put_nowait,
-                    {"k": small["k"], "v": small["v"], "first": int(first),
+                    {"row": row, "first": int(first),
                      "bucket": int(bucket), "start": int(start)})
                 req.loop.call_soon_threadsafe(req.out.put_nowait, None)
                 return
@@ -694,7 +735,7 @@ class LLMEngine:
                 return
             try:
                 self._decode_cache = self._insert_row(
-                    self._decode_cache, small["k"], small["v"],
+                    self._decode_cache, row,
                     jnp.int32(slot), jnp.int32(bucket), jnp.int32(start))
             except BaseException:
                 # insert_row donates the shared cache: a failure here loses
@@ -800,6 +841,8 @@ class LLMEngine:
                 "prefix_misses": self.prefix_misses,
                 "prefix_hit_tokens": self.prefix_hit_tokens,
                 "prefix_entries": len(self._prefix_store),
+                "prefix_cache_entries": self.prefix_cache_entries,
+                "cache_bytes": dict(self._cache_bytes),
                 "kv_handoffs": self.kv_handoffs,
                 "active_slots": sum(1 for s in self._slots
                                     if s is not None),
@@ -809,7 +852,7 @@ class LLMEngine:
 def greedy_reference_check(engine: "LLMEngine", tokens: list[int],
                            generated: list[int]) -> dict:
     """Hold a finished greedy request against the training-path model:
-    one `llama.forward` over prompt + generated with the engine's own
+    one `forward` of the engine's model over prompt + generated with its own
     params (no KV cache, no left padding, `_block`'s attention instead
     of `_decode_block`'s). Position by position, the token the engine
     emitted must be the argmax of the reference's logits given the same
@@ -831,7 +874,7 @@ def greedy_reference_check(engine: "LLMEngine", tokens: list[int],
         # under the engine's mesh: with tp > 1 a flash kernel in the
         # reference has to be split per shard (ops/attention.py)
         with jax.sharding.use_abstract_mesh(engine.mesh.abstract_mesh):
-            return llama.forward(params, toks, cfg)
+            return engine._model.forward(params, toks, cfg)
 
     logits = jax.jit(forward)(engine.params, jnp.asarray(toks))
     rows = np.asarray(logits[0, len(tokens) - 1:len(seq) - 1], np.float32)
@@ -874,8 +917,8 @@ class LlamaService:
     "temperature": float} -> streams {"token": id} dicts.
     """
 
-    def __init__(self, preset: str = "debug", **engine_kw):
-        self.engine = LLMEngine(preset, **engine_kw)
+    def __init__(self, model: Any = "debug", **engine_kw):
+        self.engine = LLMEngine(model, **engine_kw)
 
     async def __call__(self, payload: dict):
         tokens = payload["tokens"]
@@ -909,9 +952,10 @@ class LlamaService:
         return greedy_reference_check(self.engine, tokens, generated)
 
 
-def llm_app(preset: str = "debug", *, num_replicas: int = 1,
+def llm_app(model: Any = "debug", *, num_replicas: int = 1,
             max_ongoing_requests: int = 64, **engine_kw):
-    """Build a Serve application for a TP-sharded Llama."""
+    """Build a Serve application for one model behind an LLMEngine:
+    `model` is a model config or the name of a llama preset."""
     from ray_tpu.serve.deployment import deployment
 
     dep = deployment(
@@ -920,7 +964,7 @@ def llm_app(preset: str = "debug", *, num_replicas: int = 1,
         max_ongoing_requests=max_ongoing_requests,
         ray_actor_options=_replica_chips(engine_kw),
     )
-    return dep.bind(preset, **engine_kw)
+    return dep.bind(model, **engine_kw)
 
 
 class MultiplexedLoraService:
@@ -1058,8 +1102,8 @@ class PrefillWorker:
     ``{"bytes", "edge_kind", "n_arrays", "bucket", "start"}``.
     """
 
-    def __init__(self, preset: str = "debug", **engine_kw):
-        self.engine = LLMEngine(preset, **engine_kw)
+    def __init__(self, model: Any = "debug", **engine_kw):
+        self.engine = LLMEngine(model, **engine_kw)
 
     async def __call__(self, payload: dict) -> dict:
         from ray_tpu.dag.dcn_channel import attach_channel
@@ -1069,7 +1113,7 @@ class PrefillWorker:
         tokens = [int(t) for t in payload["tokens"]]
         handoff = await self.engine.prefill_only(
             tokens, temperature=float(payload.get("temperature", 0.0)))
-        nbytes = int(tree_nbytes({"k": handoff["k"], "v": handoff["v"]}))
+        nbytes = int(tree_nbytes(handoff["row"]))
         loop = asyncio.get_running_loop()
         ch = await loop.run_in_executor(None, attach_channel, spec)
         kind = _edge_kind(ch, spec)
@@ -1109,15 +1153,16 @@ class DecodeLlamaService:
     prefills can no longer dip this pool's decode-batch occupancy.
     """
 
-    def __init__(self, prefill, preset: str = "debug", **engine_kw):
-        self.engine = LLMEngine(preset, **engine_kw)
+    def __init__(self, prefill, model: Any = "debug", **engine_kw):
+        self.engine = eng = LLMEngine(model, **engine_kw)
         self._prefill = prefill  # DeploymentHandle (composed app node)
-        cfg = self.engine.cfg
-        bucket = max(self.engine.prompt_buckets)
-        # one tick = one prompt's k+v rows (+ pickle framing): assume
-        # <=4-byte elements and pad 25% + 64KiB so the slot always fits
-        kv = 2 * cfg.n_layers * bucket * cfg.n_kv_heads * cfg.head_dim * 4
-        self._slot_size = kv + kv // 4 + (1 << 16)
+        # one tick = one prompt's row at the largest bucket, recurrent
+        # leaves included (+ pickle framing): pad 25% + 64KiB so the
+        # slot always fits
+        row = eng._row(jax.eval_shape(lambda: eng._model.init_cache(
+            eng.cfg, 1, max_len=max(eng.prompt_buckets))))
+        nbytes = sum(a.size * a.dtype.itemsize for a in row.values())
+        self._slot_size = nbytes + nbytes // 4 + (1 << 16)
 
     def _request_context(self, obs) -> Optional[dict]:
         if not obs or not obs.get("request_id"):
@@ -1170,8 +1215,7 @@ class DecodeLlamaService:
             obs["pool"] = "decode"
         async for tok in self.engine.generate_prefilled(
                 tokens,
-                {k: tick[k] for k in ("k", "v", "first", "bucket",
-                                      "start")},
+                {k: tick[k] for k in ("row", "first", "bucket", "start")},
                 max_new_tokens=int(payload.get("max_new_tokens", 32)),
                 temperature=float(payload.get("temperature", 0.0))):
             yield {"token": int(tok)}
@@ -1180,7 +1224,7 @@ class DecodeLlamaService:
         return self.engine.stats()
 
 
-def disagg_llm_app(preset: str = "debug", *,
+def disagg_llm_app(model: Any = "debug", *,
                    prefill_replicas: int | None = None,
                    decode_replicas: int | None = None,
                    max_ongoing_requests: int = 64, **engine_kw):
@@ -1189,7 +1233,7 @@ def disagg_llm_app(preset: str = "debug", *,
     prefill pool and hands its KV rows over a device-channel edge. Pool
     sizes default from RAYT_SERVE_PREFILL_REPLICAS /
     RAYT_SERVE_DECODE_REPLICAS (1 each). Both pools build identical
-    weights (same preset + seed), so KV rows graft across them."""
+    weights (same model + seed), so rows graft across them."""
     from ray_tpu.serve.deployment import deployment
 
     if prefill_replicas is None:
@@ -1205,5 +1249,5 @@ def disagg_llm_app(preset: str = "debug", *,
         DecodeLlamaService, num_replicas=decode_replicas,
         max_ongoing_requests=max_ongoing_requests,
         ray_actor_options=chips)
-    return decode_dep.bind(prefill_dep.bind(preset, **engine_kw),
-                           preset, **engine_kw)
+    return decode_dep.bind(prefill_dep.bind(model, **engine_kw),
+                           model, **engine_kw)

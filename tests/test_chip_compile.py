@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.models import llama
+from ray_tpu.models import granite_hybrid, llama
 from ray_tpu.ops.pallas import flash_attention as fa
 
 HBM_BYTES = 16 * 2**30  # one v5e chip
@@ -177,3 +177,67 @@ def test_decode_step_moves_no_cache(chip, batch, max_len, s, per_row):
                                   "dynamic-update-slice"), line[:200]
         elif max_len in dims and cfg.head_dim in dims:
             assert math.prod(dims) < repeated, line[:200]
+
+
+# granite-4.0-h-micro, whole, as the benchmark's serve cell holds it
+_HYBRID_PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+
+
+@pytest.mark.parametrize("batch,max_len,s,per_row", [
+    (32, 4096, 1, True),      # the engine's decode step, 32 slots
+    (1, 1024, 256, False),    # one chunk of a prompt's prefill
+], ids=["decode-32x4096", "chunk-1x1024-s256"])
+def test_hybrid_decode_step_moves_no_cache_and_no_state(chip, batch,
+                                                        max_len, s, per_row):
+    """granite_hybrid.decode_step under the rule llama's is held to: with
+    the cache donated, K, V, the recurrent state (2.4 GB for 32 slots)
+    and the convolution tails are updated in their own buffers, the
+    step's temporaries hold less than ONE layer's state for 32 slots (a
+    chunk's activations, 10 MB, need no more either), and nothing but
+    the in-place writes produces an array of a whole stack's shape
+    (stacks under 16 MB aside: the compiler re-lays a batch-1 tail
+    stack, 0.9 MB, out for the chunk's convolution).
+    (With the input projection held fused, [36, 2048, 8512], the
+    compiler copied that whole stack, 1.25 GB, on every step: PERF.md,
+    PR 28.)"""
+    cfg = granite_hybrid.GraniteHybridConfig(
+        layer_types=_HYBRID_PERIOD * 4, max_seq_len=max_len)
+    on = SingleDeviceSharding(chip)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=on), tree)
+
+    params = place(jax.eval_shape(
+        lambda key: granite_hybrid.init_params(cfg, key),
+        jax.random.PRNGKey(0)))
+    cache = dict(jax.eval_shape(
+        lambda: granite_hybrid.init_cache(cfg, batch, max_len)))
+    if per_row:
+        cache["length"] = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((batch, s), jnp.int32, sharding=on)
+    compiled = jax.jit(
+        lambda p, c, t: granite_hybrid.decode_step(p, c, t, cfg),
+        donate_argnums=(1,)).lower(params, place(cache), tokens).compile()
+
+    rows = ("k", "v", "state", "conv")
+    nbytes = {key: math.prod(cache[key].shape) * cache[key].dtype.itemsize
+              for key in rows}
+    stacks = {tuple(cache[key].shape) for key in rows
+              if nbytes[key] > 16e6}
+    stack_bytes = sum(nbytes.values())
+    layer_state = 32 * 4 * math.prod(cache["state"].shape[2:])
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < HBM_BYTES)
+    assert mem.temp_size_in_bytes < layer_state
+    assert mem.alias_size_in_bytes >= stack_bytes
+    for line in compiled.as_text().splitlines():
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if dims in stacks and m.group(3) != "fusion":
+            assert m.group(3) in ("parameter", "get-tuple-element",
+                                  "dynamic-update-slice", "bitcast"), \
+                line[:200]

@@ -788,8 +788,7 @@ def run_disagg(*, streams: int = 4, stream_new_tokens: int = 100,
                 tick = await loop.run_in_executor(
                     None, lambda: ch.read(timeout=30.0))
                 handoffs.append(
-                    {"bytes": int(tree_nbytes({"k": h["k"],
-                                               "v": h["v"]})),
+                    {"bytes": int(tree_nbytes(h["row"])),
                      "edge_kind": _edge_kind(prod, spec),
                      "n_arrays": int(prod.device_arrays)})
                 return tick
