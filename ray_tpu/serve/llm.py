@@ -96,6 +96,15 @@ class _PendingPrefill:
     pos: int = 0            # tokens already prefilled
 
 
+@dataclass
+class _InFlight:
+    """A decode step the device has been given and the host has not
+    read yet."""
+    tokens: Any             # the step's sampled tokens, [max_batch] on device
+    rows: list              # per row, the _Request it was dispatched for
+    active: int             # how many rows that is
+
+
 class LLMEngine:
     """Continuously-batched TP generation engine over the local device
     mesh.
@@ -108,6 +117,14 @@ class LLMEngine:
     batch to drain. Finished slots free immediately and refill from the
     queue between steps. Static shapes throughout: one decode trace
     ever, one prefill + insert trace per prompt bucket.
+
+    Decoding is a pipeline of depth one (`_decode_step_locked`): step
+    k+1 is dispatched, fed by step k's tokens where they lie on the
+    device, BEFORE the host reads step k's tokens to stream them, so the
+    device works through the read, the emit loop and the event loop's
+    turn. `stats()` counts how often the pipeline was full
+    (`decode_overlapped` of `batches`) and what the look-ahead wasted
+    (`decode_rows_discarded`).
 
     `model` is a model config (a `llama.LlamaConfig`, a
     `granite_hybrid.GraniteHybridConfig`) or the name of a llama preset;
@@ -209,15 +226,17 @@ class LLMEngine:
                     return nxt.astype(jnp.int32), cache, key
 
         # one jit; prefill (s=bucket) and decode (s=1) are separate traces
-        # of the same function, cached per shape. Donation keeps the
-        # decode state ON-CHIP between ticks: cache (1), tokens (2) and
-        # PRNG key (3) are all rebound from the return at every call
-        # site, and the step overwrites them — decode_step writes the
-        # new rows into the donated cache's own buffers and makes no
-        # copy of it, so a cache handed to a step is gone, whole, even
-        # when the step fails. temps (4) is NOT donated: decode reuses
-        # it across steps.
-        self._step_jit = jax.jit(step, donate_argnums=(1, 2, 3))
+        # of the same function, cached per shape. Donated: the cache (1)
+        # and the PRNG key (3), both rebound from the return at every
+        # call site — decode_step writes the new rows into the donated
+        # cache's own buffers and makes no copy of it, so a cache handed
+        # to a step is gone, whole, even when the step fails. NOT
+        # donated: tokens (2), because a decode step's sampled tokens
+        # feed the next step before the host has read them (the step in
+        # flight, _decode_step_locked) and a donated array cannot be
+        # read afterwards; and temps (4), which decode reuses across
+        # steps.
+        self._step_jit = jax.jit(step, donate_argnums=(1, 3))
         self._key_seed = seed ^ 0x5EED
         self._key_reseeds = 0
 
@@ -252,7 +271,8 @@ class LLMEngine:
         def set_slot(cur, temps, slot, tok, temp):
             return cur.at[slot].set(tok), temps.at[slot, 0].set(temp)
 
-        self._set_slot = jax.jit(set_slot, donate_argnums=(0, 1))
+        # `cur` may be the unread tokens of the step in flight: not donated
+        self._set_slot = jax.jit(set_slot, donate_argnums=(1,))
         self._queue: asyncio.Queue[_Request] = None  # type: ignore
         self._task = None
         self._loop = None
@@ -269,6 +289,9 @@ class LLMEngine:
         self._cur = jnp.zeros((max_batch,), jnp.int32)
         self._temps = jnp.zeros((max_batch, 1), jnp.float32)
         self._key = jax.random.PRNGKey(seed ^ 0x5EED)
+        # the decode step dispatched and not read yet; whenever it is
+        # set, some slot still holds a request one of its rows is for
+        self._inflight: Optional[_InFlight] = None
         self._pending_prefills: list[_PendingPrefill] = []
         # prefix KV cache: completed prefills park their small-cache
         # rows here (LRU, `prefix_cache_entries` deep) keyed by the
@@ -287,7 +310,12 @@ class LLMEngine:
         self._prefix_store: "OrderedDict[tuple, dict]" = OrderedDict()
         # perf counters (for the serve bench)
         self.generated_tokens = 0
-        self.batches = 0       # decode steps executed
+        self.batches = 0       # decode steps dispatched
+        # of those, read while a later step was already dispatched
+        self.decode_overlapped = 0
+        # rows of a look-ahead step whose request had ended (eos) by
+        # the time the step was read or dropped: the wasted work
+        self.decode_rows_discarded = 0
         self.prefills = 0
         self.prefill_chunks = 0
         self.prefix_hits = 0
@@ -332,6 +360,7 @@ class LLMEngine:
                         except asyncio.QueueEmpty:
                             break
                 self._slots = [None] * self.max_batch
+                self._inflight = None
                 self._decode_cache = None
                 self._cur = jnp.zeros((self.max_batch,), jnp.int32)
                 self._temps = jnp.zeros((self.max_batch, 1), jnp.float32)
@@ -438,8 +467,11 @@ class LLMEngine:
 
     async def _engine_loop(self):
         """Continuous-batching scheduler: admit into free slots between
-        decode steps; a late-arriving request starts decoding one step
-        after its prefill, regardless of how deep the other slots are."""
+        decode rounds; a late-arriving request starts decoding at the
+        step after the one in flight at its prefill, regardless of how
+        deep the other slots are. A slot stays taken until its
+        request's last token is emitted, so "some slot decodes" also
+        covers a step in flight that is still to be read."""
         loop = asyncio.get_running_loop()
         epoch = self._epoch
         queue = self._queue  # bound once: after a rebind self._queue is
@@ -698,8 +730,12 @@ class LLMEngine:
                         bucket: int, start: int, store: bool = True):
         """Deliver the prefill's sampled token and graft the request's
         row into the slot (callers hold _mutex). `first` is the step's
-        sampled-token array, read here (the host waits for the prefill),
-        or the int a prefill pool already read."""
+        sampled-token array, read here (the host waits for the prefill
+        and for the decode step in flight ahead of it: the pipeline
+        drains in this round), or the int a prefill pool already read.
+        insert_row and set_slot are queued behind the step in flight and
+        apply to its outputs (`_decode_cache`, `_cur`), so device order
+        makes the graft safe and the request joins at the step after."""
         row = self._row(small)
         with _span("rayt.engine.finish_prefill", request_id=req.request_id,
                    slot=slot, row_bytes=sum(a.nbytes for a in row.values())):
@@ -769,6 +805,7 @@ class LLMEngine:
             pf.req.loop.call_soon_threadsafe(pf.req.out.put_nowait, err)
         self._pending_prefills = []
         self._slots = [None] * self.max_batch
+        self._inflight = None
         self._decode_cache = None
         self._cur = jnp.zeros((self.max_batch,), jnp.int32)
         self._temps = jnp.zeros((self.max_batch, 1), jnp.float32)
@@ -780,36 +817,88 @@ class LLMEngine:
             self._decode_step_locked()
 
     def _decode_step_locked(self):
-        """One decode step across all slots (free rows compute masked
-        garbage — the price of a single static-shape trace)."""
-        active = sum(1 for s in self._slots
-                     if s is not None and s.emitted >= 0)
+        """One round of the decode pipeline: dispatch step k+1, fed by
+        step k's tokens where they lie on the device, THEN read step k's
+        tokens and emit them. The device holds the next step queued
+        while the host waits for the read, runs the emit loop and gives
+        the event loop its turn. An admission (insert_row, set_slot) is
+        queued behind the step in flight and applies to its outputs, so
+        the admitted request joins at the step after."""
+        prev = self._inflight
         try:
-            # t_host is this process's perf_counter read as the span
-            # opens: the one event that carries both clocks, so request
-            # records and a client's stamps (CLOCK_MONOTONIC, one clock
-            # for the host) can be laid on the profiler's time axis
-            with _span("rayt.engine.decode_dispatch", active=active,
-                       t_host=time.perf_counter()):
-                nxt, self._decode_cache, self._key = self._step(
-                    self.params, self._decode_cache, self._cur,
-                    self._key, self._temps)
+            self._inflight = self._dispatch_decode(prev)
+            if prev is None:
+                return
+            # a device fault of the step just dispatched surfaces at a
+            # later read: this one, or _finish_prefill's
+            with _span("rayt.engine.token_sync", active=prev.active):
+                toks = np.asarray(prev.tokens)  # host sync: step k's tokens
         except BaseException:
             self._poison_recover()
             raise
-        with _span("rayt.engine.token_sync", active=active):
-            toks = np.asarray(nxt)  # host sync: this step's sampled tokens
+        if self._inflight is not None:
+            self.decode_overlapped += 1
+        self._emit(prev, toks)
+        ahead = self._inflight
+        if ahead is not None and not self._owned_rows(ahead):
+            # every request of the look-ahead step ended at this read
+            # (eos): nobody waits for its tokens, so no host read
+            self.decode_rows_discarded += ahead.active
+            self._inflight = None
+
+    def _dispatch_decode(self, prev: Optional[_InFlight]):
+        """Dispatch a decode step across all slots (free rows compute
+        masked garbage — the price of a single static-shape trace) for
+        the rows that are live in it, and return its record; None, and
+        no dispatch, when no row is. A row that gets its last token from
+        `prev`, the step in flight, is not live: the ends the host can
+        count are known here, an eos only at the read."""
+        rows: list = [None] * self.max_batch
+        for i, s in enumerate(self._slots):
+            if s is None or s.emitted < 0:  # free or mid-prefill
+                continue
+            if (prev is not None and prev.rows[i] is s.req
+                    and (s.emitted + 1 >= s.req.max_new_tokens
+                         or s.length + 1 >= self.cfg.max_seq_len - 1)):
+                continue
+            rows[i] = s.req
+        active = sum(1 for r in rows if r is not None)
+        if not active:
+            return None
+        # t_host is this process's perf_counter read as the span opens:
+        # the one event that carries both clocks, so request records and
+        # a client's stamps (CLOCK_MONOTONIC, one clock for the host)
+        # can be laid on the profiler's time axis
+        with _span("rayt.engine.decode_dispatch", active=active,
+                   t_host=time.perf_counter()):
+            nxt, self._decode_cache, self._key = self._step(
+                self.params, self._decode_cache, self._cur,
+                self._key, self._temps)
         self._cur = nxt  # stays on device for the next step
         self.batches += 1
+        return _InFlight(nxt, rows, active)
+
+    def _owned_rows(self, rec: _InFlight) -> list:
+        """(row, slot) for every row of a dispatched step whose slot
+        still holds the request the row was dispatched for. The other
+        rows' requests ended (eos) while the step was in flight: their
+        slots are free, or taken by a later admission that must see
+        none of their tokens."""
+        return [(i, s) for i, s in enumerate(self._slots)
+                if s is not None and s.req is rec.rows[i]]
+
+    def _emit(self, rec: _InFlight, toks):
+        """Hand a read step's tokens to the requests its rows were
+        dispatched for, and free the slots of those that end."""
         # occupancy of THIS step, stamped into each participant's obs:
         # mean over a request's steps = how full its decode batches ran
-        occupancy = active / self.max_batch
-        with _span("rayt.engine.emit", active=active) as span:
+        occupancy = rec.active / self.max_batch
+        with _span("rayt.engine.emit", active=rec.active) as span:
             now = time.perf_counter()
             finished = 0
-            for i, s in enumerate(self._slots):
-                if s is None or s.emitted < 0:  # free or mid-prefill
-                    continue
+            owned = self._owned_rows(rec)
+            self.decode_rows_discarded += rec.active - len(owned)
+            for i, s in owned:
                 t = int(toks[i])
                 s.length += 1
                 if self.eos_token_id is not None and t == self.eos_token_id:
@@ -844,6 +933,8 @@ class LLMEngine:
                 "prefix_cache_entries": self.prefix_cache_entries,
                 "cache_bytes": dict(self._cache_bytes),
                 "kv_handoffs": self.kv_handoffs,
+                "decode_overlapped": self.decode_overlapped,
+                "decode_rows_discarded": self.decode_rows_discarded,
                 "active_slots": sum(1 for s in self._slots
                                     if s is not None),
                 "tp": self.mesh.shape.get("tensor", 1)}

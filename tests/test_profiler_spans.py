@@ -58,10 +58,11 @@ def test_helper_is_the_profilers_own_span_and_nothing_else(tmp_path):
     assert os.listdir(tmp_path) == []
 
 
-def test_engine_writes_every_span_into_the_profilers_trace(tmp_path):
-    """(a) a debug engine run under a profiler session: a short prompt
+def _traced_engine_run(tmp_path, max_new_tokens=4):
+    """A debug engine run under a profiler session: a short prompt
     (one-shot prefill inside admit) and a chunked one (prefill_chunk
-    from the engine loop), a few decode steps each."""
+    from the engine loop), a few decode steps each. -> (engine, its
+    stats() before the traced run, the streams)."""
     eng = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=256,
                     prompt_buckets=(16, 64), prefill_chunk=16,
                     prefix_cache_entries=0)
@@ -69,7 +70,8 @@ def test_engine_writes_every_span_into_the_profilers_trace(tmp_path):
     async def one(rid, tokens):
         token = request_context._set_request_obs({"request_id": rid})
         try:
-            return [t async for t in eng.generate(tokens, max_new_tokens=4)]
+            return [t async for t in eng.generate(
+                tokens, max_new_tokens=max_new_tokens)]
         finally:
             request_context._reset_request_obs(token)
 
@@ -78,11 +80,19 @@ def test_engine_writes_every_span_into_the_profilers_trace(tmp_path):
                                     one("long", list(range(1, 41))))
 
     asyncio.run(run())          # compile outside the session
+    before = eng.stats()
     jax.profiler.start_trace(str(tmp_path), profiler_options=_trace_options())
     try:
         outs = asyncio.run(run())
     finally:
         jax.profiler.stop_trace()
+    return eng, before, outs
+
+
+def test_engine_writes_every_span_into_the_profilers_trace(tmp_path):
+    """(a) every engine span, with its fields, nested as the readers
+    expect."""
+    _, _, outs = _traced_engine_run(tmp_path)
     assert [len(o) for o in outs] == [4, 4]
 
     spans = _host_spans(tmp_path)
@@ -121,6 +131,43 @@ def test_engine_writes_every_span_into_the_profilers_trace(tmp_path):
              and st.get("request_id") == "short"]
     assert set(inner) == {"rayt.engine.admit", "rayt.engine.prefill_chunk",
                           "rayt.engine.finish_prefill"}
+
+
+def test_token_sync_of_a_step_follows_the_dispatch_of_the_next(tmp_path):
+    """The decode pipeline in the trace: the token_sync span that reads
+    step k opens after the decode_dispatch span of step k+1 has closed,
+    whenever a row was live in step k+1; `decode_overlapped` counts
+    exactly those reads."""
+    eng, before, outs = _traced_engine_run(tmp_path, max_new_tokens=12)
+    assert [len(o) for o in outs] == [12, 12]
+    by_name: dict = {}
+    for _, name, start, end, stats in sorted(_host_spans(tmp_path),
+                                             key=lambda s: s[2]):
+        by_name.setdefault(name.removeprefix("rayt.engine."), []).append(
+            (start, end, stats))
+    dispatch, sync, emit = (by_name[n] for n in
+                            ("decode_dispatch", "token_sync", "emit"))
+    # every dispatched step is read once, in order, and then emitted
+    assert len(dispatch) == len(sync) == len(emit) >= 12
+    overlapped = 0
+    for k, (s_start, s_end, s_stats) in enumerate(sync):
+        assert dispatch[k][1] <= s_start            # its own dispatch
+        assert s_end <= emit[k][0]
+        assert s_stats["active"] == dispatch[k][2]["active"] \
+            == emit[k][2]["active"]
+        if k + 1 < len(dispatch) and dispatch[k + 1][1] <= s_start:
+            overlapped += 1
+        else:
+            # read with no later step dispatched: no row was live in
+            # one, i.e. every row of step k got its last token from it
+            assert emit[k][2]["finished"] == emit[k][2]["active"]
+            assert k + 1 == len(dispatch) or emit[k][1] <= dispatch[k + 1][0]
+    st = eng.stats()
+    steps = st["batches"] - before["batches"]
+    assert steps == len(dispatch)
+    assert st["decode_overlapped"] - before["decode_overlapped"] == overlapped
+    assert overlapped >= steps - (st["prefills"] - before["prefills"]) - 1
+    assert st["decode_rows_discarded"] == 0
 
 
 def test_step_recorder_phases_and_report_are_spans(tmp_path):

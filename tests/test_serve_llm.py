@@ -25,22 +25,9 @@ def test_engine_greedy_matches_unbatched_decode():
     """Batched left-padded generation must equal a plain single-sequence
     greedy decode with the same params."""
     eng = LLMEngine("debug", tp=2, max_batch=4)
-    cfg = eng.cfg
     prompt = [5, 9, 11, 42, 7]
     got = _collect(eng, prompt, max_new_tokens=8)
-
-    # plain reference decode: no padding, batch 1
-    params = jax.device_get(eng.params)
-    cache = llama.init_kv_cache(cfg, 1, max_len=cfg.max_seq_len)
-    toks = np.asarray([prompt], np.int32)
-    logits, cache = llama.decode_step(params, cache, toks, cfg)
-    want = []
-    for _ in range(8):
-        nxt = int(np.argmax(np.asarray(logits)[0]))
-        want.append(nxt)
-        logits, cache = llama.decode_step(
-            params, cache, np.asarray([[nxt]], np.int32), cfg)
-    assert got == want
+    assert got == _greedy_reference(eng, prompt, 8)
 
 
 def test_engine_batches_concurrent_requests():
@@ -238,3 +225,241 @@ def test_chunked_prefill_interleaves_with_decode():
     mono = _collect(eng2, prompt, max_new_tokens=6)
     chunked = _collect(eng3, prompt, max_new_tokens=6)
     assert mono == chunked
+
+
+# --------------------------------------------------------------------------
+# The decode pipeline (one step in flight): what the streams see, and faults
+# --------------------------------------------------------------------------
+_reference_step = jax.jit(llama.decode_step, static_argnums=3)
+
+
+def _greedy_reference(eng, prompt, max_new_tokens):
+    """What the engine must stream for one greedy request, from a plain
+    unbatched, unpadded decode with the engine's params, ended as the
+    engine ends a request: eos is not emitted, `max_new_tokens` counts
+    the prefill's token, and a row's depth counts from its bucket."""
+    cfg, eos = eng.cfg, eng.eos_token_id
+    params = jax.device_get(eng.params)
+    cache = llama.init_kv_cache(cfg, 1, max_len=cfg.max_seq_len)
+    logits, cache = _reference_step(
+        params, cache, np.asarray([prompt], np.int32), cfg)
+    length = next(b for b in eng.prompt_buckets if len(prompt) <= b)
+    out = []
+    while True:
+        t = int(np.argmax(np.asarray(logits)[0]))
+        if t == eos:
+            return out
+        out.append(t)
+        if len(out) >= max_new_tokens or (
+                len(out) > 1 and length >= cfg.max_seq_len - 1):
+            return out
+        logits, cache = _reference_step(
+            params, cache, np.asarray([[t]], np.int32), cfg)
+        length += 1
+
+
+async def _when(cond):
+    for _ in range(3000):
+        if cond():
+            return
+        await asyncio.sleep(0.01)
+    raise AssertionError("the engine never got there")
+
+
+def _case_late_arrival():
+    eng = LLMEngine("debug", tp=1, max_batch=4)
+    reqs = [([1, 2, 3], 40), ([7, 7], 5)]
+
+    async def run():
+        first = asyncio.ensure_future(_agen_list(
+            eng.generate(reqs[0][0], max_new_tokens=reqs[0][1])))
+        await _when(lambda: eng.batches >= 5)
+        late = await _agen_list(
+            eng.generate(reqs[1][0], max_new_tokens=reqs[1][1]))
+        assert not first.done()
+        return [await first, late]
+    return eng, reqs, run
+
+
+def _case_max_new(n):
+    def case():
+        eng = LLMEngine("debug", tp=1, max_batch=2)
+        reqs = [([4, 8, 15, 16], 9), ([23, 42], n)]
+
+        async def run():
+            return await asyncio.gather(*[
+                _agen_list(eng.generate(p, max_new_tokens=m))
+                for p, m in reqs])
+        return eng, reqs, run
+    return case
+
+
+def _case_ends_at_max_seq_len():
+    # bucket 32 of 40 positions: the row ends at depth 39, after the
+    # prefill's token and seven decode steps, whatever was asked for
+    eng = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=40,
+                    prompt_buckets=(32,))
+    reqs = [([5, 9, 11, 42, 7], 30), ([3, 1, 4], 4)]
+
+    async def run():
+        outs = await asyncio.gather(*[
+            _agen_list(eng.generate(p, max_new_tokens=m))
+            for p, m in reqs])
+        assert len(outs[0]) == 8
+        return outs
+    return eng, reqs, run
+
+
+def _case_chunked_prefill():
+    eng = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=512,
+                    prompt_buckets=(32, 256), prefill_chunk=64,
+                    prefix_cache_entries=0)
+    reqs = [([1, 2, 3], 30), ([(7 * i) % 251 + 1 for i in range(150)], 4)]
+
+    async def run():
+        first = asyncio.ensure_future(_agen_list(
+            eng.generate(reqs[0][0], max_new_tokens=reqs[0][1])))
+        await _when(lambda: eng.batches >= 3)
+        before = eng.batches
+        late = await _agen_list(
+            eng.generate(reqs[1][0], max_new_tokens=reqs[1][1]))
+        # 106 pad slots: one chunk skipped, three run, decode steps between
+        assert eng.prefill_chunks == 3 and eng.batches - before >= 3
+        return [await first, late]
+    return eng, reqs, run
+
+
+def _case_eos(max_batch):
+    """A stream ends by eos while its look-ahead step is in flight, and a
+    waiting request takes its slot at once. With another stream live the
+    look-ahead step is read (and the ended row's token must reach
+    nobody); alone, the step nobody waits for is dropped unread."""
+    def case():
+        eng = LLMEngine("debug", tp=1, max_batch=max_batch)
+        # a prompt whose greedy stream has a token, a few steps in, that
+        # it has not produced before: that token becomes eos
+        for p in ([5, 9, 11, 42, 7], [1, 2, 3], [9, 9], [200, 3, 77]):
+            free = _greedy_reference(eng, p, 12)
+            k = next((k for k in range(2, 8) if free[k] not in free[:k]),
+                     None)
+            if k is not None:
+                break
+        assert k is not None, "no usable eos among the debug streams"
+        eng.eos_token_id = free[k]
+        reqs = [(p, 12)] + [([17, 4], 20)] * (max_batch - 1) + [([8, 8, 1], 6)]
+
+        async def run():
+            # queue order: the last request waits for the first free slot
+            outs = await asyncio.gather(*[
+                _agen_list(eng.generate(p_, max_new_tokens=m))
+                for p_, m in reqs])
+            assert outs[0] == free[:k]
+            assert eng.decode_rows_discarded >= 1
+            return outs
+        return eng, reqs, run
+    return case
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param(_case_late_arrival, id="late_arrival"),
+    pytest.param(_case_eos(2), id="eos_refill_beside_a_live_stream"),
+    pytest.param(_case_eos(1), id="eos_refill_alone"),
+    pytest.param(_case_max_new(1), id="max_new_1"),
+    pytest.param(_case_max_new(2), id="max_new_2"),
+    pytest.param(_case_max_new(3), id="max_new_3"),
+    pytest.param(_case_ends_at_max_seq_len, id="ends_at_max_seq_len"),
+    pytest.param(_case_chunked_prefill, id="chunked_prefill"),
+])
+def test_greedy_streams_equal_unbatched_decode(case):
+    """With one decode step in flight every greedy stream is still, token
+    for token, the unbatched decode of its own prompt: no token lost,
+    doubled, reordered or handed to another request."""
+    eng, reqs, run = case()
+    outs = asyncio.run(run())
+    for (prompt, max_new), got in zip(reqs, outs):
+        assert got == _greedy_reference(eng, prompt, max_new), prompt
+    if eng.eos_token_id is None:
+        assert eng.decode_rows_discarded == 0
+    assert eng._inflight is None and eng.stats()["active_slots"] == 0
+
+
+class _Unreadable:
+    def __array__(self, *a, **kw):
+        raise RuntimeError("boom at the read")
+
+
+@pytest.mark.parametrize("where", ["dispatch", "read"])
+def test_fault_with_a_step_in_flight_fails_each_request_once(where):
+    """A step that raises while another is in flight (at its dispatch,
+    or, as a device fault does, at the deferred read) fails every live
+    request exactly once with the engine's error, leaves no in-flight
+    record, and the next request is served."""
+    eng = LLMEngine("debug", tp=1, max_batch=2)
+    step_jit = eng._step_jit
+
+    def boom(*a):
+        raise RuntimeError("boom at the dispatch")
+
+    async def consume(prompt):
+        got = []
+        try:
+            async for t in eng.generate(prompt, max_new_tokens=100):
+                got.append(t)
+        except RuntimeError as e:
+            return got, e
+        return got, None
+
+    async def run():
+        tasks = [asyncio.ensure_future(consume(p))
+                 for p in ([1, 2, 3], [7, 7])]
+        await _when(lambda: eng.batches >= 4 and eng._inflight is not None
+                    and all(s is not None for s in eng._slots))
+        with eng._mutex:
+            live = [s.req for s in eng._slots]
+            assert eng._inflight is not None
+            if where == "dispatch":
+                eng._step_jit = boom
+            else:
+                eng._inflight.tokens = _Unreadable()
+        results = await asyncio.wait_for(asyncio.gather(*tasks), 60)
+        with eng._mutex:
+            eng._step_jit = step_jit
+            assert eng._inflight is None
+            assert eng._slots == [None, None]
+        # exactly once: the error and nothing after it, no end marker
+        assert all(r.out.empty() for r in live)
+        after = await _agen_list(eng.generate([5, 9, 11], max_new_tokens=6))
+        return results, after
+
+    results, after = asyncio.run(run())
+    for got, err in results:
+        assert 4 <= len(got) < 100
+        assert "decode cache lost to a failed engine step" in str(err)
+    assert after == _greedy_reference(eng, [5, 9, 11], 6)
+    assert eng._inflight is None
+
+
+def test_restart_on_a_new_loop_drops_the_step_in_flight():
+    """ensure_started on a new event loop, with a step of the old loop's
+    requests still in flight, drops the record: the new loop's first
+    request starts from an empty pipeline and streams what it should."""
+    eng = LLMEngine("debug", tp=1, max_batch=2)
+
+    async def abandoned():
+        task = asyncio.ensure_future(_agen_list(
+            eng.generate([1, 2, 3], max_new_tokens=100)))
+        await _when(lambda: eng.batches >= 4 and eng._inflight is not None)
+        return task  # asyncio.run cancels it, and the engine's loop
+
+    old = asyncio.run(abandoned())
+    assert old.cancelled()
+    with eng._mutex:  # waits out the old loop's last round
+        assert eng._inflight is not None and eng._slots[0] is not None
+
+    async def fresh():
+        await eng.ensure_started()
+        assert eng._inflight is None and eng._slots == [None, None]
+        return await _agen_list(eng.generate([7, 7], max_new_tokens=6))
+
+    assert asyncio.run(fresh()) == _greedy_reference(eng, [7, 7], 6)
+    assert eng._inflight is None and eng.decode_rows_discarded == 0
