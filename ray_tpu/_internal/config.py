@@ -32,8 +32,6 @@ class Config:
 
     # ---- GCS / head ----
     gcs_health_check_period_s: float = 1.0
-    gcs_health_check_timeout_s: float = 5.0
-    gcs_health_check_failure_threshold: int = 5
     # Snapshot path for GCS table persistence ("" = in-memory only). With
     # a path set, a restarted head reloads cluster state and nodes
     # re-register (ref analog: gcs/store_client/redis_store_client.h).
@@ -91,11 +89,6 @@ class Config:
     max_concurrent_worker_boots: int = 8
     # Number of pre-forked idle workers kept per node.
     idle_worker_pool_size: int = 1
-    idle_worker_ttl_s: float = 300.0
-    # Top-k candidate nodes considered by the hybrid scheduling policy
-    # (analog of ref raylet/scheduling/policy/hybrid_scheduling_policy.h:85).
-    scheduler_top_k_fraction: float = 0.2
-    scheduler_spread_threshold: float = 0.5
 
     # ---- object store ----
     # Objects <= this many bytes are returned inline in RPC replies /
@@ -123,8 +116,6 @@ class Config:
     # memory_monitor.h + worker_killing_policy_retriable_fifo).
     memory_usage_threshold: float = 0.95
     memory_monitor_interval_s: float = 1.0
-    # Seconds a get() waits between liveness re-checks of the owner.
-    get_poll_interval_s: float = 0.2
 
     # ---- streaming generators ----
     # Max yielded-but-unconsumed items buffered at the owner before the
@@ -136,8 +127,6 @@ class Config:
     # Max retained reconstructable-task specs (lineage) per owner; beyond
     # this, freed objects lose reconstructability (ref: RAY_max_lineage...).
     max_lineage_entries: int = 10000
-    default_actor_max_restarts: int = 0
-    actor_death_cache_size: int = 1024
 
     # ---- metrics / observability ----
     # GCS time-series store: history kept per series, and the bin width
@@ -266,10 +255,6 @@ class Config:
     # ---- logging ----
     log_level: str = "INFO"
     log_dir: str = ""
-
-    # ---- train / collective ----
-    rendezvous_timeout_s: float = 120.0
-    collective_barrier_timeout_s: float = 120.0
 
     def to_json(self) -> str:
         return json.dumps(dataclasses.asdict(self))

@@ -19,8 +19,8 @@ from typing import Any
 from ray_tpu.core.common import (NodeAffinitySchedulingStrategy,
                                  NodeLabelSchedulingStrategy)
 
-# Hybrid policy knobs (ref: RAY_scheduler_top_k_fraction /
-# scheduler_spread_threshold in ray_config_def.h)
+# Hybrid policy constants (the reference makes both user options,
+# ray_config_def.h; nothing here has needed a second value)
 TOP_K = 3
 SPREAD_THRESHOLD = 0.5
 

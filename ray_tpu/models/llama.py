@@ -18,9 +18,9 @@ Design (idiomatic JAX, nothing torch-shaped):
 * Compute in bf16, params f32 (configurable), softmax/norm/rope in f32.
 * ``jax.checkpoint`` around each block (policy: save nothing but dots'
   inputs) trades FLOPs for HBM — the standard TPU recipe.
-* Attention dispatches to the Pallas flash kernel on TPU, XLA elsewhere,
-  and to ring attention (ppermute over the ICI ring) when the mesh has a
-  nontrivial ``seq`` axis.
+* Attention dispatches to the Pallas flash kernel on TPU, XLA elsewhere
+  (``ops/ring_attention.py`` holds the sequence-parallel forms; no model
+  config selects them yet).
 """
 
 from __future__ import annotations
@@ -32,13 +32,11 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.attention import cached_attention, dot_product_attention
 from ray_tpu.ops.cross_entropy import softmax_cross_entropy
 from ray_tpu.ops.norms import rms_norm
-from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
 
@@ -57,22 +55,15 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
-    remat_save_attn: bool = False
-    # activation-saving policy under remat (PERF.md: dots saves ~8.5GB of
-    # activations at b8 s2048 on the 410m config and OOMs b16 on 16GB
-    # HBM; "nothing" saves only the ~32MB/layer block carry, trading one
-    # extra block forward in the backward for the batch headroom):
+    # activation-saving policy under remat:
     #   "dots"    — dots_with_no_batch_dims_saveable (matmul outputs)
-    #   "nothing" — full per-block recompute (minimum memory)
+    #   "nothing" — save only the block carry and recompute the whole
+    #               block in the backward pass (minimum memory; the only
+    #               one that fits the benchmark's published widths)
     remat_policy: str = "dots"
-    # attention impl: "auto" | "xla" | "flash" | "ring" | "ulysses"
+    # attention impl: "auto" (flash on TPU at s >= 1024, else xla) |
+    # "xla" | "flash"
     attn_impl: str = "auto"
-    # flash-kernel tile shapes (PERF.md: attention is the MFU sink at the
-    # bench geometry; wider K blocks feed the MXU a longer contraction
-    # between softmax rescales — sweep via tools/mfu_sweep.py)
-    attn_block_q: int = 512
-    attn_block_k: int = 512
-    seq_axis: str = "seq"          # mesh axis used by ring/ulysses attention
     # LoRA: scale numerator for the low-rank path (scale = alpha / rank,
     # rank inferred from the adapter's shape; see models/lora.py)
     lora_alpha: float = 16.0
@@ -99,13 +90,6 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.dim // self.n_heads
 
-    def flops_per_token(self) -> float:
-        """Approximate training FLOPs per token (fwd+bwd, 6ND rule plus
-        attention quadratic term)."""
-        n_params = self.num_params(include_embed=False)
-        attn = 12 * self.n_layers * self.dim * self.max_seq_len
-        return 6 * n_params + attn
-
     def num_params(self, include_embed: bool = True) -> int:
         d, h = self.dim, self.hidden_dim
         kv_dim = self.n_kv_heads * self.head_dim
@@ -123,27 +107,8 @@ PRESETS: dict[str, dict] = {
     # debug-size model for tests / CI (CPU-mesh friendly)
     "debug": dict(vocab_size=256, dim=64, n_layers=2, n_heads=4,
                   n_kv_heads=2, hidden_dim=128, max_seq_len=128),
-    "160m": dict(vocab_size=32000, dim=768, n_layers=12, n_heads=12,
-                 n_kv_heads=12, hidden_dim=2048, max_seq_len=2048),
-    "410m": dict(vocab_size=32000, dim=1024, n_layers=24, n_heads=16,
-                 n_kv_heads=16, hidden_dim=2816, max_seq_len=2048),
-    # same params/FLOPs as 410m with head_dim=128 (8x128 instead of
-    # 16x64): fills the MXU's 128-wide contraction and the 128-lane
-    # tiling — the bench geometry matching Llama-2-7B's head_dim
-    # (PERF.md: the biggest modeled MFU lever for the attention kernel)
-    "410m-hd128": dict(vocab_size=32000, dim=1024, n_layers=24, n_heads=8,
-                       n_kv_heads=8, hidden_dim=2816, max_seq_len=2048),
     "1b": dict(vocab_size=32000, dim=2048, n_layers=16, n_heads=16,
                n_kv_heads=8, hidden_dim=5632, max_seq_len=2048),
-    "llama2-7b": dict(vocab_size=32000, dim=4096, n_layers=32, n_heads=32,
-                      n_kv_heads=32, hidden_dim=11008, max_seq_len=4096),
-    "llama2-13b": dict(vocab_size=32000, dim=5120, n_layers=40, n_heads=40,
-                       n_kv_heads=40, hidden_dim=13824, max_seq_len=4096),
-    "llama3-8b": dict(vocab_size=128256, dim=4096, n_layers=32, n_heads=32,
-                      n_kv_heads=8, hidden_dim=14336, max_seq_len=8192,
-                      rope_theta=500000.0),
-    "llama2-70b": dict(vocab_size=32000, dim=8192, n_layers=80, n_heads=64,
-                       n_kv_heads=8, hidden_dim=28672, max_seq_len=4096),
 }
 
 
@@ -235,18 +200,6 @@ def param_logical_axes(cfg: LlamaConfig) -> dict:
 
 
 # ------------------------------------------------------------------ forward
-def _attention(cfg: LlamaConfig, q, k, v):
-    if cfg.attn_impl == "ring":
-        return ring_attention(q, k, v, cfg.seq_axis, causal=True)
-    if cfg.attn_impl == "ulysses":
-        from ray_tpu.ops.ring_attention import ulysses_attention
-
-        return ulysses_attention(q, k, v, cfg.seq_axis, causal=True)
-    return dot_product_attention(q, k, v, causal=True, impl=cfg.attn_impl,
-                                 block_q=cfg.attn_block_q,
-                                 block_k=cfg.attn_block_k)
-
-
 def _proj(cfg: LlamaConfig, layer: dict, name: str, h):
     """Frozen matmul + optional LoRA low-rank path (shared by the
     training block and the KV-cache decode block so adapters behave
@@ -291,12 +244,9 @@ def _block(cfg: LlamaConfig, x, layer, cos, sin, positions):
         q = apply_rope(q, cos, sin, positions)
         kk = apply_rope(kk, cos, sin, positions)
     with jax.named_scope("attn"):
-        attn = _attention(cfg, q, kk, vv).reshape(b, s, nh * hd)
-    # Named so the remat policy can save it: attention outputs are dots
-    # WITH batch dims, so dots_with_no_batch_dims_saveable would rerun
-    # the whole flash kernel forward inside the backward pass (~+33% on
-    # the attention budget) to rebuild this one activation.
-    attn = checkpoint_name(attn, "attn_out")
+        attn = dot_product_attention(
+            q, kk, vv, causal=True, impl=cfg.attn_impl
+        ).reshape(b, s, nh * hd)
     with jax.named_scope("attn_out"):
         x = x + proj("wo", attn)
 
@@ -349,15 +299,6 @@ def backbone(params: dict, tokens: jax.Array, cfg: LlamaConfig,
         else:
             raise ValueError(
                 f"unknown remat_policy {cfg.remat_policy!r}")
-        if cfg.remat_save_attn:
-            # also save flash-attention outputs (dots WITH batch dims are
-            # not covered by the base policy, so the kernel forward would
-            # rerun inside the backward); costs b*s*d*2B per layer
-            save_attn = jax.checkpoint_policies.save_only_these_names(
-                "attn_out")
-            policy = (save_attn if policy is None else
-                      jax.checkpoint_policies.save_from_both_policies(
-                          policy, save_attn))
         step = jax.checkpoint(step, policy=policy)
     (x, aux_sum), _ = jax.lax.scan(
         step, (x, jnp.zeros((), jnp.float32)), scanned_layers)

@@ -4,8 +4,7 @@ The reference has no first-class LoRA: fine-tuning arrives via
 torch/DeepSpeed examples (ref: doc/source/train/examples/deepspeed/,
 release/air_examples/dolly_v2_lightning_fsdp_finetuning/). Here LoRA is a
 native model-layer feature because the adapter shardings, the frozen-base
-gradient cut, and the remat policy must be co-designed with GSPMD
-(BASELINE.json config #3: Llama-2-7B LoRA fine-tune at >=35% MFU).
+gradient cut, and the remat policy must be co-designed with GSPMD.
 
 Design:
 

@@ -1,4 +1,4 @@
-"""Small MLP (MNIST-class) — BASELINE.json config #2's model.
+"""Small MLP (MNIST-class).
 
 Used by the JaxTrainer DDP path and tests; trivially shardable on the
 ``data`` axis (pure DP: params replicated, batch sharded).

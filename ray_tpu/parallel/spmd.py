@@ -49,8 +49,7 @@ def shard_batch(batch: Any, mesh: Mesh, seq_axis: bool = False) -> Any:
 
 def build_train_step(loss_fn: Callable, optimizer: optax.GradientTransformation,
                      params: Any, logical_axes: Any, mesh: Mesh,
-                     rules: dict | None = None, seq_sharded_batch: bool = False,
-                     grad_accum: int = 1,
+                     rules: dict | None = None, grad_accum: int = 1,
                      trainable_keys: tuple | None = None):
     """Returns (compiled_step, sharded_initial_state).
 
@@ -141,7 +140,6 @@ def build_train_step(loss_fn: Callable, optimizer: optax.GradientTransformation,
             out["frozen"] = state["frozen"]  # donated buffers pass through
         return (out, aux)
 
-    b_shard = batch_sharding(mesh, seq_sharded_batch)
     step = jax.jit(
         one_step,
         in_shardings=(state_shardings, None),

@@ -452,7 +452,7 @@ class IMPALA:
         require_discrete(probe, type(self).__name__)
         obs_shape = getattr(probe, "observation_shape", None)
         if obs_shape is not None:
-            # image env -> CNN module (config #4's Atari-shaped path)
+            # image env -> CNN module (the Atari-shaped path)
             self.module_cfg = CNNModuleConfig(
                 obs_shape=tuple(obs_shape), num_actions=probe.num_actions)
         else:

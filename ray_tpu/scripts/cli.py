@@ -1101,8 +1101,7 @@ def main(argv=None):
     sp.add_argument("--duration", type=float, default=2.0)
     sp.add_argument("--num-cpus", type=int)
     sp.add_argument("--json-out", metavar="PATH",
-                    help="also write results as JSON (MICROBENCH.json "
-                         "format)")
+                    help="also write results as JSON")
     sp.set_defaults(fn=cmd_microbenchmark)
 
     sp = sub.add_parser("stack", help="stack traces of all workers")

@@ -1,11 +1,11 @@
 """TP-sharded LLM serving: batched prefill/decode engine + Serve app.
 
-BASELINE config #5 (Llama TP Serve replicas): a replica pins a
-pjit-sharded Llama across the host's local mesh (tensor axis over chips,
-ICI collectives inserted by GSPMD), decodes concurrent requests in a
-continuously-batched slot ring (finished slots refill between steps),
-and streams tokens through the existing streaming-return path (SSE at
-the proxy).
+A replica pins a pjit-sharded model across the host's local mesh
+(tensor axis over chips, ICI collectives inserted by GSPMD), decodes
+concurrent requests in a continuously-batched slot ring (finished slots
+refill between steps), and streams tokens through the existing
+streaming-return path (SSE at the proxy).
+
 
 Ref analogs: python/ray/serve/_private/replica.py:750 (user-callable
 host), router.py:321 (request path); the engine itself has no reference

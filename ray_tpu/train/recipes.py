@@ -177,7 +177,7 @@ def build_lora_step(config: dict, mesh):
 
 
 def lora_finetune_loop(config: dict):
-    """LoRA fine-tune a Llama-family model (BASELINE.json config #3).
+    """LoRA fine-tune a Llama-family model.
 
     Runs inside each TrainWorker: builds the mesh from ScalingConfig,
     initializes (or loads) frozen base params + LoRA adapters, and trains

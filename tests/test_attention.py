@@ -75,9 +75,8 @@ def test_flash_bwd_parity_gqa():
 
 @pytest.mark.parametrize("bq,bk", [(64, 128), (128, 64), (32, 256)])
 def test_flash_parity_rectangular_blocks(bq, bk):
-    """Non-square tiles (the mfu_sweep retune axis: wider K blocks feed
-    the MXU a longer contraction per softmax rescale) must stay exact in
-    fwd and bwd."""
+    """Non-square tiles (wider K blocks feed the MXU a longer
+    contraction per softmax rescale) must stay exact in fwd and bwd."""
     q, k, v = _make_qkv(1, 256, 2, 2, 64, seed=5)
 
     out = flash_attention(q, k, v, True, None, bq, bk)

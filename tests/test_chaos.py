@@ -9,9 +9,8 @@ replicas.
 
 Slow+chaos marked: excluded from the tier-1 `-m "not slow"` run but
 each leg fits the tier-1 per-test budget, so `pytest -m chaos` is a
-usable local gate. The full kill schedule under load lives in
-``python tools/envelope_bench.py --only chaos`` (SLOs land in
-ENVELOPE.json)."""
+usable local gate. The drills are the `measure_chaos_*` drivers of
+tools/envelope_bench."""
 
 from __future__ import annotations
 

@@ -331,7 +331,7 @@ def test_actor_pool_autoscales_with_queue_depth(local_cluster):
 
 def test_streaming_split_feeds_training_under_pressure(local_cluster):
     """streaming_split output of a backpressured pipeline feeds per-worker
-    iteration (the Train ingest shape, config #2)."""
+    iteration (the Train ingest shape)."""
     import numpy as np
 
     from ray_tpu import data

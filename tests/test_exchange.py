@@ -120,17 +120,45 @@ def test_dedup_block_kernels():
 
 
 # -------------------------------------------- controller: pipelining
-def test_reduce_starts_before_all_maps_finish(local_cluster):
+def test_reduce_starts_before_all_maps_finish(local_cluster, tmp_path):
     """The acceptance criterion: reduce-side folds launch while map
     tasks are still outstanding (controller instrumentation — a barrier
-    executor would always show 0 folds before maps done)."""
+    executor would always show 0 folds before maps done). The order is
+    a fact of the test, not of the host's clock: the last map does not
+    finish until the controller has launched a fold, so a controller
+    that waits for every map before it folds leaves that map to its
+    deadline and then shows folds == 0."""
+    import threading
+    import time
+
+    gate = str(tmp_path / "a-fold-was-launched")
+
+    def gated_map(block, n, idx):
+        if idx == 9:
+            deadline = time.monotonic() + 60.0
+            while not os.path.exists(gate) and time.monotonic() < deadline:
+                time.sleep(0.01)
+        return split_partition(block, n, idx)
+
     refs = [rt.put(NumpyBlock({"x": np.full(1000, i)}))
             for i in range(10)]
-    spec = ExchangeSpec(
-        4, map_fn=lambda b, n, i: split_partition(b, n, i), fold_min=2)
+    spec = ExchangeSpec(4, map_fn=gated_map, fold_min=2)
     ctl = ExchangeController(spec,
                              options=ExecutionOptions(max_in_flight=2))
-    out = ctl.run(refs)
+    ran = threading.Event()
+
+    def open_gate():
+        while ctl.stats.folds == 0 and not ran.is_set():
+            time.sleep(0.005)
+        open(gate, "w").close()
+
+    watcher = threading.Thread(target=open_gate, daemon=True)
+    watcher.start()
+    try:
+        out = ctl.run(refs)
+    finally:
+        ran.set()
+        watcher.join(timeout=10)
     stats = ctl.stats
     assert stats.map_tasks == 10 and stats.maps_done == 10
     # folds only launch while the map side is unfinished, so folds > 0

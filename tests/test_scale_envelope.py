@@ -5,9 +5,7 @@ MEMORY of delta resource sync (GCS RSS per heartbeating node) and the
 hybrid scheduler (driver RSS per actor/PG).
 
 Slow-marked: the legs are dominated by process spawn on a 1-core box
-(each virtual node is a real node_main subprocess). The CLI twin is
-``python tools/envelope_bench.py --profile scale`` which records the
-same dimensions into ENVELOPE.json.
+(each virtual node is a real node_main subprocess).
 """
 
 from __future__ import annotations

@@ -1,4 +1,4 @@
-"""TP-sharded LLM serving (BASELINE config #5 shape, on the CPU mesh):
+"""TP-sharded LLM serving (on the CPU mesh):
 engine batching/parity + Serve deployment streaming (ref analog:
 serve/_private/replica.py:750 + response streaming; the engine itself is
 TPU-native, no reference equivalent)."""

@@ -1,7 +1,7 @@
 """Sustained-load serve data-plane floor gate (slow-marked so tier-1
 stays fast; ISSUE 10 acceptance leg).
 
-Runs the serve_bench ``sustained`` leg — open-loop arrival through the
+Runs `tools/serve_bench.run_sustained` — open-loop arrival through the
 HTTP ingress with a >=30s steady state and a burst at ~2x min-replica
 capacity — and floors:
 
@@ -22,9 +22,7 @@ and hit-TTFT-vs-cold floors), and disaggregated prefill/decode (decode
 occupancy must not dip vs fused; KV handoff rides the shm/device edge
 with zero pickle fallbacks).
 
-CLI twins refreshing SERVE_BENCH.json:
-``python tools/serve_bench.py --leg sustained`` /
-``--leg multi_proxy``.
+The drivers live in tools/serve_bench.
 """
 
 from __future__ import annotations
@@ -40,9 +38,9 @@ pytestmark = pytest.mark.slow
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
 
-# committed SERVE_BENCH.json sustained_load leg on this class of box:
-# steady 13.4 qps / p99 ~160ms, burst 44 qps admitted / p99 ~1.4s with
-# shed_rate ~0.17 and peak_replicas 3. Floors sit 2-4x below committed,
+# How the floors were sized: an idle CPU host of this class read steady
+# 13.4 qps / p99 ~160ms, burst 44 qps admitted / p99 ~1.4s with
+# shed_rate ~0.17 and peak_replicas 3. Floors sit 2-4x below that,
 # clearing loaded-suite noise while still failing a reintroduced
 # unbounded-queueing or broken-autoscaler regression by an order of
 # magnitude.
@@ -55,10 +53,10 @@ BURST_SHED_RATE_CEIL = 0.9
 # latency leg (ISSUE 16): the paced app yields its first chunk
 # immediately, so client TTFT is pure serve-path overhead (proxy
 # admission + routing + dispatch + replica queue + first yield).
-# Committed SERVE_BENCH.json measures p99 ~= tens of ms on this class
-# of box; the ceiling sits an order of magnitude above to clear
-# loaded-suite noise while still failing a reintroduced
-# poll-loop/blocking-dispatch regression (which lands at seconds).
+# An idle CPU host of this class reads p99 ~= tens of ms; the ceiling
+# sits an order of magnitude above to clear loaded-suite noise while
+# still failing a reintroduced poll-loop/blocking-dispatch regression
+# (which lands at seconds).
 LATENCY_TTFT_P99_MS_CEIL = 1000.0
 # server-side proxy waterfall stages must tile the proxied e2e: the
 # stage means (admission+router+dispatch+stream) must sum to within
@@ -144,8 +142,8 @@ def test_request_latency_floors_and_waterfall_tiling():
     assert wf.get("ttft_mean_ms") is not None, wf
 
 
-# multi_proxy leg (ISSUE 19) floors. Committed SERVE_BENCH.json on this
-# class of box: fanout 236 admitted qps across 3 proxies with 0
+# multi_proxy floors (ISSUE 19). An idle CPU host of this class read:
+# fanout 236 admitted qps across 3 proxies with 0
 # timeouts/500s, share error 3.1% before / 0% after the kill,
 # redistribution 3.6s; prefix hit_rate 0.6, warm TTFT 0.32x cold;
 # disagg occupancy 1.0 vs fused 0.989.

@@ -3,8 +3,7 @@
 Floors the `shuffle_gb_per_s` leg: the pipelined exchange shuffle
 (data/exchange.py) must clear an absolute GB/s floor AND beat the old
 barrier executor (per-row dict sharding, reduce-waits-for-every-map) on
-the same leg. CLI twin refreshing ENVELOPE.json:
-``python tools/envelope_bench.py --only shuffle``.
+the same leg (`tools/envelope_bench.measure_shuffle`).
 """
 
 from __future__ import annotations
@@ -20,9 +19,9 @@ pytestmark = pytest.mark.slow
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
 
-# committed ENVELOPE.json: pipelined 0.036 GiB/s at 128MiB on this
-# class of box, the per-row barrier path 0.002 — the floor sits ~2.5x
-# below the committed pipelined number, an order of magnitude above a
+# an idle CPU host of this class read: pipelined 0.036 GiB/s at 128MiB,
+# the per-row barrier path 0.002 — the floor sits ~2.5x below the
+# pipelined reading, an order of magnitude above a
 # reintroduced per-row path, and clears CI noise
 PIPELINED_FLOOR_GIB_S = 0.015
 

@@ -298,3 +298,24 @@ def test_profile_worker_cpu_and_memory(local_cluster):
     mem = state_api.profile_worker(aid, mode="memory", duration_s=0.5)
     assert mem["type"] == "memory_window"
     assert isinstance(mem["top_allocations"], list)
+
+
+def test_every_config_field_is_read():
+    """Each `Config` field is a `RAYT_<NAME>` a user can set, so each is
+    read somewhere under ray_tpu/ outside config.py: as an attribute, or
+    by its quoted name (the `_system_config` dicts and `getattr` calls).
+    A name left only in a comment does not count."""
+    import dataclasses
+    import pathlib
+    import re
+
+    import ray_tpu
+    from ray_tpu._internal.config import Config
+
+    root = pathlib.Path(ray_tpu.__file__).parent
+    source = "\n".join(
+        p.read_text() for p in sorted(root.rglob("*.py"))
+        if p != root / "_internal" / "config.py")
+    unread = [f.name for f in dataclasses.fields(Config)
+              if not re.search(rf"""\.{f.name}\b|["']{f.name}["']""", source)]
+    assert not unread, f"config fields nothing reads: {unread}"

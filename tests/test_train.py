@@ -201,10 +201,10 @@ def _lora_loop(config):
 
 
 def test_lora_finetune(local_cluster, tmp_path):
-    """North-star config #3 shape: LoRA fine-tune via JaxTrainer on a
-    dp×fsdp×tensor CPU mesh — loss falls and the adapters-only
-    checkpoint artifact is produced (base params never train: covered at
-    the unit level by test_models.test_lora_train_step_freezes_base)."""
+    """LoRA fine-tune via JaxTrainer on a dp×fsdp×tensor CPU mesh — loss
+    falls and the adapters-only checkpoint artifact is produced (base
+    params never train: covered at the unit level by
+    test_models.test_lora_train_step_freezes_base)."""
     from ray_tpu import train
     from ray_tpu.train.checkpoint import load_pytree
 

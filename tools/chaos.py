@@ -20,9 +20,8 @@ Fault primitives cover the planes this runtime can lose:
   routing on their last table and self-heal the controller, which
   restores its GCS checkpoint).
 
-Used three ways: tests/test_chaos.py (tier-1 smoke legs), ``python
-tools/envelope_bench.py --only chaos`` (the full schedule under load,
-SLOs recorded in ENVELOPE.json), or interactively::
+Used two ways: tests/test_chaos.py (through the drills in
+tools/envelope_bench), or interactively::
 
     monkey = ChaosMonkey(cluster)
     monkey.at(2.0, monkey.kill_random_actor, runners)

@@ -1,25 +1,15 @@
-"""Scalability-envelope benchmark -> ENVELOPE.json (ref analog:
-release/benchmarks/README.md tables + release/benchmarks/distributed/*.
+"""Drivers for the envelope, shuffle, placement and chaos tests
+(`tests/test_scale_envelope.py`, `test_shuffle_envelope.py`,
+`test_placement.py`, `test_chaos.py`).
 
-The reference publishes *envelope* numbers (max nodes / actors / queued
-tasks / PGs / object shapes it has demonstrated) rather than golden
-throughputs. This harness demonstrates the same envelope dimensions at
-sandbox scale (defaults sized for a 1-core CI box; every dimension is a
-flag, so a real cluster can push the same legs to reference scale) and
-records measured values + wall time per leg.
-
-Run: python tools/envelope_bench.py [--nodes 16 --actors 64 ...]
-     python tools/envelope_bench.py --profile scale   # 160 nodes /
-                                                      # 640 actors / 500 PGs
-The scale profile is the 10-30x envelope push (slow CI runs it via
-tests/test_scale_envelope.py): every leg also records the head/driver
-RSS deltas so delta resource sync and the hybrid scheduler can be held
-to BOUNDED memory, not just correctness.
+Each `measure_*` function drives one drill on the cluster the test hands
+it and returns a dict of counts and host-clock readings for the test to
+assert on; `rss_kb` reads a process's resident memory. Nothing is written
+to disk, and no number from here is a chip metric.
 """
 
 from __future__ import annotations
 
-import argparse
 import json
 import os
 import sys
@@ -40,30 +30,6 @@ def rss_kb(pid: int = 0) -> int:
     except OSError:
         pass
     return 0
-
-
-# --only filter (set from the CLI): when non-empty, legs whose dimension
-# matches no substring are skipped and the surviving rows are MERGED into
-# an existing --out document instead of overwriting it (re-measure one
-# leg without redoing a multi-hour scale run)
-_only: list[str] = []
-
-
-def _leg(results, dimension, unit, reference, fn):
-    if _only and not any(s in dimension for s in _only):
-        return
-    t0 = time.monotonic()
-    try:
-        value = fn()
-        row = {"dimension": dimension, "value": value, "unit": unit,
-               "elapsed_s": round(time.monotonic() - t0, 2),
-               "reference_envelope": reference}
-    except Exception as e:  # record honestly, keep going
-        row = {"dimension": dimension, "error": f"{type(e).__name__}: {e}",
-               "elapsed_s": round(time.monotonic() - t0, 2),
-               "reference_envelope": reference}
-    print(json.dumps(row))
-    results.append(row)
 
 
 def measure_shuffle(rt, *, mib: int = 128, legacy_mib: int = 32,
@@ -148,68 +114,6 @@ def measure_shuffle(rt, *, mib: int = 128, legacy_mib: int = 32,
         # folds only launch while the map side is unfinished, so this
         # count is reduce work that ran before all maps completed
         "reduce_folds_before_maps_done": stats.folds if stats else 0,
-    }
-
-
-def measure_sched(rt, cluster, target_nodes: int = 8,
-                  oversubscribe: float = 6.0):
-    """Scheduling decision-plane observability leg (ISSUE 11):
-    oversubscribe a small multi-node fleet with short 1-CPU tasks so
-    leases grant, queue, and spill across nodes, then read the GCS
-    decision-trace rollup — spillback-hop and queue-wait percentiles
-    come straight from the coalesced per-shape trace (the same feed
-    `rayt status` / `rayt why-pending` render)."""
-    from ray_tpu import state_api
-
-    view = cluster._cluster_view()
-    for _ in range(max(0, target_nodes - len(view))):
-        cluster.add_node(num_cpus=2)
-    view = cluster._cluster_view()
-    total_cpus = sum(v.get("total", {}).get("CPU", 0.0)
-                     for v in view.values() if v.get("alive"))
-
-    @rt.remote(num_cpus=1)
-    def sched_probe(t):
-        time.sleep(t)
-        return 1
-
-    # long enough that the wave outlives the grant burst: leases must
-    # actually park (queue-wait) and spill across nodes, or the trace
-    # has nothing to show
-    n = int(total_cpus * oversubscribe)
-    t0 = time.monotonic()
-    assert all(rt.get([sched_probe.remote(0.25) for _ in range(n)],
-                      timeout=900))
-    wall = time.monotonic() - t0
-    time.sleep(2.5)  # sched reports ride the 1s heartbeat cadence
-    s = state_api.summarize_scheduling()
-    shape = s["shapes"].get("CPU:1", {})
-    waits = sorted(r.get("queue_wait_s", 0.0)
-                   for r in shape.get("recent", ())
-                   if r.get("queue_wait_s", 0.0) > 0.0)
-
-    def pct(p):
-        if not waits:
-            return 0.0
-        return round(waits[min(len(waits) - 1,
-                               int(p * len(waits)))], 4)
-
-    return {
-        "nodes": len(view), "cluster_cpus": total_cpus, "tasks": n,
-        "wall_s": round(wall, 2),
-        "tasks_per_s": round(n / wall, 1),
-        "granted": shape.get("granted", 0),
-        "queued": shape.get("queued", 0),
-        "spillbacks": shape.get("spillback", 0),
-        "infeasible": shape.get("infeasible", 0),
-        "max_spill_hops": shape.get("max_spill_hops", 0),
-        "queue_wait_p50_s": pct(0.50),
-        "queue_wait_p95_s": pct(0.95),
-        "queue_wait_max_s": round(shape.get("queue_wait_max_s", 0.0),
-                                  4),
-        "queue_wait_total_s": round(
-            shape.get("queue_wait_s_total", 0.0), 3),
-        "pending_peak_reported": s.get("pending_total", 0),
     }
 
 
@@ -531,387 +435,3 @@ def measure_chaos_serve(rt, *, load_s: float = 8.0,
             "controller_restored_s": round(restored_s, 2),
             "replicas_adopted": len(before & after),
             "replicas": len(before)}
-
-
-def measure_chaos_node_drain(rt, cluster, *, tasks: int = 40) -> dict:
-    """SLO: a node drained under mixed serve+task load — the drain
-    completes within its deadline, ZERO admitted serve requests fail
-    (replacement replicas warm before victims are de-routed), every
-    restartable actor lands back ALIVE on a live node, and every task
-    completes."""
-    import threading
-
-    from chaos import ChaosMonkey
-
-    from ray_tpu import serve, state_api
-
-    node = cluster.add_node(num_cpus=4)
-
-    @serve.deployment(num_replicas=2)
-    def echo(x):
-        return x
-
-    handle = serve.run(echo.bind(), name="drain_app")
-    assert handle.remote(0).result(timeout=30) == 0
-
-    @rt.remote(num_cpus=0.25, max_restarts=-1,
-               scheduling_strategy="SPREAD")
-    class Worker:
-        def ping(self):
-            return 1
-
-    actors = [Worker.remote() for _ in range(4)]
-    rt.get([a.ping.remote() for a in actors], timeout=120)
-
-    @rt.remote(num_cpus=0.25, scheduling_strategy="SPREAD")
-    def work(i):
-        time.sleep(0.2)
-        return i
-
-    stats = {"ok": 0, "fail": 0}
-    stop = threading.Event()
-
-    def drive():
-        i = 0
-        while not stop.is_set():
-            try:
-                assert handle.remote(i).result(timeout=60) == i
-                stats["ok"] += 1
-            except Exception:
-                stats["fail"] += 1
-            i += 1
-
-    thread = threading.Thread(target=drive, daemon=True)
-    thread.start()
-    try:
-        refs = [work.remote(i) for i in range(tasks)]
-        time.sleep(1.0)
-        monkey = ChaosMonkey(cluster)
-        t0 = time.monotonic()
-        nid = monkey.drain_node(cluster.worker_nodes.index(node),
-                                deadline_s=120.0, reason="envelope drill")
-        drained_s = None
-        while time.monotonic() - t0 < 120.0:
-            rec = state_api.drain_status().get(nid)
-            if rec is not None and rec.get("state") == "DRAINED":
-                drained_s = time.monotonic() - t0
-                break
-            time.sleep(0.25)
-        got = rt.get(refs, timeout=300)
-    finally:
-        stop.set()
-        thread.join(timeout=60)
-    # migrated actors must be ALIVE somewhere OTHER than the drained node
-    rt.get([a.ping.remote() for a in actors], timeout=120)
-    for row in state_api.list_actors(state="ALIVE"):
-        if row["class_name"] == "Worker":
-            assert row["node_id"] != nid, row
-    rec = state_api.drain_status().get(nid) or {}
-    serve.shutdown()
-    for a in actors:
-        rt.kill(a)
-    cluster.remove_node(node)
-    assert drained_s is not None, "drain missed its deadline"
-    assert stats["fail"] == 0, stats
-    assert sorted(got) == list(range(tasks)), got
-    return {"requests": stats["ok"], "failed": stats["fail"],
-            "tasks": tasks, "drain_s": round(drained_s, 2),
-            "migrated": rec.get("migrated", {})}
-
-
-def main():
-    p = argparse.ArgumentParser()
-    p.add_argument("--nodes", type=int, default=16)
-    p.add_argument("--actors", type=int, default=64)
-    p.add_argument("--queued-tasks", type=int, default=20_000)
-    p.add_argument("--object-args", type=int, default=2_000)
-    p.add_argument("--task-returns", type=int, default=300)
-    p.add_argument("--get-objects", type=int, default=5_000)
-    p.add_argument("--big-object-gib", type=float, default=1.0)
-    p.add_argument("--broadcast-mib", type=int, default=128)
-    p.add_argument("--broadcast-fetchers", type=int, default=0,
-                   help="0 = min(8, nodes)")
-    p.add_argument("--placement-groups", type=int, default=50)
-    p.add_argument("--profile", choices=("sandbox", "scale"),
-                   default="sandbox",
-                   help="scale = the 10-30x envelope push: >=160 nodes, "
-                        ">=640 actors, >=500 PGs on one core")
-    p.add_argument("--out", default="ENVELOPE.json")
-    p.add_argument("--only", default="",
-                   help="comma-separated dimension substrings: run only "
-                        "matching legs and merge their rows into an "
-                        "existing --out document")
-    args = p.parse_args()
-    if args.only:
-        _only.extend(s for s in args.only.split(",") if s)
-    if args.profile == "scale":
-        args.nodes = max(args.nodes, 160)
-        args.actors = max(args.actors, 640)
-        args.placement_groups = max(args.placement_groups, 500)
-        # 1-core CI: worker spawn is SERIALIZED, so the last actors of a
-        # 640-actor fleet legitimately wait many minutes for their spawn
-        # turn. Raise the per-worker startup bounds so the envelope
-        # measures capacity, not the sandbox's spawn latency. (Must be
-        # set before the first get_config(); children inherit via
-        # RAYT_CONFIG_JSON.)
-        os.environ.setdefault("RAYT_WORKER_STARTUP_TIMEOUT_S", "1800")
-        os.environ.setdefault("RAYT_ACTOR_CREATION_PUSH_TIMEOUT_S",
-                              "2400")
-        os.environ.setdefault("RAYT_LEASE_TIMEOUT_S", "600")
-
-    import ray_tpu as rt
-    from ray_tpu.cluster_utils import Cluster
-
-    results = []
-
-    # ---- multi-node legs on an in-process cluster (ref: the 2000-node
-    # distributed table; node_main processes stand in for machines) ----
-    cluster = Cluster(head_resources={"CPU": 4.0})
-
-    def add_nodes():
-        head_rss0 = rss_kb(cluster.head_proc.pid)
-        for _ in range(args.nodes - 1):
-            cluster.add_node(num_cpus=2)  # cluster tracks for shutdown
-        rt_nodes = len(cluster._cluster_view())
-        assert rt_nodes >= args.nodes, rt_nodes
-        time.sleep(2.0)  # a few heartbeat/delta-sync rounds at full size
-        head_rss1 = rss_kb(cluster.head_proc.pid)
-        return {"nodes": rt_nodes, "head_rss_kb": head_rss1,
-                # delta resource sync boundedness: GCS memory paid per
-                # registered+heartbeating node
-                "head_rss_kb_per_node": round(
-                    (head_rss1 - head_rss0) / max(1, rt_nodes - 1), 1)}
-
-    _leg(results, "nodes_registered_and_heartbeating", "nodes",
-         "2000+ (64-core machines)", add_nodes)
-
-    cluster.connect()
-    try:
-        @rt.remote(num_cpus=0.01)
-        class Trivial:
-            def ping(self):
-                return 1
-
-        def actor_fleet():
-            rss0 = rss_kb()
-            actors = [Trivial.remote() for _ in range(args.actors)]
-            assert all(rt.get([a.ping.remote() for a in actors],
-                              timeout=1800))
-            rss1 = rss_kb()
-            for a in actors:
-                rt.kill(a)
-            return {"actors": args.actors,
-                    "driver_rss_kb_per_actor": round(
-                        (rss1 - rss0) / args.actors, 1)}
-
-        _leg(results, "actors_alive_simultaneously", "actors",
-             "40,000+", actor_fleet)
-
-        @rt.remote
-        def tiny(i=0):
-            return i
-
-        def queue_storm():
-            refs = [tiny.remote(i) for i in range(args.queued_tasks)]
-            rt.get(refs[-1], timeout=1200)  # drain (FIFO-ish: last ~ done)
-            rt.get(refs, timeout=1200)
-            return args.queued_tasks
-
-        _leg(results, "tasks_queued_then_drained_one_driver", "tasks",
-             "1,000,000+ queued (single node)", queue_storm)
-
-        def many_args():
-            refs = [rt.put(i) for i in range(args.object_args)]
-
-            @rt.remote
-            def count(*xs):
-                return len(xs)
-
-            got = rt.get(count.remote(*refs), timeout=600)
-            assert got == args.object_args, got
-            return got
-
-        _leg(results, "object_args_to_single_task", "objects",
-             "10,000+", many_args)
-
-        def many_returns():
-            n = args.task_returns
-
-            @rt.remote(num_returns=n)
-            def fan():
-                return list(range(n))
-
-            refs = fan.remote()
-            vals = rt.get(refs, timeout=600)
-            assert vals == list(range(n))
-            return n
-
-        _leg(results, "returns_from_single_task", "objects",
-             "3,000+", many_returns)
-
-        def one_big_get():
-            refs = [rt.put(np.float64(i)) for i in range(args.get_objects)]
-            vals = rt.get(refs, timeout=600)
-            assert len(vals) == args.get_objects
-            return args.get_objects
-
-        _leg(results, "objects_in_single_get", "objects",
-             "10,000+", one_big_get)
-
-        def big_object():
-            nbytes = int(args.big_object_gib * (1 << 30))
-            arr = np.zeros(nbytes, np.uint8)
-            t0 = time.monotonic()
-            ref = rt.put(arr)
-            out = rt.get(ref, timeout=600)
-            dt = time.monotonic() - t0
-            assert out.nbytes == nbytes
-            del out
-            return {"gib": args.big_object_gib,
-                    "roundtrip_gib_per_s": round(
-                        2 * args.big_object_gib / dt, 2)}
-
-        _leg(results, "max_numpy_object", "GiB",
-             "100+ GiB", big_object)
-
-        def bulk_throughput():
-            # data-plane bandwidth next to the control-plane rates: the
-            # put+get round trip (one memcpy into shm) and the repeated
-            # zero-copy get (views over the mapping, no copy at all)
-            arr = np.zeros(128 << 20, np.uint8)
-            gib = arr.nbytes / (1 << 30)
-            rt.get(rt.put(arr))  # warm
-            t0 = time.monotonic()
-            n = 0
-            while time.monotonic() - t0 < 2.0:
-                rt.get(rt.put(arr))
-                n += 1
-            put_get = n * gib / (time.monotonic() - t0)
-            ref = rt.put(arr)
-            rt.get(ref)
-            t0 = time.monotonic()
-            n = 0
-            while time.monotonic() - t0 < 2.0:
-                rt.get(ref)
-                n += 1
-            get_only = n * gib / (time.monotonic() - t0)
-            del ref
-            return {"object_mib": 128,
-                    "put_get_gib_per_s": round(put_get, 2),
-                    "get_gib_per_s": round(get_only, 2)}
-
-        _leg(results, "bulk_data_plane_throughput", "GiB/s",
-             "plasma zero-copy reads (memcpy-bound put, copy-free get)",
-             bulk_throughput)
-
-        _leg(results, "shuffle_gb_per_s", "GiB/s",
-             "task-based exchange shuffle (pipelined map/reduce, "
-             "columnar kernels)",
-             lambda: measure_shuffle(rt))
-
-        _leg(results, "sched_decision_traces", "decisions",
-             "lease verdicts coalesced per demand shape: grant/queue/"
-             "spill/infeasible + queue-wait percentiles + hop chains",
-             lambda: measure_sched(rt, cluster))
-
-        _leg(results, "placement_multi_tenant_fair_share", "tenants",
-             "placement plane: quota'd serve/train tenants hold their "
-             "floors while an unfloored shuffle tenant bursts; train "
-             "gang placed via SLICE_PACK compiles preferred channel "
-             "kinds",
-             lambda: measure_placement(rt, cluster))
-
-        def broadcast():
-            arr = np.zeros(args.broadcast_mib << 20, np.uint8)
-            ref = rt.put(arr)
-
-            @rt.remote(scheduling_strategy="SPREAD")
-            def fetch(x):
-                return x.nbytes
-
-            fetchers = args.broadcast_fetchers or min(8, args.nodes)
-            sizes = rt.get([fetch.remote(ref) for _ in range(fetchers)],
-                           timeout=600)
-            assert all(s == arr.nbytes for s in sizes)
-            return {"mib": args.broadcast_mib, "fetchers": fetchers,
-                    "nodes": args.nodes}
-
-        _leg(results, "object_broadcast_across_nodes", "MiB",
-             "1 GiB to 50+ nodes", broadcast)
-
-        def pg_storm():
-            # placement_group() is synchronous: bundles are reserved (2-
-            # phase commit) by the time it returns
-            rss0 = rss_kb()
-            pgs = [rt.placement_group([{"CPU": 0.01}], strategy="PACK")
-                   for _ in range(args.placement_groups)]
-            assert all(pg.placement for pg in pgs)
-            rss1 = rss_kb()
-            for pg in pgs:
-                rt.remove_placement_group(pg)
-            return {"pgs": args.placement_groups,
-                    "driver_rss_kb_per_pg": round(
-                        (rss1 - rss0) / args.placement_groups, 1)}
-
-        _leg(results, "placement_groups_ready_simultaneously", "PGs",
-             "1,000+", pg_storm)
-
-        # ---- chaos legs: recovery SLOs under injected faults --------
-        _leg(results, "chaos_task_reexecution_node_kill", "tasks",
-             "nightly chaos: sudden node loss under load, every task "
-             "completes (retries + lineage re-execution)",
-             lambda: measure_chaos_tasks(rt, cluster))
-
-        _leg(results, "chaos_dag_runner_kill_recovery", "ticks",
-             "compiled-DAG ring rides a runner death: detect -> "
-             "recompile -> resume, zero ticks lost",
-             lambda: measure_chaos_dag(rt))
-
-        _leg(results, "chaos_serve_controller_bounce", "requests",
-             "serve data plane rides a controller bounce: zero failed "
-             "requests, replicas adopted not cold-started",
-             lambda: measure_chaos_serve(rt))
-
-        _leg(results, "chaos_node_drain", "requests",
-             "graceful drain under mixed serve+task load: within "
-             "deadline, zero failed requests, actors re-placed live",
-             lambda: measure_chaos_node_drain(rt, cluster))
-    finally:
-        cluster.shutdown()
-
-    if _only and not results:
-        # a typo'd substring must not exit 0 claiming a refresh happened
-        sys.exit(f"--only {','.join(_only)!r} matched no dimension: "
-                 f"nothing was measured, {args.out} left untouched")
-    doc = None
-    if _only and os.path.exists(args.out):
-        try:
-            with open(args.out) as f:
-                doc = json.load(f)
-            rows = {r["dimension"]: r for r in doc.get("results", [])}
-        except (OSError, ValueError, KeyError, TypeError) as e:
-            # never quietly replace a (possibly multi-hour) envelope doc
-            # with just the re-run legs
-            sys.exit(f"--only merge: cannot parse existing {args.out} "
-                     f"({e!r}); fix or remove it first")
-        for r in results:
-            rows[r["dimension"]] = r
-        doc["results"] = list(rows.values())
-    if doc is None:
-        doc = {
-            "suite": f"scalability envelope ({args.profile} profile)",
-            "host": {"cpus": os.cpu_count()},
-            "note": ("reference envelope numbers were demonstrated on"
-                     " 2000-node clusters / 64-core machines"
-                     " (release/benchmarks); these legs exercise the same"
-                     " dimensions on a 1-core CI sandbox — every scale is"
-                     " a flag for real-cluster runs"),
-            "results": results,
-        }
-    with open(args.out, "w") as f:
-        json.dump(doc, f, indent=1)
-    print(f"wrote {args.out}")
-
-
-if __name__ == "__main__":
-    main()

@@ -1,53 +1,40 @@
-"""Serve benchmarks (BASELINE config #5 artifact + the ISSUE-10
-sustained-load data-plane leg).
+"""Load drivers for the serve tests (`tests/test_serve_load.py`).
 
-Leg ``engine`` drives `ray_tpu.serve.llm.LLMEngine` directly
-(in-process, no HTTP hop) with N concurrent closed-loop streams and
-reports generated tokens/s, TTFT p50/p99, inter-token latency p50/p99,
-and late-join TTFT (the continuous-batching headline).
+Each `run_*` function builds its own app on the cluster (or engine) the
+test gives it, drives it, and returns a dict of counts and host-clock
+latencies for the test to assert on. Nothing is written to disk, and a
+number from here is a host reading on whatever backend jax resolved,
+never a chip metric (those come from `python3 -m benchmarks.run`).
 
-Leg ``sustained`` exercises the FULL serve data plane end to end:
+``run_sustained`` exercises the full serve data plane end to end:
 cluster + controller + autoscaled replicas + HTTP ingress proxy, driven
 OPEN-LOOP (arrivals fire on a fixed schedule regardless of completions
-— the only honest way to measure an admission-controlled system):
+-- the only honest way to load an admission-controlled system):
 
-  1. steady state (>=30s) below capacity — p50/p99 admitted latency and
+  1. steady state below capacity: p50/p99 admitted latency and
      achieved QPS,
-  2. a burst at ~2x min-replica capacity — excess requests must SHED
+  2. a burst at ~2x min-replica capacity: excess requests must SHED
      with 503 (zero admitted-request timeouts) while the autoscaler
      scales replicas up,
-  3. drain — replicas must return to min_replicas.
+  3. drain: replicas must return to min_replicas.
 
-Ref analog: release/benchmarks/README.md throughput/latency tables +
-serve benchmarks in release/serve_tests; the engine design itself is
-TPU-native (static slots, per-row KV depths) with no reference
-equivalent.
+``run_latency`` drives the streaming request path the way a client sees
+it: open-loop SSE arrivals through the HTTP proxy against a paced
+async-generator app, client-observed TTFT (first SSE chunk) and TPOT
+(inter-chunk gap), cross-checked against the server-side per-request
+waterfall records in the GCS serve-state store (mean seconds per stage:
+admission/router/dispatch/stream plus the replica queue/service nest).
 
-Leg ``latency`` (ISSUE 16) measures the streaming request path the way
-a client sees it: open-loop SSE arrivals through the HTTP proxy against
-a paced async-generator app, client-observed TTFT (first SSE chunk) and
-TPOT (inter-chunk gap) p50/p99, then cross-checks against the
-server-side per-request waterfall records in the GCS serve-state store
-(mean seconds per stage: admission/router/dispatch/stream plus the
-replica queue/service nest) so the two clocks can be compared in one
-artifact.
-
-Leg ``multi_proxy`` (ISSUE 19) covers the sharded data plane in three
-sub-legs: ``fanout`` — open-loop arrivals round-robined across N HTTP
-proxy replicas sharing one admission window (per-proxy shares checked
-against the cluster window), with one proxy KILLED mid-burst — zero
-admitted failures allowed and the dead member's share must
-redistribute within one heartbeat TTL; ``prefix`` — repeated-prefix
-TTFT vs cold through the engine's prefix KV store; ``disagg`` —
-decode-pool occupancy with long prompts prefilled in a SEPARATE engine
-and handed over the shm device edge as one packed raw-shard tick,
-vs the fused baseline that prefills inside the decode engine.
-
-Writes SERVE_BENCH.json at the repo root ({"engine": ..,
-"sustained_load": .., "request_latency": .., "multi_proxy": ..};
---leg selects, existing legs are preserved on a partial refresh). Platform: runs on whatever
-backend jax resolves, with "platform" recorded in every leg so a CPU
-leg is never read as a device number.
+``run_multi_proxy_fanout``: open-loop arrivals round-robined across N
+HTTP proxy replicas sharing one admission window (per-proxy shares
+checked against the cluster window), with one proxy KILLED mid-burst:
+zero admitted failures allowed and the dead member's share must
+redistribute within one heartbeat TTL. ``run_prefix_reuse``:
+repeated-prefix TTFT vs cold through the engine's prefix KV store.
+``run_disagg``: decode-pool occupancy with long prompts prefilled in a
+SEPARATE engine and handed over the shm device edge as one packed
+raw-shard tick, vs the fused baseline that prefills inside the decode
+engine.
 """
 
 from __future__ import annotations
@@ -68,93 +55,6 @@ def _pct(xs, p):
     xs = sorted(xs)
     i = min(len(xs) - 1, int(round(p / 100 * (len(xs) - 1))))
     return xs[i]
-
-
-async def _run_bench(preset: str, concurrency: int, requests: int,
-                     max_new: int, prompt_len: int):
-    import numpy as np
-
-    from ray_tpu.serve.llm import LLMEngine
-
-    eng = LLMEngine(preset, max_batch=concurrency,
-                    prompt_buckets=(32, 128), max_seq_len=512)
-    rng = np.random.default_rng(0)
-
-    # warmup: trace prefill + decode + insert paths once
-    async for _ in eng.generate(list(rng.integers(1, 100, prompt_len)),
-                                max_new_tokens=4):
-        pass
-
-    ttfts: list[float] = []
-    itls: list[float] = []
-    done = 0
-
-    async def one_stream():
-        nonlocal done
-        while done < requests:
-            done += 1
-            prompt = list(rng.integers(1, 100, prompt_len))
-            t0 = time.perf_counter()
-            last = None
-            async for _tok in eng.generate(prompt, max_new_tokens=max_new):
-                now = time.perf_counter()
-                if last is None:
-                    ttfts.append(now - t0)
-                else:
-                    itls.append(now - last)
-                last = now
-
-    t_start = time.perf_counter()
-    gen0 = eng.generated_tokens
-    await asyncio.gather(*[one_stream() for _ in range(concurrency)])
-    elapsed = time.perf_counter() - t_start
-    tokens = eng.generated_tokens - gen0
-
-    # late-join probe: saturate all slots with long generations, then
-    # inject one short request and time its first token
-    async def long_stream():
-        async for _ in eng.generate(list(rng.integers(1, 100, prompt_len)),
-                                    max_new_tokens=max_new * 4):
-            pass
-
-    base_steps = eng.batches
-    background = [asyncio.ensure_future(long_stream())
-                  for _ in range(max(1, concurrency - 1))]
-    # wait until the background streams are admitted and well into
-    # decode, so the probe measures joining a SATURATED batch
-    while (eng.batches - base_steps < 5
-           and not all(b.done() for b in background)):
-        await asyncio.sleep(0.005)
-    t0 = time.perf_counter()
-    late_ttft = None
-    async for _tok in eng.generate(list(rng.integers(1, 100, prompt_len)),
-                                   max_new_tokens=2):
-        if late_ttft is None:
-            late_ttft = time.perf_counter() - t0
-    await asyncio.gather(*background)
-
-    import jax
-
-    def _ms(v, nd=2):
-        return None if v is None else round(v * 1e3, nd)
-
-    return {
-        "metric": "serve_llm_engine_throughput",
-        "preset": preset,
-        "platform": jax.devices()[0].platform,
-        "concurrency": concurrency,
-        "requests": requests,
-        "prompt_len": prompt_len,
-        "max_new_tokens": max_new,
-        "tokens_per_sec": round(tokens / elapsed, 1),
-        "ttft_p50_ms": _ms(_pct(ttfts, 50)),
-        "ttft_p99_ms": _ms(_pct(ttfts, 99)),
-        "itl_p50_ms": _ms(_pct(itls, 50), 3),
-        "itl_p99_ms": _ms(_pct(itls, 99), 3),
-        "late_join_ttft_ms": _ms(late_ttft),
-        "decode_steps": eng.batches,
-        "prefills": eng.prefills,
-    }
 
 
 # --------------------------------------------------------- sustained leg
@@ -842,24 +742,6 @@ def run_disagg(*, streams: int = 4, stream_new_tokens: int = 100,
     }
 
 
-def run_multi_proxy() -> dict:
-    """The full PR-19 data-plane leg: sharded-ingress fan-out (with the
-    chaos drill) inside a cluster, then the in-process prefix-reuse and
-    disagg comparisons."""
-    import ray_tpu as rt
-    from ray_tpu import serve
-
-    rt.init(num_cpus=4)
-    try:
-        fanout = run_multi_proxy_fanout()
-    finally:
-        serve.shutdown()
-        rt.shutdown()
-    return {"fanout": fanout,
-            "prefix": run_prefix_reuse(),
-            "disagg": run_disagg()}
-
-
 def _serve_metric_totals() -> dict:
     """Cluster-wide serve counters from the GCS metrics store (proves
     the Prometheus family is emitting: rayt_serve_{shed,admitted}_total
@@ -881,73 +763,3 @@ def _serve_metric_totals() -> dict:
     except Exception:
         pass
     return out
-
-
-def _load_existing(path: str) -> dict:
-    try:
-        with open(path) as f:
-            data = json.load(f)
-    except Exception:
-        return {}
-    if "metric" in data:  # pre-ISSUE-10 single-leg layout
-        return {"engine": data}
-    return data if isinstance(data, dict) else {}
-
-
-def main():
-    import argparse
-
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--leg",
-                    choices=("engine", "sustained", "latency",
-                             "multi_proxy", "all"),
-                    default="all")
-    ap.add_argument("--preset", default="debug")
-    ap.add_argument("--concurrency", type=int, default=8)
-    ap.add_argument("--requests", type=int, default=32)
-    ap.add_argument("--max-new", type=int, default=32)
-    ap.add_argument("--prompt-len", type=int, default=24)
-    ap.add_argument("--steady-s", type=float, default=30.0)
-    ap.add_argument("--burst-s", type=float, default=10.0)
-    ap.add_argument("--out", default=os.path.join(ROOT, "SERVE_BENCH.json"))
-    ap.add_argument("--no-write", action="store_true")
-    args = ap.parse_args()
-
-    out = _load_existing(args.out)
-    if args.leg in ("engine", "all"):
-        out["engine"] = asyncio.run(_run_bench(
-            args.preset, args.concurrency, args.requests, args.max_new,
-            args.prompt_len))
-    if args.leg in ("sustained", "all"):
-        import ray_tpu as rt
-        from ray_tpu import serve
-
-        rt.init(num_cpus=4)
-        try:
-            out["sustained_load"] = run_sustained(
-                steady_s=args.steady_s, burst_s=args.burst_s)
-        finally:
-            serve.shutdown()
-            rt.shutdown()
-    if args.leg in ("latency", "all"):
-        import ray_tpu as rt
-        from ray_tpu import serve
-
-        rt.init(num_cpus=4)
-        try:
-            out["request_latency"] = run_latency()
-        finally:
-            serve.shutdown()
-            rt.shutdown()
-    if args.leg in ("multi_proxy", "all"):
-        out["multi_proxy"] = run_multi_proxy()
-    out["captured_at"] = time.strftime("%Y-%m-%dT%H:%M:%SZ",
-                                       time.gmtime())
-    print(json.dumps(out, indent=1))
-    if not args.no_write:
-        with open(args.out, "w") as f:
-            json.dump(out, f, indent=1)
-
-
-if __name__ == "__main__":
-    main()
