@@ -15,6 +15,7 @@ GQA (n_kv_heads < n_heads) handled in both paths.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,10 @@ import jax.numpy as jnp
 from ray_tpu.ops.rope import apply_rope
 
 NEG_INF = -1e30
+
+
+def _on_tpu() -> bool:
+    return any(d.platform == "tpu" for d in jax.devices())
 
 
 def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
@@ -68,8 +73,7 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           impl: str = "auto",
                           block_q: int = 512, block_k: int = 512) -> jax.Array:
     if impl == "auto":
-        on_tpu = any(d.platform == "tpu" for d in jax.devices())
-        impl = ("flash" if on_tpu and q.shape[1] >= 1024
+        impl = ("flash" if _on_tpu() and q.shape[1] >= 1024
                 and segment_ids is None else "xla")
     if impl == "flash":
         from ray_tpu.ops.pallas.flash_attention import flash_attention
@@ -97,6 +101,82 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         scale=scale)
 
 
+def decode_block_len(kv_heads: int, head_dim: int, max_len: int,
+                     dtype, mesh) -> int | None:
+    """Positions in one block of the decode kernel
+    (ops/pallas/decode_attention.py) for a cache of this shape under
+    `mesh` (a mesh or an abstract one; kv heads split as the rules say),
+    or None where `cached_attention` reads the whole layer on the XLA
+    path. A block takes all of a device's kv heads of a row and about
+    1 MiB of K (as much of V; both double-buffered, 4 MiB of VMEM): a
+    live row's range is rarely under a few hundred positions and ends
+    inside a block at either side, so larger blocks read more than they
+    save in grid steps (0.16 us a step against 2.8 us a block, PERF.md,
+    PR 31), and smaller ones gain nothing measurable."""
+    if not _on_tpu():
+        return None
+    kv_heads //= _kv_head_shards(mesh)
+    block = 128
+    while (max_len % (2 * block) == 0 and 2 * block * kv_heads * head_dim
+           * jnp.dtype(dtype).itemsize <= 2 ** 20):
+        block *= 2
+    return block if max_len % block == 0 else None
+
+
+def _kv_head_shards(mesh) -> int:
+    if mesh.empty:
+        return 1
+    from ray_tpu.parallel.mesh import spec_for
+
+    axes = spec_for(("kv_heads",), mesh=mesh)[0] or ()
+    axes = (axes,) if isinstance(axes, str) else axes
+    return math.prod(mesh.shape[a] for a in axes)
+
+
+def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                     li, start: jax.Array, length: jax.Array, *,
+                     scale: float, block_len: int) -> jax.Array:
+    """The decode kernel on this device's kv heads: q ``[b, kv_heads,
+    group, hd]`` against layer `li` of the stacked caches, row r over
+    positions ``[start[r], length[r]]`` read in blocks of `block_len`.
+    With head_dim under a lane row (64, the hybrid model) the compiler
+    holds V with positions minor, ``[hd, len]`` like K, and a kernel
+    that takes V as declared costs a re-laid copy of the whole stack a
+    step (1.07 GB for 32 slots x 4096; sandbox compile, PR 31): the
+    kernel is then given V in that order, which is no operation.
+    Under a mesh the call runs per shard, as the flash kernel does
+    (`dot_product_attention`): kv heads attend independently."""
+    from ray_tpu.ops.pallas.decode_attention import (
+        decode_attention as kernel)
+
+    v_positions_minor = q.shape[-1] < 128
+    if v_positions_minor:
+        v_cache = jnp.swapaxes(v_cache, 3, 4)
+
+    def call(q, k_cache, v_cache, li, start, length):
+        return kernel(q, k_cache, v_cache, li, start, length, scale=scale,
+                      block_len=block_len,
+                      v_positions_minor=v_positions_minor)
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return call(q, k_cache, v_cache, li, start, length)
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.parallel.mesh import spec_for
+
+    q_spec = spec_for(("batch", "kv_heads", None, None), mesh=mesh)
+    k_spec = spec_for(("layers", "batch", "kv_heads", "head_dim", None),
+                      mesh=mesh)
+    v_spec = k_spec if v_positions_minor else spec_for(
+        ("layers", "batch", "kv_heads", None, "head_dim"), mesh=mesh)
+    rows = spec_for(("batch",), mesh=mesh)
+    return jax.shard_map(call, mesh=mesh,
+                         in_specs=(q_spec, k_spec, v_spec, P(), rows, rows),
+                         out_specs=q_spec, check_vma=False)(
+                             q, k_cache, v_cache, li, start, length)
+
+
 def cached_attention(q: jax.Array, kk: jax.Array, vv: jax.Array,
                      k_cache: jax.Array, v_cache: jax.Array, li,
                      cache_len, abs_positions: jax.Array, start, *,
@@ -117,6 +197,14 @@ def cached_attention(q: jax.Array, kk: jax.Array, vv: jax.Array,
     slots of each row. `scale` multiplies the scores. `rope` is (cos,
     sin, positions) for rotary embeddings on q and k, or None for a
     model without position embeddings.
+
+    A decode step with per-row depths on a TPU reads, for each row, only
+    the blocks of K and V that overlap ``[start[row], cache_len[row]]``
+    (`decode_attention`; a row with ``cache_len < start``, which is how
+    the engine marks a slot that holds no request, reads nothing and
+    gets zeros). Prefill chunks (s > 1), the lock-step batch (a scalar
+    `cache_len`), every other platform and the shapes `decode_block_len`
+    turns down read the layer whole and mask, below.
     Returns (attn [b, s, heads * hd], k_cache, v_cache)."""
     b, s, nh, hd = q.shape
     nkv = kk.shape[2]
@@ -157,6 +245,16 @@ def cached_attention(q: jax.Array, kk: jax.Array, vv: jax.Array,
         # Over kv-head groups, K and V as they lie in the cache: the
         # group's query heads are rows of one matmul per kv head, so no
         # GQA repeat of K or V exists anywhere.
+        block = decode_block_len(
+            nkv, hd, v_cache.shape[3], v_cache.dtype,
+            jax.sharding.get_abstract_mesh()) if (
+                s == 1 and jnp.ndim(cache_len) == 1) else None
+        if block is not None:
+            attn = decode_attention(
+                q.reshape(b, nkv, group, hd), k_cache, v_cache, li,
+                jnp.zeros_like(cache_len) if start is None else start,
+                cache_len, scale=scale, block_len=block)
+            return attn.reshape(b, s, nh * hd), k_cache, v_cache
         k_l = jax.lax.dynamic_index_in_dim(k_cache, li, 0, keepdims=False)
         v_l = jax.lax.dynamic_index_in_dim(v_cache, li, 0, keepdims=False)
         max_len = v_l.shape[2]

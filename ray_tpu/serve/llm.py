@@ -31,6 +31,7 @@ import numpy as np
 
 from ray_tpu._internal.profiler import span_type
 from ray_tpu.models import llama, module_for
+from ray_tpu.ops.attention import decode_block_len
 from ray_tpu.parallel.mesh import build_mesh, shard_params, spec_for
 from ray_tpu.serve.multiplex import multiplexed
 
@@ -80,6 +81,7 @@ class _Slot:
     req: _Request
     emitted: int = 0
     length: int = 0  # host view of the row's cache depth
+    start: int = 0   # the row's first real position (left padding)
 
 
 @dataclass
@@ -214,9 +216,20 @@ class LLMEngine:
                 tokens = tokens[:, None]
             # the phase, known from the static shape, names every device
             # operation of this trace in the profiler (metadata only)
-            with jax.named_scope("prefill" if tokens.shape[1] > 1
-                                 else "decode"):
+            # under the engine's mesh, so that a kernel in the model
+            # finds it and runs per shard (ops/attention.py)
+            with jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh), \
+                    jax.named_scope("prefill" if tokens.shape[1] > 1
+                                    else "decode"):
+                depth = cache["length"]
                 logits, cache = mod.decode_step(params, cache, tokens, cfg)
+                if depth.ndim:
+                    # the slots' cache: a row that holds no request
+                    # (depth < 0, `retire`) stays that way until
+                    # insert_row gives it one; the model's attention
+                    # reads nothing for it
+                    cache["length"] = jnp.where(depth < 0, depth,
+                                                cache["length"])
                 with jax.named_scope("sample"):
                     key, sub = jax.random.split(key)
                     greedy = jnp.argmax(logits, axis=-1)
@@ -267,6 +280,16 @@ class LLMEngine:
             return out
 
         self._insert_row = jax.jit(insert_row, donate_argnums=(0,))
+        # the rows of `gone` hold no request from the next step on
+        self._retire = jax.jit(
+            lambda length, gone: jnp.where(gone, -1, length),
+            donate_argnums=(0,),
+            out_shardings=self._cache_sharding["length"])
+        # positions in a block of the decode step's attention reads;
+        # None where it reads the whole cache (ops/attention.py)
+        self._decode_block = decode_block_len(
+            cfg.n_kv_heads, cfg.head_dim, cfg.max_seq_len, cfg.dtype,
+            self.mesh)
 
         def set_slot(cur, temps, slot, tok, temp):
             return cur.at[slot].set(tok), temps.at[slot, 0].set(temp)
@@ -284,6 +307,9 @@ class LLMEngine:
         self._epoch = 0
         self._slots: list[Optional[_Slot]] = [None] * max_batch
         self._decode_cache = None  # lazy: built on first request
+        # per row, whether the device's cache["length"] says it holds a
+        # request: set by insert_row, cleared by _retire
+        self._row_live = [False] * max_batch
         # device-resident between steps: re-uploading from host every
         # decode step would cost two H2D transfers per token
         self._cur = jnp.zeros((max_batch,), jnp.int32)
@@ -316,6 +342,12 @@ class LLMEngine:
         # rows of a look-ahead step whose request had ended (eos) by
         # the time the step was read or dropped: the wasted work
         self.decode_rows_discarded = 0
+        # summed over dispatched decode steps: positions inside the live
+        # rows' [start, length], and positions of the blocks the step's
+        # attention is asked to read (the whole cache where no kernel
+        # bounds the read)
+        self.decode_kv_positions_live = 0
+        self.decode_kv_positions_read = 0
         self.prefills = 0
         self.prefill_chunks = 0
         self.prefix_hits = 0
@@ -360,6 +392,7 @@ class LLMEngine:
                         except asyncio.QueueEmpty:
                             break
                 self._slots = [None] * self.max_batch
+                self._row_live = [False] * self.max_batch
                 self._inflight = None
                 self._decode_cache = None
                 self._cur = jnp.zeros((self.max_batch,), jnp.int32)
@@ -521,7 +554,12 @@ class LLMEngine:
                                            max_len=self.cfg.max_seq_len)
             # per-row depths: each slot is an independent request
             cache["length"] = jnp.zeros((self.max_batch,), jnp.int32)
-            self._decode_cache = jax.device_put(cache, self._cache_sharding)
+            cache = jax.device_put(cache, self._cache_sharding)
+            # and holds none yet (through _retire, so that its program
+            # exists before the first request ends)
+            cache["length"] = self._retire(
+                cache["length"], np.ones((self.max_batch,), bool))
+            self._decode_cache = cache
 
     def _finish(self, i: int):
         s = self._slots[i]
@@ -778,7 +816,9 @@ class LLMEngine:
                 # every active slot's KV, not just the new request's
                 self._poison_recover()
                 raise
-            self._slots[slot] = _Slot(req, emitted=1, length=bucket)
+            self._slots[slot] = _Slot(req, emitted=1, length=bucket,
+                                      start=start)
+            self._row_live[slot] = True
             self._cur, self._temps = self._set_slot(
                 self._cur, self._temps, jnp.int32(slot), jnp.int32(first),
                 jnp.float32(req.temperature))
@@ -805,6 +845,7 @@ class LLMEngine:
             pf.req.loop.call_soon_threadsafe(pf.req.out.put_nowait, err)
         self._pending_prefills = []
         self._slots = [None] * self.max_batch
+        self._row_live = [False] * self.max_batch
         self._inflight = None
         self._decode_cache = None
         self._cur = jnp.zeros((self.max_batch,), jnp.int32)
@@ -865,18 +906,49 @@ class LLMEngine:
         active = sum(1 for r in rows if r is not None)
         if not active:
             return None
+        gone = [live and r is None for live, r in zip(self._row_live, rows)]
+        if any(gone):
+            # freed since the last step (_finish, or the rule above):
+            # tell the device, so the step reads none of their cache
+            self._decode_cache["length"] = self._retire(
+                self._decode_cache["length"], np.asarray(gone))
+            self._row_live = [r is not None for r in rows]
+        live, read = self._kv_positions(rows, prev)
         # t_host is this process's perf_counter read as the span opens:
         # the one event that carries both clocks, so request records and
         # a client's stamps (CLOCK_MONOTONIC, one clock for the host)
         # can be laid on the profiler's time axis
         with _span("rayt.engine.decode_dispatch", active=active,
-                   t_host=time.perf_counter()):
+                   live_positions=live, t_host=time.perf_counter()):
             nxt, self._decode_cache, self._key = self._step(
                 self.params, self._decode_cache, self._cur,
                 self._key, self._temps)
         self._cur = nxt  # stays on device for the next step
         self.batches += 1
+        self.decode_kv_positions_live += live
+        self.decode_kv_positions_read += read
         return _InFlight(nxt, rows, active)
+
+    def _kv_positions(self, rows: list, prev: Optional[_InFlight]):
+        """(live, read) of the step about to be dispatched for `rows`:
+        the positions its live rows attend to, each [start, the slot the
+        step writes], and the positions of the blocks its attention is
+        asked to read: the blocks that overlap those ranges (and one
+        that nobody reads where row 0 holds no request:
+        ops/pallas/decode_attention.py), or the whole cache where
+        nothing bounds the read."""
+        # per live row (start, the slot the step writes); the step in
+        # flight has not been counted into a slot's length yet
+        spans = [(self._slots[i].start, self._slots[i].length
+                  + int(prev is not None and prev.rows[i] is req))
+                 for i, req in enumerate(rows) if req is not None]
+        live = sum(last - start + 1 for start, last in spans)
+        block = self._decode_block
+        if not block:
+            return live, self.max_batch * self.cfg.max_seq_len
+        blocks = sum(last // block - start // block + 1
+                     for start, last in spans)
+        return live, (blocks + (rows[0] is None)) * block
 
     def _owned_rows(self, rec: _InFlight) -> list:
         """(row, slot) for every row of a dispatched step whose slot
@@ -935,6 +1007,8 @@ class LLMEngine:
                 "kv_handoffs": self.kv_handoffs,
                 "decode_overlapped": self.decode_overlapped,
                 "decode_rows_discarded": self.decode_rows_discarded,
+                "decode_kv_positions_live": self.decode_kv_positions_live,
+                "decode_kv_positions_read": self.decode_kv_positions_read,
                 "active_slots": sum(1 for s in self._slots
                                     if s is not None),
                 "tp": self.mesh.shape.get("tensor", 1)}

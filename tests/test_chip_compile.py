@@ -21,6 +21,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from ray_tpu.models import granite_hybrid, llama
+from ray_tpu.ops import attention
+from ray_tpu.ops.pallas import decode_attention as da
 from ray_tpu.ops.pallas import flash_attention as fa
 
 HBM_BYTES = 16 * 2**30  # one v5e chip
@@ -58,6 +60,14 @@ def compiled_not_interpreted(monkeypatch):
     """The tests run with JAX_PLATFORMS=cpu, which is what selects
     interpret mode; these compile the kernel itself."""
     monkeypatch.setattr(fa, "_interpret", lambda: False)
+
+
+@pytest.fixture
+def on_the_chip(monkeypatch, compiled_not_interpreted):
+    """What `cached_attention` selects by on a TPU: the decode kernel
+    where the shape allows it, compiled. (`jax.devices()` here is the
+    CPU's, whatever the program is compiled for.)"""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
 
 
 def _flash_fwd(q, k, v, o, lse, do, bq, bk):
@@ -102,6 +112,46 @@ def test_flash_kernel_compiles_for_the_chip(chip, compiled_not_interpreted,
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("layers,batch,kv_heads,group,head_dim", [
+    (24, 8, 8, 2, 128),     # InternLM2-1.8B, the chat cell's 8 slots
+    (4, 32, 8, 4, 64),      # granite-4.0-h-micro's attention layers
+], ids=["internlm2-8x4096", "granite-32x4096"])
+def test_decode_kernel_compiles_for_the_chip(chip, on_the_chip, layers,
+                                             batch, kv_heads, group,
+                                             head_dim):
+    """The decode kernel alone at the serve cells' shapes, in the blocks
+    `decode_block_len` gives them: it fits VMEM at both and takes the
+    stacks as they lie (temporaries under a MiB). With head_dim 64 that
+    is V in K's order: the compiler holds such a V with positions minor,
+    and re-lays the whole stack out (1.07 GB here) for a kernel that
+    takes it as declared."""
+    max_len = 4096
+    on = SingleDeviceSharding(chip)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    args = (arg((batch, kv_heads, group, head_dim)),
+            arg((layers, batch, kv_heads, head_dim, max_len)),
+            arg((layers, batch, kv_heads, max_len, head_dim)),
+            arg((), jnp.int32), arg((batch,), jnp.int32),
+            arg((batch,), jnp.int32))
+    block = attention.decode_block_len(kv_heads, head_dim, max_len,
+                                       jnp.bfloat16, jax.sharding.Mesh(
+                                           [chip], ("tensor",)))
+    assert block == 2 ** 20 // (kv_heads * head_dim * 2)
+    compiled = jax.jit(lambda *a: attention.decode_attention(
+        *a, scale=head_dim ** -0.5, block_len=block)).lower(*args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    if head_dim < 128:
+        as_declared = jax.jit(lambda *a: da.decode_attention(
+            *a, scale=head_dim ** -0.5, block_len=block)).lower(
+                *args).compile()
+        assert as_declared.memory_analysis().temp_size_in_bytes >= (
+            2 * layers * batch * kv_heads * max_len * head_dim)
+
+
 def _compiled_decode_step(chip, cfg, batch, max_len, s, per_row):
     """llama.decode_step for `batch` rows x `s` tokens against a cache
     `max_len` deep, cache donated, compiled for the described chip.
@@ -144,11 +194,15 @@ _RESULT = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = ([a-z0-9]+)\[([0-9,]*)\][^ ]* ([\w\-]+)\(")
 
 
-@pytest.mark.parametrize("batch,max_len,s,per_row", [
-    (8, 4096, 1, True),       # the engine's decode step, 8 slots
-    (1, 3584, 256, False),    # one chunk of a long prompt's prefill
-], ids=["decode-8x4096", "chunk-1x3584-s256"])
-def test_decode_step_moves_no_cache(chip, batch, max_len, s, per_row):
+@pytest.mark.parametrize("batch,max_len,s,per_row,kernels", [
+    (8, 4096, 1, True, 0),    # the engine's decode step, 8 slots: XLA path
+    (1, 3584, 256, False, 0),  # one chunk of a long prompt's prefill
+    (8, 4096, 1, True, 1),    # the decode step as a TPU runs it
+    (1, 3584, 256, False, 0),  # the chunk as a TPU runs it: no kernel
+], ids=["decode-8x4096", "chunk-1x3584-s256", "decode-8x4096-on-chip",
+        "chunk-1x3584-s256-on-chip"])
+def test_decode_step_moves_no_cache(chip, request, batch, max_len, s,
+                                    per_row, kernels):
     """Per step every cache byte is read at most once, by attention, and
     only the new rows are written (PERF.md, PR 25): with the cache
     donated the step's temporaries hold less than ONE layer of it (seed:
@@ -156,10 +210,15 @@ def test_decode_step_moves_no_cache(chip, batch, max_len, s, per_row):
     is updated in its own buffers, nothing but the in-place row writes
     produces an array of a whole stack's shape, and no array of positions
     x head_dim is as large as a layer's K repeated over its group's query
-    heads."""
+    heads. On the chip the decode step's attention is the decode kernel
+    (PR 31), which takes the stacks as they lie: the same rule, with one
+    Mosaic call in the layer loop; a chunk keeps the XLA path."""
+    if request.node.callspec.id.endswith("on-chip"):
+        request.getfixturevalue("on_the_chip")
     cfg = llama.LlamaConfig(max_seq_len=max_len, **_SERVE_CFG)
     compiled, cache = _compiled_decode_step(chip, cfg, batch, max_len, s,
                                             per_row)
+    assert compiled.as_text().count("tpu_custom_call") == kernels
 
     stacks = {tuple(cache[key].shape) for key in ("k", "v")}
     layer_bytes = 2 * math.prod(cache["k"].shape[1:])
@@ -186,8 +245,9 @@ _HYBRID_PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 @pytest.mark.parametrize("batch,max_len,s,per_row", [
     (32, 4096, 1, True),      # the engine's decode step, 32 slots
     (1, 1024, 256, False),    # one chunk of a prompt's prefill
-], ids=["decode-32x4096", "chunk-1x1024-s256"])
-def test_hybrid_decode_step_moves_no_cache_and_no_state(chip, batch,
+    (32, 4096, 1, True),      # the decode step as a TPU runs it
+], ids=["decode-32x4096", "chunk-1x1024-s256", "decode-32x4096-on-chip"])
+def test_hybrid_decode_step_moves_no_cache_and_no_state(chip, request, batch,
                                                         max_len, s, per_row):
     """granite_hybrid.decode_step under the rule llama's is held to: with
     the cache donated, K, V, the recurrent state (2.4 GB for 32 slots)
@@ -199,7 +259,11 @@ def test_hybrid_decode_step_moves_no_cache_and_no_state(chip, batch,
     stack, 0.9 MB, out for the chunk's convolution).
     (With the input projection held fused, [36, 2048, 8512], the
     compiler copied that whole stack, 1.25 GB, on every step: PERF.md,
-    PR 28.)"""
+    PR 28.) On the chip the attention layers' decode is the decode
+    kernel (PR 31), given V in the order the compiler holds it in: the
+    same rule, with a Mosaic call in the period's loop."""
+    if request.node.callspec.id.endswith("on-chip"):
+        request.getfixturevalue("on_the_chip")
     cfg = granite_hybrid.GraniteHybridConfig(
         layer_types=_HYBRID_PERIOD * 4, max_seq_len=max_len)
     on = SingleDeviceSharding(chip)
@@ -220,6 +284,8 @@ def test_hybrid_decode_step_moves_no_cache_and_no_state(chip, batch,
         lambda p, c, t: granite_hybrid.decode_step(p, c, t, cfg),
         donate_argnums=(1,)).lower(params, place(cache), tokens).compile()
 
+    on_chip = request.node.callspec.id.endswith("on-chip")
+    assert ("tpu_custom_call" in compiled.as_text()) == on_chip
     rows = ("k", "v", "state", "conv")
     nbytes = {key: math.prod(cache[key].shape) * cache[key].dtype.itemsize
               for key in rows}

@@ -118,6 +118,8 @@ def test_engine_writes_every_span_into_the_profilers_trace(tmp_path):
         [(16, 16, 0), (32, 16, 0), (48, 16, 1)]
     for s in by_name["rayt.engine.decode_dispatch"]:
         assert isinstance(s[3]["t_host"], float) and s[3]["active"] >= 1
+        # at least the token each live row writes in the step
+        assert s[3]["live_positions"] >= s[3]["active"]
     assert {s[3]["finished"] for s in by_name["rayt.engine.emit"]} >= {0, 1}
     # token_sync is a unit of its own: inside no other engine span
     for line, start, end, _ in by_name["rayt.engine.token_sync"]:
@@ -169,6 +171,9 @@ def test_token_sync_of_a_step_follows_the_dispatch_of_the_next(tmp_path):
     steps = st["batches"] - before["batches"]
     assert steps == len(dispatch)
     assert st["decode_overlapped"] - before["decode_overlapped"] == overlapped
+    # the live depths of the traced steps are what stats() summed
+    assert sum(d[2]["live_positions"] for d in dispatch) == \
+        st["decode_kv_positions_live"] - before["decode_kv_positions_live"]
     assert overlapped >= steps - (st["prefills"] - before["prefills"]) - 1
     assert st["decode_rows_discarded"] == 0
 

@@ -463,3 +463,91 @@ def test_restart_on_a_new_loop_drops_the_step_in_flight():
 
     assert asyncio.run(fresh()) == _greedy_reference(eng, [7, 7], 6)
     assert eng._inflight is None and eng.decode_rows_discarded == 0
+
+
+# ------------------------------------------- live rows, live positions
+_KERNEL_BLOCK = 32
+
+
+@pytest.fixture(params=["xla", "kernel"])
+def decode_path(request, monkeypatch):
+    """The decode step's attention on the XLA path, as every platform
+    but a TPU takes it, and through the decode kernel (interpreted, in
+    blocks of _KERNEL_BLOCK positions), as a TPU does."""
+    if request.param == "kernel":
+        from ray_tpu.ops import attention
+        from ray_tpu.serve import llm
+
+        monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+        for mod in (attention, llm):
+            monkeypatch.setattr(mod, "decode_block_len",
+                                lambda *a: _KERNEL_BLOCK)
+    return request.param
+
+
+def _small_engine(max_batch=2):
+    return LLMEngine("debug", tp=1, max_batch=max_batch, max_seq_len=256,
+                     prompt_buckets=(16, 64), prefill_chunk=0,
+                     prefix_cache_entries=0)
+
+
+def test_request_in_a_left_slot_streams_as_in_a_fresh_engine(decode_path):
+    """A slot another request has left is told to the device as holding
+    nothing (`_retire`) and is given to the next request by insert_row:
+    the greedy tokens of a request admitted into it, beside a stream
+    that is still live, are those a fresh engine gives it, whatever the
+    slot's row held before (a deeper bucket's K and V here)."""
+    eng = _small_engine()
+    leaver = (list(range(1, 41)), 4)        # bucket 64, leaves first
+    stayer = ([17, 4, 9], 120)
+    late = ([5, 9, 11], 12)                 # bucket 16, into the left slot
+
+    async def run():
+        a = asyncio.ensure_future(_agen_list(
+            eng.generate(leaver[0], max_new_tokens=leaver[1])))
+        b = asyncio.ensure_future(_agen_list(
+            eng.generate(stayer[0], max_new_tokens=stayer[1])))
+        await a
+        c = await _agen_list(eng.generate(late[0], max_new_tokens=late[1]))
+        assert not b.done()         # beside a stream that is still live
+        return c, await b
+
+    got_late, got_stayer = asyncio.run(run())
+    fresh = _small_engine()
+    assert got_late == _collect(fresh, late[0], max_new_tokens=late[1])
+    assert got_stayer == _collect(fresh, stayer[0],
+                                  max_new_tokens=stayer[1])
+    # every row is told as left once nothing decodes
+    assert eng._row_live.count(True) <= 1
+
+
+def test_kv_position_counters_rise_with_dispatched_steps_only(decode_path):
+    """`decode_kv_positions_live` counts, per dispatched decode step, the
+    positions inside the live rows' [start, length];
+    `decode_kv_positions_read` those of the blocks the step's attention
+    is asked to read: at least the live ones, at most the whole cache."""
+    eng = _small_engine()
+    keys = ("batches", "decode_kv_positions_live",
+            "decode_kv_positions_read")
+    _collect(eng, [3, 8, 1], max_new_tokens=1)     # a prefill, no step
+    st0 = eng.stats()
+    assert [st0[k] for k in keys] == [0, 0, 0]
+    n, new = 5, 6
+    _collect(eng, list(range(1, n + 1)), max_new_tokens=new)
+    st = eng.stats()
+    steps = new - 1
+    assert st["batches"] == steps
+    # alone in the engine: step k attends to the prompt, the k tokens
+    # decoded before it and the one it writes
+    assert st["decode_kv_positions_live"] == sum(
+        n + 1 + k for k in range(steps))
+    whole = steps * eng.max_batch * eng.cfg.max_seq_len
+    read = st["decode_kv_positions_read"]
+    assert st["decode_kv_positions_live"] <= read <= whole
+    if decode_path == "kernel":
+        # bucket 16: the row's one block, and none for the empty slot
+        assert read == steps * _KERNEL_BLOCK
+    else:
+        assert read == whole
+    _collect(eng, [3, 8, 1], max_new_tokens=1)
+    assert [eng.stats()[k] for k in keys] == [st[k] for k in keys]
