@@ -12,12 +12,21 @@ def module_for(cfg):
     """The model module that serves a config, by the config's type. Each
     has the function set serve/llm.py's engine calls: init_params,
     param_logical_axes, forward, init_cache, cache_logical_axes,
-    CACHE_LEN_AXIS, decode_step, TENSOR_PARALLEL."""
-    from ray_tpu.models import granite_hybrid
+    CACHE_LEN_AXIS (the cache leaves with a position axis as deep as the
+    cache; every other leaf with a batch axis is grafted whole: a
+    recurrent state, a window's ring), decode_step, decode_read_block
+    (the positions in a block of a decode step's cache reads, or None),
+    TENSOR_PARALLEL. What the engine asks only where a module has it:
+    decode_counters (what a decode step counts of its live rows),
+    CACHE_KIND (the kind `stats()["cache_bytes"]` files a leaf under,
+    beside `kv` and `state`) and STEP_AUX (counters the step decides on
+    the device and returns in cache["aux"])."""
+    from ray_tpu.models import dots3_note, granite_hybrid
 
     for module, config_type in ((llama, llama.LlamaConfig),
                                 (granite_hybrid,
-                                 granite_hybrid.GraniteHybridConfig)):
+                                 granite_hybrid.GraniteHybridConfig),
+                                (dots3_note, dots3_note.Dots3NoteConfig)):
         if isinstance(cfg, config_type):
             return module
     raise TypeError(f"no model module serves a {type(cfg).__name__}")

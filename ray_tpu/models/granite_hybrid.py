@@ -34,7 +34,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import cached_attention, xla_attention
+# decode_read_block is this module's (models.module_for): K and V of
+# n_kv_heads x head_dim in every attention layer
+from ray_tpu.ops.attention import (cached_attention,  # noqa: F401
+                                   decode_read_block, xla_attention)
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ssm import causal_conv, ssd_scan, ssm_step
 

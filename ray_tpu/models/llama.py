@@ -34,7 +34,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops.attention import cached_attention, dot_product_attention
+# decode_read_block is this module's (models.module_for): K and V of
+# n_kv_heads x head_dim in every attention layer
+from ray_tpu.ops.attention import (cached_attention,  # noqa: F401
+                                   decode_read_block, dot_product_attention)
 from ray_tpu.ops.cross_entropy import softmax_cross_entropy
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies

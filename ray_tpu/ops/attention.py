@@ -123,6 +123,16 @@ def decode_block_len(kv_heads: int, head_dim: int, max_len: int,
     return block if max_len % block == 0 else None
 
 
+def decode_read_block(cfg, mesh) -> int | None:
+    """A model module's `decode_read_block` where every attention layer
+    caches K and V of `cfg.n_kv_heads` x `cfg.head_dim`: positions in a
+    block of the decode step's K and V reads under `mesh`, or None where
+    a step reads a layer's whole depth: what serve/llm.py counts
+    `decode_kv_positions_read` in."""
+    return decode_block_len(cfg.n_kv_heads, cfg.head_dim, cfg.max_seq_len,
+                            cfg.dtype, mesh)
+
+
 def _kv_head_shards(mesh) -> int:
     if mesh.empty:
         return 1

@@ -143,3 +143,95 @@ def moe_ffn(params: dict, x: jax.Array, cfg: MoEConfig,
     # combine: [g, s, E, C] x [E, g, C, d] -> [g, s, d]
     out = jnp.einsum("gsec,egcd->gsd", combine.astype(dt), expert_out)
     return out, aux_loss * cfg.aux_loss_weight
+
+
+# --------------------------------------------------------------------------
+# Dropless expert layer for one shard of the experts (serving)
+# --------------------------------------------------------------------------
+def route_sigmoid_topk(x: jax.Array, router: jax.Array, bias: jax.Array,
+                       top_k: int, *, normalize: bool = True,
+                       scaling: float = 1.0):
+    """The `noaux_tc` router over ALL routed experts, in float32: scores
+    ``s = sigmoid(x W_r)`` [T, E], the `top_k` experts of largest
+    ``s + bias`` (the bias moves the choice and nothing else), and their
+    weights ``s_e`` normalised over the chosen ones times `scaling`.
+    Returns (scores [T, E], chosen [T, k] int32, weights [T, k])."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if normalize:
+        weights = weights / weights.sum(-1, keepdims=True)
+    return scores, chosen.astype(jnp.int32), weights * scaling
+
+
+def held_experts_ffn(x: jax.Array, chosen: jax.Array, weights: jax.Array,
+                     w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+                     first: int = 0, *, valid: jax.Array | None = None,
+                     block_rows: int | None = None):
+    """The part of a routed expert layer that THIS holder of experts
+    computes: ``sum_j weights[t, j] * SwiGLU_e(x[t])`` over the chosen
+    experts e = chosen[t, j] that lie in [first, first + held), `held`
+    the leading axis of the stacked weights (w_gate, w_up ``[held, d,
+    f]``, w_down ``[held, f, d]``). What the other experts would add is
+    some other holder's (under a mesh with an `expert` axis this function
+    is what each shard runs; the exchange between shards is not here).
+
+    No capacity and no dropped token. The token-expert pairs are laid
+    out by expert, each expert's group padded to whole tiles of
+    `block_rows` rows, and a loop over the tiles THAT EXIST (its trip
+    count is decided on the device) multiplies each by its expert's
+    matrices: an expert no token chose is never read, one that many
+    chose takes as many tiles as it needs, and the buffer is sized for
+    the worst case (every pair on one held expert). x: [T, d]; chosen,
+    weights: [T, k]; valid: [T] bool or None, rows that are no token
+    (padding, a slot that holds no request) and reach no expert.
+    Returns (y [T, d] float32, pairs computed, held experts hit)."""
+    T, d = x.shape
+    k = chosen.shape[1]
+    held = w_gate.shape[0]
+    bm = block_rows or (16 if T <= 64 else 128)
+    pairs = T * k
+    n_slots = -(-pairs // bm) * bm + held * bm
+
+    local = chosen.reshape(pairs) - first
+    mine = (local >= 0) & (local < held)
+    if valid is not None:
+        mine = mine & jnp.repeat(valid, k)
+    local = jnp.where(mine, local, held)
+    onehot = (local[:, None] == jnp.arange(held)[None, :]).astype(jnp.int32)
+    counts = onehot.sum(0)                                    # [held]
+    rank = ((jnp.cumsum(onehot, axis=0) - onehot) * onehot).sum(-1)
+    ends = jnp.cumsum(-(-counts // bm) * bm)
+    offsets = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    slot = jnp.where(mine, offsets[jnp.minimum(local, held - 1)] + rank,
+                     n_slots)                                 # [pairs]
+    token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
+    slot_token = jnp.zeros((n_slots,), jnp.int32).at[slot].set(
+        token, mode="drop")
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        ends, jnp.arange(n_slots // bm) * bm, side="right"), held - 1)
+
+    def tile(i, y_slots):
+        e = tile_expert[i]
+        rows = jax.lax.dynamic_slice_in_dim(slot_token, i * bm, bm)
+        xt = jnp.take(x, rows, axis=0)
+        pick = lambda w: jax.lax.dynamic_index_in_dim(
+            w, e, 0, keepdims=False).astype(x.dtype)
+        h = jax.nn.silu(xt @ pick(w_gate)) * (xt @ pick(w_up))
+        return jax.lax.dynamic_update_slice_in_dim(
+            y_slots, h @ pick(w_down), i * bm, 0)
+
+    # zeros that depend on x: as a bare constant the compiler merges the
+    # layers' buffers into one broadcast that carries no scope's name
+    # (84 MB a layer a chunk, a quarter of the cell's unscoped device
+    # time; my chip run, PR 32)
+    y_slots = jax.lax.fori_loop(
+        0, ends[-1] // bm, tile,
+        jnp.broadcast_to(x[:1, :1] * jnp.zeros((), x.dtype), (n_slots, d)))
+    got = jnp.take(y_slots, jnp.minimum(slot, n_slots - 1), axis=0)
+    w = jnp.where(mine, weights.reshape(pairs), 0.0).astype(jnp.float32)
+    y = (got.astype(jnp.float32) * w[:, None]).reshape(T, k, d).sum(1)
+    return y, mine.sum().astype(jnp.int32), (counts > 0).sum().astype(
+        jnp.int32)

@@ -31,7 +31,6 @@ import numpy as np
 
 from ray_tpu._internal.profiler import span_type
 from ray_tpu.models import llama, module_for
-from ray_tpu.ops.attention import decode_block_len
 from ray_tpu.parallel.mesh import build_mesh, shard_params, spec_for
 from ray_tpu.serve.multiplex import multiplexed
 
@@ -102,7 +101,9 @@ class _PendingPrefill:
 class _InFlight:
     """A decode step the device has been given and the host has not
     read yet."""
-    tokens: Any             # the step's sampled tokens, [max_batch] on device
+    # the step's sampled tokens on the device, [max_batch], and behind
+    # them the counters the step decided there (the module's STEP_AUX)
+    tokens: Any
     rows: list              # per row, the _Request it was dispatched for
     active: int             # how many rows that is
 
@@ -135,16 +136,36 @@ class LLMEngine:
     that module's pytree: besides the bookkeeping leaves `length` and
     `start`, every leaf has a batch axis (where its logical axes say
     "batch"), so a request's ROW is that leaf at one batch index, and
-    `CACHE_LEN_AXIS` names the leaves that also have a position axis
-    (K, V). A leaf without one is recurrent state: what the whole prefix
-    left behind, valid at the one position it was computed to.
+    `CACHE_LEN_AXIS` names the leaves that also have a position axis as
+    deep as the cache (K and V; a latent-attention model's latent rows
+    and index keys). A leaf not named there is recurrent: what the
+    whole prefix left behind, valid at the one position it was computed
+    to, and grafted whole. That is a state-space layer's state, and also
+    a sliding-window layer's RING of the last positions, whose depth
+    does not follow the bucket or `max_seq_len`: a request's prefill
+    cache holds a ring of the same shape, in which position p lies in
+    row p mod ring, and the slot's positions are the bucket's, so
+    `insert_row` copies the ring as it stands (the chunked prefill
+    needs the ring between chunks anyway; cutting "the last window" out
+    of a bucket-deep leaf would mean holding that leaf, 45 MB a layer at
+    20,480, to keep 1.4 MB of it). `stats()["cache_bytes"]` files every
+    leaf under its kind: `kv`, `state`, or what the module's
+    `CACHE_KIND` names (`latent`, `index`, `window`). Beside `length`
+    and `start` a module may keep a third bookkeeping leaf, `aux`: the
+    counters its last step decided on the device (`STEP_AUX`).
+
+    What a decode step reads is the module's to say: `decode_read_block`
+    (the positions in a block of its attention's reads, or None where a
+    step reads a layer whole) and, where it has one, `decode_counters`
+    (further counters of `stats()`, from the live rows' ranges).
 
     The prefix store grafts a block-aligned PREFIX of a stored row's
     positions into a new request's cache. Recurrent state has no such
-    prefix to cut: grafting K and V beside a state that saw other
-    tokens would be wrong, so for a model with recurrent state the
-    store holds nothing (`prefix_cache_entries` is 0 in `stats()`),
-    whatever was asked for.
+    prefix to cut, and a ring holds the positions before ITS end and no
+    others: grafting K and V (or latent rows) beside a state or a ring
+    that saw other tokens would be wrong, so for a model with either
+    the store holds nothing (`prefix_cache_entries` is 0 in
+    `stats()`), whatever was asked for.
     """
 
     def __init__(self, model: Any = "debug", *, tp: int | None = None,
@@ -200,20 +221,31 @@ class LLMEngine:
         # a request's row: every leaf but the bookkeeping, by batch axis
         self._batch_axis = {name: ax.index("batch")
                             for name, ax in cache_axes.items()
-                            if name not in ("length", "start")}
+                            if name not in ("length", "start", "aux")}
         self._len_axis = mod.CACHE_LEN_AXIS
         recurrent = set(self._batch_axis) - set(self._len_axis)
         shapes = jax.eval_shape(lambda: mod.init_cache(
             cfg, max_batch, max_len=cfg.max_seq_len))
-        self._cache_bytes = {
-            kind: sum(shapes[n].size * shapes[n].dtype.itemsize
-                      for n in names)
-            for kind, names in (("kv", self._len_axis),
-                                ("state", recurrent))}
+        kinds = getattr(mod, "CACHE_KIND", {})
+        self._cache_bytes = {"kv": 0, "state": 0}
+        for name in self._batch_axis:
+            kind = kinds.get(name, "state" if name in recurrent else "kv")
+            self._cache_bytes[kind] = self._cache_bytes.get(kind, 0) + (
+                shapes[name].size * shapes[name].dtype.itemsize)
+        # counters the model's step decides on the device and returns
+        # in cache["aux"]: field of the emit span -> counter of stats()
+        self._aux = dict(getattr(mod, "STEP_AUX", {}))
+        self._aux_totals = dict.fromkeys(self._aux.values(), 0)
+        # what the module counts of a step's live ranges, summed
+        self._decode_counters = getattr(mod, "decode_counters", None)
+        self._model_counters = dict.fromkeys(
+            self._decode_counters(cfg, []), 0) if self._decode_counters \
+            else {}
 
         def step(params, cache, tokens, key, temperature):
-            if tokens.ndim == 1:  # decode path: device-resident [b]
-                tokens = tokens[:, None]
+            decode = tokens.ndim == 1
+            if decode:  # device-resident [b], the last step's aux behind
+                tokens = tokens[:max_batch, None]
             # the phase, known from the static shape, names every device
             # operation of this trace in the profiler (metadata only)
             # under the engine's mesh, so that a kernel in the model
@@ -235,8 +267,12 @@ class LLMEngine:
                     greedy = jnp.argmax(logits, axis=-1)
                     sampled = jax.random.categorical(
                         sub, logits / jnp.maximum(temperature, 1e-4))
-                    nxt = jnp.where(temperature[:, 0] > 0, sampled, greedy)
-                    return nxt.astype(jnp.int32), cache, key
+                    nxt = jnp.where(temperature[:, 0] > 0, sampled,
+                                    greedy).astype(jnp.int32)
+                    if decode and self._aux:
+                        # one array for the one host read of the step
+                        nxt = jnp.concatenate([nxt, cache["aux"]])
+                    return nxt, cache, key
 
         # one jit; prefill (s=bucket) and decode (s=1) are separate traces
         # of the same function, cached per shape. Donated: the cache (1)
@@ -272,9 +308,10 @@ class LLMEngine:
             its own batch axis; K and V as deep as the bucket, recurrent
             leaves whole) into `slot` of the persistent cache and reset
             that row's depth/start."""
-            out = {name: jax.lax.dynamic_update_slice_in_dim(
-                cache[name], leaf, slot, self._batch_axis[name])
-                for name, leaf in row.items()}
+            out = dict(cache)
+            for name, leaf in row.items():
+                out[name] = jax.lax.dynamic_update_slice_in_dim(
+                    cache[name], leaf, slot, self._batch_axis[name])
             out["length"] = cache["length"].at[slot].set(length)
             out["start"] = cache["start"].at[slot].set(start)
             return out
@@ -286,10 +323,8 @@ class LLMEngine:
             donate_argnums=(0,),
             out_shardings=self._cache_sharding["length"])
         # positions in a block of the decode step's attention reads;
-        # None where it reads the whole cache (ops/attention.py)
-        self._decode_block = decode_block_len(
-            cfg.n_kv_heads, cfg.head_dim, cfg.max_seq_len, cfg.dtype,
-            self.mesh)
+        # None where it reads the whole cache: the model's to say
+        self._decode_block = mod.decode_read_block(cfg, self.mesh)
 
         def set_slot(cur, temps, slot, tok, temp):
             return cur.at[slot].set(tok), temps.at[slot, 0].set(temp)
@@ -312,7 +347,7 @@ class LLMEngine:
         self._row_live = [False] * max_batch
         # device-resident between steps: re-uploading from host every
         # decode step would cost two H2D transfers per token
-        self._cur = jnp.zeros((max_batch,), jnp.int32)
+        self._cur = jnp.zeros((max_batch + len(self._aux),), jnp.int32)
         self._temps = jnp.zeros((max_batch, 1), jnp.float32)
         self._key = jax.random.PRNGKey(seed ^ 0x5EED)
         # the decode step dispatched and not read yet; whenever it is
@@ -395,7 +430,8 @@ class LLMEngine:
                 self._row_live = [False] * self.max_batch
                 self._inflight = None
                 self._decode_cache = None
-                self._cur = jnp.zeros((self.max_batch,), jnp.int32)
+                self._cur = jnp.zeros(
+                    (self.max_batch + len(self._aux),), jnp.int32)
                 self._temps = jnp.zeros((self.max_batch, 1), jnp.float32)
             self._queue = asyncio.Queue()
             self._task = asyncio.ensure_future(self._engine_loop())
@@ -848,7 +884,7 @@ class LLMEngine:
         self._row_live = [False] * self.max_batch
         self._inflight = None
         self._decode_cache = None
-        self._cur = jnp.zeros((self.max_batch,), jnp.int32)
+        self._cur = jnp.zeros((self.max_batch + len(self._aux),), jnp.int32)
         self._temps = jnp.zeros((self.max_batch, 1), jnp.float32)
 
     def _decode_step_all(self, epoch: int):
@@ -913,13 +949,14 @@ class LLMEngine:
             self._decode_cache["length"] = self._retire(
                 self._decode_cache["length"], np.asarray(gone))
             self._row_live = [r is not None for r in rows]
-        live, read = self._kv_positions(rows, prev)
+        live, read, counters = self._kv_positions(rows, prev)
         # t_host is this process's perf_counter read as the span opens:
         # the one event that carries both clocks, so request records and
         # a client's stamps (CLOCK_MONOTONIC, one clock for the host)
         # can be laid on the profiler's time axis
         with _span("rayt.engine.decode_dispatch", active=active,
-                   live_positions=live, t_host=time.perf_counter()):
+                   live_positions=live, t_host=time.perf_counter(),
+                   **counters):
             nxt, self._decode_cache, self._key = self._step(
                 self.params, self._decode_cache, self._cur,
                 self._key, self._temps)
@@ -927,28 +964,34 @@ class LLMEngine:
         self.batches += 1
         self.decode_kv_positions_live += live
         self.decode_kv_positions_read += read
+        for name, n in counters.items():
+            self._model_counters[name] = self._model_counters.get(
+                name, 0) + n
         return _InFlight(nxt, rows, active)
 
     def _kv_positions(self, rows: list, prev: Optional[_InFlight]):
-        """(live, read) of the step about to be dispatched for `rows`:
-        the positions its live rows attend to, each [start, the slot the
-        step writes], and the positions of the blocks its attention is
-        asked to read: the blocks that overlap those ranges (and one
-        that nobody reads where row 0 holds no request:
+        """(live, read, counters) of the step about to be dispatched for
+        `rows`: the positions inside its live rows' ranges, each [start,
+        the slot the step writes]; the positions of the blocks its
+        attention is asked to read: the blocks that overlap those ranges
+        (and one that nobody reads where row 0 holds no request:
         ops/pallas/decode_attention.py), or the whole cache where
-        nothing bounds the read."""
+        nothing bounds the read; and what the model's module counts of
+        those ranges for itself (`decode_counters`)."""
         # per live row (start, the slot the step writes); the step in
         # flight has not been counted into a slot's length yet
         spans = [(self._slots[i].start, self._slots[i].length
                   + int(prev is not None and prev.rows[i] is req))
                  for i, req in enumerate(rows) if req is not None]
         live = sum(last - start + 1 for start, last in spans)
+        counters = self._decode_counters(self.cfg, spans) \
+            if self._decode_counters else {}
         block = self._decode_block
         if not block:
-            return live, self.max_batch * self.cfg.max_seq_len
+            return live, self.max_batch * self.cfg.max_seq_len, counters
         blocks = sum(last // block - start // block + 1
                      for start, last in spans)
-        return live, (blocks + (rows[0] is None)) * block
+        return live, (blocks + (rows[0] is None)) * block, counters
 
     def _owned_rows(self, rec: _InFlight) -> list:
         """(row, slot) for every row of a dispatched step whose slot
@@ -970,6 +1013,15 @@ class LLMEngine:
             finished = 0
             owned = self._owned_rows(rec)
             self.decode_rows_discarded += rec.active - len(owned)
+            aux: dict = {}
+            if self._aux:
+                # what the step counted on the device, read with its
+                # tokens: on the span, so that a reader can count them
+                # inside a traced stretch, and summed for stats()
+                aux = {field: int(n) for field, n in zip(
+                    self._aux, toks[self.max_batch:])}
+                for field, name in self._aux.items():
+                    self._aux_totals[name] += aux[field]
             for i, s in owned:
                 t = int(toks[i])
                 s.length += 1
@@ -991,7 +1043,7 @@ class LLMEngine:
                         or s.length >= self.cfg.max_seq_len - 1):
                     self._finish(i)
                     finished += 1
-            span.set_metadata(finished=finished)
+            span.set_metadata(finished=finished, **aux)
 
     def stats(self) -> dict:
         return {"generated_tokens": self.generated_tokens,
@@ -1009,6 +1061,7 @@ class LLMEngine:
                 "decode_rows_discarded": self.decode_rows_discarded,
                 "decode_kv_positions_live": self.decode_kv_positions_live,
                 "decode_kv_positions_read": self.decode_kv_positions_read,
+                **self._model_counters, **self._aux_totals,
                 "active_slots": sum(1 for s in self._slots
                                     if s is not None),
                 "tp": self.mesh.shape.get("tensor", 1)}

@@ -307,3 +307,60 @@ def test_hybrid_decode_step_moves_no_cache_and_no_state(chip, request, batch,
             assert m.group(3) in ("parameter", "get-tuple-element",
                                   "dynamic-update-slice", "bitcast"), \
                 line[:200]
+
+
+def test_dots3_decode_step_moves_no_cache(chip):
+    """models/dots3_note.decode_step at the benchmark's 32 slots x 24,576
+    under the rule the other two models' steps are held to: with the
+    cache donated, the latent rows (2.0 GB), the index keys and the rings
+    are updated in their own buffers, the step's temporaries stay under a
+    quarter of one layer's latent rows (the index scores of 32 x 64 heads
+    x 24,576 in float32 are the largest, 0.2 GB), and nothing but the
+    in-place writes produces an array of a whole stack's or a whole
+    layer's shape. Found by this compile (PR 32): a cached row of 576
+    numbers, no multiple of the 128 lanes, made the compiler hold the
+    stack with positions minor and copy all of it around every step's
+    write (1.8 GB twice), so rows are filled to 640; `latent[li]` before
+    the gather of the selected rows was a copy of the layer (1 GB), so
+    the gather indexes the stack."""
+    from ray_tpu.models import dots3_note
+
+    batch, max_len = 32, 24576
+    cfg = dots3_note.Dots3NoteConfig(vocab_size=19008, experts_held=32,
+                                     max_seq_len=max_len)
+    on = SingleDeviceSharding(chip)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=on), tree)
+
+    params = place(jax.eval_shape(
+        lambda key: dots3_note.init_params(cfg, key), jax.random.PRNGKey(0)))
+    cache = dict(jax.eval_shape(
+        lambda: dots3_note.init_cache(cfg, batch, max_len)))
+    cache["length"] = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=on)
+    compiled = jax.jit(
+        lambda p, c, t: dots3_note.decode_step(p, c, t, cfg),
+        donate_argnums=(1,)).lower(params, place(cache), tokens).compile()
+
+    rows = ("latent", "index", "window")
+    nbytes = {key: math.prod(cache[key].shape) * cache[key].dtype.itemsize
+              for key in rows}
+    assert nbytes["latent"] == 2 * 32 * 24576 * 640 * 2
+    whole = {tuple(cache[key].shape) for key in rows} | {
+        tuple(cache[key].shape[1:]) for key in ("latent", "index")}
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < HBM_BYTES)
+    assert mem.temp_size_in_bytes < nbytes["latent"] / 2 / 4
+    assert mem.alias_size_in_bytes >= sum(nbytes.values())
+    for line in compiled.as_text().splitlines():
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if dims in whole and m.group(3) != "fusion":
+            assert m.group(3) in ("parameter", "get-tuple-element",
+                                  "dynamic-update-slice", "bitcast"), \
+                line[:200]

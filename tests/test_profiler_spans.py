@@ -41,11 +41,14 @@ def _trace_options():
     return opts
 
 
-def test_helper_is_the_profilers_own_span_and_nothing_else(tmp_path):
+def test_helper_is_the_profilers_own_span_and_nothing_else(tmp_path,
+                                                           monkeypatch):
     """(e) no span store of ours: the helper is TraceAnnotation itself,
     and opening spans with no session leaves no thread and no file."""
     assert span_type() is jax.profiler.TraceAnnotation
-    os.chdir(tmp_path)
+    # put back afterwards: a later test of this worker spawns
+    # `python -c "from ray_tpu..."` from the checkout it stands in
+    monkeypatch.chdir(tmp_path)
     threads = threading.active_count()
     for i in range(100):
         with span_type()("rayt.engine.emit", active=i) as span:
@@ -336,3 +339,100 @@ def test_spawned_processes_key_their_compile_cache_on_metadata():
     kept = spawn.child_env("/pkg", base={
         spawn.COMPILE_CACHE_METADATA_ENV: "false"})
     assert kept[spawn.COMPILE_CACHE_METADATA_ENV] == "false"
+
+
+# ------------------------------- a model whose step counts on the device
+def _traced_sparse_moe_run(tmp_path):
+    """The dots3_note twin (models/dots3_note.py: its step returns the
+    token-expert pairs it computed and the held experts it hit) under a
+    profiler session. -> (engine, stats() before the traced run,
+    {span: [stats, ...]} in order of start)."""
+    from ray_tpu.models import dots3_note
+
+    cfg = dots3_note.Dots3NoteConfig(
+        vocab_size=128, dim=32, hidden_dim=48, moe_hidden_dim=16,
+        n_routed_experts=16, experts_first=4, experts_held=8,
+        experts_per_tok=4, n_heads=2, qk_nope_dim=8, qk_rope_dim=4,
+        v_head_dim=8, q_rank=16, kv_rank=12, swa_n_heads=2,
+        swa_qk_nope_dim=8, swa_qk_rope_dim=4, swa_v_head_dim=8,
+        swa_q_rank=16, swa_kv_rank=12, sliding_window=9, ring_multiple=4,
+        index_n_heads=8, index_head_dim=8, index_topk=12, max_seq_len=96,
+        dtype=jnp.float32, param_dtype=jnp.float32)
+    eng = LLMEngine(cfg, tp=1, max_batch=2, prompt_buckets=(16, 64),
+                    prefill_chunk=16)
+
+    async def run():
+        async def one(tokens):
+            return [t async for t in eng.generate(tokens, max_new_tokens=6)]
+        return await asyncio.gather(one([5, 9, 11]), one(list(range(1, 41))))
+
+    asyncio.run(run())          # compile outside the session
+    before = eng.stats()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=_trace_options())
+    try:
+        asyncio.run(run())
+    finally:
+        jax.profiler.stop_trace()
+    by_name: dict = {}
+    for _, name, start, _, stats in sorted(_host_spans(tmp_path),
+                                           key=lambda s: s[2]):
+        by_name.setdefault(name.removeprefix("rayt.engine."), []).append(
+            stats)
+    return eng, before, by_name
+
+
+@pytest.fixture(scope="module")
+def sparse_moe_run(tmp_path_factory):
+    return _traced_sparse_moe_run(tmp_path_factory.mktemp("sparse_moe"))
+
+
+def test_emit_span_carries_what_the_step_counted_on_the_device(
+        sparse_moe_run):
+    """`expert_rows` and `experts_hit` are decided inside the step and
+    read with its tokens; each step's pair is on the emit span that
+    hands out its tokens, and stats() sums them."""
+    eng, before, by_name = sparse_moe_run
+    emit, dispatch = by_name["emit"], by_name["decode_dispatch"]
+    assert len(emit) == len(dispatch) >= 5
+    for e, d in zip(emit, dispatch):
+        assert {"active", "finished", "expert_rows", "experts_hit"} <= set(e)
+        # 4 expert layers, 4 of 16 experts a token, 8 held: at most 4 x 4
+        # pairs a live row, and no more experts hit than pairs or held
+        assert 0 <= e["expert_rows"] <= 16 * d["active"]
+        assert e["experts_hit"] <= min(e["expert_rows"], 4 * 8)
+        assert (e["expert_rows"] == 0) == (e["experts_hit"] == 0)
+    st = eng.stats()
+    assert sum(e["expert_rows"] for e in emit) == \
+        st["moe_expert_rows"] - before["moe_expert_rows"] > 0
+    assert sum(e["experts_hit"] for e in emit) == \
+        st["moe_experts_hit"] - before["moe_experts_hit"] > 0
+    # a token is never read in a second host sync for them: one
+    # token_sync a step, as for any model
+    assert len(by_name["token_sync"]) == len(emit)
+
+
+def test_dispatch_span_carries_the_modules_counters(sparse_moe_run):
+    """The five counters of stats(): three from the live rows' ranges,
+    on the decode_dispatch span beside `active` and `live_positions`."""
+    eng, before, by_name = sparse_moe_run
+    st = eng.stats()
+    names = ("decode_index_positions_scored",
+             "decode_latent_positions_attended",
+             "decode_window_positions_attended")
+    for d in by_name["decode_dispatch"]:
+        assert {"active", "live_positions", "t_host", *names} <= set(d)
+        # two full layers score every live position; three sliding
+        # layers attend to at most the window of each live row
+        assert d["decode_index_positions_scored"] == 2 * d["live_positions"]
+        assert d["decode_latent_positions_attended"] <= \
+            2 * min(d["live_positions"], 12 * d["active"])
+        assert d["decode_window_positions_attended"] <= 3 * 9 * d["active"]
+    for name in names:
+        assert sum(d[name] for d in by_name["decode_dispatch"]) == \
+            st[name] - before[name] > 0
+    for name in names + ("moe_expert_rows", "moe_experts_hit"):
+        assert isinstance(st[name], int)
+    # a model that counts nothing of its own keeps its stats as they were
+    plain = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=64,
+                      prompt_buckets=(16,), prefill_chunk=0)
+    assert not set(plain.stats()) & set(names + ("moe_expert_rows",))

@@ -476,12 +476,12 @@ def decode_path(request, monkeypatch):
     blocks of _KERNEL_BLOCK positions), as a TPU does."""
     if request.param == "kernel":
         from ray_tpu.ops import attention
-        from ray_tpu.serve import llm
 
+        # the engine asks the model's module (`decode_read_block`), which
+        # asks ops/attention.py, as the step's own attention does
         monkeypatch.setattr(attention, "_on_tpu", lambda: True)
-        for mod in (attention, llm):
-            monkeypatch.setattr(mod, "decode_block_len",
-                                lambda *a: _KERNEL_BLOCK)
+        monkeypatch.setattr(attention, "decode_block_len",
+                            lambda *a: _KERNEL_BLOCK)
     return request.param
 
 
