@@ -40,7 +40,7 @@ Same function set as models/llama.py, so serve/llm.py's engine runs it:
 `init_params`, `param_logical_axes`, `forward`, `init_cache`,
 `cache_logical_axes`, `CACHE_LEN_AXIS`, `decode_step`, and what the
 engine asks a module about its decode step: `CACHE_KIND`,
-`decode_read_block`, `decode_counters`, `STEP_AUX`.
+`decode_read_block`, `decode_counters`, `prefill_counters`, `STEP_AUX`.
 """
 
 from __future__ import annotations
@@ -51,9 +51,12 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ray_tpu.ops import attention as _attention
 from ray_tpu.ops.moe import held_experts_ffn, route_sigmoid_topk
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.pallas.latent_attention import latent_attention, tiles
 from ray_tpu.ops.rope import apply_rope
 
 F32 = jnp.float32
@@ -453,6 +456,27 @@ def _attend_done(state, dt):
     return out.transpose(0, 2, 1, 3).astype(dt)          # [b, s, H, v]
 
 
+def _chunk_tiles(a: AttnSizes, s: int, n: int):
+    """The kernel's tiles for a chunk of `s` queries against `n` keys, or
+    None where the chunk keeps the plain form (`_attend_block`): chosen,
+    as `ops/attention.cached_attention` chooses its decode kernel, from
+    the platform and the shapes alone. On a TPU, more than one query, and
+    heads, chunk and keys whole in the kernel's tiles."""
+    if s == 1 or not _attention._on_tpu():
+        return None
+    return tiles(a.heads, a.nope, a.rope, a.v, a.kv_rank, s, n)
+
+
+def _attend_kernel(a: AttnSizes, layer, q, rows, li, mask, t):
+    """ops/pallas/latent_attention.py over layer `li` of the stacked
+    `rows` [layers, b, n, row]: what folding `_attend_block` over the
+    keys and `_attend_done` give, [b, s, H, v], with no block's scores
+    in memory."""
+    return latent_attention(
+        q, rows, li, layer["w_kvb_k"], layer["w_kvb_v"], mask,
+        kv_rank=a.kv_rank, rope=a.rope, t=t).transpose(0, 2, 1, 3)
+
+
 def _absorbed(a: AttnSizes, layer, q_nope, q_rope, rows, mask, dt):
     """The ABSORBED form for one query a row: q_nope [b, H, nope],
     q_rope [b, H, rope] against rows [b, n, row] under mask [b, n];
@@ -633,6 +657,63 @@ def decode_counters(cfg: Dots3NoteConfig, spans: list) -> dict:
             ns * sum(min(n, cfg.sliding_window) for n in depth)}
 
 
+def _tiles_visited(t, start: int, pos: int, chunk: int, k_pos, reach: int):
+    """Pairs (query, key) in the kernel's tiles `t` that hold one with
+    start <= key <= query < key + reach, for `chunk` queries from `pos`
+    against keys at positions `k_pos`: the tiles that padding,
+    causality and the window leave live."""
+    pairs = 0
+    for t0 in range(pos, pos + chunk, t.q):
+        t1 = t0 + t.q - 1
+        seen = ((k_pos >= max(start, 0)) & (k_pos <= t1) & (t1 >= start)
+                & (k_pos > max(t0, start) - reach))
+        pairs += t.q * t.k * int(seen.reshape(-1, t.k).any(1).sum())
+    return pairs
+
+
+def prefill_counters(cfg: Dots3NoteConfig, start: int, pos: int, chunk: int,
+                     depth: int) -> dict:
+    """What the attention of one prefill chunk does: `chunk` queries at
+    positions [pos, pos + chunk) of a row whose first real token lies at
+    `start`, against a cache `depth` deep. By layer kind, key positions
+    summed over the chunk's queries: `visible`, those a query attends to
+    (the selected ones, the window's), and `visited`, those whose scores
+    are computed: under the kernel the live tiles (the selection could
+    empty one more, which this does not know), in the plain form every
+    block from the first real position to the last written, and the
+    ring and the chunk whole. visible / visited says how much of the
+    computed scores count."""
+    # how deep each real query of the chunk lies in its row
+    depths = np.arange(max(pos, start), pos + chunk) - start + 1
+    ring = cfg.ring_len
+    t = _chunk_tiles(cfg.attn(KINDS[0]), chunk, depth)
+    if t is None:
+        blk = min(depth, 1024)
+        full = chunk * blk * ((pos + chunk - 1) // blk + 1 - start // blk)
+    else:
+        full = _tiles_visited(t, start, pos, chunk, np.arange(depth),
+                              depth + chunk)
+    t = _chunk_tiles(cfg.attn(KINDS[1]), chunk, ring + chunk)
+    if t is None:
+        window = chunk * (ring + chunk)
+    else:
+        # the keys as `_sliding_layer` lays them out: the ring as the
+        # chunk found it, then the chunk
+        k_pos = np.concatenate([
+            pos - 1 - (pos - 1 - np.arange(ring)) % ring,
+            pos + np.arange(chunk)])
+        window = _tiles_visited(t, start, pos, chunk, k_pos,
+                                cfg.sliding_window)
+    nf, ns = cfg.count(KINDS[0]), cfg.count(KINDS[1])
+    return {
+        "prefill_latent_keys_visited": nf * full,
+        "prefill_latent_keys_visible":
+            nf * int(np.minimum(depths, cfg.index_topk).sum()),
+        "prefill_window_keys_visited": ns * window,
+        "prefill_window_keys_visible":
+            ns * int(np.minimum(depths, cfg.sliding_window).sum())}
+
+
 def _write_rows(stack, li: int, new, cache_len, at=None):
     """new [b, s, w] into layer `li` of `stack` [layers, b, len, w] at
     positions `at` (default `cache_len`): one block for a scalar, one
@@ -717,15 +798,20 @@ def _full_layer(cfg, a, layer, li, h, c_q, q_nope, q_rope, rel, latent,
                                      cfg.index_topk)
     with jax.named_scope("sparse_attn"):
         q = _heads_first(q_nope, q_rope)
+        t = _chunk_tiles(a, s, depth)
+        if t is not None:
+            # nothing is selected past the last position written or
+            # before the first real one: those tiles are never fetched
+            attn = _attend_kernel(a, layer, q, latent, li, selected, t)
+        else:
+            def attend(j, state):
+                rows, at, own = cut(latent, j)
+                mask = (jax.lax.dynamic_slice_in_dim(selected, at, blk, axis=2)
+                        & own[None, None, :])
+                return _attend_block(state, a, layer, q, rows, mask, dt)
 
-        def attend(j, state):
-            rows, at, own = cut(latent, j)
-            mask = (jax.lax.dynamic_slice_in_dim(selected, at, blk, axis=2)
-                    & own[None, None, :])
-            return _attend_block(state, a, layer, q, rows, mask, dt)
-
-        attn = _attend_done(jax.lax.fori_loop(
-            first, stop, attend, _attend_init(q, a)), dt)
+            attn = _attend_done(jax.lax.fori_loop(
+                first, stop, attend, _attend_init(q, a)), dt)
         attn = attn * q_visible[..., None, None].astype(dt)
     aux = {"selected": selected, "index_scores": scores} if collect else {}
     return attn, latent, index, aux
@@ -764,8 +850,12 @@ def _sliding_layer(cfg, a, layer, li, h, q_nope, q_rope, rel, window,
                 & (abs_pos >= start[:, None])[:, :, None])
         rows = jnp.concatenate([window[li], new], axis=1)
         q = _heads_first(q_nope, q_rope)
-        attn = _attend_done(_attend_block(
-            _attend_init(q, a), a, layer, q, rows, mask, dt), dt)
+        t = _chunk_tiles(a, s, ring + s)
+        if t is not None:
+            attn = _attend_kernel(a, layer, q, rows[None], 0, mask, t)
+        else:
+            attn = _attend_done(_attend_block(
+                _attend_init(q, a), a, layer, q, rows, mask, dt), dt)
     with jax.named_scope("mla_kv"):
         # the chunk's last ring_len rows, each to its own slot
         keep = min(s, ring)

@@ -156,8 +156,10 @@ class LLMEngine:
 
     What a decode step reads is the module's to say: `decode_read_block`
     (the positions in a block of its attention's reads, or None where a
-    step reads a layer whole) and, where it has one, `decode_counters`
-    (further counters of `stats()`, from the live rows' ranges).
+    step reads a layer whole) and, where it has them, `decode_counters`
+    (further counters of `stats()`, from the live rows' ranges) and
+    `prefill_counters` (the same of a prefill call's attention, from
+    where its row starts and where the call's tokens lie).
 
     The prefix store grafts a block-aligned PREFIX of a stored row's
     positions into a new request's cache. Recurrent state has no such
@@ -236,11 +238,16 @@ class LLMEngine:
         # in cache["aux"]: field of the emit span -> counter of stats()
         self._aux = dict(getattr(mod, "STEP_AUX", {}))
         self._aux_totals = dict.fromkeys(self._aux.values(), 0)
-        # what the module counts of a step's live ranges, summed
+        # what the module counts of a step's live ranges and of a
+        # chunk's attention, summed
         self._decode_counters = getattr(mod, "decode_counters", None)
+        self._prefill_counters = getattr(mod, "prefill_counters", None)
         self._model_counters = dict.fromkeys(
             self._decode_counters(cfg, []), 0) if self._decode_counters \
             else {}
+        if self._prefill_counters:
+            self._model_counters.update(dict.fromkeys(
+                self._prefill_counters(cfg, 0, 0, 0, cfg.max_seq_len), 0))
 
         def step(params, cache, tokens, key, temperature):
             decode = tokens.ndim == 1
@@ -687,12 +694,18 @@ class LLMEngine:
         whole rest of a short prompt, or one chunk of a long one). The
         call is asynchronous: its device time is scope `prefill` in a
         profiler trace, not this span. Returns (sampled token, cache)."""
+        bucket = prompts.shape[1]
+        counters = self._prefill_counters(
+            self.cfg, bucket - len(req.tokens), pos, chunk, bucket) \
+            if self._prefill_counters else {}
         with _span("rayt.engine.prefill_chunk", request_id=req.request_id,
-                   pos=pos, chunk=chunk,
-                   last=int(pos + chunk >= prompts.shape[1])):
+                   pos=pos, chunk=chunk, last=int(pos + chunk >= bucket),
+                   **counters):
             nxt, small, self._key = self._step(
                 self.params, small, jnp.asarray(prompts[:, pos:pos + chunk]),
                 self._key, jnp.asarray([[req.temperature]], np.float32))
+        for name, n in counters.items():
+            self._model_counters[name] += n
         if req.obs is not None:
             req.obs["prefill_chunks"] = req.obs.get("prefill_chunks", 0) + 1
         return nxt, small

@@ -309,6 +309,13 @@ def test_hybrid_decode_step_moves_no_cache_and_no_state(chip, request, batch,
                 line[:200]
 
 
+def _dots3_cell_config(max_len):
+    from ray_tpu.models import dots3_note
+
+    return dots3_note.Dots3NoteConfig(vocab_size=19008, experts_held=32,
+                                      max_seq_len=max_len)
+
+
 def test_dots3_decode_step_moves_no_cache(chip):
     """models/dots3_note.decode_step at the benchmark's 32 slots x 24,576
     under the rule the other two models' steps are held to: with the
@@ -326,8 +333,7 @@ def test_dots3_decode_step_moves_no_cache(chip):
     from ray_tpu.models import dots3_note
 
     batch, max_len = 32, 24576
-    cfg = dots3_note.Dots3NoteConfig(vocab_size=19008, experts_held=32,
-                                     max_seq_len=max_len)
+    cfg = _dots3_cell_config(max_len)
     on = SingleDeviceSharding(chip)
 
     def place(tree):
@@ -364,3 +370,79 @@ def test_dots3_decode_step_moves_no_cache(chip):
             assert m.group(3) in ("parameter", "get-tuple-element",
                                   "dynamic-update-slice", "bitcast"), \
                 line[:200]
+
+
+@pytest.mark.parametrize("kind,keys,layers", [
+    ("full_attention", 20480, 2), ("sliding_attention", 640 + 1024, 1),
+], ids=["full-20480", "sliding-ring+chunk"])
+def test_latent_attention_kernel_compiles_for_the_chip(
+        chip, compiled_not_interpreted, kind, keys, layers):
+    """ops/pallas/latent_attention.py alone at the dots3 cell's two layer
+    kinds, a chunk of 1,024 queries: 128 heads of 128 + 64 against the
+    deepest bucket's latent rows, 64 heads of 192 + 64 against the ring
+    and the chunk. It fits VMEM in the tiles `tiles` gives, and takes
+    the stacked rows as they lie: its temporaries are the mask as int8,
+    the two up-projections with the head first and, in this program
+    alone, q and the mask copied into the layouts the call takes them
+    in: together a quarter of ONE block's scores in float32."""
+    from ray_tpu.ops.pallas import latent_attention as la
+
+    a = _dots3_cell_config(24576).attn(kind)
+    s = 1024
+    t = la.tiles(a.heads, a.nope, a.rope, a.v, a.kv_rank, s, keys)
+    assert t == (8, 512, 1024 if keys > 2048 else keys)
+    on = SingleDeviceSharding(chip)
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
+
+    compiled = jax.jit(lambda q, rows, li, w_k, w_v, mask: la.latent_attention(
+        q, rows, li, w_k, w_v, mask, kv_rank=a.kv_rank, rope=a.rope, t=t)
+    ).lower(arg((1, a.heads, s, a.nope + a.rope)),
+            arg((layers, 1, keys, a.row)), arg((), jnp.int32),
+            arg((a.kv_rank, a.heads, a.nope)), arg((a.kv_rank, a.heads, a.v)),
+            arg((1, s, keys), jnp.bool_)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
+
+
+def test_dots3_chunk_holds_no_block_of_scores(chip, on_the_chip):
+    """The dots3 cell's chunk program, `decode_step` of 1,024 tokens with
+    a scalar length against a batch-1 cache of the deepest bucket, as a
+    TPU takes it: five calls of the latent attention kernel (two full
+    layers, three sliding), not interpreted; it fits HBM beside nothing,
+    its temporaries stay under 0.5 GB (the plain form's were 1.6: a
+    block's scores, twice), and no float32 array of heads x 1,024
+    queries x a block of keys or more exists anywhere in it: in the
+    plain form f32[1,128,1024,1024], 0.54 GB written after the score
+    product and read back twice, and f32[1,64,1024,1664] in a sliding
+    layer."""
+    from ray_tpu.models import dots3_note
+
+    depth, s = 20480, 1024
+    cfg = _dots3_cell_config(24576)
+    on = SingleDeviceSharding(chip)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=on), tree)
+
+    params = place(jax.eval_shape(
+        lambda key: dots3_note.init_params(cfg, key), jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda: dots3_note.init_cache(cfg, 1, depth)))
+    tokens = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=on)
+    compiled = jax.jit(
+        lambda p, c, t: dots3_note.decode_step(p, c, t, cfg),
+        donate_argnums=(1,)).lower(params, cache, tokens).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == cfg.n_layers
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < HBM_BYTES)
+    assert mem.temp_size_in_bytes < 0.5e9
+    # the smaller of the two; the largest float32 array left is the
+    # indexer's, 64 heads x 1,024 queries x a block of 1,024 keys
+    scores = cfg.swa_n_heads * s * (cfg.ring_len + s)
+    for dims in set(re.findall(r"f32\[([0-9,]+)\]", text)):
+        assert math.prod(int(d) for d in dims.split(",")) < scores, dims

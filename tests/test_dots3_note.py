@@ -130,13 +130,40 @@ def _prefill_then_decode(cfg, params, toks, start, bucket, chunk, steps,
     return jnp.stack(outs), cache
 
 
-@pytest.mark.parametrize("chunk", [8, 16, 32])
+@pytest.fixture
+def chunk_kernel(monkeypatch):
+    """A chunk's attention through ops/pallas/latent_attention.py
+    (interpreted), as a TPU takes it for shapes whole in the kernel's
+    tiles: here in tiles of 8 queries x 4 keys, which the twin's chunk
+    of 16, its depth of 48 and its ring of 12 are whole in."""
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.pallas import latent_attention as la
+
+    calls = []
+
+    def tiles(heads, nope, rope, v, kv_rank, queries, keys):
+        if queries % 8 or keys % 4:
+            return None
+        calls.append((heads, queries, keys))
+        return la.Tiles(2, 8, 4)
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(m, "tiles", tiles)
+    return calls
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 32, "16-kernel"])
 def test_chunked_prefill_then_cached_decode_agree_with_the_reference(
-        model, chunk):
+        model, chunk, request):
     """Two rows in one batch, one left-padded by 5; the ring (12 rows)
     turns twice over the prompt and again during decode; the selection
-    picks 12 of up to 38 positions."""
+    picks 12 of up to 38 positions. The last case takes the chunks'
+    attention through the kernel."""
     _, cfg, params, hp = model
+    kernel_calls = None
+    if chunk == "16-kernel":
+        kernel_calls = request.getfixturevalue("chunk_kernel")
+        chunk = 16
     toks = _tokens(48, seed=5, batch=2)
     start, bucket, steps = [5, 0], 32, 6
     got, cache = _prefill_then_decode(cfg, params, toks, start, bucket,
@@ -149,6 +176,10 @@ def test_chunked_prefill_then_cached_decode_agree_with_the_reference(
     # the step's own count of what it sent to the held experts
     assert cache["aux"].shape == (2,) and int(cache["aux"][0]) > 0
     assert int(cache["aux"][1]) <= 4 * cfg.experts_held
+    if kernel_calls is not None:
+        # one trace of the chunk program: two full layers against the
+        # depth, three sliding ones against ring + chunk; no decode step
+        assert kernel_calls == [(4, 16, 48)] * 2 + [(2, 16, 28)] * 3
 
 
 def test_absorbed_decode_equals_the_expanded_form(model):
@@ -346,6 +377,77 @@ def test_engine_counts_what_the_steps_scored_attended_and_routed(served,
     assert 300 < stats["moe_expert_rows"] < 700
     assert 0 < stats["moe_experts_hit"] <= stats["moe_expert_rows"]
     assert stats["moe_experts_hit"] <= 4 * 8 * stats["batches"]
+    # the chunks' attention (the plain form here): every prompt token is
+    # a query once, in two full layers at up to 12 selected positions
+    # and three sliding ones at up to 9
+    eng, prompts, _ = served
+    want = {"latent": 0, "window": 0}
+    for p in prompts:
+        for depth in range(1, len(p) + 1):
+            want["latent"] += 2 * min(depth, 12)
+            want["window"] += 3 * min(depth, 9)
+    assert stats["prefill_latent_keys_visible"] == want["latent"]
+    assert stats["prefill_window_keys_visible"] == want["window"]
+    assert stats["prefill_latent_keys_visited"] > want["latent"]
+    # a chunk of 8 against a ring of 12: 20 keys a query, padding too
+    chunked = sum(-(-len(p) // 8) * 8 for p in prompts if len(p) > 8)
+    assert stats["prefill_window_keys_visited"] >= 3 * 20 * chunked
+
+
+def test_prefill_counters_from_where_the_row_and_the_chunk_lie(
+        model, monkeypatch):
+    _, cfg, _, _ = model                 # index_topk 12, window 9, ring 12
+    names = ("prefill_latent_keys_visited", "prefill_latent_keys_visible",
+             "prefill_window_keys_visited", "prefill_window_keys_visible")
+    # the plain form, as here on the CPU. Left padding of 5 inside the
+    # first block of a cache 48 deep (one block): 16 queries from
+    # position 0, 11 of them real, at depths 1..11
+    got = m.prefill_counters(cfg, 5, 0, 16, 48)
+    assert tuple(got) == names
+    assert got == dict(zip(names, (
+        2 * 16 * 48, 2 * sum(range(1, 12)),
+        3 * 16 * (12 + 16), 3 * (sum(range(1, 10)) + 2 * 9))))
+    # a chunk that ends the bucket, all real, far over topk and window
+    got = m.prefill_counters(cfg, 5, 32, 16, 48)
+    assert got == dict(zip(names, (2 * 16 * 48, 2 * 16 * 12,
+                                   3 * 16 * 28, 3 * 16 * 9)))
+    # blocks of 1,024: a chunk of 1,024 at 2,048 of a row that starts at
+    # 1,100 walks blocks 1 and 2
+    assert m.prefill_counters(cfg, 1100, 2048, 1024, 4096)[names[0]] == \
+        2 * 1024 * 2 * 1024
+    # padding only: nothing visible
+    got = m.prefill_counters(cfg, 40, 0, 16, 48)
+    assert got[names[1]] == 0 and got[names[3]] == 0
+    assert m.prefill_counters(cfg, 0, 0, 0, 96) == dict.fromkeys(names, 0)
+
+    # the kernel's tiles, 8 queries x 4 keys
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.pallas import latent_attention as la
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(m, "tiles", lambda *a: la.Tiles(2, 8, 4))
+    got = m.prefill_counters(cfg, 5, 0, 16, 48)
+    # full: queries 0-7 reach key tile 1 (positions 4-7) alone, queries
+    # 8-15 tiles 1 to 3. Sliding: the ring is empty (nothing before
+    # position 0), the window of 9 reaches back to position 5 - 8 < 4
+    # from queries 5-7 (tile 4-7) and to 8 - 8 = 0 from queries 8-15,
+    # of which tiles 4-7, 8-11 and 12-15 hold a real position
+    assert got[names[0]] == 2 * 8 * 4 * (1 + 3)
+    assert got[names[2]] == 3 * 8 * 4 * (1 + 3)
+    assert got[names[1]] == 2 * sum(range(1, 12))
+    # a later chunk: the ring holds positions 20-31 (slot p % 12), the
+    # chunk 32-47. Queries 32-39 see 24-39: ring slots 0-7 (24-31), in
+    # ring tiles 0 and 1, and the chunk's first two tiles; queries 40-47
+    # see 32-47: the chunk's four tiles
+    got = m.prefill_counters(cfg, 5, 32, 16, 48)
+    assert got[names[2]] == 3 * 8 * 4 * (4 + 4)
+    assert got[names[0]] == 2 * 8 * 4 * (9 + 11)
+    # and the tiles are what the kernel's own tables say of that mask
+    pos = 32 + np.arange(16)
+    k_pos = np.concatenate([31 - (31 - np.arange(12)) % 12, pos])
+    dist = pos[:, None] - k_pos[None, :]
+    mask = (dist >= 0) & (dist < 9) & (k_pos >= 5)[None, :]
+    live, _ = la.tile_tables(jnp.asarray(mask)[None], la.Tiles(2, 8, 4))
+    assert int(live.sum()) == 4 + 4
 
 
 def test_decode_counters_from_row_ranges(model):

@@ -436,3 +436,30 @@ def test_dispatch_span_carries_the_modules_counters(sparse_moe_run):
     plain = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=64,
                       prompt_buckets=(16,), prefill_chunk=0)
     assert not set(plain.stats()) & set(names + ("moe_expert_rows",))
+    assert not any(k.startswith("prefill_") and k.endswith(("_visited",
+                   "_visible")) for k in plain.stats())
+
+
+def test_prefill_chunk_span_carries_what_the_chunks_attention_visits(
+        sparse_moe_run):
+    """`dots3_note.prefill_counters` of every prefill call, chunked or
+    whole, on its prefill_chunk span beside `pos` and `chunk`, and
+    summed into stats()."""
+    eng, before, by_name = sparse_moe_run
+    st = eng.stats()
+    names = ("prefill_latent_keys_visited", "prefill_latent_keys_visible",
+             "prefill_window_keys_visited", "prefill_window_keys_visible")
+    chunks = by_name["prefill_chunk"]
+    # 3 tokens in bucket 16, whole; 40 in bucket 64 from the chunk that
+    # holds its first token (position 24): 16-31, 32-47, 48-63
+    assert [(c["pos"], c["chunk"]) for c in chunks if c["chunk"] == 16] \
+        == [(0, 16), (16, 16), (32, 16), (48, 16)]
+    for c in chunks:
+        assert {"pos", "chunk", "last", *names} <= set(c)
+        # two full layers, three sliding: a real query sees at most
+        # index_topk 12 and the window's 9, and no more than is visited
+        assert c[names[1]] <= 2 * 12 * c["chunk"] and \
+            c[names[3]] <= 3 * 9 * c["chunk"]
+        assert c[names[0]] >= c[names[1]] and c[names[2]] >= c[names[3]]
+    for name in names:
+        assert sum(c[name] for c in chunks) == st[name] - before[name] > 0
