@@ -16,6 +16,7 @@ per-host {TPU: chips_per_host} bundles (`tpu_slice_bundles`).
 
 from __future__ import annotations
 
+import errno
 import json
 import os
 import urllib.request
@@ -90,6 +91,42 @@ def _count_devfs_chips() -> int:
         else:
             n = max(n, len([e for e in names if e.isdigit()]))
     return n
+
+
+def chips_being_released(vfio_dir: str = "/dev/vfio") -> list[str]:
+    """The chips' vfio groups that refuse to be opened (EBUSY) though no
+    process has them open. A group has one owner at a time. When its
+    owner is killed the kernel tears the mappings down as the process
+    exits, which takes many seconds on a host without huge pages; the
+    process has no open files to show by then, and whoever opens the
+    group meanwhile (libtpu, in the next cluster's worker) is refused.
+    Opening a group and closing it again asks nothing of the device.
+    A group held by a process that can be seen is that process's, and
+    is not listed: waiting would not free it."""
+    try:
+        groups = {os.path.join(vfio_dir, e) for e in os.listdir(vfio_dir)
+                  if e.isdigit()}
+    except OSError:
+        return []
+    for pid in os.listdir("/proc"):
+        if not groups:
+            break
+        if not pid.isdigit():
+            continue
+        fds = f"/proc/{pid}/fd"
+        try:
+            for fd in os.listdir(fds):
+                groups.discard(os.readlink(os.path.join(fds, fd)))
+        except OSError:  # gone meanwhile, or not ours to read
+            continue
+    busy = []
+    for path in sorted(groups):
+        try:
+            os.close(os.open(path, os.O_RDWR))
+        except OSError as e:
+            if e.errno == errno.EBUSY:
+                busy.append(path)
+    return busy
 
 
 def detect_tpu_slice(env: Optional[dict] = None,

@@ -23,6 +23,7 @@ import sys
 import time
 from typing import Any
 
+from ray_tpu._internal import accelerators
 from ray_tpu._internal.config import get_config
 from ray_tpu._internal.ids import ActorID, NodeID, ObjectID, WorkerID
 from ray_tpu._internal.logging_utils import setup_logger
@@ -34,6 +35,9 @@ from ray_tpu.core.gcs_object_manager import CH_OBJECTS
 from ray_tpu.core.object_store import make_shm_store
 
 logger = setup_logger("node_manager")
+
+# how long `stop` waits for a worker it had to kill to be gone
+_WORKER_EXIT_TIMEOUT_S = 60.0
 
 
 def _holds_tpu(demand: dict[str, float]) -> bool:
@@ -366,17 +370,24 @@ class NodeManager:
         self._stopping = True
         for t in self._tasks:
             t.cancel()
-        for w in list(self.workers.values()) + self._unregistered + self._doomed:
+        procs = [w.proc for w in list(self.workers.values())
+                 + self._unregistered + self._doomed]
+        for proc in procs:
             try:
-                w.proc.terminate()
+                proc.terminate()
             except Exception:
                 pass
-        for w in list(self.workers.values()) + self._unregistered + self._doomed:
+        for proc in procs:
             try:
-                w.proc.wait(timeout=3)
+                proc.wait(timeout=3)
             except Exception:
+                # a worker that held chips gives them back as it exits,
+                # signal or no signal, and that can take much longer:
+                # this node is down only when the chips are free for the
+                # next one on the host
                 try:
-                    w.proc.kill()
+                    proc.kill()
+                    proc.wait(timeout=_WORKER_EXIT_TIMEOUT_S)
                 except Exception:
                     pass
         for oid in list(self.object_dir):
@@ -959,6 +970,21 @@ class NodeManager:
             cand = self._try_claim_idle(tpu)
             if cand is not None:
                 return cand
+        if tpu:
+            # a cluster that ran on this host just before may have left
+            # a worker that is still giving the chips back: started
+            # now, this one's jax would find them busy and fail. Half
+            # of the time there is goes to this wait at most, the rest
+            # is the worker's: a group that stays busy is somebody's
+            # this node cannot see, and jax will say so if it is needed
+            now = time.monotonic()
+            patience = now + (deadline - now) / 2
+            while busy := accelerators.chips_being_released():
+                if time.monotonic() >= patience:
+                    logger.warning("%s stay busy with no process holding "
+                                   "them: starting the worker anyway", busy)
+                    break
+                await asyncio.sleep(0.2)
         spawned = self._spawn_worker(tpu)
         while time.monotonic() < deadline:
             if spawned.info is not None and spawned.conn is not None \

@@ -194,7 +194,9 @@ def shutdown():
         if ctx.owns_cluster and ctx.head_proc is not None:
             ctx.head_proc.terminate()
             try:
-                ctx.head_proc.wait(timeout=10)
+                # the node manager waits for its workers to be gone, and
+                # one that held chips takes its time (node_manager.stop)
+                ctx.head_proc.wait(timeout=90)
             except subprocess.TimeoutExpired:
                 ctx.head_proc.kill()
     try:

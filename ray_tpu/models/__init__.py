@@ -17,7 +17,8 @@ def module_for(cfg):
     recurrent state, a window's ring), decode_step, decode_read_block
     (the positions in a block of a decode step's cache reads, or None),
     TENSOR_PARALLEL. What the engine asks only where a module has it:
-    decode_counters (what a decode step counts of its live rows),
+    decode_counters (what a decode step counts of its live rows and of
+    all the rows it has),
     prefill_counters (what a prefill call's attention visits and sees),
     CACHE_KIND (the kind `stats()["cache_bytes"]` files a leaf under,
     beside `kv` and `state`) and STEP_AUX (counters the step decides on

@@ -642,11 +642,12 @@ def decode_read_block(cfg: Dots3NoteConfig, mesh) -> int | None:
     return None
 
 
-def decode_counters(cfg: Dots3NoteConfig, spans: list) -> dict:
-    """What one decode step does for live rows at `spans` [(start, the
-    position the step writes)], by layer kind: positions the indexers
-    score, latent rows the full layers gather and attend to, ring rows
-    the sliding layers attend to."""
+def decode_counters(cfg: Dots3NoteConfig, spans: list, rows: int) -> dict:
+    """What one decode step of `rows` rows does for live rows at `spans`
+    [(start, the position the step writes)], by layer kind: positions
+    the indexers score, latent rows the full layers gather and attend
+    to, ring rows the sliding layers attend to. (Nothing here counts the
+    rows that hold no request.)"""
     depth = [last - start + 1 for start, last in spans]
     nf, ns = cfg.count(KINDS[0]), cfg.count(KINDS[1])
     return {

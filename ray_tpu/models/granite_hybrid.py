@@ -16,7 +16,8 @@ stack, 1.25 GB, on every step.
 
 Same function set as models/llama.py, so serve/llm.py's engine runs
 either: `init_params`, `param_logical_axes`, `forward`, `init_cache`,
-`cache_logical_axes`, `CACHE_LEN_AXIS`, `decode_step`. Per-layer weights
+`cache_logical_axes`, `CACHE_LEN_AXIS`, `decode_step`, and of what the
+engine asks a module only where it has it, `decode_counters`. Per-layer weights
 are stacked BY KIND ("mamba", "attention") and the layer loop runs over
 the periods of `layer_types`, inside a period over its runs of one kind:
 one compiled block per run, whatever the depth. A request's cache is the
@@ -34,11 +35,13 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import attention as _attention
 # decode_read_block is this module's (models.module_for): K and V of
 # n_kv_heads x head_dim in every attention layer
 from ray_tpu.ops.attention import (cached_attention,  # noqa: F401
                                    decode_read_block, xla_attention)
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.pallas import ssm_update as _kernel
 from ray_tpu.ops.ssm import causal_conv, ssd_scan, ssm_step
 
 F32 = jnp.float32
@@ -230,14 +233,38 @@ def param_logical_axes(cfg: GraniteHybridConfig) -> dict:
 
 
 # ------------------------------------------------------------------- mixers
-def _mamba_mixer(cfg: GraniteHybridConfig, x, layer, state, tail, valid):
-    """x: [b, s, d]; state: [b, heads, p, n]; tail: [b, d_conv - 1,
-    conv_dim]; valid: [b, s] bool or None. Positions that are not valid
-    (left padding) reach neither the convolution's window nor the state:
-    the input is zeroed before the projection, which has no bias, and
-    again after the convolution's activation, whose bias would otherwise
-    write silu(bias) into x, B and C. Returns (out [b, s, d], state,
-    tail)."""
+def _state_update(states, li, x, dt, a, b, c, d):
+    """One token for layer `li` of the stacked state [layers, b, heads,
+    p, n]: (y [b, heads, p] float32, the stack). One recurrence, two
+    schedules: on a TPU, for a state the kernel takes, one call that
+    reads each block once, writes it in place and emits y
+    (ops/pallas/ssm_update.py); everywhere else the plain form on the
+    layer cut from the stack, which the compiler makes two passes of."""
+    hb = _kernel.heads_per_block(*states.shape[2:], states.dtype) \
+        if _attention._on_tpu() else None
+    if hb:
+        return _kernel.ssm_update(states, li, x, dt, a, b, c, d,
+                                  heads_block=hb)
+    y, state = ssm_step(
+        jax.lax.dynamic_index_in_dim(states, li, 0, keepdims=False),
+        x, dt, a, b, c, d)
+    return y, jax.lax.dynamic_update_slice(states, state[None],
+                                           (li, 0, 0, 0, 0))
+
+
+def _mamba_mixer(cfg: GraniteHybridConfig, x, layer, states, li, tail,
+                 valid):
+    """x: [b, s, d]; states: the stacked state [layers, b, heads, p, n],
+    of which this is layer `li`; tail: [b, d_conv - 1, conv_dim]; valid:
+    [b, s] bool or None. Positions that are not valid (left padding)
+    reach neither the convolution's window nor the state: the input is
+    zeroed before the projection, which has no bias, and again after the
+    convolution's activation, whose bias would otherwise write
+    silu(bias) into x, B and C. The layer's state is cut from its stack
+    and written back under the scope of the operation that uses it: the
+    compiler fuses the update into the write, and the fusion carries
+    the write's name (one token on a TPU takes neither cut nor write:
+    `_state_update`). Returns (out [b, s, d], states, tail)."""
     b, s, _ = x.shape
     dt_, di, n = cfg.dtype, cfg.d_inner, cfg.mamba_d_state
     nh, p = cfg.mamba_n_heads, cfg.mamba_d_head
@@ -258,17 +285,22 @@ def _mamba_mixer(cfg: GraniteHybridConfig, x, layer, state, tail, valid):
         a = -jnp.exp(layer["A_log"])
     if s == 1:
         with jax.named_scope("ssm_update"):
-            y, state = ssm_step(state, xs.reshape(b, nh, p), dt[:, 0], a,
-                                bmat[:, 0], cmat[:, 0], layer["D"])
+            y, states = _state_update(states, li, xs.reshape(b, nh, p),
+                                      dt[:, 0], a, bmat[:, 0], cmat[:, 0],
+                                      layer["D"])
     else:
         with jax.named_scope("ssm_scan"):
-            y, state = ssd_scan(state, xs.reshape(b, s, nh, p), dt, a, bmat,
-                                cmat, layer["D"], cfg.mamba_chunk_size)
+            y, state = ssd_scan(
+                jax.lax.dynamic_index_in_dim(states, li, 0, keepdims=False),
+                xs.reshape(b, s, nh, p), dt, a, bmat, cmat, layer["D"],
+                cfg.mamba_chunk_size)
+            states = jax.lax.dynamic_update_slice(states, state[None],
+                                                  (li, 0, 0, 0, 0))
     with jax.named_scope("ssm_gate_norm"):
         y = y.reshape(b, s, di) * jax.nn.silu(z.astype(F32))
         y = rms_norm(y, layer["gate_norm"], cfg.norm_eps).astype(dt_)
     with jax.named_scope("ssm_out"):
-        return y @ layer["out_proj"].astype(dt_), state, tail
+        return y @ layer["out_proj"].astype(dt_), states, tail
 
 
 def _qkv(cfg: GraniteHybridConfig, x, layer):
@@ -336,10 +368,11 @@ def forward(params: dict, tokens: jax.Array, cfg: GraniteHybridConfig
     r = cfg.residual_multiplier
 
     def mamba(x, layer, li):
-        state = jnp.zeros((b, cfg.mamba_n_heads, cfg.mamba_d_head,
-                           cfg.mamba_d_state), F32)
+        # a stack of one layer: the empty prefix's state
+        states = jnp.zeros((1, b, cfg.mamba_n_heads, cfg.mamba_d_head,
+                            cfg.mamba_d_state), F32)
         tail = jnp.zeros((b, cfg.mamba_d_conv - 1, cfg.conv_dim), cfg.dtype)
-        out, _, _ = _mamba_mixer(cfg, x, layer, state, tail, None)
+        out, _, _ = _mamba_mixer(cfg, x, layer, states, 0, tail, None)
         return _mlp(cfg, x + r * out, layer)
 
     def attention(x, layer, li):
@@ -395,6 +428,19 @@ def cache_logical_axes(cfg: GraniteHybridConfig) -> dict:
             "length": (), "start": ("batch",)}
 
 
+def decode_counters(cfg: GraniteHybridConfig, spans: list, rows: int) -> dict:
+    """What one decode step of `rows` rows does for the live rows at
+    `spans` (one entry a live row), in states of one row of one Mamba
+    layer (`mamba_n_heads x mamba_d_head x mamba_d_state` numbers, read
+    and written once each): those the step had to update, and those it
+    did update: every row's, whatever the row holds, in the kernel as
+    in the plain form. Their ratio is the share of the state traffic
+    that is required work."""
+    m = cfg.count("mamba")
+    return {"decode_state_rows_live": m * len(spans),
+            "decode_state_rows_updated": m * rows}
+
+
 def decode_step(params: dict, cache: dict, tokens: jax.Array,
                 cfg: GraniteHybridConfig) -> tuple[jax.Array, dict]:
     """Append `tokens` [b, s] to the cache, return logits for the last
@@ -419,23 +465,15 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array,
              if s > 1 and start is not None else None)
     r = cfg.residual_multiplier
 
-    # the layer's state and tail are cut from and written back to their
-    # stacks under the scopes of the operations that use them: the
-    # compiler fuses the update into the write, and the fusion carries
-    # the write's name
-    recur = "ssm_update" if s == 1 else "ssm_scan"
-
+    # the layer's tail is cut from and written back to its stack under
+    # the scope of the operation that uses it, as the mixer does with
+    # the state
     def mamba(carry, layer, li):
         x, kc, vc, states, tails = carry
-        with jax.named_scope(recur):
-            state = jax.lax.dynamic_index_in_dim(states, li, 0,
-                                                 keepdims=False)
         with jax.named_scope("ssm_conv"):
             tail = jax.lax.dynamic_index_in_dim(tails, li, 0, keepdims=False)
-        out, state, tail = _mamba_mixer(cfg, x, layer, state, tail, valid)
-        with jax.named_scope(recur):
-            states = jax.lax.dynamic_update_slice(
-                states, state[None], (li, 0, 0, 0, 0))
+        out, states, tail = _mamba_mixer(cfg, x, layer, states, li, tail,
+                                         valid)
         with jax.named_scope("ssm_conv"):
             tails = jax.lax.dynamic_update_slice(
                 tails, tail[None], (li, 0, 0, 0))

@@ -157,7 +157,8 @@ class LLMEngine:
     What a decode step reads is the module's to say: `decode_read_block`
     (the positions in a block of its attention's reads, or None where a
     step reads a layer whole) and, where it has them, `decode_counters`
-    (further counters of `stats()`, from the live rows' ranges) and
+    (further counters of `stats()`, from the live rows' ranges and the
+    rows the step has) and
     `prefill_counters` (the same of a prefill call's attention, from
     where its row starts and where the call's tokens lie).
 
@@ -243,7 +244,8 @@ class LLMEngine:
         self._decode_counters = getattr(mod, "decode_counters", None)
         self._prefill_counters = getattr(mod, "prefill_counters", None)
         self._model_counters = dict.fromkeys(
-            self._decode_counters(cfg, []), 0) if self._decode_counters \
+            self._decode_counters(cfg, [], max_batch), 0) \
+            if self._decode_counters \
             else {}
         if self._prefill_counters:
             self._model_counters.update(dict.fromkeys(
@@ -997,7 +999,7 @@ class LLMEngine:
                   + int(prev is not None and prev.rows[i] is req))
                  for i, req in enumerate(rows) if req is not None]
         live = sum(last - start + 1 for start, last in spans)
-        counters = self._decode_counters(self.cfg, spans) \
+        counters = self._decode_counters(self.cfg, spans, self.max_batch) \
             if self._decode_counters else {}
         block = self._decode_block
         if not block:

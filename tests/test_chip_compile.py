@@ -261,7 +261,15 @@ def test_hybrid_decode_step_moves_no_cache_and_no_state(chip, request, batch,
     compiler copied that whole stack, 1.25 GB, on every step: PERF.md,
     PR 28.) On the chip the attention layers' decode is the decode
     kernel (PR 31), given V in the order the compiler holds it in: the
-    same rule, with a Mosaic call in the period's loop."""
+    same rule, with a Mosaic call in the period's loop. Since PR 39 the
+    state update there is a kernel too (ops/pallas/ssm_update.py), the
+    first of this repo that writes its operand in place: its result has
+    the stack's shape, which is admitted only where that result is
+    aliased to the call's operand, and no copy of the state stack may
+    exist anywhere, inside a fusion either (a compiler that did not
+    honour the alias in the period's loop would copy 2.4 GB around every
+    call: the temporaries would hold a stack and the aliased bytes lose
+    one)."""
     if request.node.callspec.id.endswith("on-chip"):
         request.getfixturevalue("on_the_chip")
     cfg = granite_hybrid.GraniteHybridConfig(
@@ -285,7 +293,19 @@ def test_hybrid_decode_step_moves_no_cache_and_no_state(chip, request, batch,
         donate_argnums=(1,)).lower(params, place(cache), tokens).compile()
 
     on_chip = request.node.callspec.id.endswith("on-chip")
-    assert ("tpu_custom_call" in compiled.as_text()) == on_chip
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == on_chip
+    state = ",".join(map(str, cache["state"].shape))
+    # the update's calls: one for each run of Mamba layers in the period
+    updates = [line for line in text.splitlines()
+               if f"= (f32[{state}]" in line and " custom-call(" in line]
+    mamba_runs = sum(1 for kind, _, _ in cfg.runs if kind == "mamba")
+    assert len(updates) == (mamba_runs if on_chip and s == 1 else 0)
+    for line in updates:
+        assert "output_to_operand_aliasing={{0}: (1, {})}" in line, \
+            line[:300]
+        assert re.match(r"\s*%?ssm_update[.\d]* = ", line), line[:100]
+    assert not re.search(rf"f32\[{state}\]\S* copy\(", text)
     rows = ("k", "v", "state", "conv")
     nbytes = {key: math.prod(cache[key].shape) * cache[key].dtype.itemsize
               for key in rows}
@@ -298,7 +318,7 @@ def test_hybrid_decode_step_moves_no_cache_and_no_state(chip, request, batch,
             < HBM_BYTES)
     assert mem.temp_size_in_bytes < layer_state
     assert mem.alias_size_in_bytes >= stack_bytes
-    for line in compiled.as_text().splitlines():
+    for line in text.splitlines():
         m = _RESULT.match(line)
         if not m:
             continue
@@ -307,6 +327,47 @@ def test_hybrid_decode_step_moves_no_cache_and_no_state(chip, request, batch,
             assert m.group(3) in ("parameter", "get-tuple-element",
                                   "dynamic-update-slice", "bitcast"), \
                 line[:200]
+
+
+@pytest.mark.parametrize("layers,batch,heads,p,n,block", [
+    (36, 32, 64, 64, 128, 64),    # granite-4.0-h-micro, the cell's 32 slots
+    (18, 8, 48, 64, 128, 48),     # another head count: one block of 1.5 MiB
+    (4, 16, 128, 64, 128, 64),    # granite-4.0-h-small's: two blocks a row
+    (2, 4, 24, 128, 256, 8),      # another p and n: three blocks of 1 MiB
+], ids=["granite-micro-32", "48-heads", "128-heads", "p128-n256"])
+def test_ssm_update_kernel_compiles_for_the_chip(
+        chip, compiled_not_interpreted, layers, batch, heads, p, n, block):
+    """ops/pallas/ssm_update.py alone, in the blocks `heads_per_block`
+    gives each shape: it fits VMEM unasked, takes the stack as it lies
+    and writes it in place: with the stack donated nothing is held
+    beside it (temporaries under one block) and its bytes are aliased,
+    also when the layer index is a loop's counter."""
+    from ray_tpu.ops.pallas import ssm_update as su
+
+    on = SingleDeviceSharding(chip)
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=on)
+
+    assert su.heads_per_block(heads, p, n, jnp.float32) == block
+
+    def every_layer(states, x, dt, a, b, c, d):
+        def one(li, carry):
+            states, y = carry
+            return su.ssm_update(states, li, x + y, dt, a, b, c, d,
+                                 heads_block=block)[::-1]
+        return jax.lax.fori_loop(0, layers, one, (states, x))
+
+    compiled = jax.jit(every_layer, donate_argnums=(0,)).lower(
+        arg(layers, batch, heads, p, n), arg(batch, heads, p),
+        arg(batch, heads), arg(heads), arg(batch, n), arg(batch, n),
+        arg(heads)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "output_to_operand_aliasing={{0}: (1, {})}" in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < block * p * n * 4
+    assert mem.alias_size_in_bytes >= layers * batch * heads * p * n * 4
 
 
 def _dots3_cell_config(max_len):

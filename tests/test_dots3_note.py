@@ -452,11 +452,11 @@ def test_prefill_counters_from_where_the_row_and_the_chunk_lie(
 
 def test_decode_counters_from_row_ranges(model):
     _, cfg, _, _ = model
-    got = m.decode_counters(cfg, [(0, 4), (10, 40)])   # depths 5 and 31
+    got = m.decode_counters(cfg, [(0, 4), (10, 40)], 2)   # depths 5 and 31
     assert got == {"decode_index_positions_scored": 2 * 36,
                    "decode_latent_positions_attended": 2 * (5 + 12),
                    "decode_window_positions_attended": 3 * (5 + 9)}
-    assert m.decode_counters(cfg, []) == dict.fromkeys(got, 0)
+    assert m.decode_counters(cfg, [], 2) == dict.fromkeys(got, 0)
     assert m.decode_read_block(cfg, None) is None
 
 

@@ -7,6 +7,7 @@ through LLMEngine's slots."""
 
 import asyncio
 import dataclasses
+import types
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,7 @@ from benchmarks.reference import granite_hybrid_ref
 from ray_tpu.models import granite_hybrid as gh
 from ray_tpu.models import llama, module_for
 from ray_tpu.ops import ssm
+from ray_tpu.ops.pallas import ssm_update
 from ray_tpu.serve.llm import LLMEngine, greedy_reference_check
 
 PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
@@ -308,6 +310,71 @@ def test_chunked_prefill_equals_one_shot(exact):
                                    atol=1e-4)
 
 
+def test_decode_step_with_the_kernel_equals_without_it_and_forward(
+        monkeypatch):
+    """Eight decode steps of two rows at their own depths (prompts of 20
+    and 9 tokens, left-padded into buckets of 32 and 16, in one cache)
+    with the state update through ops/pallas/ssm_update.py (interpreted;
+    what a TPU takes for a state of whole lanes, and nothing else of a
+    TPU's choices) against the same steps in the plain form, and both
+    against `forward` over the bare tokens at the next position."""
+    cfg = gh.GraniteHybridConfig(**{**TWIN, "mamba_d_state": 128},
+                                 dtype=jnp.float32, param_dtype=jnp.float32)
+    params = gh.init_params(cfg, jax.random.PRNGKey(2))
+    lens, buckets, steps = (20, 9), (32, 16), 8
+    toks = [_tokens(21, lens[0] + steps), _tokens(22, lens[1] + steps)]
+    rows = [_prefill(cfg, params, t[:n], bucket, max_len=64)
+            for t, n, bucket in zip(toks, lens, buckets)]
+    first = np.concatenate([np.asarray(lg) for lg, _ in rows])
+    cache = {leaf: jnp.concatenate([c[leaf] for _, c in rows], axis=1)
+             for leaf in ("k", "v", "state", "conv")}
+    cache["length"] = jnp.asarray(buckets, jnp.int32)
+    cache["start"] = jnp.asarray([b - n for b, n in zip(buckets, lens)],
+                                 jnp.int32)
+
+    def run(cache):
+        step = jax.jit(lambda p, c, t: gh.decode_step(p, c, t, cfg))
+        out = []
+        for i in range(steps):
+            logits, cache = step(params, cache, jnp.asarray(
+                [[t[n + i]] for t, n in zip(toks, lens)], jnp.int32))
+            out.append(np.asarray(logits))
+        return np.stack(out), cache
+
+    plain, plain_cache = run(cache)
+    calls = []
+    real = ssm_update.ssm_update
+    monkeypatch.setattr(ssm_update, "ssm_update", lambda *a, **kw: (
+        calls.append(kw["heads_block"]), real(*a, **kw))[1])
+    monkeypatch.setattr(gh, "_attention",
+                        types.SimpleNamespace(_on_tpu=lambda: True))
+    kernel, kernel_cache = run(cache)
+    # one call a run of Mamba layers in the traced step: 5 and 4
+    assert calls == [8, 8]
+    np.testing.assert_allclose(kernel, plain, rtol=1e-4, atol=1e-5)
+    for leaf in ("state", "conv", "k", "v"):
+        np.testing.assert_allclose(kernel_cache[leaf], plain_cache[leaf],
+                                   rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(kernel_cache["length"],
+                                  np.asarray(buckets) + steps)
+    for r, (t, n) in enumerate(zip(toks, lens)):
+        want = np.asarray(gh.forward(params, jnp.asarray(t[None]), cfg)[0])
+        np.testing.assert_allclose(first[r], want[n - 1], rtol=1e-3,
+                                   atol=1e-4)
+        np.testing.assert_allclose(kernel[:, r], want[n:n + steps],
+                                   rtol=1e-3, atol=1e-4)
+
+
+def test_decode_counters_count_states_of_live_rows_and_of_all(exact):
+    """Nine Mamba layers in the twin: a step of 4 rows with 2 live had
+    to update 18 states of a row and a layer, and updated 36."""
+    cfg, _ = exact
+    assert gh.decode_counters(cfg, [(0, 4), (10, 40)], 4) == {
+        "decode_state_rows_live": 18, "decode_state_rows_updated": 36}
+    assert gh.decode_counters(cfg, [], 4) == {
+        "decode_state_rows_live": 0, "decode_state_rows_updated": 36}
+
+
 # ------------------------------------------------------- through LLMEngine
 def _engine(cfg, **kw):
     kw = {"tp": 1, "max_batch": 4, "prompt_buckets": (16, 64, 128),
@@ -355,6 +422,10 @@ def test_engine_holds_state_beside_kv_in_its_slots(exact):
     assert stats["cache_bytes"] == {
         "kv": 2 * 1 * 4 * 2 * 16 * 256 * 4, "state": state}
     assert stats["prefill_chunks"] > 0 and stats["active_slots"] == 0
+    # every dispatched step updated all 4 rows' state in the 9 layers
+    assert stats["decode_state_rows_updated"] == 9 * 4 * stats["batches"]
+    assert 0 < stats["decode_state_rows_live"] \
+        < stats["decode_state_rows_updated"]
 
 
 def test_engine_refuses_a_tensor_axis_for_this_model(exact):

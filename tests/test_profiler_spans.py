@@ -358,6 +358,14 @@ def _traced_sparse_moe_run(tmp_path):
         swa_q_rank=16, swa_kv_rank=12, sliding_window=9, ring_multiple=4,
         index_n_heads=8, index_head_dim=8, index_topk=12, max_seq_len=96,
         dtype=jnp.float32, param_dtype=jnp.float32)
+    return _traced_model_run(tmp_path, cfg)
+
+
+def _traced_model_run(tmp_path, cfg):
+    """An engine of two slots for model config `cfg` under a profiler
+    session, a short prompt and a chunked one, six tokens each.
+    -> (engine, stats() before the traced run, {span: [stats, ...]} in
+    order of start)."""
     eng = LLMEngine(cfg, tp=1, max_batch=2, prompt_buckets=(16, 64),
                     prefill_chunk=16)
 
@@ -463,3 +471,30 @@ def test_prefill_chunk_span_carries_what_the_chunks_attention_visits(
         assert c[names[0]] >= c[names[1]] and c[names[2]] >= c[names[3]]
     for name in names:
         assert sum(c[name] for c in chunks) == st[name] - before[name] > 0
+
+
+def test_dispatch_span_carries_the_state_rows_a_step_updates(tmp_path):
+    """`granite_hybrid.decode_counters` on the decode_dispatch span and
+    summed in stats(): the states a step had to update (its live rows x
+    the Mamba layers) and those it did (every row's)."""
+    from ray_tpu.models import granite_hybrid
+
+    cfg = granite_hybrid.GraniteHybridConfig(
+        vocab_size=128, dim=32, hidden_dim=48, n_heads=2, n_kv_heads=1,
+        layer_types=("mamba", "attention", "mamba"), mamba_n_heads=4,
+        mamba_d_head=16, mamba_d_state=16, mamba_chunk_size=16,
+        max_seq_len=96, dtype=jnp.float32, param_dtype=jnp.float32)
+    eng, before, by_name = _traced_model_run(tmp_path, cfg)
+    st = eng.stats()
+    names = ("decode_state_rows_live", "decode_state_rows_updated")
+    dispatch = by_name["decode_dispatch"]
+    assert dispatch
+    for d in dispatch:
+        assert {"active", "live_positions", "t_host", *names} <= set(d)
+        assert d[names[0]] == 2 * d["active"] and d[names[1]] == 2 * 2
+    for name in names:
+        assert isinstance(st[name], int)
+        assert sum(d[name] for d in dispatch) == st[name] - before[name] > 0
+    assert st[names[1]] == 2 * 2 * st["batches"]
+    # the short request ends first: some steps had one live row of two
+    assert st[names[0]] < st[names[1]]

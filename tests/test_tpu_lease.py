@@ -6,17 +6,23 @@ handed — the JAX_PLATFORMS it would give jax — and on what jax does with
 it, not on a device.
 """
 
+import asyncio
+import errno
 import os
+import subprocess
 import sys
 import time
+import types
 
 import pytest
 
 import ray_tpu as rt
 from ray_tpu import state_api
+from ray_tpu._internal import accelerators
 from ray_tpu._internal.spawn import (COMPILE_CACHE_ENV, child_env,
                                      compile_cache_dir, jax_platforms_env)
-from ray_tpu.core.node_manager import _holds_tpu
+from ray_tpu.core import node_manager
+from ray_tpu.core.node_manager import NodeManager, _holds_tpu
 
 
 class Probe:
@@ -84,6 +90,106 @@ def test_platform_handed_to_a_worker(node, leased, want):
 ])
 def test_which_demands_hold_a_chip(demand, holds):
     assert _holds_tpu(demand) is holds
+
+
+@pytest.mark.parametrize("refused,held,want", [
+    ((), (), []),                  # every group opens: the chips are free
+    (("1",), (), ["1"]),           # refused and nobody has it: in teardown
+    (("0", "1"), ("1",), ["0"]),   # refused and somebody has it: theirs
+    (("0",), ("0",), []),
+], ids=["free", "released", "one-held", "held"])
+def test_groups_refused_with_no_holder_are_being_released(
+        tmp_path, monkeypatch, refused, held, want):
+    """`chips_being_released`: a vfio group that answers EBUSY while no
+    process has it open is a killed owner's, still torn down by the
+    kernel (what the four-chip train cell's next run met: PERF.md,
+    PR 39). Files stand in for the groups; this process holds `held`."""
+    for name in ("0", "1", "vfio"):
+        (tmp_path / name).write_bytes(b"")
+    mine = [os.open(tmp_path / name, os.O_RDWR) for name in held]
+    real = os.open
+
+    def open_(path, flags, *a, **kw):
+        if os.path.basename(path) in refused:
+            raise OSError(errno.EBUSY, "Device or resource busy", str(path))
+        return real(path, flags, *a, **kw)
+
+    monkeypatch.setattr(accelerators.os, "open", open_)
+    try:
+        assert accelerators.chips_being_released(str(tmp_path)) == [
+            str(tmp_path / name) for name in want]
+    finally:
+        for fd in mine:
+            os.close(fd)
+    # a host with no such directory has no group to wait for
+    assert accelerators.chips_being_released(str(tmp_path / "none")) == []
+
+
+@pytest.mark.parametrize("tpu,busy_polls,polled", [
+    (True, 2, 3),         # waits while they are busy, then starts
+    (True, None, None),   # busy for half its time: starts all the same
+    (False, None, 0),     # no lease on chips: never asks
+], ids=["waits", "gives-up-waiting", "cpu-worker"])
+def test_a_chip_worker_starts_once_the_chips_are_free(
+        monkeypatch, tpu, busy_polls, polled):
+    """A group that stays busy is held from where this node cannot see
+    (another container's process): the wait has an end, the worker is
+    started with the rest of the time, and jax says what it finds."""
+    polls = []
+
+    def being_released():
+        polls.append(time.monotonic())
+        busy = busy_polls is None or len(polls) <= busy_polls
+        return ["/dev/vfio/0"] if busy else []
+
+    monkeypatch.setattr(accelerators, "chips_being_released", being_released)
+    spawned = types.SimpleNamespace(info=object(), conn=object(), busy=False)
+    started = []
+    nm = types.SimpleNamespace(
+        _try_claim_idle=lambda tpu: None, _unregistered=[],
+        _spawn_worker=lambda tpu: (started.append(tpu), spawned)[1])
+    t0 = time.monotonic()
+    assert asyncio.run(NodeManager._get_idle_worker(
+        nm, timeout_s=1.0, tpu=tpu)) is spawned and spawned.busy
+    assert started == [tpu]
+    if polled is None:  # half of the second, and the other half unspent
+        assert 0.5 <= polls[-1] - t0 and time.monotonic() - t0 < 0.9
+    else:
+        assert len(polls) == polled
+
+
+def test_stop_waits_for_a_worker_it_had_to_kill():
+    """A worker that held chips is gone only when the kernel has given
+    them back, long after the signal: `stop` returns after that, so the
+    next cluster on the host finds them free."""
+    class Proc:
+        def __init__(self, slow):
+            self.slow, self.calls = slow, []
+
+        def terminate(self):
+            self.calls.append("terminate")
+
+        def kill(self):
+            self.calls.append("kill")
+
+        def wait(self, timeout=None):
+            self.calls.append(("wait", timeout))
+            if self.slow and len(self.calls) == 2:
+                raise subprocess.TimeoutExpired("worker", timeout)
+
+    async def nothing():
+        pass
+
+    quick, slow = Proc(False), Proc(True)
+    nm = types.SimpleNamespace(
+        _tasks=[], workers={1: types.SimpleNamespace(proc=quick)},
+        _unregistered=[], _doomed=[types.SimpleNamespace(proc=slow)],
+        object_dir={}, shm=object(), gcs_conn=None,
+        server=types.SimpleNamespace(stop=nothing))
+    asyncio.run(NodeManager.stop(nm))
+    assert quick.calls == ["terminate", ("wait", 3)]
+    assert slow.calls == ["terminate", ("wait", 3), "kill",
+                          ("wait", node_manager._WORKER_EXIT_TIMEOUT_S)]
 
 
 def test_compile_cache_is_one_fixed_dir_unless_set_outside(monkeypatch):
