@@ -717,8 +717,12 @@ class CoreWorker:
         self.gcs.on_reconnect.append(self._reset_object_report_baseline)
         self._spawn(self._task_event_flush_loop())
         if self.mode == "worker":
-            await self.node_conn.call(
+            spawn = await self.node_conn.call(
                 "register_worker", (self.worker_info, os.getpid()))
+            if isinstance(spawn, dict):
+                from ray_tpu._internal.profiler import process_log
+
+                process_log().spawned(spawn)
 
     def shutdown(self):
         if self._shutdown:

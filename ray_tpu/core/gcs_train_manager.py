@@ -192,7 +192,9 @@ class GcsTrainManager:
         rec = {"step_id": f"{run_id}:{rank}:{step}", "run_id": run_id,
                "experiment": run["experiment"], "rank": rank,
                "step": step, "ts": ts, "wall_s": wall, "stages": stages}
-        for k in ("ckpt_commit_s", "tokens", "loss"):
+        # `startup` and `programs`: the worker process's own log, on the
+        # first record whole and later as what was asked for since
+        for k in ("ckpt_commit_s", "tokens", "loss", "startup", "programs"):
             if m.get(k) is not None:
                 rec[k] = m[k]
         self._steps[rec["step_id"]] = rec

@@ -54,6 +54,12 @@ class _Worker:
         # worker whose jax may leave the CPU (see _spawn_worker). It is
         # never pooled — it lives exactly as long as its lease
         self.tpu = tpu
+        # for the worker's own account of its start (the reply to its
+        # registration) and the worker_started event: when the process
+        # was started, when the lease that caused it was asked for, and
+        # how long chips still being released held the spawn back
+        self.spawned = self.lease_asked = time.time()
+        self.chip_wait_s = 0.0
         self.info: WorkerInfo | None = None
         self.conn: Connection | None = None
         self.registered = asyncio.Event()
@@ -920,9 +926,11 @@ class NodeManager:
             "worker_started",
             f"worker {info.worker_id.hex()[:12]} (pid {w.proc.pid}) "
             f"registered", worker_id=info.worker_id.hex(),
-            pid=w.proc.pid)
+            pid=w.proc.pid, chip_wait_s=w.chip_wait_s,
+            boot_s=time.time() - w.spawned)
         self._maybe_grant_pending()
-        return True
+        return {"tpu": w.tpu, "lease_asked": w.lease_asked,
+                "spawned": w.spawned, "chip_wait_s": w.chip_wait_s}
 
     def _try_claim_idle(self, tpu: bool = False) -> _Worker | None:
         """Atomically (no awaits) claim an idle worker of the wanted kind.
@@ -954,6 +962,7 @@ class NodeManager:
         w = self._try_claim_idle(tpu)
         if w is not None:
             return w
+        asked, chip_wait_s = time.time(), 0.0
         cfg = get_config()
         deadline = time.monotonic() + (
             cfg.worker_startup_timeout_s if timeout_s is None
@@ -979,13 +988,18 @@ class NodeManager:
             # this node cannot see, and jax will say so if it is needed
             now = time.monotonic()
             patience = now + (deadline - now) / 2
+            held = False
             while busy := accelerators.chips_being_released():
+                held = True
                 if time.monotonic() >= patience:
                     logger.warning("%s stay busy with no process holding "
                                    "them: starting the worker anyway", busy)
                     break
                 await asyncio.sleep(0.2)
+            if held:   # 0 where no group was ever busy
+                chip_wait_s = time.monotonic() - now
         spawned = self._spawn_worker(tpu)
+        spawned.lease_asked, spawned.chip_wait_s = asked, chip_wait_s
         while time.monotonic() < deadline:
             if spawned.info is not None and spawned.conn is not None \
                     and not spawned.busy:

@@ -100,30 +100,31 @@ def build_train_step(loss_fn: Callable, optimizer: optax.GradientTransformation,
             loss, aux = loss_fn(full, b)
             return loss, aux
 
-        if grad_accum > 1:
-            def micro(carry, mb):
-                g_acc, aux_acc = carry
-                (_, aux), g = jax.value_and_grad(
-                    compute, has_aux=True)(state["params"], mb)
-                g_acc = jax.tree.map(jnp.add, g_acc, g)
-                aux_acc = jax.tree.map(jnp.add, aux_acc, aux)
-                return (g_acc, aux_acc), None
+        # scope names are HLO metadata: a profiler trace of the step
+        # says which device operations are the loss (jax adds jvp,
+        # transpose and checkpoint to the path itself; under accumulation
+        # the scan's own slices and sums are the loss's too) and which
+        # the optimizer
+        with jax.named_scope("loss"):
+            if grad_accum > 1:
+                def micro(carry, mb):
+                    g_acc, aux_acc = carry
+                    (_, aux), g = jax.value_and_grad(
+                        compute, has_aux=True)(state["params"], mb)
+                    g_acc = jax.tree.map(jnp.add, g_acc, g)
+                    aux_acc = jax.tree.map(jnp.add, aux_acc, aux)
+                    return (g_acc, aux_acc), None
 
-            mb0 = jax.tree.map(
-                lambda x: x.reshape((grad_accum, -1) + x.shape[1:]), batch)
-            zeros_g = jax.tree.map(jnp.zeros_like, state["params"])
-            (_, aux0), _ = jax.value_and_grad(compute, has_aux=True)(
-                state["params"], jax.tree.map(lambda x: x[0], mb0))
-            zeros_aux = jax.tree.map(jnp.zeros_like, aux0)
-            (grads, aux), _ = jax.lax.scan(micro, (zeros_g, zeros_aux), mb0)
-            grads = jax.tree.map(lambda g: g / grad_accum, grads)
-            aux = jax.tree.map(lambda a: a / grad_accum, aux)
-        else:
-            # scope names are HLO metadata: a profiler trace of the step
-            # says which device operations are the loss (jax adds jvp,
-            # transpose and checkpoint to the path itself) and which the
-            # optimizer
-            with jax.named_scope("loss"):
+                mb0 = jax.tree.map(
+                    lambda x: x.reshape((grad_accum, -1) + x.shape[1:]), batch)
+                zeros_g = jax.tree.map(jnp.zeros_like, state["params"])
+                (_, aux0), _ = jax.value_and_grad(compute, has_aux=True)(
+                    state["params"], jax.tree.map(lambda x: x[0], mb0))
+                zeros_aux = jax.tree.map(jnp.zeros_like, aux0)
+                (grads, aux), _ = jax.lax.scan(micro, (zeros_g, zeros_aux), mb0)
+                grads = jax.tree.map(lambda g: g / grad_accum, grads)
+                aux = jax.tree.map(lambda a: a / grad_accum, aux)
+            else:
                 (_, aux), grads = jax.value_and_grad(
                     compute, has_aux=True)(state["params"], batch)
         with jax.named_scope("optimizer"):

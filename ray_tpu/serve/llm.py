@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import functools
 import os
 import threading
 import time
@@ -29,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu._internal.profiler import span_type
+from ray_tpu._internal.profiler import process_log, site_type, span_type
 from ray_tpu.models import llama, module_for
 from ray_tpu.parallel.mesh import build_mesh, shard_params, spec_for
 from ray_tpu.serve.multiplex import multiplexed
@@ -38,6 +39,23 @@ from ray_tpu.serve.multiplex import multiplexed
 # into the JAX profiler's trace when one is being taken of this process
 # and a flag check otherwise (_internal/profiler.py).
 _span = span_type()
+# The same span, where a jitted call may be made under it: it also names,
+# for its thread, the programs asked for inside it (the site and the
+# static key the engine holds there), for the process's log of them.
+_site = site_type()
+
+
+def _engine_build_phase(init):
+    """`LLMEngine.__init__` as the process's `engine_build` phase, after
+    the backend is up (its first touch, where this is it, is phase
+    `backend`); only the first engine of a process is a phase."""
+    @functools.wraps(init)
+    def wrapped(self, *args, **kwargs):
+        log = process_log()
+        log.backend_up()
+        with log.phase("engine_build"):
+            init(self, *args, **kwargs)
+    return wrapped
 
 
 def _bucket(n: int, buckets: tuple[int, ...]) -> int:
@@ -171,6 +189,7 @@ class LLMEngine:
     `stats()`), whatever was asked for.
     """
 
+    @_engine_build_phase
     def __init__(self, model: Any = "debug", *, tp: int | None = None,
                  max_batch: int = 4, max_seq_len: int | None = None,
                  prompt_buckets: tuple[int, ...] = (32, 128, 512, 1024),
@@ -310,7 +329,15 @@ class LLMEngine:
                 self._reseed_key()
                 raise
 
-        self._step = _step_guarded
+        def _first_step(*args):
+            # the first step of any kind, returned, ends the process's
+            # start-up (phase `ready`); every later one is _step_guarded
+            self._step = _step_guarded
+            with process_log().phase("ready"):
+                return _step_guarded(*args)
+
+        self._step = _first_step
+        self._decode_program = f"decode_dispatch[{max_batch}]"
 
         def insert_row(cache, row, slot, length, start):
             """Graft a freshly prefilled request's row (each leaf along
@@ -626,7 +653,8 @@ class LLMEngine:
         slot = next(i for i, s in enumerate(self._slots) if s is None)
         bucket = (int(req.prefilled["bucket"]) if req.prefilled is not None
                   else _bucket(len(req.tokens), self.prompt_buckets))
-        with _span("rayt.engine.admit", request_id=req.request_id,
+        with _site("rayt.engine.admit", f"admit[{bucket}]",
+                   request_id=req.request_id,
                    prompt_len=len(req.tokens), bucket=bucket, slot=slot):
             try:
                 self._ensure_decode_cache()
@@ -700,7 +728,9 @@ class LLMEngine:
         counters = self._prefill_counters(
             self.cfg, bucket - len(req.tokens), pos, chunk, bucket) \
             if self._prefill_counters else {}
-        with _span("rayt.engine.prefill_chunk", request_id=req.request_id,
+        with _site("rayt.engine.prefill_chunk",
+                   f"prefill_chunk[{chunk}@{bucket}]",
+                   request_id=req.request_id,
                    pos=pos, chunk=chunk, last=int(pos + chunk >= bucket),
                    **counters):
             nxt, small, self._key = self._step(
@@ -826,7 +856,8 @@ class LLMEngine:
         apply to its outputs (`_decode_cache`, `_cur`), so device order
         makes the graft safe and the request joins at the step after."""
         row = self._row(small)
-        with _span("rayt.engine.finish_prefill", request_id=req.request_id,
+        with _site("rayt.engine.finish_prefill", f"finish_prefill[{bucket}]",
+                   request_id=req.request_id,
                    slot=slot, row_bytes=sum(a.nbytes for a in row.values())):
             if not isinstance(first, int):
                 first = int(np.asarray(first)[0])
@@ -969,7 +1000,8 @@ class LLMEngine:
         # the one event that carries both clocks, so request records and
         # a client's stamps (CLOCK_MONOTONIC, one clock for the host)
         # can be laid on the profiler's time axis
-        with _span("rayt.engine.decode_dispatch", active=active,
+        with _site("rayt.engine.decode_dispatch", self._decode_program,
+                   active=active,
                    live_positions=live, t_host=time.perf_counter(),
                    **counters):
             nxt, self._decode_cache, self._key = self._step(
@@ -1061,6 +1093,12 @@ class LLMEngine:
             span.set_metadata(finished=finished, **aux)
 
     def stats(self) -> dict:
+        """The engine's counters, and the process's own log
+        (_internal/profiler.ProcessLog): `programs`, every program the
+        process asked XLA for, by the site that asked (`last` is the
+        answer to "which step recompiled, and when"), and `startup`, the
+        phases of the process's start."""
+        log = process_log()
         return {"generated_tokens": self.generated_tokens,
                 "batches": self.batches,
                 "prefills": self.prefills,
@@ -1079,7 +1117,8 @@ class LLMEngine:
                 **self._model_counters, **self._aux_totals,
                 "active_slots": sum(1 for s in self._slots
                                     if s is not None),
-                "tp": self.mesh.shape.get("tensor", 1)}
+                "tp": self.mesh.shape.get("tensor", 1),
+                "programs": log.programs(), "startup": log.startup()}
 
 
 def greedy_reference_check(engine: "LLMEngine", tokens: list[int],

@@ -19,6 +19,8 @@ from typing import Any, Optional
 
 import cloudpickle
 
+from ray_tpu._internal.profiler import process_log
+
 # cumulative engine reports piggyback on the request-recording path at
 # most this often (differenced into rates GCS-side)
 _ENGINE_REPORT_INTERVAL_S = 2.0
@@ -42,7 +44,15 @@ class ReplicaActor:
         self._total = 0
         self._overloaded_rejects = 0
         self._max_ongoing = max(1, int(max_ongoing_requests))
-        target = cloudpickle.loads(callable_blob)
+        def load():
+            return cloudpickle.loads(callable_blob)
+
+        log = process_log()
+        # a worker started for a lease on chips: loading the callable
+        # (which imports its modules, jax among them) and the touch of
+        # the backend that its constructor would make a moment later
+        # are the process's `backend` phase
+        target = log.backend_up(load) if log.leased_chips else load()
         args = tuple(self._resolve(a) for a in init_args)
         kwargs = {k: self._resolve(v) for k, v in init_kwargs.items()}
         if isinstance(target, type):

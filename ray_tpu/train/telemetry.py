@@ -29,10 +29,15 @@ profiler's trace when one is being taken of this worker
 (``_internal/profiler.span_type``; a flag check otherwise), so the
 waterfall's stages sit on the device operations' clock.
 
-XLA compile accounting rides :meth:`wrap_jit`: the first call per
-argument-shape signature is timed as the compile (first-trace) event;
-a NEW signature after the first is a retrace, published with the shape
-delta that caused it (the GCS surfaces it as a WARNING cluster event).
+XLA compile accounting rides :meth:`wrap_jit` and the process's own
+log of the programs it asks XLA for (``_internal/profiler.ProcessLog``):
+the wrapped step is a labelled site, and a call under which jax traced,
+lowered, compiled or loaded anything is published, with what those
+stages cost, as the ``compile`` event, or after the first as a
+``retrace`` with the shape delta that caused it (the GCS surfaces it as
+a WARNING cluster event). The first step record carries the log's
+``startup`` and ``programs`` sections whole, a later one what was asked
+for since, if anything was.
 """
 
 from __future__ import annotations
@@ -44,7 +49,8 @@ import uuid
 import weakref
 from typing import Optional
 
-from ray_tpu._internal.profiler import span_type
+from ray_tpu._internal.profiler import (listen, no_programs, process_log,
+                                        span_type)
 from ray_tpu.core.gcs_train_manager import CH_TRAIN
 
 # phase name -> waterfall stage key (manager TRAIN_STAGES order)
@@ -247,6 +253,10 @@ class StepRecorder:
         self._last_step_end: Optional[float] = None
         self._last_mem_ts = 0.0
         self._jit_shapes: dict[str, str] = {}
+        # the process's log as the last published step record left it
+        self._log = process_log()
+        self._programs_seen = -1
+        self._programs_prev = no_programs()
         self._closed = False
 
     # ------------------------------------------------------- phase marks
@@ -304,37 +314,64 @@ class StepRecorder:
             rec["loss"] = float(loss)
         if ckpt_commit_s is not None:
             rec["ckpt_commit_s"] = float(ckpt_commit_s)
+        if self._log.appended != self._programs_seen:
+            self._add_programs(rec)
         self._step += 1
         self._pub.publish(rec)
 
+    def _add_programs(self, rec: dict):
+        """The process's log on a step record: whole, with `startup`,
+        on the first; afterwards what was asked for since the last
+        (the totals' differences and `last`, the newest record)."""
+        log = self._log
+        first = self._programs_seen < 0
+        self._programs_seen = log.appended
+        programs = log.programs()
+        now = {k: programs[k] for k in self._programs_prev}
+        if first:
+            rec["startup"] = log.startup()
+            rec["programs"] = programs
+        else:
+            rec["programs"] = {
+                **{k: v - self._programs_prev[k] for k, v in now.items()},
+                "last": programs["last"]}
+        self._programs_prev = now
+
     # ------------------------------------------------------ XLA compiles
     def wrap_jit(self, fn, name: str):
-        """Wrap a jitted callable with compile accounting: the first
-        call per argument-shape signature is timed (block-until-ready)
-        and published as a ``compile`` event; later NEW signatures are
-        ``retrace`` events carrying the shape delta."""
-        def wrapped(*args, **kwargs):
-            prev = self._jit_shapes.get(name)
-            sig = _shape_sig(args, kwargs)
-            if sig == prev:
-                return fn(*args, **kwargs)
-            t0 = time.perf_counter()
-            out = fn(*args, **kwargs)
-            try:
-                import jax
+        """Wrap a jitted callable as a labelled site of the process's
+        log: a call under which a stage fired (trace, lower, compile,
+        cache_load) is published as a ``compile`` event with the stages'
+        seconds, a later one as a ``retrace`` carrying the shape delta.
+        The first call's return ends the process's start-up."""
+        log = self._log
+        listen()
 
-                jax.block_until_ready(out)
-            except Exception:
-                pass
-            elapsed = time.perf_counter() - t0
-            self._jit_shapes[name] = sig
-            self._pub.publish({
-                "kind": "compile", "run_id": self.run_id,
-                "experiment": self.experiment, "rank": self.rank,
-                "fn": name,
-                "event": "compile" if prev is None else "retrace",
-                "compile_s": elapsed, "shape": sig,
-                "prev_shape": prev or "", "ts": time.time()})
+        def wrapped(*args, **kwargs):
+            before = log.appended
+            outer = log.label(name)
+            try:
+                if log.is_ready:
+                    out = fn(*args, **kwargs)
+                else:
+                    with log.phase("ready"):
+                        out = fn(*args, **kwargs)
+            finally:
+                log.label(outer)
+            asked = log.appended - before
+            if asked:
+                prev = self._jit_shapes.get(name)
+                sig = self._jit_shapes[name] = _shape_sig(args, kwargs)
+                self._pub.publish({
+                    "kind": "compile", "run_id": self.run_id,
+                    "experiment": self.experiment, "rank": self.rank,
+                    "fn": name,
+                    "event": "compile" if prev is None else "retrace",
+                    "compile_s": sum(r["seconds"]
+                                     for r in log.records()[-asked:]
+                                     if r["program"] == name),
+                    "shape": sig, "prev_shape": prev or "",
+                    "ts": time.time()})
             return out
         wrapped.__name__ = f"rayt_obs_{name}"
         return wrapped

@@ -16,6 +16,7 @@ from typing import Any, Callable, Optional
 import cloudpickle
 
 import ray_tpu as rt
+from ray_tpu._internal.profiler import process_log
 from ray_tpu.train.config import RunConfig, ScalingConfig
 from ray_tpu.train import session
 
@@ -29,6 +30,13 @@ class TrainWorker:
               ingest_spec=None, run_id: Optional[str] = None) -> dict:
         from ray_tpu.util import collective
 
+        log = process_log()
+        if log.leased_chips and world_size == 1:
+            # the import of jax and the touch of the backend that the
+            # session and the loop would make a moment later, as the
+            # process's `backend` phase (a gang's loop may have to join
+            # its processes first: that one is left alone)
+            log.backend_up()
         self._group_name = group_name
         node_id = ""
         try:

@@ -498,3 +498,284 @@ def test_dispatch_span_carries_the_state_rows_a_step_updates(tmp_path):
     assert st[names[1]] == 2 * 2 * st["batches"]
     # the short request ends first: some steps had one live row of two
     assert st[names[0]] < st[names[1]]
+
+
+# ------------------------------------------- the process's own log (PR 40)
+@pytest.fixture
+def fresh_log(monkeypatch):
+    """A log of its own for the test: the listeners write to whichever
+    the module holds, and the engine asks the module each time."""
+    from ray_tpu._internal import profiler
+
+    log = profiler.ProcessLog()
+    monkeypatch.setattr(profiler, "_LOG", log)
+    return log
+
+
+def _stages_of(records, program, fun):
+    """The stages logged under `program` for jax's function `fun`
+    (`step` where it is traced, `jit(step)` from there on)."""
+    return sorted(r["stage"] for r in records if r["program"] == program
+                  and r["fun_name"] in (fun, f"jit({fun})"))
+
+
+def test_engine_names_every_program_it_asks_for_by_its_site(fresh_log):
+    """(a) of the log: two requests of one bucket (the benchmark's
+    warm-up: alone, then again) ask for each program of that bucket
+    under the site's name; a third asks for nothing; a new bucket asks
+    for exactly its own programs; `last` is the newest record."""
+    log = fresh_log
+    eng = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=256,
+                    prompt_buckets=(16, 64), prefill_chunk=16,
+                    prefix_cache_entries=0)
+
+    def serve(tokens):
+        async def one():
+            return [t async for t in eng.generate(tokens, max_new_tokens=4)]
+        return asyncio.run(one())
+
+    assert len(serve([5, 9, 11])) == 4 and len(serve([5, 9, 12])) == 4
+    recs = log.records()
+    once = ["compile", "lower", "trace"]
+    assert _stages_of(recs, "finish_prefill[16]", "insert_row") == once
+    assert _stages_of(recs, "finish_prefill[16]", "set_slot") == once
+    assert _stages_of(recs, "admit[16]", "<lambda>") == once   # retire
+    # `step` is one function behind both phases: the site tells them
+    # apart. Each is asked for once, and once more when its arguments
+    # stop being the arrays the host made for the very first call
+    # (ROADMAP S7: the same shape, compiled or loaded again)
+    for site in ("prefill_chunk[16@16]", "decode_dispatch[2]"):
+        got = _stages_of(recs, site, "step")
+        assert got in (once, sorted(once * 2)), (site, got)
+    assert not [r for r in recs if r["program"] == "unlabelled"]
+    assert {r["stage"] for r in recs} == set(once)   # no cache here
+    assert all(r["t"] >= log.t0 and r["seconds"] >= 0 for r in recs)
+
+    asked = log.appended
+    callbacks = log.callbacks
+    assert len(serve([5, 9, 13])) == 4
+    # a warm request: no record, and no listener was even called
+    assert (log.appended, log.callbacks) == (asked, callbacks)
+
+    assert len(serve(list(range(1, 41)))) == 4    # bucket 64, in chunks
+    new = log.records()[asked:]
+    # (`admit` makes the request's own cache with jnp's zeros, which a
+    # process compiles once for a shape, whoever asks first)
+    assert {"prefill_chunk[16@64]", "finish_prefill[64]"} <= {
+        r["program"] for r in new} <= {
+        "admit[64]", "prefill_chunk[16@64]", "finish_prefill[64]"}
+    assert _stages_of(new, "finish_prefill[64]", "insert_row") == once
+    assert "compile" in _stages_of(new, "prefill_chunk[16@64]", "step")
+    assert not _stages_of(new, "finish_prefill[64]", "set_slot")
+
+    programs = eng.stats()["programs"]
+    assert programs["last"] == log.records()[-1]
+    assert programs["last"]["program"] == "finish_prefill[64]"
+    compiles = [r for r in log.records() if r["stage"] == "compile"]
+    assert programs["asked"] == len(compiles) == sum(
+        p["count"] for p in programs["by_program"].values())
+    assert programs["dropped"] == 0 and programs["unlabelled"] == 0
+    assert programs["by_program"]["finish_prefill[16]"]["fun_names"] == {
+        "jit(insert_row)": 1, "jit(set_slot)": 1}
+    for key in ("trace_s", "lower_s", "compile_s", "cache_load_s"):
+        assert programs[key] == pytest.approx(sum(
+            r["seconds"] for r in log.records() if r["stage"] + "_s" == key))
+    # per program asked for: when, and the totals up to it
+    assert [p[2] for p in programs["timeline"]] == list(
+        range(1, len(compiles) + 1))
+    assert [p[0] for p in programs["timeline"]] == [r["t"] for r in compiles]
+
+    # a stage outside any site keeps jax's name for the function
+    def outside(x):
+        return x + 1
+
+    jax.jit(outside)(jnp.ones((3,)))
+    last = eng.stats()["programs"]["last"]
+    assert (last["program"], last["fun_name"], last["stage"]) == (
+        "unlabelled", "jit(outside)", "compile")
+    assert eng.stats()["programs"]["unlabelled_since_ready"] >= 1
+
+
+def test_startup_phases_are_ordered_and_end_at_ready(fresh_log):
+    """(b) of the log: each phase once, in order, none inside another,
+    the last the first step's return; what the node manager said in the
+    handshake becomes `spawn_wait` and `boot` before them."""
+    from ray_tpu._internal import profiler
+
+    log = fresh_log
+    wall, perf = log.anchor
+    log.spawned({"tpu": False, "lease_asked": wall - 2.0,
+                 "spawned": wall - 0.5, "chip_wait_s": 1.25})
+    eng = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=64,
+                    prompt_buckets=(16,), prefill_chunk=0,
+                    prefix_cache_entries=0)
+    assert "ready" not in eng.stats()["startup"]["phases"]
+
+    async def one():
+        return [t async for t in eng.generate([3, 4, 5], max_new_tokens=2)]
+
+    assert len(asyncio.run(one())) == 2
+    startup = eng.stats()["startup"]
+    phases = startup["phases"]
+    assert list(phases) == list(profiler.PHASES)
+    flat = [t for name in profiler.PHASES for t in phases[name]]
+    assert flat == sorted(flat)
+    assert phases["spawn_wait"] == pytest.approx([-1.5, 0.0])
+    assert phases["boot"][0] == 0.0 and startup["chip_wait_s"] == 1.25
+    assert startup["process_start"] == pytest.approx(perf - 0.5)
+    assert startup["anchor"] == {"wall": wall, "perf_counter": perf}
+    assert "unlabelled" not in eng.stats()["programs"]["by_program"]
+    # a second engine, and a later step, are no phases
+    LLMEngine("debug", tp=1, max_batch=2, max_seq_len=64,
+              prompt_buckets=(16,), prefill_chunk=0, prefix_cache_entries=0)
+    asyncio.run(one())
+    assert eng.stats()["startup"] == startup
+
+
+def test_log_keeps_the_newest_records_and_counts_the_rest(fresh_log,
+                                                          monkeypatch):
+    from ray_tpu._internal import profiler
+
+    log = fresh_log
+    for i in range(profiler._MAX_RECORDS + 10):
+        log.on_duration("/jax/core/compile/backend_compile_duration", 0.5,
+                        f"jit(f{i})")
+    programs = log.programs()
+    assert programs["asked"] == profiler._MAX_RECORDS + 10
+    assert programs["dropped"] == 10
+    assert len(log.records()) == profiler._MAX_RECORDS
+    assert programs["last"]["fun_name"] == \
+        f"jit(f{profiler._MAX_RECORDS + 9})"
+    assert programs["compile_s"] == pytest.approx(
+        0.5 * (profiler._MAX_RECORDS + 10))
+    assert set(programs["by_program"]) == {"unlabelled"}
+    # asked for inside a phase, under no site: the phase's
+    with log.phase("engine_build"):
+        log.on_duration("/jax/core/compile/backend_compile_duration", 0.5,
+                        "jit(zeros)")
+    assert log.programs()["by_program"]["engine_build"]["count"] == 1
+
+
+def test_a_loaded_program_is_a_hit_and_its_load_is_not_counted_twice(
+        fresh_log):
+    """The cache's events carry no name: they belong to the compile
+    stage that ends after them in their thread, under its label."""
+    log = fresh_log
+    log.label("decode_dispatch[8]")
+    try:
+        log.on_duration("/jax/core/compile/jaxpr_trace_duration", 0.25,
+                        "step")
+        # (a lowering that began before a trace did holds that trace,
+        # of a helper of its own: this one, of no length, began after)
+        log.on_duration("/jax/core/compile/jaxpr_to_mlir_module_duration",
+                        0.0, "jit(step)")
+        log.on_event("/jax/compilation_cache/compile_requests_use_cache")
+        log.on_event("/jax/compilation_cache/cache_hits")
+        log.on_duration("/jax/compilation_cache/compile_time_saved_sec", 9.0)
+        log.on_duration("/jax/compilation_cache/cache_retrieval_time_sec",
+                        1.5)
+        log.on_duration("/jax/core/compile/backend_compile_duration", 1.75,
+                        "jit(step)")
+        # compiled, and written to the cache: a miss
+        log.on_duration("/jax/core/compile/jaxpr_to_mlir_module_duration",
+                        0.5, "jit(step)")
+        log.on_event("/jax/compilation_cache/cache_misses")
+        log.on_duration("/jax/core/compile/backend_compile_duration", 12.0,
+                        "jit(step)")
+    finally:
+        log.label(None)
+    got = [(r["stage"], r["fun_name"], r["seconds"], r["cache"])
+           for r in log.records()]
+    assert got == [("trace", "step", 0.25, None),
+                   ("lower", "jit(step)", 0.0, None),
+                   ("cache_load", "jit(step)", 1.5, "hit"),
+                   ("compile", "jit(step)", 0.25, "hit"),
+                   ("lower", "jit(step)", 0.5, None),
+                   ("compile", "jit(step)", 12.0, "miss")]
+    assert {r["program"] for r in log.records()} == {"decode_dispatch[8]"}
+    totals = log.programs()
+    assert (totals["asked"], totals["cache_hits"],
+            totals["cache_misses"]) == (2, 1, 1)
+    assert totals["cache_load_s"] == 1.5 and totals["compile_s"] == 12.25
+    assert log.callbacks == 10
+
+
+def test_only_the_outermost_of_nested_traces_is_recorded(fresh_log,
+                                                        monkeypatch):
+    """jnp's own functions are jitted, so a model step's trace holds
+    thousands of traces that end before it: they are dropped as the
+    enclosing one ends (from the end of the list, not by a scan of it:
+    a scan a trace cost the hybrid cell 2.7 s of warm-up, PR 40), and
+    traces of the lowering's own helpers with the lowering."""
+    from ray_tpu._internal import profiler
+
+    log = fresh_log
+    clock = iter(range(100, 10_000))
+    monkeypatch.setattr(profiler.time, "perf_counter",
+                        lambda: float(next(clock)))
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    for _ in range(50):                       # end at 100..149, 0.5 long
+        log.on_duration(trace, 0.5, "add")
+    assert len(log._thread.traces) == 50
+    log.on_duration(trace, 60.0, "step")      # ends at 150, began at 90
+    assert [r["fun_name"] for r in log._thread.traces] == ["step"]
+    log.on_duration(trace, 0.25, "_threefry_split")   # in the lowering
+    log.on_duration("/jax/core/compile/jaxpr_to_mlir_module_duration",
+                    1.5, "jit(step)")         # ends at 152, began at 150.5
+    assert [(r["stage"], r["fun_name"], r["seconds"])
+            for r in log.records()] == [("trace", "step", 60.0),
+                                        ("lower", "jit(step)", 1.5)]
+    assert log._thread.traces == [] and log.callbacks == 53
+
+
+def test_step_recorder_puts_the_log_on_its_step_records(fresh_log):
+    """The first step record carries `startup` and `programs` whole; a
+    later one what was asked for since, and only if anything was. The
+    compile event is what the stages under the step's label cost."""
+    log = fresh_log
+    rec = telemetry.StepRecorder("run", "exp")
+    out: list = []
+    rec._pub.publish = out.append
+    step = rec.wrap_jit(jax.jit(lambda x: x * 2 + 1), "lora_step")
+    x5, x7 = jnp.ones((5,)), jnp.ones((7,))   # asked for under no site
+
+    step(x5)
+    rec.end_step(1)
+    (event,), (first,) = ([r for r in out if r["kind"] == k]
+                          for k in ("compile", "step"))
+    under = [r for r in log.records() if r["program"] == "lora_step"]
+    assert sorted(r["stage"] for r in under) == ["compile", "lower", "trace"]
+    assert event["event"] == "compile" and event["compile_s"] == \
+        pytest.approx(sum(r["seconds"] for r in under))
+    assert list(first["startup"]["phases"]) == ["ready"]
+    assert first["programs"]["by_program"]["lora_step"]["count"] == 1
+    assert first["programs"]["last"] == under[-1]
+
+    del out[:]
+    step(x5)
+    rec.end_step(2)
+    assert [r["kind"] for r in out] == ["step"]
+    assert "programs" not in out[0] and "startup" not in out[0]
+
+    del out[:]
+    step(x7)
+    rec.end_step(3)
+    event, third = out
+    assert (event["event"], event["prev_shape"]) == (
+        "retrace", "(float32[5])") and "7" in event["shape"]
+    assert "startup" not in third
+    delta = third["programs"]
+    assert delta["asked"] == 1 and delta["last"]["program"] == "lora_step"
+    assert set(delta) == {"asked", "cache_hits", "cache_misses", "trace_s",
+                          "lower_s", "compile_s", "cache_load_s", "last"}
+
+    # the GCS keeps both sections on the step records it serves
+    from ray_tpu.core.gcs_train_manager import GcsTrainManager
+
+    mgr = GcsTrainManager()
+    mgr.ingest([first, third])
+    kept = {s["step"]: s
+            for s in mgr.list_steps(run_id="run", limit=0)["steps"]}
+    assert kept[1]["startup"] == first["startup"]
+    assert kept[1]["programs"] == first["programs"]
+    assert kept[3]["programs"] == delta and "startup" not in kept[3]

@@ -158,6 +158,74 @@ def test_a_chip_worker_starts_once_the_chips_are_free(
         assert len(polls) == polled
 
 
+@pytest.mark.parametrize("busy_polls", [3, 0], ids=["held", "free"])
+def test_the_wait_for_chips_reaches_the_event_and_the_worker(
+        monkeypatch, busy_polls):
+    """What held a chip worker's spawn back is on the `worker_started`
+    event and, through the reply to its registration, in the worker's
+    own account of its start: the time `chips_being_released` named a
+    group (three polls of 0.2 s here), and 0 where it named none."""
+    from ray_tpu._internal.profiler import ProcessLog
+
+    polls = []
+
+    def being_released():
+        polls.append(1)
+        return ["/dev/vfio/0"] if len(polls) <= busy_polls else []
+
+    async def connect(host, port):
+        return object()
+
+    monkeypatch.setattr(accelerators, "chips_being_released", being_released)
+    monkeypatch.setattr(node_manager, "connect", connect)
+    events, unregistered = [], []
+
+    def spawn(tpu):
+        w = node_manager._Worker(node_manager._FakeProc(), tpu=tpu)
+        unregistered.append(w)
+        return w
+
+    nm = types.SimpleNamespace(
+        _try_claim_idle=lambda tpu: None, _unregistered=unregistered,
+        _spawn_worker=spawn, workers={}, _maybe_grant_pending=lambda: None,
+        _emit_event=lambda kind, message, **data: events.append(
+            (kind, data)))
+    info = types.SimpleNamespace(
+        worker_id=bytes(8),
+        address=types.SimpleNamespace(host="127.0.0.1", port=1))
+
+    async def lease():
+        t0 = time.monotonic()
+        getting = asyncio.ensure_future(NodeManager._get_idle_worker(
+            nm, timeout_s=5.0, tpu=True))
+        while not unregistered:      # the wait for the chips is over
+            await asyncio.sleep(0.01)
+        waited = time.monotonic() - t0
+        reply = await NodeManager.rpc_register_worker(
+            nm, None, (info, node_manager._FakeProc.pid))
+        return await getting, reply, waited
+
+    w, reply, waited = asyncio.run(lease())
+    assert w.tpu and w.busy and len(polls) == busy_polls + 1
+    (kind, data), = events
+    assert kind == "worker_started" and data["boot_s"] >= 0
+    assert reply["tpu"] is True
+    assert reply["lease_asked"] <= reply["spawned"] <= time.time()
+    log = ProcessLog()
+    log.spawned(reply)
+    startup = log.startup()
+    assert log.leased_chips
+    assert startup["chip_wait_s"] == data["chip_wait_s"] \
+        == reply["chip_wait_s"]
+    wait, boot = startup["phases"]["spawn_wait"], startup["phases"]["boot"]
+    assert wait[0] <= wait[1] == boot[0] == 0.0 <= boot[1]
+    if busy_polls:
+        assert 0.4 <= data["chip_wait_s"] <= waited
+        assert wait[1] - wait[0] >= data["chip_wait_s"] - 1e-3
+    else:
+        assert data["chip_wait_s"] == 0
+
+
 def test_stop_waits_for_a_worker_it_had_to_kill():
     """A worker that held chips is gone only when the kernel has given
     them back, long after the signal: `stop` returns after that, so the
@@ -253,6 +321,46 @@ def test_leased_actor_is_not_pinned_and_the_next_lease_waits(one_chip_node):
     assert _gone(v["pid"])
     rt.kill(second)
     assert _gone(v2["pid"])
+
+
+def _own_start():
+    from ray_tpu._internal.profiler import process_log
+
+    log = process_log()
+    return {"leased": log.leased_chips, "startup": log.startup(),
+            "jax_imported": "jax" in sys.modules, "pid": os.getpid()}
+
+
+def test_a_worker_knows_how_it_was_started(one_chip_node):
+    """The reply to a worker's registration carries what the node
+    manager knows of its start; the same is on the `worker_started`
+    event. No chips were being released here: the wait is 0. And a
+    worker that merely holds a lease is not touched: only a serve
+    replica and a train worker make the backend's first touch."""
+    leased = rt.remote(_own_start).options(num_tpus=1)
+    plain = rt.remote(_own_start)
+    mine, other = rt.get([leased.remote(), plain.remote()], timeout=60)
+    assert mine["leased"] and not other["leased"]
+    assert not mine["jax_imported"]
+    for own in (mine, other):
+        phases = own["startup"]["phases"]
+        assert list(phases) == ["spawn_wait", "boot"]
+        assert phases["spawn_wait"][0] <= phases["spawn_wait"][1] == 0.0 \
+            == phases["boot"][0] < phases["boot"][1]
+        assert own["startup"]["chip_wait_s"] == 0
+    started = {}
+    end = time.monotonic() + 20.0     # events ride the heartbeat
+    while mine["pid"] not in started and time.monotonic() < end:
+        started = {e["data"]["pid"]: e["data"]
+                   for e in state_api.list_cluster_events(
+                       kind="worker_started", limit=1000)}
+        time.sleep(0.2)
+    event = started[mine["pid"]]
+    assert event["chip_wait_s"] == 0 and event["boot_s"] > 0
+    # the event's boot is the node manager's clock around the same
+    # stretch as the worker's own phase
+    assert event["boot_s"] == pytest.approx(
+        mine["startup"]["phases"]["boot"][1], abs=0.5)
 
 
 def test_leased_worker_without_a_chip_raises(one_chip_node):
