@@ -171,14 +171,20 @@ def _smoke_train_loop(config: dict):
 
 
 def _kernel_parity(b: int, s: int, h: int, hk: int, d: int,
-                   seed: int) -> dict:
-    """Compiled flash forward/backward against xla_attention, bf16."""
+                   seed: int, blocks: tuple | None = None,
+                   flash_attention=None) -> dict:
+    """Compiled flash forward/backward against xla_attention, bf16, in
+    tiles of `blocks` (block_q, block_k) or, None, in those the kernel's
+    caller chooses from the shape, as the train step runs it.
+    `flash_attention`: another tree's op held to the same reference
+    (tools/flash_attention_probe.py)."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops.attention import xla_attention
-    from ray_tpu.ops.pallas.flash_attention import flash_attention
+    from ray_tpu.ops.pallas import flash_attention as fa
 
+    flash_attention = flash_attention or fa.flash_attention
     k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(k1, (b, s, h, d), jnp.bfloat16)
     k = jax.random.normal(k2, (b, s, hk, d), jnp.bfloat16)
@@ -194,8 +200,9 @@ def _kernel_parity(b: int, s: int, h: int, hk: int, d: int,
             loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
         return (out,) + tuple(grads)
 
+    bq, bk = blocks or (None, None)
     flash = run(lambda q, k, v: flash_attention(q, k, v, True, None,
-                                                512, 512))
+                                                bq, bk))
     ref = run(lambda q, k, v: xla_attention(q, k, v, causal=True))
 
     def rel(a, r):
@@ -208,9 +215,10 @@ def _kernel_parity(b: int, s: int, h: int, hk: int, d: int,
     finite = all(bool(jnp.isfinite(a.astype(jnp.float32)).all())
                  for a in flash)
     text = jax.jit(lambda q, k, v: flash_attention(
-        q, k, v, True, None, 512, 512)).lower(q, k, v).compile().as_text()
+        q, k, v, True, None, bq, bk)).lower(q, k, v).compile().as_text()
     return {"shape": {"b": b, "s": s, "heads": h, "kv_heads": hk, "d": d,
                       "dtype": "bfloat16"},
+            "blocks": list(blocks or fa.default_blocks(s, s)),
             "rel_err": errs, "finite": finite,
             "tpu_custom_call": "tpu_custom_call" in text,
             "tolerance": {"fwd": KERNEL_FWD_TOL, "bwd": KERNEL_BWD_TOL},

@@ -113,6 +113,7 @@ class ProcessLog:
             maxlen=_MAX_RECORDS)
         self._totals = no_programs()
         self._by_program: dict = {}
+        self._choices: dict = {}         # {program: {what: choice}}
         # per program asked for: [t, the four stages' seconds so far,
         # asked so far], so that a reader can cut the totals at a time
         self._timeline: collections.deque = collections.deque(maxlen=1024)
@@ -194,6 +195,17 @@ class ProcessLog:
         self._thread.label = program
         return prev
 
+    def chose(self, what: str, **choice):
+        """A choice the code being traced made from its shapes, which
+        fixes what the program will run (a kernel's path, its tile):
+        kept under the label of the site the trace runs under, like its
+        stages, as `by_program[...]["choices"][what]`; the newest of a
+        name stands."""
+        program = (getattr(self._thread, "label", None) or self._open_phase
+                   or UNLABELLED)
+        with self._lock:
+            self._choices.setdefault(program, {})[what] = choice
+
     def on_event(self, event: str):
         self.callbacks += 1
         if event in _CACHE_EVENTS:
@@ -267,7 +279,8 @@ class ProcessLog:
 
     def programs(self) -> dict:
         """The totals, `by_program` (the same per name, `asked` under
-        `count`, and how often each function was asked for under it),
+        `count`, how often each function was asked for under it, and
+        the `choices` its traces reported),
         `last`, the newest record whole, `timeline`: for each of the
         newest 1024 programs asked for, [t, the four stages' seconds up
         to it, `asked` up to it], and `callbacks`: how often jax called
@@ -278,6 +291,9 @@ class ProcessLog:
             for name, tot in self._by_program.items():
                 tot = dict(tot, fun_names=dict(tot["fun_names"]))
                 tot["count"] = tot.pop("asked")
+                if name in self._choices:
+                    tot["choices"] = {w: dict(c) for w, c in
+                                      self._choices[name].items()}
                 by[name] = tot
             return {**self._totals,
                     "dropped": self.appended - len(self._records),

@@ -6,7 +6,8 @@ it); here it is a core op. Two paths:
 * `dot_product_attention(..., impl="xla")` — jnp einsum path, numerically
   exact, runs anywhere (CPU tests, interpret mode).
 * `impl="flash"` — Pallas TPU kernel (ray_tpu/ops/pallas/flash_attention.py),
-  blockwise online-softmax, O(seq) memory, causal-block skipping.
+  blockwise online-softmax, O(seq) memory, only the tiles causality
+  leaves; its tile a function of the shape.
 
 `impl="auto"` picks flash on TPU for long sequences, xla otherwise.
 GQA (n_kv_heads < n_heads) handled in both paths.
@@ -71,7 +72,10 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           segment_ids: jax.Array | None = None,
                           scale: float | None = None,
                           impl: str = "auto",
-                          block_q: int = 512, block_k: int = 512) -> jax.Array:
+                          block_q: int | None = None,
+                          block_k: int | None = None) -> jax.Array:
+    """`block_q` / `block_k`: the flash kernel's tile; left None it is
+    `flash_attention.default_blocks` of the lengths."""
     if impl == "auto":
         impl = ("flash" if _on_tpu() and q.shape[1] >= 1024
                 and segment_ids is None else "xla")

@@ -36,42 +36,116 @@ def test_flash_fwd_parity_gqa():
     np.testing.assert_allclose(out_flash, out_ref, atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_bwd_parity(causal):
-    q, k, v = _make_qkv(1, 256, 2, 2, 64, seed=2)
-
-    def loss_flash(q, k, v):
-        out = flash_attention(q, k, v, causal, None, 128, 128)
+def _grads(attn, q, k, v):
+    def loss(q, k, v):
+        out = attn(q, k, v)
         return (out * jnp.cos(out)).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    def loss_ref(q, k, v):
-        out = xla_attention(q, k, v, causal=causal)
-        return (out * jnp.cos(out)).sum()
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+# (heads, kv_heads, seq, block_q, block_k, causal, path, strip): every
+# path `_flash_backward` keeps. Three tiles a side, so tiles below the
+# diagonal, on it and above it (no grid step) all occur; `strip` cuts
+# the square tiles on the diagonal as 256 cuts the chip's; "split" is
+# what a dq scratch that does not fit falls to (forced here by a budget
+# of nothing: the shapes the repo runs all fit).
+_BWD_CASES = {
+    "mha": (2, 2, 256, 128, 128, True, "fused", None),
+    "mha-full": (2, 2, 256, 128, 128, False, "fused", None),
+    "gqa2": (4, 2, 256, 128, 128, True, "fused", None),
+    "gqa2-three-tiles": (4, 2, 384, 128, 128, True, "fused", None),
+    "gqa4-three-tiles": (8, 2, 384, 128, 128, True, "fused", None),
+    "gqa4-full": (8, 2, 384, 128, 128, False, "fused", None),
+    "gqa2-strips": (4, 2, 384, 128, 128, True, "fused", 32),
+    "gqa2-wide-k": (4, 2, 384, 64, 128, True, "fused", None),
+    "gqa4-wide-q": (8, 2, 384, 128, 64, True, "fused", None),
+    "gqa2-split": (4, 2, 384, 128, 128, True, "split", None),
+    "gqa4-split-strips": (8, 2, 384, 128, 128, True, "split", 64),
+    "gqa2-split-full": (4, 2, 256, 64, 128, False, "split", None),
+}
+
+
+@pytest.mark.parametrize("case", _BWD_CASES)
+def test_flash_bwd_parity(monkeypatch, case):
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    h, hk, s, bq, bk, causal, path, strip = _BWD_CASES[case]
+    if path == "split":
+        monkeypatch.setattr(fa, "_DQ_VMEM_BYTES", 0)
+    if strip:
+        monkeypatch.setattr(fa, "_DIAG_SUB", strip)
+    q, k, v = _make_qkv(1, s, h, hk, 64, seed=2)
+    # the path is a function of the shape, and says which it is
+    assert fa.backward_path(s, 64, h // hk, q.dtype) == path
+    assert (fa._strips(s, s, bq, bk) == strip) if causal else True
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal, None, bq, bk)
+
+    calls = str(jax.make_jaxpr(lambda *a: _grads(flash, *a))(q, k, v)
+                ).count("pallas_call")
+    assert calls == (2 if path == "fused" else 3)     # forward + backward
+    ref = lambda q, k, v: xla_attention(q, k, v, causal=causal)
+    np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=2e-5,
+                               rtol=2e-5)
+    gf, gr = _grads(flash, q, k, v), _grads(ref, q, k, v)
     for a, b, name in zip(gf, gr, "qkv"):
+        assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4,
                                    err_msg=f"d{name} mismatch")
 
 
-def test_flash_bwd_parity_gqa():
-    q, k, v = _make_qkv(1, 256, 4, 2, 64, seed=3)
+def test_flash_cross_attention_with_more_keys_than_queries():
+    """sq != sk, not causal: every tile of the rectangle is live, in the
+    forward's order and in the backward's."""
+    q, _, _ = _make_qkv(1, 128, 4, 2, 64, seed=7)
+    _, k, v = _make_qkv(1, 384, 4, 2, 64, seed=8)
 
-    def loss(attn):
-        def f(q, k, v):
-            out = attn(q, k, v)
-            return (out ** 2).sum()
-        return f
+    def flash(q, k, v):
+        return flash_attention(q, k, v, False, None, 64, 128)
 
-    flash = loss(lambda q, k, v: flash_attention(q, k, v, True, None,
-                                                 128, 128))
-    ref = loss(lambda q, k, v: xla_attention(q, k, v, causal=True))
-    gf = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b, name in zip(gf, gr, "qkv"):
-        np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4,
-                                   err_msg=f"d{name} mismatch")
+    ref = lambda q, k, v: xla_attention(q, k, v, causal=False)
+    np.testing.assert_allclose(flash(q, k, v), ref(q, k, v), atol=2e-5,
+                               rtol=2e-5)
+    for a, b in zip(_grads(flash, q, k, v), _grads(ref, q, k, v)):
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
+
+def test_live_tiles_are_the_ones_causality_leaves():
+    """The grid's tables at the train cells' shape, 2,048 in tiles of
+    512: 10 of 16 tiles take a step, 4 of them masked; the backward
+    walks them k tile by k tile over the group's heads and marks each q
+    tile's last k tile, where its dq is written."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    def tables(*a, **kw):
+        cols, kinds = fa._live_tiles(*a, **kw)
+        return [np.asarray(c) for c in cols] + [kinds]
+
+    qi, ki, flags, kinds = tables(4, 4, 512, 512, True, k_major=False)
+    assert kinds == (False, True)
+    assert list(zip(qi, ki)) == [(q, k) for q in range(4)
+                                 for k in range(q + 1)]
+    assert [bool(f & fa._MASKED) for f in flags] == [
+        q == k for q in range(4) for k in range(q + 1)]
+    ki, gi, qi, flags, _ = tables(4, 4, 512, 512, True, k_major=True,
+                                  group=2)
+    assert list(zip(ki, gi, qi)) == [(k, g, q) for k in range(4)
+                                     for g in range(2) for q in range(k, 4)]
+    assert [bool(f & fa._DQ_DONE) for f in flags] == [
+        q == k for k in range(4) for g in range(2) for q in range(k, 4)]
+    assert sum(bool(f & fa._FIRST) for f in flags) == 4
+    assert sum(bool(f & fa._LAST) for f in flags) == 4
+    # rectangular: a tile is masked where the diagonal crosses it
+    qi, ki, flags, _ = tables(4, 2, 256, 512, True, k_major=False)
+    assert [(q, k, bool(f & fa._MASKED)) for q, k, f in zip(
+        qi, ki, flags)] == [(0, 0, True), (1, 0, True), (2, 0, False),
+                            (2, 1, True), (3, 0, False), (3, 1, True)]
+    # not causal: the whole rectangle, nothing masked
+    *_, flags, kinds = tables(4, 2, 256, 512, False, k_major=True)
+    assert len(flags) == 8 and kinds == (False,)
+    # one tile a head, the cells' choice: only the masked body is built
+    assert tables(1, 1, 2048, 2048, True, k_major=False)[-1] == (True,)
 
 
 @pytest.mark.parametrize("bq,bk", [(64, 128), (128, 64), (32, 256)])

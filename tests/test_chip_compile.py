@@ -75,41 +75,55 @@ def _flash_fwd(q, k, v, o, lse, do, bq, bk):
                              block_q=bq, block_k=bk)
 
 
-def _flash_bwd_dq(q, k, v, o, lse, do, bq, bk):
+def _flash_bwd(q, k, v, o, lse, do, bq, bk):
     return fa._flash_backward(q, k, v, o, lse, do, causal=True, scale=None,
-                              block_q=bq, block_k=bk)[0]
+                              block_q=bq, block_k=bk)
 
 
-def _flash_bwd_dkv(q, k, v, o, lse, do, bq, bk):
-    return fa._flash_backward(q, k, v, o, lse, do, causal=True, scale=None,
-                              block_q=bq, block_k=bk)[1:]
+# the two train cells' attention a chip: InternLM2-1.8B on one, and a
+# tensor shard of Mistral-7B on four (n_rep 4)
+SHAPES["lora-ft"] = SHAPES["1b"]
+SHAPES["lora-ft-4chip"] = (4, 2048, 16, 4, 128)
 
 
-@pytest.mark.parametrize("kernel", [_flash_fwd, _flash_bwd_dq,
-                                    _flash_bwd_dkv],
-                         ids=["fwd", "bwd_dq", "bwd_dkv"])
-@pytest.mark.parametrize("preset,block_q,block_k", [
-    ("1b", 512, 512),
-    ("1b", 256, 1024),   # rectangular: the tile-retune axis
-    ("410m", 512, 512),
-])
+@pytest.mark.parametrize("kernel,preset,block_q,block_k,budget", [
+    (_flash_fwd, "lora-ft", None, None, None),     # the caller's tile
+    (_flash_fwd, "1b", 512, 512, None),
+    (_flash_fwd, "1b", 256, 1024, None),   # rectangular: no strips
+    (_flash_fwd, "410m", 512, 512, None),
+    (_flash_bwd, "lora-ft", None, None, None),
+    (_flash_bwd, "lora-ft-4chip", None, None, None),
+    (_flash_bwd, "1b", 512, 512, None),
+    (_flash_bwd, "1b", 256, 1024, None),
+    (_flash_bwd, "410m", 512, 512, None),
+    (_flash_bwd, "lora-ft-4chip", 1024, 1024, None),
+    (_flash_bwd, "lora-ft", None, None, 0),        # dq does not fit: split
+], ids=lambda v: getattr(v, "__name__", None) or str(v))
 def test_flash_kernel_compiles_for_the_chip(chip, compiled_not_interpreted,
-                                            kernel, preset, block_q,
-                                            block_k):
+                                            monkeypatch, kernel, preset,
+                                            block_q, block_k, budget):
+    """One Mosaic call forward, one backward where dq's scratch fits
+    (fused: every shape here) and two where it does not, and no
+    gradient per query head in float32 left for XLA to sum."""
     b, s, h, hk, d = SHAPES[preset]
     on = SingleDeviceSharding(chip)
+    if budget is not None:
+        monkeypatch.setattr(fa, "_DQ_VMEM_BYTES", budget)
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
 
     q, o, do = (arg((b, s, h, d)) for _ in range(3))
     k, v = (arg((b, s, hk, d)) for _ in range(2))
-    lse = arg((b, h, s, 1), jnp.float32)
-    compiled = jax.jit(
+    lse = arg((b, h, s), jnp.float32)
+    text = jax.jit(
         lambda *a: kernel(*a, block_q, block_k)).lower(
-            q, k, v, o, lse, do).compile()
-    # one Mosaic kernel each: the unused half of the backward is dropped
-    assert compiled.as_text().count("tpu_custom_call") == 1
+            q, k, v, o, lse, do).compile().as_text()
+    fused = fa.backward_path(s, d, h // hk, jnp.bfloat16) == "fused"
+    assert fused == (budget is None)
+    assert text.count("tpu_custom_call") == (
+        1 if kernel is _flash_fwd or fused else 2)
+    assert f"f32[{b},{h},{s},{d}]" not in text
 
 
 @pytest.mark.parametrize("layers,batch,kv_heads,group,head_dim", [

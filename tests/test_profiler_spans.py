@@ -245,8 +245,11 @@ def test_engine_step_names_its_phase_and_parts(phase, tokens):
 
 def test_lora_train_step_names_loss_optimizer_and_kernels():
     """(b) the LoRA step with the flash kernels (interpret mode here) and
-    full remat: loss, optimizer, the parts, the three kernels, the
-    adapters' branch; jax itself marks the recomputed forward."""
+    full remat: loss, optimizer, the parts, the kernels, the adapters'
+    branch; jax itself marks the recomputed forward. The backward is one
+    kernel under `flash_bwd_dkv` where the shape lets it be fused (every
+    shape the repo runs), and `flash_bwd_dq` then names nothing."""
+    from ray_tpu.ops.pallas import flash_attention as fa
     from ray_tpu.parallel.mesh import build_mesh
     from ray_tpu.train.recipes import build_lora_step
 
@@ -260,9 +263,13 @@ def test_lora_train_step_names_loss_optimizer_and_kernels():
              "targets": jnp.zeros((2, 128), jnp.int32)}
     paths = _scope_paths(step.lower(state, batch))
     for name in ("loss", "optimizer", "embed", "ce", "attn_qkv", "attn",
-                 "attn_out", "mlp", "lora", "flash_fwd", "flash_bwd_dq",
-                 "flash_bwd_dkv", trace_spans.RECOMPUTE):
+                 "attn_out", "mlp", "lora", "flash_fwd", "flash_bwd_dkv",
+                 trace_spans.RECOMPUTE):
         assert any(name in p.split("/") for p in paths), name
+    cfg = llama.config_for("debug", max_seq_len=128)
+    fused = fa.backward_path(128, cfg.head_dim, cfg.n_heads
+                             // cfg.n_kv_heads, cfg.dtype) == "fused"
+    assert fused != any("flash_bwd_dq" in p.split("/") for p in paths)
     assert trace_spans.scope_of(
         "jit(one_step)/loss/transpose(jvp())/while/body/closed_call/"
         "checkpoint/rematted_computation/attn_qkv/lora/dot_general") == \
@@ -749,6 +756,7 @@ def test_step_recorder_puts_the_log_on_its_step_records(fresh_log):
         pytest.approx(sum(r["seconds"] for r in under))
     assert list(first["startup"]["phases"]) == ["ready"]
     assert first["programs"]["by_program"]["lora_step"]["count"] == 1
+    assert "choices" not in first["programs"]["by_program"]["lora_step"]
     assert first["programs"]["last"] == under[-1]
 
     del out[:]
@@ -766,6 +774,7 @@ def test_step_recorder_puts_the_log_on_its_step_records(fresh_log):
     assert "startup" not in third
     delta = third["programs"]
     assert delta["asked"] == 1 and delta["last"]["program"] == "lora_step"
+
     assert set(delta) == {"asked", "cache_hits", "cache_misses", "trace_s",
                           "lower_s", "compile_s", "cache_load_s", "last"}
 
@@ -779,3 +788,31 @@ def test_step_recorder_puts_the_log_on_its_step_records(fresh_log):
     assert kept[1]["startup"] == first["startup"]
     assert kept[1]["programs"] == first["programs"]
     assert kept[3]["programs"] == delta and "startup" not in kept[3]
+
+
+@pytest.mark.parametrize("path", ["fused", "split"])
+def test_first_step_record_says_which_backward_the_flash_kernel_took(
+        fresh_log, monkeypatch, path):
+    """The backward's path and tile are fixed from the shapes as the
+    step is traced; the process's log keeps them under the site the
+    trace ran under, so a train worker's first step record names them
+    beside `lora_step`."""
+    from ray_tpu.ops.pallas import flash_attention as fa
+
+    if path == "split":     # what a dq scratch that does not fit takes
+        monkeypatch.setattr(fa, "_DQ_VMEM_BYTES", 0)
+    rec = telemetry.StepRecorder("run", "exp")
+    out: list = []
+    rec._pub.publish = out.append
+    step = rec.wrap_jit(jax.jit(jax.grad(
+        lambda q, k, v: fa.flash_attention(q, k, v, True, None, 128,
+                                           None).sum(),
+        argnums=(0, 1, 2))), "lora_step")
+    q, kv = jnp.ones((1, 256, 4, 64)), jnp.ones((1, 256, 2, 64))
+    step(q, kv, kv)
+    rec.end_step(1)
+    (first,) = (r for r in out if r["kind"] == "step")
+    assert first["programs"]["by_program"]["lora_step"]["choices"] == {
+        "flash_backward": {"path": path, "block_q": 128,
+                           "block_k": fa.default_blocks(256, 256)[1],
+                           "n_rep": 2, "seq": 256, "head_dim": 64}}
