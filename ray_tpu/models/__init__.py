@@ -21,14 +21,16 @@ def module_for(cfg):
     all the rows it has),
     prefill_counters (what a prefill call's attention visits and sees),
     CACHE_KIND (the kind `stats()["cache_bytes"]` files a leaf under,
-    beside `kv` and `state`) and STEP_AUX (counters the step decides on
+    beside `kv` and `state`; a function of the config and the leaf where
+    two kinds share one leaf's position axis) and STEP_AUX (counters the step decides on
     the device and returns in cache["aux"])."""
-    from ray_tpu.models import dots3_note, granite_hybrid
+    from ray_tpu.models import dots3_note, evabyte, granite_hybrid
 
     for module, config_type in ((llama, llama.LlamaConfig),
                                 (granite_hybrid,
                                  granite_hybrid.GraniteHybridConfig),
-                                (dots3_note, dots3_note.Dots3NoteConfig)):
+                                (dots3_note, dots3_note.Dots3NoteConfig),
+                                (evabyte, evabyte.EvaByteConfig)):
         if isinstance(cfg, config_type):
             return module
     raise TypeError(f"no model module serves a {type(cfg).__name__}")

@@ -1,0 +1,590 @@
+"""EvaByte: a byte-level decoder whose attention keeps exact keys and
+values for the current WINDOW of `window_size` positions only, and for
+everything before it one summary key and one summary value for each
+CHUNK of `chunk_size` positions (EVA: Zheng et al., "Efficient Attention
+via Control Variates", ICLR 2023; the EvaByte release, HKU NLP and
+SambaNova, 2025-01). The block is llama's (RMSNorm, RoPE on rotated
+halves, SwiGLU) with MHA, the norm's weight held as an offset from one,
+a float32 residual stream, and `n_pred_heads` output heads of which head
+i is the distribution of byte t + 1 + i; the served stream samples head 0.
+
+Per head h the layer adds two learned vectors phi_h, mu_h. With t a
+request's own position from 0, w(t) = t // window_size, chunk j the
+positions [c j, c j + c):
+
+  a_{j,s} = softmax over s in chunk j of (phi_h . k_s - |k_s|^2 / 2)
+  v~_j    = sum_s a_{j,s} v_s          k~_j = (1/c) sum_s k_s + mu_h
+  query t attends exactly to E_t = {s : w(s) = w(t), s <= t} and through
+  (k~_j, v~_j) to C_t = {j : chunk j lies in a window before w(t)}: one
+  float32 softmax over both.
+
+The cache, a row and layer, is ONE position axis of `window_size +
+summaries(max_len)` in each of K ``[layers, b, heads, hd, n]`` and V
+``[layers, b, heads, n, hd]`` (llama's orders, so that the decode kernel
+the models share reads it as it lies):
+
+  index W - 1 - (t mod W)   the exact key of position t (the window's
+                            rows fill DOWNWARD from W - 1)
+  index W + j               the summary of chunk j (UPWARD from W)
+
+so what query t attends to is one contiguous range, [W - 1 - t mod W,
+W + (W / c) w(t) - 1]: `ops/pallas/decode_attention.py` takes it as a
+row's [start, length]. No row moves at a window's end: the range's lower
+end jumps back to W - 1 and its upper end rises by W / c, a comparison.
+A cache made for a shallower `max_len` has fewer summaries and the same
+origin, so the engine's `insert_row` grafts it as it lies. Nothing of it
+is as deep as the context: `CACHE_LEN_AXIS` is empty, every leaf is
+grafted whole, and the engine keeps no prefix store for this model.
+
+A decode step writes k_t, v_t, recomputes the summary of the chunk t
+lies in from that chunk's rows in the window (an overwrite with no
+branch; final when the chunk ends, and visible only once the window
+has), and attends. A prefill chunk reads the window's rows as it found
+them, attends its queries to those of their own window, to the chunk's
+own keys of their own window and to the summaries of earlier windows
+INCLUDING one that ends inside the chunk, and then lays its rows over
+the window's. Positions before cache["start"] are left padding: no bytes,
+in no window, chunk or summary.
+
+Scopes beside llama's `attn_qkv`, `attn_out`, `mlp`, `embed`, `lm_head`:
+`eva_window_attn` (scores and values over E, the window's rows written;
+a decode step's one kernel over the whole range; a chunk's one write of
+its layer, summaries and all), `eva_chunk_attn` (a chunk's scores and
+values over C and the merge of the two parts), `eva_summarise` (k~, v~
+computed; a decode step's written).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.ops.attention import decode_attention, decode_block_len
+from ray_tpu.ops.rope import apply_rope, rope_frequencies
+
+NEG = -1e30
+TENSOR_PARALLEL = False
+# std of phi and mu, float32: they move a summary and never dominate it
+EVA_VECTOR_STD = 0.02
+
+
+@dataclasses.dataclass(frozen=True)
+class EvaByteConfig:
+    vocab_size: int = 320
+    dim: int = 4096
+    n_layers: int = 32
+    n_heads: int = 32
+    hidden_dim: int = 11008
+    max_seq_len: int = 32768
+    window_size: int = 2048
+    chunk_size: int = 16
+    n_pred_heads: int = 8
+    rope_theta: float = 100000.0
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        if self.dim % self.n_heads or self.window_size % self.chunk_size:
+            raise ValueError("heads must divide dim and chunk_size "
+                             "window_size")
+
+    @property
+    def head_dim(self) -> int:
+        return self.dim // self.n_heads
+
+    @property
+    def chunks_per_window(self) -> int:
+        return self.window_size // self.chunk_size
+
+    def summaries(self, max_len: int | None = None) -> int:
+        """Summary rows of a cache for `max_len` positions: whole
+        windows' worth, so that a chunk's index never leaves the leaf."""
+        return (-(-(max_len or self.max_seq_len) // self.window_size)
+                * self.chunks_per_window)
+
+
+def from_published(config: dict, **overrides) -> EvaByteConfig:
+    """The program's config from the published keys."""
+    if config.get("attention_class", "eva") != "eva" or (
+            config["num_key_value_heads"] != config["num_attention_heads"]):
+        raise ValueError("EVA attention over one key head a query head")
+    kw = dict(vocab_size=int(config["vocab_size"]),
+              dim=int(config["hidden_size"]),
+              n_layers=int(config["num_hidden_layers"]),
+              n_heads=int(config["num_attention_heads"]),
+              hidden_dim=int(config["intermediate_size"]),
+              max_seq_len=int(config["max_position_embeddings"]),
+              window_size=int(config["window_size"]),
+              chunk_size=int(config["chunk_size"]),
+              n_pred_heads=int(config["num_pred_heads"]),
+              rope_theta=float(config["rope_theta"]),
+              norm_eps=float(config["rms_norm_eps"]))
+    kw.update(overrides)
+    return EvaByteConfig(**kw)
+
+
+# ------------------------------------------------------------------- params
+def init_params(cfg: EvaByteConfig, key: jax.Array) -> dict:
+    """Every matrix N(0, 1/fan_in) in the parameter dtype, the norms'
+    offsets 0, phi and mu N(0, EVA_VECTOR_STD^2) in float32; per-layer
+    weights stacked on a leading [n_layers] axis."""
+    pd = cfg.param_dtype
+    d, f, L, H, hd = (cfg.dim, cfg.hidden_dim, cfg.n_layers, cfg.n_heads,
+                      cfg.head_dim)
+    k = iter(jax.random.split(key, 11))
+
+    def dense(shape, fan_in):
+        return (jax.random.normal(next(k), shape, jnp.float32)
+                / math.sqrt(fan_in)).astype(pd)
+
+    def vector():
+        return jax.random.normal(next(k), (L, H, hd),
+                                 jnp.float32) * EVA_VECTOR_STD
+
+    return {
+        "embed": dense((cfg.vocab_size, d), d),
+        "layers": {
+            "wq": dense((L, d, d), d), "wk": dense((L, d, d), d),
+            "wv": dense((L, d, d), d), "wo": dense((L, d, d), d),
+            "phi": vector(), "mu": vector(),
+            "w_gate": dense((L, d, f), d), "w_up": dense((L, d, f), d),
+            "w_down": dense((L, f, d), f),
+            "attn_norm": jnp.zeros((L, d), pd),
+            "mlp_norm": jnp.zeros((L, d), pd)},
+        "final_norm": jnp.zeros((d,), pd),
+        "lm_head": dense((d, cfg.n_pred_heads * cfg.vocab_size), d),
+    }
+
+
+def param_logical_axes(cfg: EvaByteConfig) -> dict:
+    return {"embed": ("vocab", "embed"),
+            "layers": {"wq": ("layers", "embed", "heads"),
+                       "wk": ("layers", "embed", "kv_heads"),
+                       "wv": ("layers", "embed", "kv_heads"),
+                       "wo": ("layers", "heads", "embed"),
+                       "phi": ("layers", None, None),
+                       "mu": ("layers", None, None),
+                       "w_gate": ("layers", "embed", "mlp"),
+                       "w_up": ("layers", "embed", "mlp"),
+                       "w_down": ("layers", "mlp", "embed"),
+                       "attn_norm": ("layers", None),
+                       "mlp_norm": ("layers", None)},
+            "final_norm": (None,), "lm_head": ("embed", "vocab")}
+
+
+# -------------------------------------------------------------------- cache
+# No leaf is as deep as the context: both are grafted whole.
+CACHE_LEN_AXIS: dict = {}
+
+
+def _kind_bytes(cfg: EvaByteConfig, leaf) -> dict:
+    """A leaf's bytes by kind: the window's rows and the summaries share
+    its position axis."""
+    n = leaf.shape[-1] * leaf.shape[-2] // cfg.head_dim
+    per = leaf.size * leaf.dtype.itemsize // n
+    return {"window": per * cfg.window_size,
+            "summary": per * (n - cfg.window_size)}
+
+
+# what `LLMEngine.stats()["cache_bytes"]` files each leaf under
+CACHE_KIND = {"k": _kind_bytes, "v": _kind_bytes}
+
+
+def init_cache(cfg: EvaByteConfig, batch: int,
+               max_len: int | None = None) -> dict:
+    """An empty cache (the module's docstring has the layout): its depth
+    follows `max_len` only by the summaries, one a chunk."""
+    n = cfg.window_size + cfg.summaries(max_len)
+    lead = (cfg.n_layers, batch, cfg.n_heads)
+    return {"k": jnp.zeros(lead + (cfg.head_dim, n), cfg.dtype),
+            "v": jnp.zeros(lead + (n, cfg.head_dim), cfg.dtype),
+            "length": jnp.zeros((), jnp.int32),
+            "start": jnp.zeros((batch,), jnp.int32)}
+
+
+def cache_logical_axes(cfg: EvaByteConfig) -> dict:
+    return {"k": ("layers", "batch", "kv_heads", "head_dim", None),
+            "v": ("layers", "batch", "kv_heads", None, "head_dim"),
+            "length": (), "start": ("batch",)}
+
+
+def _read_block(cfg: EvaByteConfig) -> int | None:
+    return decode_block_len(
+        cfg.n_heads, cfg.head_dim, cfg.window_size + cfg.summaries(),
+        cfg.dtype, jax.sharding.get_abstract_mesh())
+
+
+def decode_read_block(cfg: EvaByteConfig, mesh) -> int | None:
+    """None: the engine counts `decode_kv_positions_read` in positions of
+    the context, which this cache does not have; what a step reads of
+    the window's rows and of the summaries is `decode_counters`'."""
+    return None
+
+
+def decode_counters(cfg: EvaByteConfig, spans: list, rows: int) -> dict:
+    """What one decode step of `rows` rows does for live rows at `spans`
+    [(start, the position the step writes)], summed over the layers:
+    `_live`, the window's rows and the summaries its queries attend to;
+    `_read`, those the step is asked to read: the kernel's blocks that
+    overlap a row's range, or both parts whole for every row where
+    nothing bounds the read. `windows_folded`: rows whose query is the
+    first of a window, so that the window before it has just become its
+    summaries."""
+    W, cpw, L = cfg.window_size, cfg.chunks_per_window, cfg.n_layers
+    t = np.asarray([last - start for start, last in spans], np.int64)
+    win_live, sum_live = t % W + 1, cpw * (t // W)
+    block = _read_block(cfg)
+    if block:
+        win_read = W - (W - 1 - t % W) // block * block
+        sum_read = -(-sum_live // block) * block
+    else:
+        win_read, sum_read = rows * W, rows * cfg.summaries()
+    return {"decode_window_positions_live": L * int(win_live.sum()),
+            "decode_summaries_live": L * int(sum_live.sum()),
+            "decode_window_positions_read": L * int(np.sum(win_read)),
+            "decode_summaries_read": L * int(np.sum(sum_read)),
+            "windows_folded": int(((t > 0) & (t % W == 0)).sum())}
+
+
+def prefill_counters(cfg: EvaByteConfig, start: int, pos: int, chunk: int,
+                     depth: int) -> dict:
+    """What the attention of one prefill call does: `chunk` queries at
+    positions [pos, pos + chunk) of a row whose first byte lies at
+    `start`, in a cache made for `depth` positions. Pairs of query and
+    key summed over the layers: `_visible`, those a query attends to (its
+    window's rows up to itself, the summaries of the windows before),
+    and `_visited`, those whose scores are computed: the window as the
+    call found it and the call's own keys, and every summary row, for
+    every query."""
+    W, cpw, L = cfg.window_size, cfg.chunks_per_window, cfg.n_layers
+    t = np.arange(max(pos, start), pos + chunk, dtype=np.int64) - start
+    return {
+        "prefill_window_keys_visible": L * int((t % W + 1).sum()),
+        "prefill_window_keys_visited": L * chunk * (W + chunk),
+        "prefill_summaries_visible": L * int((cpw * (t // W)).sum()),
+        "prefill_summaries_visited": L * chunk * cfg.summaries(depth),
+        "windows_folded": int(((t > 0) & (t % W == 0)).sum())}
+
+
+# --------------------------------------------------------------------- step
+def _norm(x, g, eps: float, dt):
+    """RMSNorm of the float32 stream, the weight an offset from one."""
+    y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+    return (y * (1.0 + g.astype(jnp.float32))).astype(dt)
+
+
+def _summarise(layer, k, v, ok, c: int):
+    """k, v [b, g, c, H, hd], g chunks of c positions of which `ok` [b,
+    g, c] are bytes the chunk has -> (k~, v~) each [b, g, H, hd], in the
+    cache's dtype. float32 from the keys as the cache holds them."""
+    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+    has = ok[..., None]
+    score = (jnp.einsum("hd,bgchd->bgch", layer["phi"], k32)
+             - 0.5 * (k32 * k32).sum(-1))
+    score = jnp.where(has, score, NEG)
+    e = jnp.exp(score - score.max(2, keepdims=True)) * has
+    a = e / jnp.maximum(e.sum(2, keepdims=True), 1e-30)
+    v_sum = jnp.einsum("bgch,bgchd->bghd", a, v32)
+    k_sum = (k32 * has[..., None]).sum(2) / c + layer["mu"]
+    return k_sum.astype(k.dtype), v_sum.astype(v.dtype)
+
+
+def _decode_attend(cfg, layer, li, q, kk, vv, kc, vc, t, live):
+    """One new position a row against layer `li`: q, kk, vv [b, 1, H,
+    hd], `t` [b] each row's own position, `live` [b] whether the row
+    holds a request. Returns (attn [b, 1, H * hd], kc, vc)."""
+    b, _, H, hd = q.shape
+    W, c, cpw = cfg.window_size, cfg.chunk_size, cfg.chunks_per_window
+    t = jnp.maximum(t, 0)
+    ring = W - 1 - t % W
+    with jax.named_scope("eva_window_attn"):
+        # one small write in place a row, the row cut out before it is
+        # transposed: ops/attention.cached_attention says why
+        for r in range(b):
+            kc = jax.lax.dynamic_update_slice(
+                kc, kk[r:r + 1].transpose(0, 2, 3, 1)[None],
+                (li, r, 0, 0, ring[r]))
+            vc = jax.lax.dynamic_update_slice(
+                vc, vv[r:r + 1].transpose(0, 2, 1, 3)[None],
+                (li, r, 0, ring[r], 0))
+    with jax.named_scope("eva_summarise"):
+        # the chunk t lies in: c rows of the window, the latest first
+        j = t // c
+        base = W - c - (j % cpw) * c
+        kch = jnp.stack([jax.lax.dynamic_slice(
+            kc, (li, r, 0, 0, base[r]), (1, 1, H, hd, c))[0, 0]
+            for r in range(b)])                          # [b, H, hd, c]
+        vch = jnp.stack([jax.lax.dynamic_slice(
+            vc, (li, r, 0, base[r], 0), (1, 1, H, c, hd))[0, 0]
+            for r in range(b)])                          # [b, H, c, hd]
+        ok = jnp.arange(c)[None, :] >= (c - 1 - t % c)[:, None]
+        k_sum, v_sum = _summarise(
+            layer, kch.transpose(0, 3, 1, 2)[:, None],
+            vch.transpose(0, 2, 1, 3)[:, None], ok[:, None], c)
+        for r in range(b):
+            kc = jax.lax.dynamic_update_slice(
+                kc, k_sum[r:r + 1].transpose(0, 2, 3, 1)[None],
+                (li, r, 0, 0, W + j[r]))
+            vc = jax.lax.dynamic_update_slice(
+                vc, v_sum[r:r + 1].transpose(0, 2, 1, 3)[None],
+                (li, r, 0, W + j[r], 0))
+    with jax.named_scope("eva_window_attn"):
+        # the window's rows and the summaries before it: one range; a
+        # row that holds no request gets an empty one
+        lo = jnp.where(live, ring, 1)
+        hi = jnp.where(live, W + cpw * (t // W) - 1, 0)
+        n = vc.shape[3]
+        block = decode_block_len(H, hd, n, vc.dtype,
+                                 jax.sharding.get_abstract_mesh())
+        if block is not None:
+            attn = decode_attention(q.reshape(b, H, 1, hd), kc, vc, li,
+                                    lo, hi, scale=hd ** -0.5,
+                                    block_len=block)
+            return attn.reshape(b, 1, H * hd), kc, vc
+        k_l = jax.lax.dynamic_index_in_dim(kc, li, 0, keepdims=False)
+        v_l = jax.lax.dynamic_index_in_dim(vc, li, 0, keepdims=False)
+        z = jnp.einsum("bhd,bhdn->bhn", q[:, 0], k_l,
+                       preferred_element_type=jnp.float32) * hd ** -0.5
+        idx = jnp.arange(n)[None, :]
+        seen = (idx >= lo[:, None]) & (idx <= hi[:, None])
+        p = jax.nn.softmax(jnp.where(seen[:, None], z, NEG), axis=-1)
+        attn = jnp.einsum("bhn,bhnd->bhd", p.astype(v_l.dtype), v_l)
+    return attn.reshape(b, 1, H * hd), kc, vc
+
+
+def _part(z, ok, v_of):
+    """One part of the softmax: scores z [b, H, q, k] of which `ok` [b,
+    1, q, k] count -> (running max, sum, values [b, q, H, hd]) for the
+    merge; `v_of(p)` multiplies the weights into the part's values."""
+    z = jnp.where(ok, z, NEG)
+    m = z.max(-1)
+    p = jnp.exp(z - m[..., None]) * ok
+    return m, p.sum(-1), v_of(p)
+
+
+def _rows_at(a, first, n: int):
+    """a [b, len, ...] -> rows [first[r], first[r] + n) of each batch
+    row."""
+    return jax.vmap(lambda x, i: jax.lax.dynamic_slice_in_dim(x, i, n, 0))(
+        a, first)
+
+
+def _laid_at(new, first, n: int):
+    """new [b, g, ...] laid at rows [first[r], first[r] + g) of zeros
+    [b, n, ...] (first + g may pass n: the rest is dropped)."""
+    g = new.shape[1]
+    buf = jnp.zeros((new.shape[0], n + g) + new.shape[2:], new.dtype)
+    return jax.vmap(lambda x, y, i: jax.lax.dynamic_update_slice_in_dim(
+        x, y, i, 0))(buf, new, first)[:, :n]
+
+
+def _chunk_attend(cfg, layer, li, q, kk, vv, kc, vc, t):
+    """`s` new positions of a batch in lock-step against layer `li`: q,
+    kk, vv [b, s, H, hd], `t` [b, s] each position's own place in its
+    request (negative: left padding, no byte). Returns (attn [b, s,
+    H * hd], kc, vc). The layer is read once, before anything of it is
+    written, and written once, so that the stack is updated where it
+    lies."""
+    b, s, H, hd = q.shape
+    W, c, cpw = cfg.window_size, cfg.chunk_size, cfg.chunks_per_window
+    n = kc.shape[4]
+    S = n - W
+    dt, scale = kc.dtype, hd ** -0.5
+    real = t >= 0
+    win = jnp.maximum(t, 0) // W                       # [b, s]
+    t0 = t[:, 0]
+    first = jnp.maximum(t0, 0)      # the call's first byte, were it one
+    f32 = dict(preferred_element_type=jnp.float32)
+    k_l = jax.lax.dynamic_slice(kc, (li, 0, 0, 0, 0), (1, b, H, hd, n))[0]
+    v_l = jax.lax.dynamic_slice(vc, (li, 0, 0, 0, 0), (1, b, H, n, hd))[0]
+    ring_k, ring_v = k_l[..., :W], v_l[:, :, :W]
+    at = jnp.arange(W)               # index i holds residue W - 1 - i
+
+    with jax.named_scope("eva_window_attn"):
+        # the window as the call found it: a byte of the first query's
+        # window where its residue lies before the call's first byte
+        held = (W - 1 - at)[None, :] < (first % W)[:, None]
+        ring_ok = (real & (win == (first // W)[:, None]))[:, :, None] \
+            & held[:, None, :]
+        own_ok = (real[:, :, None] & real[:, None, :]
+                  & (win[:, :, None] == win[:, None, :])
+                  & (jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]))
+        z = jnp.concatenate([
+            jnp.einsum("bqhd,bhdk->bhqk", q, ring_k, **f32),
+            jnp.einsum("bqhd,bkhd->bhqk", q, kk, **f32)], -1) * scale
+        m1, l1, acc1 = _part(
+            z, jnp.concatenate([ring_ok, own_ok], -1)[:, None],
+            lambda p: jnp.einsum("bhqk,bhkd->bqhd", p[..., :W].astype(dt),
+                                 ring_v, **f32)
+            + jnp.einsum("bhqk,bkhd->bqhd", p[..., W:].astype(dt), vv,
+                         **f32))
+
+    with jax.named_scope("eva_summarise"):
+        # every chunk the call's bytes lie in, whole: G groups of c from
+        # chunk `j0`; of the first, what lies before the call is in the
+        # window: the c positions before the call's first, picked out as
+        # found by a product with their one-hot rows (exact)
+        G = (s + c - 2) // c + 1
+        before = W - 1 - (t0[:, None] - c + jnp.arange(c)[None, :]) % W
+        pick = (before[:, :, None] == at[None, None, :]).astype(dt)
+        prev_k = jnp.einsum("bew,bhdw->behd", pick, ring_k)
+        prev_v = jnp.einsum("bew,bhwd->behd", pick, ring_v)
+        pad = jnp.zeros((b, G * c, H, hd), dt)
+        j0 = first // c
+        e0 = c * j0 - t0 + c          # ext row of the first group's start
+        grp = lambda prev, new: _rows_at(
+            jnp.concatenate([prev, new, pad], 1), e0, G * c).reshape(
+                b, G, c, H, hd)
+        place = (c * (j0[:, None, None] + jnp.arange(G)[None, :, None])
+                 + jnp.arange(c)[None, None, :])
+        ok = place < (t0 + s)[:, None, None]
+        k_sum, v_sum = _summarise(layer, grp(prev_k, kk), grp(prev_v, vv),
+                                  ok, c)
+        hit = _laid_at(ok.any(-1), j0, S)               # [b, S]
+        sum_k = jnp.where(hit[:, None, None, :],
+                          _laid_at(k_sum, j0, S).transpose(0, 2, 3, 1),
+                          k_l[..., W:])
+        sum_v = jnp.where(hit[:, None, :, None],
+                          _laid_at(v_sum, j0, S).transpose(0, 2, 1, 3),
+                          v_l[:, :, W:])
+
+    with jax.named_scope("eva_chunk_attn"):
+        # the summaries of the windows before each query's own, one that
+        # ended inside this call among them
+        ok2 = real[:, :, None] & (jnp.arange(S)[None, None, :]
+                                  < (cpw * win)[:, :, None])
+        m2, l2, acc2 = _part(
+            jnp.einsum("bqhd,bhdj->bhqj", q, sum_k, **f32) * scale,
+            ok2[:, None],
+            lambda p: jnp.einsum("bhqj,bhjd->bqhd", p.astype(dt), sum_v,
+                                 **f32))
+        m = jnp.maximum(m1, m2)
+        a1, a2 = jnp.exp(m1 - m), jnp.exp(m2 - m)
+        total = l1 * a1 + l2 * a2                       # [b, H, s]
+        by_q = lambda x: x.transpose(0, 2, 1)[..., None]
+        attn = (acc1 * by_q(a1) + acc2 * by_q(a2)) / by_q(
+            jnp.where(total == 0, 1.0, total))
+
+    with jax.named_scope("eva_window_attn"):
+        # the call's last W rows over the window's: index i takes the
+        # call's row (W - 1 - i - tail0) mod W, where it has one; the
+        # rows are turned round while positions are their major axis
+        n_tail = min(s, W)
+        tail0 = t0 + (s - n_tail)
+        row_of = (W - 1 - at[None, :] - tail0[:, None]) % W
+        put = (row_of < n_tail) & (tail0[:, None] + row_of >= 0)
+
+        def laid(new):    # [b, s, H, hd] -> [b, W, H, hd] by window index
+            turned = jnp.concatenate(
+                [jnp.zeros((b, W - n_tail, H, hd), dt),
+                 new[:, s - n_tail:][:, ::-1]], 1)
+            return jax.vmap(lambda x, by: jnp.roll(x, by, 0))(
+                turned, -tail0 % W)
+
+        k_l = jnp.concatenate([
+            jnp.where(put[:, None, None, :], laid(kk).transpose(0, 2, 3, 1),
+                      ring_k), sum_k], -1)
+        v_l = jnp.concatenate([
+            jnp.where(put[:, None, :, None], laid(vv).transpose(0, 2, 1, 3),
+                      ring_v), sum_v], 2)
+        # the layer's one write: the window's rows and the summaries
+        kc = jax.lax.dynamic_update_slice(kc, k_l[None], (li, 0, 0, 0, 0))
+        vc = jax.lax.dynamic_update_slice(vc, v_l[None], (li, 0, 0, 0, 0))
+    return attn.astype(dt).reshape(b, s, H * hd), kc, vc
+
+
+def _hidden(params: dict, cache: dict, tokens: jax.Array,
+            cfg: EvaByteConfig) -> tuple[jax.Array, dict]:
+    """`tokens` [b, s] appended to the cache -> (the float32 stream
+    behind the last layer [b, s, d], the updated cache)."""
+    b, s = tokens.shape
+    dt, H, hd = cfg.dtype, cfg.n_heads, cfg.head_dim
+    cache_len = cache["length"]
+    per_row = jnp.ndim(cache_len) == 1
+    if s > 1 and per_row:
+        raise ValueError("a chunk advances the batch in lock-step: "
+                         "cache['length'] must be a scalar")
+    start = cache.get("start")
+    if start is None:
+        start = jnp.zeros((b,), jnp.int32)
+    at = (cache_len[:, None] if per_row else cache_len) \
+        + jnp.arange(s)[None, :]
+    t = jnp.broadcast_to(at, (b, s)) - start[:, None]
+    rel = jnp.maximum(t, 0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
+    cos, sin = rope_frequencies(hd, cfg.max_seq_len, cfg.rope_theta)
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def block(li, carry):
+        x, kc, vc = carry
+        layer = jax.tree.map(
+            lambda w: jax.lax.dynamic_index_in_dim(w, li, 0, keepdims=False),
+            params["layers"])
+        with jax.named_scope("attn_qkv"):
+            h = _norm(x, layer["attn_norm"], cfg.norm_eps, dt)
+            q, kk, vv = ((h @ layer[name].astype(dt)).reshape(b, s, H, hd)
+                         for name in ("wq", "wk", "wv"))
+            q = apply_rope(q, cos, sin, rel)
+            kk = apply_rope(kk, cos, sin, rel)
+        if per_row:
+            attn, kc, vc = _decode_attend(cfg, layer, li, q, kk, vv, kc, vc,
+                                          t[:, 0], cache_len >= 0)
+        else:
+            attn, kc, vc = _chunk_attend(cfg, layer, li, q, kk, vv, kc, vc,
+                                         t)
+        with jax.named_scope("attn_out"):
+            x = x + jnp.dot(attn, layer["wo"].astype(dt), **f32)
+        with jax.named_scope("mlp"):
+            h = _norm(x, layer["mlp_norm"], cfg.norm_eps, dt)
+            x = x + jnp.dot(jax.nn.silu(h @ layer["w_gate"].astype(dt))
+                            * (h @ layer["w_up"].astype(dt)),
+                            layer["w_down"].astype(dt), **f32)
+        return x, kc, vc
+
+    x, kc, vc = jax.lax.fori_loop(0, cfg.n_layers, block,
+                                  (x, cache["k"], cache["v"]))
+    return x, {"k": kc, "v": vc, "length": cache_len + s, "start": start}
+
+
+def _logits(params: dict, x, cfg: EvaByteConfig, all_heads: bool):
+    """Float32 logits of the stream's rows x [..., d]: of head 0, the
+    next byte, [..., vocab]; with `all_heads` [..., n_pred_heads,
+    vocab]."""
+    with jax.named_scope("lm_head"):
+        h = _norm(x, params["final_norm"], cfg.norm_eps, cfg.dtype)
+        logits = jnp.dot(h, params["lm_head"].astype(cfg.dtype),
+                         preferred_element_type=jnp.float32)
+    logits = logits.reshape(x.shape[:-1] + (cfg.n_pred_heads,
+                                            cfg.vocab_size))
+    return logits if all_heads else logits[..., 0, :]
+
+
+def decode_step(params: dict, cache: dict, tokens: jax.Array,
+                cfg: EvaByteConfig, all_heads: bool = False
+                ) -> tuple[jax.Array, dict]:
+    """Append `tokens` [b, s] to the cache, return the logits for the
+    last position and the updated cache: `llama.decode_step`'s contract,
+    the logits those of head 0 [b, vocab], the byte the served stream
+    samples (with `all_heads` every head's, [b, n_pred_heads, vocab]).
+    s = 1 with a per-row cache["length"] is the engine's decode step (a
+    row with length < 0 holds no request and reads nothing); a scalar
+    length advances the batch in lock-step by a chunk of any s."""
+    x, cache = _hidden(params, cache, tokens, cfg)
+    return _logits(params, x[:, -1], cfg, all_heads), cache
+
+
+def forward(params: dict, tokens: jax.Array, cfg: EvaByteConfig,
+            all_heads: bool = False) -> jax.Array:
+    """Logits at every position of `tokens` [b, s], no cache kept: one
+    call of the chunk's path over the whole sequence. [b, s, vocab] of
+    head 0, or [b, s, n_pred_heads, vocab]."""
+    b, s = tokens.shape
+    x, _ = _hidden(params, init_cache(cfg, b, max_len=s), tokens, cfg)
+    return _logits(params, x, cfg, all_heads)

@@ -110,9 +110,15 @@ class _PendingPrefill:
     req: _Request
     slot: int
     prompts: Any            # np [1, bucket]
-    small: Any              # per-request prefill cache (the model's pytree)
     bucket: int
     pos: int = 0            # tokens already prefilled
+    # the stored prefix the first chunk grafts, (entry, matched), or None
+    prefix: Optional[tuple] = None
+    # per-request prefill cache (the model's pytree), made when the first
+    # chunk is dispatched: of the requests admitted and waiting for their
+    # chunks only the one at work holds one (where a model's leaves do
+    # not follow the bucket that is a third of a GB each)
+    small: Any = None
 
 
 @dataclass
@@ -166,9 +172,14 @@ class LLMEngine:
     `insert_row` copies the ring as it stands (the chunked prefill
     needs the ring between chunks anyway; cutting "the last window" out
     of a bucket-deep leaf would mean holding that leaf, 45 MB a layer at
-    20,480, to keep 1.4 MB of it). `stats()["cache_bytes"]` files every
+    20,480, to keep 1.4 MB of it). A third such leaf holds a WINDOW of
+    exact rows and, behind it on the same axis, one summary a chunk of
+    everything before (`models/evabyte.py`): a prefill cache's leaf has
+    fewer summaries and the same origin, so it is grafted as it lies.
+    `stats()["cache_bytes"]` files every
     leaf under its kind: `kv`, `state`, or what the module's
-    `CACHE_KIND` names (`latent`, `index`, `window`). Beside `length`
+    `CACHE_KIND` names (`latent`, `index`, `window`, `summary`; two
+    kinds for one leaf where the module says how they share it). Beside `length`
     and `start` a module may keep a third bookkeeping leaf, `aux`: the
     counters its last step decided on the device (`STEP_AUX`).
 
@@ -180,10 +191,15 @@ class LLMEngine:
     `prefill_counters` (the same of a prefill call's attention, from
     where its row starts and where the call's tokens lie).
 
+    A request's prefill cache is made when its first prefill call is
+    dispatched, not when it is admitted: of the prompts admitted and
+    waiting for their chunks one holds a cache (a third to half a GB
+    each for a model whose leaves do not follow the bucket).
+
     The prefix store grafts a block-aligned PREFIX of a stored row's
     positions into a new request's cache. Recurrent state has no such
     prefix to cut, and a ring holds the positions before ITS end and no
-    others: grafting K and V (or latent rows) beside a state or a ring
+    others (a window with summaries behind it likewise): grafting K and V (or latent rows) beside a state or a ring
     that saw other tokens would be wrong, so for a model with either
     the store holds nothing (`prefix_cache_entries` is 0 in
     `stats()`), whatever was asked for.
@@ -252,8 +268,13 @@ class LLMEngine:
         self._cache_bytes = {"kv": 0, "state": 0}
         for name in self._batch_axis:
             kind = kinds.get(name, "state" if name in recurrent else "kv")
-            self._cache_bytes[kind] = self._cache_bytes.get(kind, 0) + (
-                shapes[name].size * shapes[name].dtype.itemsize)
+            leaf = shapes[name]
+            # a leaf whose position axis two kinds share says how
+            parts = kind(cfg, leaf) if callable(kind) else {
+                kind: leaf.size * leaf.dtype.itemsize}
+            for kind, nbytes in parts.items():
+                self._cache_bytes[kind] = self._cache_bytes.get(kind, 0) \
+                    + nbytes
         # counters the model's step decides on the device and returns
         # in cache["aux"]: field of the emit span -> counter of stats()
         self._aux = dict(getattr(mod, "STEP_AUX", {}))
@@ -675,18 +696,15 @@ class LLMEngine:
         prompts = np.zeros((1, bucket), np.int32)
         prompts[0, start:] = toks
 
-        small = self._model.init_cache(self.cfg, 1, max_len=bucket)
-        small["start"] = jnp.asarray([start], jnp.int32)
-        small = jax.device_put(small, self._cache_sharding)
         entry, matched = self._prefix_lookup(toks)
+        prefix = (entry, matched) if matched else None
         pos = 0
         if matched:
-            # prefix hit: graft the stored rows at this prompt's start
+            # prefix hit: the stored rows go in at this prompt's start
             # offset (KV content is start-RELATIVE — models/llama.py
             # rope positions — so rows are reusable across layouts) and
-            # resume the prefill at the first un-cached token
+            # the prefill resumes at the first un-cached token
             pos = start + matched
-            small = self._graft_prefix(small, entry, start, matched)
             self.prefix_hits += 1
             self.prefix_hit_tokens += matched
             if obs is not None:
@@ -706,22 +724,35 @@ class LLMEngine:
             # chunk boundary before the first real token.
             if not matched:
                 pos = (start // self.prefill_chunk) * self.prefill_chunk
-                if pos:
-                    small["length"] = jnp.int32(pos)
             self._slots[slot] = _Slot(req, emitted=-1, length=0)
             self._pending_prefills.append(_PendingPrefill(
-                req=req, slot=slot, prompts=prompts, small=small,
-                bucket=bucket, pos=pos))
+                req=req, slot=slot, prompts=prompts, bucket=bucket, pos=pos,
+                prefix=prefix))
             return
-        nxt, small = self._prefill_dispatch(req, small, prompts, pos,
-                                            bucket - pos)
+        nxt, small = self._prefill_dispatch(req, None, prompts, pos,
+                                            bucket - pos, prefix)
         self.prefills += 1
         self._finish_prefill(req, slot, small, nxt, bucket, start)
 
+    def _prefill_cache(self, bucket: int, start: int, pos: int,
+                       prefix: Optional[tuple]) -> dict:
+        """A request's prefill cache as its first call finds it: empty
+        and `pos` deep (what lies before is left padding, skipped), or
+        with the stored prefix `(entry, matched)` grafted at `start`."""
+        small = self._model.init_cache(self.cfg, 1, max_len=bucket)
+        small["start"] = jnp.asarray([start], jnp.int32)
+        small = jax.device_put(small, self._cache_sharding)
+        if prefix is not None:
+            return self._graft_prefix(small, prefix[0], start, prefix[1])
+        if pos:
+            small["length"] = jnp.int32(pos)
+        return small
+
     def _prefill_dispatch(self, req: _Request, small, prompts, pos: int,
-                          chunk: int):
+                          chunk: int, prefix: Optional[tuple] = None):
         """Launch one prefill call over prompts[:, pos:pos + chunk] (the
-        whole rest of a short prompt, or one chunk of a long one). The
+        whole rest of a short prompt, or one chunk of a long one); the
+        request's first call (`small` None) makes its cache. The
         call is asynchronous: its device time is scope `prefill` in a
         profiler trace, not this span. Returns (sampled token, cache)."""
         bucket = prompts.shape[1]
@@ -733,6 +764,9 @@ class LLMEngine:
                    request_id=req.request_id,
                    pos=pos, chunk=chunk, last=int(pos + chunk >= bucket),
                    **counters):
+            if small is None:
+                small = self._prefill_cache(
+                    bucket, bucket - len(req.tokens), pos, prefix)
             nxt, small, self._key = self._step(
                 self.params, small, jnp.asarray(prompts[:, pos:pos + chunk]),
                 self._key, jnp.asarray([[req.temperature]], np.float32))
@@ -813,7 +847,8 @@ class LLMEngine:
             try:
                 chunk = min(self.prefill_chunk, pf.bucket - pf.pos)
                 nxt, pf.small = self._prefill_dispatch(
-                    pf.req, pf.small, pf.prompts, pf.pos, chunk)
+                    pf.req, pf.small, pf.prompts, pf.pos, chunk, pf.prefix)
+                pf.prefix = None
                 pf.pos += chunk
                 self.prefill_chunks += 1
                 if pf.pos < pf.bucket:

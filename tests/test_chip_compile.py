@@ -521,3 +521,55 @@ def test_dots3_chunk_holds_no_block_of_scores(chip, on_the_chip):
     scores = cfg.swa_n_heads * s * (cfg.ring_len + s)
     for dims in set(re.findall(r"f32\[([0-9,]+)\]", text)):
         assert math.prod(int(d) for d in dims.split(",")) < scores, dims
+
+
+@pytest.mark.parametrize("batch,s,max_len", [
+    (16, 1, 32768),       # the engine's decode step, the cell's 16 slots
+    (1, 1024, 24576),     # one chunk of the deepest bucket's prefill
+], ids=["decode-16x32768-on-chip", "chunk-1x1024@24576"])
+def test_evabyte_step_moves_no_cache(chip, on_the_chip, batch, s, max_len):
+    """models/evabyte.py at the EvaByte cell's sizes (8 layers) under
+    llama's rule: with the cache donated the window's rows and the
+    summaries are written where they lie, and nothing produces an array
+    of a whole stack's shape but the in-place writes. The decode step is
+    the decode kernel the models share, given each row's one range over
+    both parts of the leaf (32 KV heads of 128, group 1), and holds
+    under 16 MB beside its arguments; a chunk reads its layer once and
+    writes it once (its largest temporaries are the float32 scores of
+    1,024 queries against the window and the chunk)."""
+    from ray_tpu.models import evabyte
+
+    cfg = evabyte.EvaByteConfig(n_layers=8)
+    on = SingleDeviceSharding(chip)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=on), tree)
+
+    params = place(jax.eval_shape(
+        lambda key: evabyte.init_params(cfg, key), jax.random.PRNGKey(0)))
+    cache = dict(jax.eval_shape(
+        lambda: evabyte.init_cache(cfg, batch, max_len)))
+    if s == 1:
+        cache["length"] = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((batch, s), jnp.int32, sharding=on)
+    compiled = jax.jit(
+        lambda p, c, t: evabyte.decode_step(p, c, t, cfg),
+        donate_argnums=(1,)).lower(params, place(cache), tokens).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (1 if s == 1 else 0)
+    stacks = {tuple(cache[key].shape) for key in ("k", "v")}
+    stack_bytes = sum(math.prod(cache[key].shape) * 2 for key in ("k", "v"))
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    assert mem.temp_size_in_bytes < (16e6 if s == 1 else 1e9)
+    assert mem.alias_size_in_bytes >= stack_bytes
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if dims in stacks and m.group(3) != "fusion":
+            assert m.group(3) in ("parameter", "get-tuple-element",
+                                  "dynamic-update-slice", "bitcast",
+                                  "custom-call"), line[:200]

@@ -507,6 +507,48 @@ def test_dispatch_span_carries_the_state_rows_a_step_updates(tmp_path):
     assert st[names[0]] < st[names[1]]
 
 
+def test_spans_carry_what_a_window_and_its_summaries_gave_a_step(tmp_path):
+    """`evabyte.decode_counters` on the decode_dispatch spans and
+    `evabyte.prefill_counters` on the prefill_chunk spans, each summed
+    in stats(): the window's rows and the summaries a step's queries
+    attend to, and those it reads; `windows_folded` on both kinds."""
+    from ray_tpu.models import evabyte
+
+    cfg = evabyte.EvaByteConfig(
+        vocab_size=128, dim=32, n_layers=2, n_heads=2, hidden_dim=48,
+        max_seq_len=96, window_size=8, chunk_size=2, n_pred_heads=8,
+        dtype=jnp.float32, param_dtype=jnp.float32)
+    eng, before, by_name = _traced_model_run(tmp_path, cfg)
+    st = eng.stats()
+    decode = ("decode_window_positions_live", "decode_summaries_live",
+              "decode_window_positions_read", "decode_summaries_read")
+    prefill = ("prefill_window_keys_visible", "prefill_window_keys_visited",
+               "prefill_summaries_visible", "prefill_summaries_visited")
+    dispatch, chunks = by_name["decode_dispatch"], by_name["prefill_chunk"]
+    assert dispatch and len(chunks) == 4
+    for d in dispatch:
+        assert {"active", "live_positions", "t_host", "windows_folded",
+                *decode} <= set(d)
+        # two layers: at most the window's 8 rows a live row, and on the
+        # CPU both parts of every row read whole (48 summaries of 96)
+        assert 2 * d["active"] <= d[decode[0]] <= 2 * 8 * d["active"]
+        assert (d[decode[2]], d[decode[3]]) == (2 * 2 * 8, 2 * 2 * 48)
+    for c in chunks:
+        assert {"pos", "chunk", "last", "windows_folded", *prefill} <= set(c)
+        assert c[prefill[0]] <= c[prefill[1]] == 2 * c["chunk"] * (
+            8 + c["chunk"])
+        assert c[prefill[2]] <= c[prefill[3]]
+    for name, spans in [(n, dispatch) for n in decode] \
+            + [(n, chunks) for n in prefill]:
+        assert isinstance(st[name], int)
+        assert sum(s[name] for s in spans) == st[name] - before[name] > 0
+    # positions 8, 16, 24, 32 of the 40-byte prompt and 40 of its six
+    # decode steps (3 + 5 of the short request crosses none)
+    assert sum(s["windows_folded"] for s in dispatch + chunks) == \
+        st["windows_folded"] - before["windows_folded"] == 5
+    assert sum(s["windows_folded"] for s in dispatch) == 1
+
+
 # ------------------------------------------- the process's own log (PR 40)
 @pytest.fixture
 def fresh_log(monkeypatch):
