@@ -401,9 +401,21 @@ def _decode_block(cfg: LlamaConfig, x, layer, li, k_cache, v_cache, cos, sin,
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     with jax.named_scope("attn_qkv"):
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = _proj(cfg, layer, "wq", h).reshape(b, s, nh, hd)
-        kk = _proj(cfg, layer, "wk", h).reshape(b, s, nkv, hd)
+        q = _proj(cfg, layer, "wq", h)
+        kk = _proj(cfg, layer, "wk", h)
         vv = _proj(cfg, layer, "wv", h).reshape(b, s, nkv, hd)
+        # q and k exist as projected, [b, s, heads * hd], before they are
+        # cut into heads for the rope. Left free, the compiler makes the
+        # product give them heads-major, takes wq and wk transposed for
+        # that, and so slices each out of its stack and copies it into
+        # the other layout on every layer of every step (12 MB a layer,
+        # a tenth of the chat cell's decode round: PERF.md, PR 45). Held
+        # here, the products read the stacks where they lie, as wv's, wo's
+        # and the MLP's do. Not v: with it held too the chunk's V stack is
+        # re-laid around the layer loop (tests/test_chip_compile.py).
+        q, kk = jax.lax.optimization_barrier((q, kk))
+        q = q.reshape(b, s, nh, hd)
+        kk = kk.reshape(b, s, nkv, hd)
     attn, k_cache, v_cache = cached_attention(
         q, kk, vv, k_cache, v_cache, li, cache_len, abs_positions, start,
         scale=hd ** -0.5, rope=(cos, sin, positions))

@@ -252,6 +252,125 @@ def test_decode_step_moves_no_cache(chip, request, batch, max_len, s,
             assert math.prod(dims) < repeated, line[:200]
 
 
+def _computations(text):
+    """{name: [instruction lines]} of a compiled module's text."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _weight_moves(compiled, params):
+    """Instructions outside every fusion whose result is ONE layer of a
+    stacked matrix of `params["layers"]` (`[d, out]` or `[1, d, out]`)
+    and that do no arithmetic: a `copy`, a bare `dynamic-slice`, or a
+    fusion with no work in it but the `dynamic-slice`. Returns
+    [(kind, leaf, bytes)], the leaf read off the stack the slice cuts
+    (through the loop's tuple back to the entry parameter's name)."""
+    shapes = {}
+    for leaf in params["layers"].values():
+        if len(leaf.shape) == 3:
+            shapes[tuple(leaf.shape[1:])] = leaf.dtype.itemsize
+            shapes[(1,) + tuple(leaf.shape[1:])] = leaf.dtype.itemsize
+    comps = _computations(compiled.as_text())
+    fused = {name for name in comps if "fused_computation" in name}
+    entry = [line for name, lines in comps.items() if name.startswith("main")
+             for line in lines]
+
+    def work(line):
+        called = re.search(r"calls=%?([\w.\-]+)", line).group(1)
+        ops = (_RESULT.match(body).group(3) for body in comps[called]
+               if _RESULT.match(body))
+        return {op for op in ops
+                if op not in ("parameter", "constant", "bitcast")}
+
+    def define(lines, name):
+        return next((d for d in lines if re.match(
+            rf"\s*(?:ROOT )?%?{re.escape(name)} = ", d)), "")
+
+    def operands(line):
+        return re.findall(r"%([\w.\-]+)", line[line.index(" = "):].split(
+            "(", 1)[1].split("), ")[0])
+
+    def leaf_of(lines, line):
+        """The stacked parameter a slice (or the copy of one) cuts: an
+        element of the layer loop's carry, which the entry computation
+        fills from its parameters."""
+        src = define(lines, operands(line)[0])
+        while src and " get-tuple-element(" not in src:
+            src = define(lines, operands(src)[0])
+        index = int(re.search(r"index=(\d+)", src).group(1)) if src else -1
+        for loop in (d for d in entry if " while(" in d):
+            carried = operands(define(entry, operands(loop)[0]))
+            stack = define(entry, carried[index]) if (
+                0 <= index < len(carried)) else ""
+            if " parameter(" in stack and "layers" in stack:
+                return re.findall(r"\w+", re.search(
+                    r'op_name="(.*?)"', stack).group(1))[-1]
+        return "?"
+
+    found = []
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = _RESULT.match(line)
+            if not m:
+                continue
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            if dims not in shapes:
+                continue
+            op = m.group(3)
+            if op == "copy" or op == "dynamic-slice" or (
+                    op == "fusion" and work(line) == {"dynamic-slice"}):
+                kind = "copy" if op == "copy" else "slice"
+                found.append((kind, leaf_of(lines, line),
+                              math.prod(dims) * shapes[dims]))
+    return found
+
+
+@pytest.mark.parametrize("batch,max_len,s,per_row,left", [
+    (8, 4096, 1, True, []),
+    (8, 4096, 1, True, []),
+    (1, 3584, 256, False, [("copy", "wv", 4 * 2 ** 20),
+                           ("slice", "wv", 4 * 2 ** 20)]),
+], ids=["decode-8x4096", "decode-8x4096-on-chip",
+        "chunk-1x3584-s256-on-chip"])
+def test_serve_step_reads_weights_where_they_lie(chip, request, batch,
+                                                 max_len, s, per_row, left):
+    """Every matrix of a layer is read from its stack by the product
+    that uses it (PERF.md, PR 45): in the serve cells' two programs no
+    instruction outside a fusion gives one layer of a stacked weight as
+    the result of a `copy`, or of a fusion whose only work is the
+    `dynamic-slice`. The seed's count (sandbox compile, PR 45, the tree
+    of PR 43): the decode step, on either attention path, 2 slices + 2
+    copies a layer, wq `bf16[1,2048,2048]` and wk `bf16[1,2048,1024]`,
+    12 MB cut out and 12 MB re-laid (the products wanted q and k heads
+    major and took the matrices transposed for it); the chunk 3 + 3, wv
+    too, 16 MB. Since PR 45 q and k are held as projected
+    (`jax.lax.optimization_barrier` in `_decode_block`) and the decode
+    step has none. ONE exception is left, named here with its bytes:
+    the chunk's wv, 1 slice + 1 copy of `bf16[1,2048,1024]`, 4 MiB
+    each a layer. Held like q and k, v makes the compiler re-lay the
+    whole V stack `bf16[24,1,8,3584,128]` (176 MB) into and out of the
+    layer loop, which `test_decode_step_moves_no_cache` forbids; so does
+    writing V a kv head at a time (PR 45: forms tried, CHANGES.md)."""
+    if request.node.callspec.id.endswith("on-chip"):
+        request.getfixturevalue("on_the_chip")
+    cfg = llama.LlamaConfig(max_seq_len=max_len, **_SERVE_CFG)
+    compiled, _ = _compiled_decode_step(chip, cfg, batch, max_len, s,
+                                        per_row)
+    params = jax.eval_shape(lambda key: llama.init_params(cfg, key),
+                            jax.random.PRNGKey(0))
+    assert sorted(_weight_moves(compiled, params)) == left
+
+
 # granite-4.0-h-micro, whole, as the benchmark's serve cell holds it
 _HYBRID_PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 

@@ -71,6 +71,13 @@ _DECODE_CASES = [
 ] + [
     pytest.param(4, 2, "per_row", 1, True, jnp.float32, id="lora"),
     pytest.param(4, 2, "scalar", 1, False, jnp.bfloat16, id="bf16"),
+    # the prefill chunk's program with adapters in the loop, and in the
+    # serve cells' precision (PR 45: q and k are held as projected, the
+    # low-rank path summed in before they are)
+    pytest.param(4, 2, "scalar", 8, True, jnp.float32, id="lora-chunk"),
+    pytest.param(4, 1, "per_row", 8, True, jnp.float32,
+                 id="lora-chunk-per-row"),
+    pytest.param(4, 2, "scalar", 8, False, jnp.bfloat16, id="bf16-chunk"),
 ]
 
 

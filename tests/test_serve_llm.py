@@ -227,6 +227,34 @@ def test_chunked_prefill_interleaves_with_decode():
     assert mono == chunked
 
 
+def test_chunked_prefill_with_a_lora_adapter_matches_unbatched_decode():
+    """The prefill chunk's program with adapters in its layer loop, under
+    the 2-way tensor mesh: a prompt prefilled in chunks of 64 streams
+    what a plain unbatched decode with the same base and adapter gives,
+    and the adapter is no no-op (PR 45 holds q and k as projected, the
+    low-rank path summed in; the decode step alone was covered)."""
+    from ray_tpu.models import lora as lora_mod
+
+    cfg = llama.config_for("debug", max_seq_len=1024)
+    adapter = lora_mod.init_lora_params(
+        cfg, lora_mod.LoraConfig(rank=4, alpha=cfg.lora_alpha),
+        jax.random.PRNGKey(7))
+    adapter = {"layers": {
+        k: (0.3 * jax.random.normal(jax.random.PRNGKey(i), v.shape, v.dtype)
+            if k.endswith("_b") else v)
+        for i, (k, v) in enumerate(sorted(adapter["layers"].items()))}}
+    base = llama.init_params(cfg, jax.random.PRNGKey(0))
+    eng = LLMEngine("debug", tp=2, max_batch=2, max_seq_len=1024,
+                    prompt_buckets=(32, 512), prefill_chunk=64,
+                    params={**base, "lora": adapter}, seed=0)
+    prompt = [5, 9, 11, 42, 7] * 30  # 150 tokens -> bucket 512
+    got = _collect(eng, prompt, max_new_tokens=6)
+    assert eng.prefill_chunks >= 3
+    assert got == _greedy_reference(eng, prompt, 6)
+    eng.params = base   # the reference reads the engine's: now without
+    assert got != _greedy_reference(eng, prompt, 6)
+
+
 # --------------------------------------------------------------------------
 # The decode pipeline (one step in flight): what the streams see, and faults
 # --------------------------------------------------------------------------
