@@ -24,13 +24,14 @@ def module_for(cfg):
     beside `kv` and `state`; a function of the config and the leaf where
     two kinds share one leaf's position axis) and STEP_AUX (counters the step decides on
     the device and returns in cache["aux"])."""
-    from ray_tpu.models import dots3_note, evabyte, granite_hybrid
+    from ray_tpu.models import dots3_note, evabyte, granite_hybrid, kimi_k2
 
     for module, config_type in ((llama, llama.LlamaConfig),
                                 (granite_hybrid,
                                  granite_hybrid.GraniteHybridConfig),
                                 (dots3_note, dots3_note.Dots3NoteConfig),
-                                (evabyte, evabyte.EvaByteConfig)):
+                                (evabyte, evabyte.EvaByteConfig),
+                                (kimi_k2, kimi_k2.KimiK2Config)):
         if isinstance(cfg, config_type):
             return module
     raise TypeError(f"no model module serves a {type(cfg).__name__}")

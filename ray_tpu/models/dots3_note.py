@@ -57,7 +57,7 @@ from ray_tpu.ops import attention as _attention
 from ray_tpu.ops.moe import held_experts_ffn, route_sigmoid_topk
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.pallas.latent_attention import latent_attention, tiles
-from ray_tpu.ops.rope import apply_rope
+from ray_tpu.ops.rope import apply_rope, yarn_inv_freq
 
 F32 = jnp.float32
 KINDS = ("full_attention", "sliding_attention")
@@ -75,6 +75,16 @@ class AttnSizes(NamedTuple):
     q_rank: int
     kv_rank: int
     theta: float
+    # YaRN over the rotated part, (factor, original context, beta_fast,
+    # beta_slow), and the temperature its family squares into the
+    # softmax scale (models/kimi_k2.py); plain RoPE where None
+    yarn: tuple | None = None
+    mscale: float = 1.0
+
+    @property
+    def scale(self) -> float:
+        """What a score is multiplied by before the softmax."""
+        return self.mscale ** 2 / math.sqrt(self.nope + self.rope)
 
     @property
     def row(self) -> int:
@@ -312,11 +322,14 @@ def param_logical_axes(cfg: Dots3NoteConfig) -> dict:
 
 
 # ---------------------------------------------------------------- the parts
-def _rope(x, positions, theta: float):
-    """Rotated halves over the last axis; x: [b, s, heads, dim],
-    positions: [b, s]."""
+def _rope(x, positions, a: AttnSizes):
+    """Rotated halves over the last axis, at `a`'s frequencies; x: [b,
+    s, heads, dim], positions: [b, s]."""
     half = x.shape[-1] // 2
-    inv = 1.0 / (theta ** (jnp.arange(half, dtype=F32) / half))
+    if a.yarn is None:
+        inv = 1.0 / (a.theta ** (jnp.arange(half, dtype=F32) / half))
+    else:
+        inv = jnp.asarray(yarn_inv_freq(2 * half, a.theta, *a.yarn))
     ang = positions.astype(F32)[..., None] * inv
     return apply_rope(x, jnp.cos(ang), jnp.sin(ang))
 
@@ -337,7 +350,7 @@ def _queries(cfg, a: AttnSizes, layer, h, rel):
         q = jnp.einsum("bsr,nr->bsn", c_q, layer["w_qb"].astype(dt)
                        ).reshape(b, s, a.heads, -1)
         q_nope, q_rope = q[..., :a.nope], q[..., a.nope:]
-        return c_q, q_nope, _rope(q_rope, rel, a.theta)
+        return c_q, q_nope, _rope(q_rope, rel, a)
 
 
 def _latent_rows(cfg, a: AttnSizes, layer, h, rel):
@@ -347,7 +360,7 @@ def _latent_rows(cfg, a: AttnSizes, layer, h, rel):
     c_kv = rms_norm(kv[..., :a.kv_rank], layer["kv_norm"], cfg.norm_eps)
     if cfg.lora_rescale:
         c_kv = c_kv * jnp.asarray(math.sqrt(cfg.dim / a.kv_rank), dt)
-    k_rope = _rope(kv[..., None, a.kv_rank:], rel, a.theta)[:, :, 0]
+    k_rope = _rope(kv[..., None, a.kv_rank:], rel, a)[:, :, 0]
     fill = jnp.zeros(kv.shape[:-1] + (a.row - kv.shape[-1],), dt)
     return jnp.concatenate([c_kv, k_rope, fill], axis=-1)
 
@@ -359,13 +372,13 @@ def _index_parts(cfg, a: AttnSizes, layer, h, c_q, rel):
     dt, hi, di, dr = cfg.dtype, cfg.index_n_heads, cfg.index_head_dim, a.rope
     q = jnp.einsum("bsr,nr->bsn", c_q, layer["wi_q"].astype(dt)
                    ).reshape(b, s, hi, di)
-    q = jnp.concatenate([_rope(q[..., :dr], rel, a.theta), q[..., dr:]], -1)
+    q = jnp.concatenate([_rope(q[..., :dr], rel, a), q[..., dr:]], -1)
     k = (h @ layer["wi_k"].astype(dt)).astype(F32)
     k = (k - k.mean(-1, keepdims=True)) * jax.lax.rsqrt(
         k.var(-1, keepdims=True) + cfg.norm_eps)
     k = (k * layer["wi_k_norm"].astype(F32)
          + layer["wi_k_bias"].astype(F32)).astype(dt)
-    k = jnp.concatenate([_rope(k[:, :, None, :dr], rel, a.theta)[:, :, 0],
+    k = jnp.concatenate([_rope(k[:, :, None, :dr], rel, a)[:, :, 0],
                          k[..., dr:]], -1)
     w = (h @ layer["wi_w"].astype(dt)).astype(F32) / math.sqrt(hi * di)
     return q, k, w
@@ -433,7 +446,7 @@ def _attend_block(state, a: AttnSizes, layer, q, rows, mask, dt):
     k = jnp.concatenate([k_nope, jnp.broadcast_to(
         kr[:, :, None], k_nope.shape[:3] + (a.rope,))], axis=-1)
     s = jnp.einsum("bhqd,bkhd->bhqk", q, k, preferred_element_type=F32
-                   ) / math.sqrt(a.nope + a.rope)
+                   ) * a.scale
     s = jnp.where(mask[:, None], s, NEG)
     m_new = jnp.maximum(m, s.max(-1))
     p = jnp.where(mask[:, None], jnp.exp(s - m_new[..., None]), 0.0)
@@ -474,19 +487,25 @@ def _attend_kernel(a: AttnSizes, layer, q, rows, li, mask, t):
     in memory."""
     return latent_attention(
         q, rows, li, layer["w_kvb_k"], layer["w_kvb_v"], mask,
-        kv_rank=a.kv_rank, rope=a.rope, t=t).transpose(0, 2, 1, 3)
+        kv_rank=a.kv_rank, rope=a.rope, t=t, scale=a.scale
+        ).transpose(0, 2, 1, 3)
+
+
+def _absorbed_query(a: AttnSizes, layer, q_nope, q_rope, dt):
+    """A query as it meets a cached row: the key's up-projection folded
+    into q_nope, [q_nope W_k | q_rope | zeros], [b, H, row]."""
+    q_abs = jnp.einsum("bhn,chn->bhc", q_nope, layer["w_kvb_k"].astype(dt))
+    fill = jnp.zeros(q_abs.shape[:-1] + (a.row - a.kv_rank - a.rope,), dt)
+    return jnp.concatenate([q_abs, q_rope, fill], axis=-1)
 
 
 def _absorbed(a: AttnSizes, layer, q_nope, q_rope, rows, mask, dt):
     """The ABSORBED form for one query a row: q_nope [b, H, nope],
     q_rope [b, H, rope] against rows [b, n, row] under mask [b, n];
     returns [b, H, v]."""
-    q_abs = jnp.einsum("bhn,chn->bhc", q_nope, layer["w_kvb_k"].astype(dt))
-    fill = jnp.zeros(q_abs.shape[:-1] + (rows.shape[-1] - a.kv_rank - a.rope,),
-                     dt)
-    q_cat = jnp.concatenate([q_abs, q_rope, fill], axis=-1)
+    q_cat = _absorbed_query(a, layer, q_nope, q_rope, dt)
     s = jnp.einsum("bhr,bkr->bhk", q_cat, rows,
-                   preferred_element_type=F32) / math.sqrt(a.nope + a.rope)
+                   preferred_element_type=F32) * a.scale
     s = jnp.where(mask[:, None], s, NEG)
     p = jnp.where(mask[:, None], jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
     p = (p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)).astype(dt)
@@ -672,6 +691,21 @@ def _tiles_visited(t, start: int, pos: int, chunk: int, k_pos, reach: int):
     return pairs
 
 
+def _dense_keys_visited(a: AttnSizes, start: int, pos: int, chunk: int,
+                        depth: int) -> int:
+    """Key positions whose scores one layer computes for `chunk` queries
+    from `pos` of a row that starts at `start`, against a cache `depth`
+    deep with nothing but causality and padding to empty a tile: under
+    the kernel its live tiles, in the plain form every block from the
+    first real position to the last written."""
+    t = _chunk_tiles(a, chunk, depth)
+    if t is None:
+        blk = min(depth, 1024)
+        return chunk * blk * ((pos + chunk - 1) // blk + 1 - start // blk)
+    return _tiles_visited(t, start, pos, chunk, np.arange(depth),
+                          depth + chunk)
+
+
 def prefill_counters(cfg: Dots3NoteConfig, start: int, pos: int, chunk: int,
                      depth: int) -> dict:
     """What the attention of one prefill chunk does: `chunk` queries at
@@ -687,13 +721,7 @@ def prefill_counters(cfg: Dots3NoteConfig, start: int, pos: int, chunk: int,
     # how deep each real query of the chunk lies in its row
     depths = np.arange(max(pos, start), pos + chunk) - start + 1
     ring = cfg.ring_len
-    t = _chunk_tiles(cfg.attn(KINDS[0]), chunk, depth)
-    if t is None:
-        blk = min(depth, 1024)
-        full = chunk * blk * ((pos + chunk - 1) // blk + 1 - start // blk)
-    else:
-        full = _tiles_visited(t, start, pos, chunk, np.arange(depth),
-                              depth + chunk)
+    full = _dense_keys_visited(cfg.attn(KINDS[0]), start, pos, chunk, depth)
     t = _chunk_tiles(cfg.attn(KINDS[1]), chunk, ring + chunk)
     if t is None:
         window = chunk * (ring + chunk)

@@ -159,15 +159,17 @@ def _kernel(li_ref, live_ref, named_ref, q_ref, rows_ref, wk_ref, wv_ref,
 
 def latent_attention(q: jax.Array, rows: jax.Array, li, w_k: jax.Array,
                      w_v: jax.Array, mask: jax.Array, *, kv_rank: int,
-                     rope: int, t: Tiles) -> jax.Array:
+                     rope: int, t: Tiles,
+                     scale: float | None = None) -> jax.Array:
     """q ``[b, H, s, nope + rope]`` (heads first, rope part last and
     rotated); rows the STACKED latent rows ``[layers, b, n, row]`` with
     ``row >= kv_rank + rope``, of which layer `li` is attended; w_k
     ``[kv_rank, H, nope]`` and w_v ``[kv_rank, H, v]``, the up-projections
     as the model holds them; mask ``[b, s, n]`` bool. `t` from `tiles`.
     Returns ``[b, H, s, v]`` in q's dtype: softmax over the positions the
-    mask lets through of q . [c W_k | k_rope] / sqrt(nope + rope), times
-    c W_v; zeros for a query it lets nothing through for."""
+    mask lets through of q . [c W_k | k_rope] * scale (1 / sqrt(nope +
+    rope) where none is given), times c W_v; zeros for a query it lets
+    nothing through for."""
     b, heads, s, d = q.shape
     n, row = rows.shape[2], rows.shape[3]
     nope, v = w_k.shape[2], w_v.shape[2]
@@ -208,8 +210,8 @@ def latent_attention(q: jax.Array, rows: jax.Array, li, w_k: jax.Array,
         ],
     )
     call = pl.pallas_call(
-        functools.partial(_kernel, scale=1.0 / math.sqrt(d), kv_rank=kv_rank,
-                          rope=rope, t=t),
+        functools.partial(_kernel, scale=scale or 1.0 / math.sqrt(d),
+                          kv_rank=kv_rank, rope=rope, t=t),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, heads, s, v), dt),
         compiler_params=pltpu.CompilerParams(

@@ -2,8 +2,11 @@
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def rope_frequencies(head_dim: int, max_len: int,
@@ -14,6 +17,47 @@ def rope_frequencies(head_dim: int, max_len: int,
     t = jnp.arange(max_len, dtype=jnp.float32)
     freqs = jnp.outer(t, inv_freq)
     return jnp.cos(freqs), jnp.sin(freqs)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature for a context stretched `factor`
+    times: ``0.1 * mscale * ln(factor) + 1`` (1 where nothing is
+    stretched). DeepSeek-V3's family multiplies the softmax scale by its
+    square, taken at `mscale_all_dim`, and cos and sin by the ratio of
+    the two it is given."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, original_max_len: int,
+                          beta_fast: float = 32.0,
+                          beta_slow: float = 1.0) -> tuple[int, int]:
+    """(low, high): the rotated pairs between which YaRN blends. A pair
+    below `low` turns more than `beta_fast` times inside the original
+    context and keeps its frequency; one above `high` turns less than
+    `beta_slow` times and is interpolated whole."""
+    def pair(turns: float) -> float:
+        return (dim * math.log(original_max_len / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    return (max(math.floor(pair(beta_fast)), 0),
+            min(math.ceil(pair(beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max_len: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """Inverse frequencies [dim // 2], float32, of RoPE under YaRN
+    (Peng et al. 2023, as DeepSeek-V3's modeling code has it): each
+    pair's plain frequency ``theta^(-2i/dim)`` blended with the same
+    divided by `factor`, by a linear ramp over the pairs from 0 at `low`
+    to 1 at `high` (`yarn_correction_range`). Constants of the shapes
+    alone, so computed on the host."""
+    plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    low, high = yarn_correction_range(dim, theta, original_max_len,
+                                      beta_fast, beta_slow)
+    ramp = np.clip((np.arange(dim // 2) - low)
+                   / (high - low if high > low else 0.001), 0.0, 1.0)
+    return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,

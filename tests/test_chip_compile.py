@@ -692,3 +692,62 @@ def test_evabyte_step_moves_no_cache(chip, on_the_chip, batch, s, max_len):
             assert m.group(3) in ("parameter", "get-tuple-element",
                                   "dynamic-update-slice", "bitcast",
                                   "custom-call"), line[:200]
+
+
+@pytest.mark.parametrize("batch,s,max_len", [
+    (32, 1, 24576),       # the engine's decode step, the cell's 32 slots
+    (1, 1024, 23552),     # one chunk of the deepest bucket's prefill
+], ids=["decode-32x24576-on-chip", "chunk-1x1024@23552"])
+def test_kimi_k2_step_reads_live_blocks_and_moves_no_cache(
+        chip, on_the_chip, batch, s, max_len):
+    """models/kimi_k2.py at the Kimi-K2.6 cell's sizes (5 layers, 12 of
+    384 experts, an eighth of the vocabulary) under the rule the other
+    models' steps are held to: with the cache donated the latent rows
+    (5.03 GB for the slots) are written where they lie, weights, cache
+    and temporaries fit 15.75 GB, and nothing but the in-place writes
+    and the kernels' operands has a whole stack's or a whole layer's
+    shape: the decode step reads the leaf through
+    ops/pallas/latent_decode_attention.py (one call a layer, the stack
+    its operand as it lies, blocks of 1,024 positions chosen in its
+    index map), and a chunk through ops/pallas/latent_attention.py."""
+    from ray_tpu.models import kimi_k2
+
+    cfg = kimi_k2.KimiK2Config(vocab_size=20480, n_layers=5,
+                               experts_held=12, max_seq_len=24576)
+    on = SingleDeviceSharding(chip)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=on), tree)
+
+    params = place(jax.eval_shape(
+        lambda key: kimi_k2.init_params(cfg, key), jax.random.PRNGKey(0)))
+    cache = dict(jax.eval_shape(
+        lambda: kimi_k2.init_cache(cfg, batch, max_len)))
+    if s == 1:
+        cache["length"] = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((batch, s), jnp.int32, sharding=on)
+    compiled = jax.jit(
+        lambda p, c, t: kimi_k2.decode_step(p, c, t, cfg),
+        donate_argnums=(1,)).lower(params, place(cache), tokens).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == cfg.n_layers
+    stack = tuple(cache["latent"].shape)
+    stack_bytes = math.prod(stack) * 2
+    if s == 1:
+        assert stack_bytes == 5 * 32 * 24576 * 640 * 2
+        assert kimi_k2.decode_read_block(cfg, None) == 1024
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75e9)
+    assert mem.temp_size_in_bytes < (0.1e9 if s == 1 else 0.8e9)
+    assert mem.alias_size_in_bytes >= stack_bytes
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if dims in (stack, stack[1:]) and m.group(3) != "fusion":
+            assert m.group(3) in ("parameter", "get-tuple-element",
+                                  "dynamic-update-slice", "bitcast"), \
+                line[:200]
