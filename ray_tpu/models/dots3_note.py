@@ -524,7 +524,7 @@ def _gate_out(cfg, layer, h, attn):
 
 
 def _ffn(cfg: Dots3NoteConfig, layer, x, valid, collect: bool):
-    """x [b, s, d] -> (x + FFN(rmsnorm(x)), (pairs, hit), aux)."""
+    """x [b, s, d] -> (x + FFN(rmsnorm(x)), (pairs, hit, tiles), aux)."""
     b, s, d = x.shape
     dt = cfg.dtype
     swiglu = lambda h, g, u, dn: (jax.nn.silu(h @ layer[g].astype(dt))
@@ -540,7 +540,7 @@ def _ffn(cfg: Dots3NoteConfig, layer, x, valid, collect: bool):
             h, layer["router"], layer["router_bias"], cfg.experts_per_tok,
             normalize=cfg.norm_topk_prob, scaling=cfg.routed_scaling)
     with jax.named_scope("moe_experts"):
-        y, pairs, hit = held_experts_ffn(
+        y, *moe = held_experts_ffn(
             h, chosen, weights, layer["we_gate"], layer["we_up"],
             layer["we_down"], cfg.experts_first,
             valid=None if valid is None else valid.reshape(b * s))
@@ -548,7 +548,7 @@ def _ffn(cfg: Dots3NoteConfig, layer, x, valid, collect: bool):
         y = y + swiglu(h, "ws_gate", "ws_up", "ws_down").astype(F32)
     aux = {"router_scores": scores.reshape(b, s, -1),
            "chosen": chosen.reshape(b, s, -1)} if collect else {}
-    return x + y.reshape(b, s, d).astype(dt), (pairs, hit), aux
+    return x + y.reshape(b, s, d).astype(dt), moe, aux
 
 
 def _embed(cfg, params, tokens):
@@ -622,7 +622,8 @@ CACHE_KIND = {"latent": "latent", "index": "index", "window": "window"}
 # the step's counters decided on the device, in the order of cache["aux"]:
 # field of the engine's emit span -> counter of stats()
 STEP_AUX = {"expert_rows": "moe_expert_rows",
-            "experts_hit": "moe_experts_hit"}
+            "experts_hit": "moe_experts_hit",
+            "expert_tiles": "moe_expert_tiles"}
 
 
 def init_cache(cfg: Dots3NoteConfig, batch: int,
@@ -908,10 +909,11 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array,
     cache["start"] are left padding: RoPE and the window count from
     `start`, and a padded position reaches neither the selection nor an
     expert. cache["aux"] comes back as (token-expert pairs computed on
-    held experts, held experts with at least one token), summed over the
-    expert layers. With `collect` a third value is returned: what each
-    full layer selected and each expert layer chose (`forward`'s dict,
-    positions as the cache's)."""
+    held experts, held experts with at least one token, tiles of rows
+    the experts' loop walked), summed over the expert layers. With
+    `collect` a third value is returned: what each full layer selected
+    and each expert layer chose (`forward`'s dict, positions as the
+    cache's)."""
     b, s = tokens.shape
     cache_len = cache["length"]
     if s > 1 and jnp.ndim(cache_len):

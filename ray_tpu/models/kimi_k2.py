@@ -399,9 +399,10 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array,
     left. Positions before cache["start"] are left padding: RoPE counts
     from `start`, and a padded position is attended to by nobody and
     reaches no expert. cache["aux"] comes back as (token-expert pairs
-    computed on held experts, held experts with at least one token),
-    summed over the expert layers. With `collect` a third value is
-    returned: what each expert layer chose (`forward`'s dict)."""
+    computed on held experts, held experts with at least one token,
+    tiles of rows the experts' loop walked), summed over the expert
+    layers. With `collect` a third value is returned: what each expert
+    layer chose (`forward`'s dict)."""
     b, s = tokens.shape
     cache_len = cache["length"]
     if s > 1 and jnp.ndim(cache_len):

@@ -19,6 +19,7 @@ the GShard/Mesh-TF einsum recipe rather than a scatter/gather kernel:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -38,8 +39,6 @@ def init_moe_params(key: jax.Array, dim: int, hidden_dim: int,
                     cfg: MoEConfig, dtype=jnp.float32) -> dict:
     """Router + per-expert SwiGLU FFN weights (stacked on a leading
     expert axis, the EP analog of the stacked-layers scan trick)."""
-    import math
-
     k1, k2, k3, k4, k5 = jax.random.split(key, 5)
     e, d, h = cfg.num_experts, dim, hidden_dim
 
@@ -182,12 +181,15 @@ def held_experts_ffn(x: jax.Array, chosen: jax.Array, weights: jax.Array,
     out by expert, each expert's group padded to whole tiles of
     `block_rows` rows, and a loop over the tiles THAT EXIST (its trip
     count is decided on the device) multiplies each by its expert's
-    matrices: an expert no token chose is never read, one that many
-    chose takes as many tiles as it needs, and the buffer is sized for
-    the worst case (every pair on one held expert). x: [T, d]; chosen,
-    weights: [T, k]; valid: [T] bool or None, rows that are no token
-    (padding, a slot that holds no request) and reach no expert.
-    Returns (y [T, d] float32, pairs computed, held experts hit)."""
+    matrices and adds its rows, weighted in float32, into their tokens'
+    rows of y: an expert no token chose is never read, one that many
+    chose takes as many tiles as it needs, and nothing of T x k rows of
+    d is written, gathered or summed; only the layout's integers are
+    sized for the worst case (every pair on one held expert). x: [T, d];
+    chosen, weights: [T, k]; valid: [T] bool or None, rows that are no
+    token (padding, a slot that holds no request) and reach no expert.
+    Returns (y [T, d] float32, pairs computed, held experts hit, tiles
+    walked)."""
     T, d = x.shape
     k = chosen.shape[1]
     held = w_gate.shape[0]
@@ -208,30 +210,43 @@ def held_experts_ffn(x: jax.Array, chosen: jax.Array, weights: jax.Array,
     slot = jnp.where(mine, offsets[jnp.minimum(local, held - 1)] + rank,
                      n_slots)                                 # [pairs]
     token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
-    slot_token = jnp.zeros((n_slots,), jnp.int32).at[slot].set(
+    # a padding row is no token: its index lies past y and its add is
+    # dropped
+    slot_token = jnp.full((n_slots,), T, jnp.int32).at[slot].set(
         token, mode="drop")
+    slot_weight = jnp.zeros((n_slots,), jnp.float32).at[slot].set(
+        weights.reshape(pairs).astype(jnp.float32), mode="drop")
     tile_expert = jnp.minimum(jnp.searchsorted(
         ends, jnp.arange(n_slots // bm) * bm, side="right"), held - 1)
+    tiles = ends[-1] // bm
+    # the scatter pays for a padding row what it pays for a token's (0.2
+    # us of 7,168 float32; my chip run, PR 47): a tile's rows are added
+    # 16 at a time, as far as its tokens reach
+    sub = math.gcd(bm, 16)
 
-    def tile(i, y_slots):
+    def tile(i, y):
         e = tile_expert[i]
         rows = jax.lax.dynamic_slice_in_dim(slot_token, i * bm, bm)
-        xt = jnp.take(x, rows, axis=0)
+        g = jax.lax.dynamic_slice_in_dim(slot_weight, i * bm, bm)
+        xt = jnp.take(x, rows, axis=0, mode="clip")
         pick = lambda w: jax.lax.dynamic_index_in_dim(
             w, e, 0, keepdims=False).astype(x.dtype)
         h = jax.nn.silu(xt @ pick(w_gate)) * (xt @ pick(w_up))
-        return jax.lax.dynamic_update_slice_in_dim(
-            y_slots, h @ pick(w_down), i * bm, 0)
+        out = (h @ pick(w_down)).astype(jnp.float32) * g[:, None]
+
+        def add(j, y):
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, j * sub, sub)
+            return y.at[cut(rows)].add(cut(out), mode="drop")
+
+        if bm == sub:  # a decode step's tile: one add, no loop around it
+            return add(0, y)
+        return jax.lax.fori_loop(0, -(-(rows < T).sum() // sub), add, y)
 
     # zeros that depend on x: as a bare constant the compiler merges the
     # layers' buffers into one broadcast that carries no scope's name
     # (84 MB a layer a chunk, a quarter of the cell's unscoped device
     # time; my chip run, PR 32)
-    y_slots = jax.lax.fori_loop(
-        0, ends[-1] // bm, tile,
-        jnp.broadcast_to(x[:1, :1] * jnp.zeros((), x.dtype), (n_slots, d)))
-    got = jnp.take(y_slots, jnp.minimum(slot, n_slots - 1), axis=0)
-    w = jnp.where(mine, weights.reshape(pairs), 0.0).astype(jnp.float32)
-    y = (got.astype(jnp.float32) * w[:, None]).reshape(T, k, d).sum(1)
+    y = jax.lax.fori_loop(0, tiles, tile, jnp.broadcast_to(
+        (x[:1, :1] * jnp.zeros((), x.dtype)).astype(jnp.float32), (T, d)))
     return y, mine.sum().astype(jnp.int32), (counts > 0).sum().astype(
-        jnp.int32)
+        jnp.int32), tiles

@@ -9,6 +9,7 @@ compile takes a second or two. Skipped where the topology cannot be
 described.
 """
 
+import functools
 import math
 import os
 import re
@@ -694,22 +695,13 @@ def test_evabyte_step_moves_no_cache(chip, on_the_chip, batch, s, max_len):
                                   "custom-call"), line[:200]
 
 
-@pytest.mark.parametrize("batch,s,max_len", [
-    (32, 1, 24576),       # the engine's decode step, the cell's 32 slots
-    (1, 1024, 23552),     # one chunk of the deepest bucket's prefill
-], ids=["decode-32x24576-on-chip", "chunk-1x1024@23552"])
-def test_kimi_k2_step_reads_live_blocks_and_moves_no_cache(
-        chip, on_the_chip, batch, s, max_len):
-    """models/kimi_k2.py at the Kimi-K2.6 cell's sizes (5 layers, 12 of
-    384 experts, an eighth of the vocabulary) under the rule the other
-    models' steps are held to: with the cache donated the latent rows
-    (5.03 GB for the slots) are written where they lie, weights, cache
-    and temporaries fit 15.75 GB, and nothing but the in-place writes
-    and the kernels' operands has a whole stack's or a whole layer's
-    shape: the decode step reads the leaf through
-    ops/pallas/latent_decode_attention.py (one call a layer, the stack
-    its operand as it lies, blocks of 1,024 positions chosen in its
-    index map), and a chunk through ops/pallas/latent_attention.py."""
+@functools.cache
+def _kimi_k2_step(chip, batch, s, max_len):
+    """(config, cache shapes, compiled `decode_step`) of models/kimi_k2.py
+    at the Kimi-K2.6 cell's sizes (5 layers, 12 of 384 experts, an eighth
+    of the vocabulary), the cache donated; compiled once a shape (a
+    chunk's program takes 15 s), under the `on_the_chip` fixture of
+    whichever test asks first."""
     from ray_tpu.models import kimi_k2
 
     cfg = kimi_k2.KimiK2Config(vocab_size=20480, n_layers=5,
@@ -730,6 +722,27 @@ def test_kimi_k2_step_reads_live_blocks_and_moves_no_cache(
     compiled = jax.jit(
         lambda p, c, t: kimi_k2.decode_step(p, c, t, cfg),
         donate_argnums=(1,)).lower(params, place(cache), tokens).compile()
+    return cfg, cache, compiled
+
+
+@pytest.mark.parametrize("batch,s,max_len", [
+    (32, 1, 24576),       # the engine's decode step, the cell's 32 slots
+    (1, 1024, 23552),     # one chunk of the deepest bucket's prefill
+], ids=["decode-32x24576-on-chip", "chunk-1x1024@23552"])
+def test_kimi_k2_step_reads_live_blocks_and_moves_no_cache(
+        chip, on_the_chip, batch, s, max_len):
+    """models/kimi_k2.py at the Kimi-K2.6 cell's sizes under the rule the
+    other models' steps are held to: with the cache donated the latent
+    rows (5.03 GB for the slots) are written where they lie, weights,
+    cache and temporaries fit 15.75 GB, and nothing but the in-place
+    writes and the kernels' operands has a whole stack's or a whole
+    layer's shape: the decode step reads the leaf through
+    ops/pallas/latent_decode_attention.py (one call a layer, the stack
+    its operand as it lies, blocks of 1,024 positions chosen in its
+    index map), and a chunk through ops/pallas/latent_attention.py."""
+    from ray_tpu.models import kimi_k2
+
+    cfg, cache, compiled = _kimi_k2_step(chip, batch, s, max_len)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == cfg.n_layers
     stack = tuple(cache["latent"].shape)
@@ -751,3 +764,31 @@ def test_kimi_k2_step_reads_live_blocks_and_moves_no_cache(
             assert m.group(3) in ("parameter", "get-tuple-element",
                                   "dynamic-update-slice", "bitcast"), \
                 line[:200]
+
+
+def test_kimi_k2_chunk_holds_no_array_of_every_pair(chip, on_the_chip):
+    """The Kimi-K2.6 cell's chunk program (1,024 tokens, bucket 23,552,
+    12 of 384 experts): its four expert layers follow the tiles that
+    exist (ops/moe.held_experts_ffn), so no array of 8,192 token-expert
+    pairs' rows of 7,168 or more exists in any dtype: not the pairs'
+    buffer bf16[9728,7168] (139 MB a layer, zero-filled), not its
+    gather bf16[8192,7168], not the weighted f32[1024,8,7168] and its
+    sum. One parameter has that shape, the attention's output
+    projection (64 heads x 128 = 8,192 rows), and is read where it lies.
+    The temporaries, 549 MB at PR 47's parent (sandbox compile, PR 47),
+    are smaller by more than the buffer."""
+    s = 1024
+    cfg, _, compiled = _kimi_k2_step(chip, 1, s, 23552)
+    text = compiled.as_text()
+    for gone in ("[9728,7168]", "f32[8192,7168]", "[1024,8,7168]"):
+        assert gone not in text, gone
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        dims = tuple(int(d) for d in m.group(2).split(",") if d) if m else ()
+        if (dims[-1:] == (cfg.dim,)
+                and math.prod(dims[:-1]) >= s * cfg.experts_per_tok):
+            # a parameter, or its view inside the fusion that reads it
+            assert (m.group(3) in ("parameter", "get-tuple-element",
+                                   "bitcast")
+                    or "calls=%bitcast_fusion" in line), line[:200]
+    assert compiled.memory_analysis().temp_size_in_bytes < 549e6 - 139e6

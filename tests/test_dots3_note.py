@@ -174,8 +174,8 @@ def test_chunked_prefill_then_cached_decode_agree_with_the_reference(
         err = jnp.abs(got[:, r] - want[first:first + steps + 1]).max()
         assert float(err) < 2e-4, (r, float(err))
     # the step's own count of what it sent to the held experts
-    assert cache["aux"].shape == (2,) and int(cache["aux"][0]) > 0
-    assert int(cache["aux"][1]) <= 4 * cfg.experts_held
+    pairs, hit, tiles = (int(n) for n in cache["aux"])
+    assert 0 < hit <= tiles <= pairs and hit <= 4 * cfg.experts_held
     if kernel_calls is not None:
         # one trace of the chunk program: two full layers against the
         # depth, three sliding ones against ring + chunk; no decode step
@@ -220,37 +220,64 @@ def _expert_weights(key, e, d, f):
             jax.random.normal(k[2], (e, f, d)) / np.sqrt(f))
 
 
-def test_all_tokens_to_one_expert_lose_none():
+@pytest.mark.parametrize("choices", [(2, 9), (2,) * 8],
+                         ids=["once", "every-pair"])
+def test_all_tokens_to_one_expert_lose_none(choices):
+    """Expert 9 is not held; with every choice on expert 2 a token's
+    pairs lie eight times in one group, which no router gives and the
+    layer still sums."""
     wg, wu, wd = _expert_weights(jax.random.PRNGKey(0), 4, 32, 16)
     x = jax.random.normal(jax.random.PRNGKey(1), (300, 32))
-    chosen = jnp.stack([jnp.full((300,), 2), jnp.full((300,), 9)], -1)
-    weights = jax.random.uniform(jax.random.PRNGKey(2), (300, 2))
-    y, pairs, hit = jax.jit(lambda *a: moe.held_experts_ffn(*a))(
+    chosen = jnp.stack([jnp.full((300,), e) for e in choices], -1)
+    weights = jax.random.uniform(jax.random.PRNGKey(2), chosen.shape)
+    y, pairs, hit, tiles = jax.jit(lambda *a: moe.held_experts_ffn(*a))(
         x, chosen, weights, wg, wu, wd)
-    want = ((jax.nn.silu(x @ wg[2]) * (x @ wu[2])) @ wd[2]) * weights[:, :1]
+    on_two = jnp.where(chosen == 2, weights, 0).sum(-1, keepdims=True)
+    want = ((jax.nn.silu(x @ wg[2]) * (x @ wu[2])) @ wd[2]) * on_two
     assert float(jnp.abs(y - want).max()) < 1e-5
-    assert (int(pairs), int(hit)) == (300, 1)   # expert 9 is not held
+    n = 300 * choices.count(2)
+    assert (int(pairs), int(hit), int(tiles)) == (n, 1, -(-n // 128))
 
 
-@pytest.mark.parametrize("block_rows", [None, 2, 8])
-def test_held_experts_part_equals_the_dense_sum(block_rows):
+@pytest.mark.parametrize("tokens,top_k,first,held,every,block_rows", [
+    (50, 3, 4, 4, 7, None), (50, 3, 4, 4, 7, 2), (50, 3, 4, 4, 7, 8),
+    (50, 3, 16, 4, 7, 8),       # the router never chooses 16..19
+    (50, 3, 4, 4, 1, 8),        # no row is a token
+    (32, 8, 4, 4, 7, None),     # a decode step's rows: tiles of 16
+    (50, 8, 0, 16, 7, 8),       # all eight choices of every token held
+    (200, 3, 4, 4, 7, None),    # a chunk's rows: tiles of 128
+], ids=["tiles-default", "tiles-2", "tiles-8", "no-pair-held",
+        "every-row-invalid", "decode-32", "all-choices-held", "chunk-200"])
+def test_held_experts_part_equals_the_dense_sum(tokens, top_k, first, held,
+                                                every, block_rows):
     wg, wu, wd = _expert_weights(jax.random.PRNGKey(3), 16, 32, 16)
-    x = jax.random.normal(jax.random.PRNGKey(4), (50, 32))
+    x = jax.random.normal(jax.random.PRNGKey(4), (tokens, 32))
     router = jax.random.normal(jax.random.PRNGKey(5), (32, 16)) / np.sqrt(32)
     bias = 0.02 * jax.random.normal(jax.random.PRNGKey(6), (16,))
-    _, chosen, weights = moe.route_sigmoid_topk(x, router, bias, 3)
-    valid = jnp.arange(50) % 7 != 0
-    y, pairs, hit = moe.held_experts_ffn(
-        x, chosen, weights, wg[4:8], wu[4:8], wd[4:8], 4, valid=valid,
-        block_rows=block_rows)
+    _, chosen, weights = moe.route_sigmoid_topk(x, router, bias, top_k)
+    valid = jnp.arange(tokens) % every != 0
+    y, pairs, hit, tiles = moe.held_experts_ffn(
+        x, chosen, weights, wg[:held], wu[:held], wd[:held], first,
+        valid=valid, block_rows=block_rows)
     want = jnp.zeros_like(x)
-    for e in range(4, 8):
-        g = jnp.where((chosen == e) & valid[:, None], weights, 0).sum(-1)
+    counts = []
+    for e in range(held):
+        on_e = (chosen == first + e) & valid[:, None]
+        g = jnp.where(on_e, weights, 0).sum(-1)
         want += ((jax.nn.silu(x @ wg[e]) * (x @ wu[e])) @ wd[e]) * g[:, None]
+        counts.append(int(on_e.sum()))
+    assert y.shape == x.shape and y.dtype == jnp.float32
     assert float(jnp.abs(y - want).max()) < 1e-5
-    mine = (chosen >= 4) & (chosen < 8) & valid[:, None]
-    assert int(pairs) == int(mine.sum())
-    assert int(hit) == len({int(e) for e in np.asarray(chosen)[np.asarray(mine)]})
+    assert int(pairs) == sum(counts)
+    assert int(hit) == sum(c > 0 for c in counts)
+    # the loop's trip count: each expert's pairs in whole tiles, and no
+    # tile for an expert nobody chose
+    bm = block_rows or (16 if tokens <= 64 else 128)
+    assert int(tiles) == sum(-(-c // bm) for c in counts)
+    if first == 16 or every == 1:
+        assert sum(counts) == 0 and not bool(y.any())
+    if held == 16:              # the whole router: every choice is held
+        assert sum(counts) == top_k * int(valid.sum())
 
 
 def test_bias_moves_the_choice_and_not_the_weight():
@@ -284,7 +311,7 @@ def test_eight_shares_of_a_layer_add_up_to_the_uncut_layer():
     pairs = 0
     for share in range(8):
         cut = slice(2 * share, 2 * share + 2)
-        y, n, _ = moe.held_experts_ffn(
+        y, n, *_ = moe.held_experts_ffn(
             h, chosen, weights, layer["we_gate"][cut], layer["we_up"][cut],
             layer["we_down"][cut], 2 * share)
         total, pairs = total + y, pairs + int(n)
@@ -300,8 +327,8 @@ def test_eight_shares_of_a_layer_add_up_to_the_uncut_layer():
          "experts_first": 6}, "serve", max_seq_len=64)
     mine = {**layer, **{k: layer[k][6:8] for k in ("we_gate", "we_up",
                                                    "we_down")}}
-    out, (n, hit), _ = m._ffn(one, mine, jnp.zeros((1, 40, cfg.dim)), None,
-                              False)
+    out, (n, hit, _), _ = m._ffn(one, mine, jnp.zeros((1, 40, cfg.dim)),
+                                 None, False)
     assert out.shape == (1, 40, cfg.dim) and int(hit) <= 2
 
 
@@ -375,7 +402,8 @@ def test_engine_counts_what_the_steps_scored_attended_and_routed(served,
     # 63 token steps x 4 of 16 experts a token, half of them held, in 4
     # expert layers: the expectation is 504; every pair lands on an expert
     assert 300 < stats["moe_expert_rows"] < 700
-    assert 0 < stats["moe_experts_hit"] <= stats["moe_expert_rows"]
+    assert 0 < stats["moe_experts_hit"] <= stats["moe_expert_tiles"] \
+        <= stats["moe_expert_rows"]
     assert stats["moe_experts_hit"] <= 4 * 8 * stats["batches"]
     # the chunks' attention (the plain form here): every prompt token is
     # a query once, in two full layers at up to 12 selected positions

@@ -204,8 +204,8 @@ def test_chunked_prefill_then_cached_decode_agree_with_the_reference(
         err = jnp.abs(got[:, r] - want[first:first + steps + 1]).max()
         assert float(err) < 2e-4, (r, float(err))
     # the step's own count of what it sent to the held experts
-    assert cache["aux"].shape == (2,) and int(cache["aux"][0]) > 0
-    assert int(cache["aux"][1]) <= 4 * cfg.experts_held
+    pairs, hit, tiles = (int(n) for n in cache["aux"])
+    assert 0 < hit <= tiles <= pairs and hit <= 4 * cfg.experts_held
     if calls is not None:
         # one trace of the chunk program and one of the decode step:
         # every layer against the whole depth
@@ -291,7 +291,7 @@ def test_shares_of_a_layer_add_up_to_the_uncut_layer():
     pairs = 0
     for share in range(8):
         cut = slice(2 * share, 2 * share + 2)
-        y, n, _ = moe.held_experts_ffn(
+        y, n, *_ = moe.held_experts_ffn(
             h, chosen, weights, layer["we_gate"][cut], layer["we_up"][cut],
             layer["we_down"][cut], 2 * share)
         total, pairs = total + y, pairs + int(n)
@@ -310,7 +310,7 @@ def test_shares_of_a_layer_add_up_to_the_uncut_layer():
     mine = {**layer, **{k: layer[k][6:8] for k in ("we_gate", "we_up",
                                                    "we_down")}}
     x = jnp.zeros((1, 40, cfg.dim))
-    out, (n, hit), _ = dots3_note._ffn(one, mine, x, None, False)
+    out, (n, hit, _), _ = dots3_note._ffn(one, mine, x, None, False)
     assert out.shape == (1, 40, cfg.dim) and int(hit) <= 2
 
 
@@ -424,6 +424,8 @@ def test_engine_counts_live_and_read_positions_and_routed_rows(served, model):
     # expert layers: the expectation is 504
     assert 300 < stats["moe_expert_rows"] < 700
     assert 0 < stats["moe_experts_hit"] <= 4 * 8 * stats["batches"]
+    assert stats["moe_experts_hit"] <= stats["moe_expert_tiles"] \
+        <= stats["moe_expert_rows"]
     # every prompt token is a query once, in five layers, and sees every
     # position from its row's first to itself
     assert stats["prefill_latent_keys_visible"] == 5 * sum(

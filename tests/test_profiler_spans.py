@@ -403,24 +403,31 @@ def sparse_moe_run(tmp_path_factory):
 
 def test_emit_span_carries_what_the_step_counted_on_the_device(
         sparse_moe_run):
-    """`expert_rows` and `experts_hit` are decided inside the step and
-    read with its tokens; each step's pair is on the emit span that
-    hands out its tokens, and stats() sums them."""
+    """`expert_rows`, `experts_hit` and `expert_tiles` are decided inside
+    the step and read with its tokens; each step's three are on the emit
+    span that hands out its tokens, and stats() sums them."""
     eng, before, by_name = sparse_moe_run
     emit, dispatch = by_name["emit"], by_name["decode_dispatch"]
     assert len(emit) == len(dispatch) >= 5
     for e, d in zip(emit, dispatch):
-        assert {"active", "finished", "expert_rows", "experts_hit"} <= set(e)
+        assert {"active", "finished", "expert_rows", "experts_hit",
+                "expert_tiles"} <= set(e)
         # 4 expert layers, 4 of 16 experts a token, 8 held: at most 4 x 4
         # pairs a live row, and no more experts hit than pairs or held
         assert 0 <= e["expert_rows"] <= 16 * d["active"]
         assert e["experts_hit"] <= min(e["expert_rows"], 4 * 8)
         assert (e["expert_rows"] == 0) == (e["experts_hit"] == 0)
+        # an expert hit walks one tile of 16 rows, and one more for each
+        # 16 pairs it holds beyond them
+        assert e["expert_tiles"] >= e["experts_hit"]
+        assert e["expert_tiles"] <= e["experts_hit"] + e["expert_rows"] // 16
     st = eng.stats()
     assert sum(e["expert_rows"] for e in emit) == \
         st["moe_expert_rows"] - before["moe_expert_rows"] > 0
     assert sum(e["experts_hit"] for e in emit) == \
         st["moe_experts_hit"] - before["moe_experts_hit"] > 0
+    assert sum(e["expert_tiles"] for e in emit) == \
+        st["moe_expert_tiles"] - before["moe_expert_tiles"] > 0
     # a token is never read in a second host sync for them: one
     # token_sync a step, as for any model
     assert len(by_name["token_sync"]) == len(emit)
@@ -445,7 +452,8 @@ def test_dispatch_span_carries_the_modules_counters(sparse_moe_run):
     for name in names:
         assert sum(d[name] for d in by_name["decode_dispatch"]) == \
             st[name] - before[name] > 0
-    for name in names + ("moe_expert_rows", "moe_experts_hit"):
+    for name in names + ("moe_expert_rows", "moe_experts_hit",
+                         "moe_expert_tiles"):
         assert isinstance(st[name], int)
     # a model that counts nothing of its own keeps its stats as they were
     plain = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=64,
