@@ -748,7 +748,7 @@ def _write_rows(stack, li: int, new, cache_len, at=None):
     """new [b, s, w] into layer `li` of `stack` [layers, b, len, w] at
     positions `at` (default `cache_len`): one block for a scalar, one
     small in-place write a row for per-row depths (as
-    ops/attention.cached_attention writes K and V)."""
+    ops/attention._write_rows writes K and V)."""
     at = cache_len if at is None else at
     if jnp.ndim(at) == 0:
         return jax.lax.dynamic_update_slice(stack, new[None], (li, 0, at, 0))

@@ -305,7 +305,7 @@ def _decode_attend(cfg, layer, li, q, kk, vv, kc, vc, t, live):
     ring = W - 1 - t % W
     with jax.named_scope("eva_window_attn"):
         # one small write in place a row, the row cut out before it is
-        # transposed: ops/attention.cached_attention says why
+        # transposed: ops/attention._write_rows says why
         for r in range(b):
             kc = jax.lax.dynamic_update_slice(
                 kc, kk[r:r + 1].transpose(0, 2, 3, 1)[None],

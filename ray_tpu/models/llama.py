@@ -353,7 +353,12 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int | None = None
     ``[layers, b, kv_heads, len, hd]``, so that per kv head the scores are
     ``q @ K`` and the output ``probs @ V`` with both operands as they lie
     (any other order costs a layout copy of each layer, or of the whole
-    stack, per step: PERF.md, PR 25)."""
+    stack, per step: PERF.md, PR 25). A position is a lane of K and a
+    sublane row of V: a prefill chunk and the XLA decode path write a
+    row's new positions with one small `dynamic_update_slice` each, and
+    the decode kernel writes its one new position as the tiles around
+    it, 128 positions of K and 16 of V, from the block it has in VMEM
+    (`ops/attention.cached_attention`; PERF.md, PR 48)."""
     max_len = max_len or cfg.max_seq_len
     lead = (cfg.n_layers, batch, cfg.n_kv_heads)
     return {

@@ -149,32 +149,42 @@ def _kv_head_shards(mesh) -> int:
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      li, start: jax.Array, length: jax.Array, *,
-                     scale: float, block_len: int) -> jax.Array:
+                     scale: float, block_len: int,
+                     new_kv: tuple[jax.Array, jax.Array] | None = None):
     """The decode kernel on this device's kv heads: q ``[b, kv_heads,
     group, hd]`` against layer `li` of the stacked caches, row r over
     positions ``[start[r], length[r]]`` read in blocks of `block_len`.
+    With `new_kv`, the step's key and value ``[b, kv_heads, hd]``, the
+    kernel attends to them at ``length[r]`` and leaves them written
+    there: the return is then (out, k_cache, v_cache), the stacks
+    updated in place, and the caller writes nothing.
     With head_dim under a lane row (64, the hybrid model) the compiler
     holds V with positions minor, ``[hd, len]`` like K, and a kernel
     that takes V as declared costs a re-laid copy of the whole stack a
     step (1.07 GB for 32 slots x 4096; sandbox compile, PR 31): the
-    kernel is then given V in that order, which is no operation.
+    kernel is then given V in that order, which is no operation (and
+    takes no `new_kv`: a swapped view is not the stack to alias).
     Under a mesh the call runs per shard, as the flash kernel does
-    (`dot_product_attention`): kv heads attend independently."""
+    (`dot_product_attention`): kv heads attend independently, and each
+    shard writes its own."""
     from ray_tpu.ops.pallas.decode_attention import (
         decode_attention as kernel)
 
     v_positions_minor = q.shape[-1] < 128
     if v_positions_minor:
+        assert new_kv is None, q.shape
         v_cache = jnp.swapaxes(v_cache, 3, 4)
 
-    def call(q, k_cache, v_cache, li, start, length):
+    def call(q, k_cache, v_cache, li, start, length, *new_kv):
         return kernel(q, k_cache, v_cache, li, start, length, scale=scale,
                       block_len=block_len,
-                      v_positions_minor=v_positions_minor)
+                      v_positions_minor=v_positions_minor,
+                      new_kv=new_kv or None)
 
+    new_kv = new_kv or ()
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1:
-        return call(q, k_cache, v_cache, li, start, length)
+        return call(q, k_cache, v_cache, li, start, length, *new_kv)
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.parallel.mesh import spec_for
@@ -185,10 +195,44 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     v_spec = k_spec if v_positions_minor else spec_for(
         ("layers", "batch", "kv_heads", None, "head_dim"), mesh=mesh)
     rows = spec_for(("batch",), mesh=mesh)
-    return jax.shard_map(call, mesh=mesh,
-                         in_specs=(q_spec, k_spec, v_spec, P(), rows, rows),
-                         out_specs=q_spec, check_vma=False)(
-                             q, k_cache, v_cache, li, start, length)
+    new_spec = spec_for(("batch", "kv_heads", None), mesh=mesh)
+    return jax.shard_map(
+        call, mesh=mesh,
+        in_specs=(q_spec, k_spec, v_spec, P(), rows, rows)
+        + (new_spec,) * len(new_kv),
+        out_specs=(q_spec, k_spec, v_spec) if new_kv else q_spec,
+        check_vma=False)(q, k_cache, v_cache, li, start, length, *new_kv)
+
+
+def _write_rows(k_cache, v_cache, kk, vv, li, cache_len):
+    """The s new rows kk, vv ``[b, s, kv_heads, hd]`` written at
+    positions [cache_len[row], cache_len[row] + s) of layer `li` of the
+    stacked caches, in place."""
+    def write(k_cache, v_cache, kk, vv, row, at):
+        # kk, vv [rows, s, nkv, hd] -> the cache's orders, at position
+        # `at` of rows [row, row + rows) of layer li
+        k_cache = jax.lax.dynamic_update_slice(
+            k_cache, kk.transpose(0, 2, 3, 1)[None], (li, row, 0, 0, at))
+        v_cache = jax.lax.dynamic_update_slice(
+            v_cache, vv.transpose(0, 2, 1, 3)[None], (li, row, 0, at, 0))
+        return k_cache, v_cache
+
+    if jnp.ndim(cache_len) == 0:
+        # whole batch advances together (left-padded batched decode,
+        # batch-1 prefill): one block of the carry
+        return write(k_cache, v_cache, kk, vv, 0, cache_len)
+    # per-row write offsets (continuous-batching slots: each row is an
+    # independent request at its own depth, vLLM-style). One small
+    # in-place write per row, the row cut out BEFORE it is transposed:
+    # as one scatter, as a vmap of dynamic_update_slice over the batch
+    # axis, or cut from the transposed batch, the compiler re-lays the
+    # carry out for the update's layout and copies the whole cache into
+    # and out of the loop (PERF.md, PR 25; tests/test_chip_compile.py
+    # holds the step to it).
+    for r in range(kk.shape[0]):
+        k_cache, v_cache = write(k_cache, v_cache, kk[r:r + 1], vv[r:r + 1],
+                                 r, cache_len[r])
+    return k_cache, v_cache
 
 
 def cached_attention(q: jax.Array, kk: jax.Array, vv: jax.Array,
@@ -205,8 +249,9 @@ def cached_attention(q: jax.Array, kk: jax.Array, vv: jax.Array,
     caller's layer loop: the s new rows are written at positions
     [cache_len[row], cache_len[row] + s) of layer `li` in place and that
     layer is read once; nothing else of the cache is read, written or
-    copied. `cache_len` is a scalar (the batch in lock-step) or [b]
-    (per-row depths). `abs_positions` [b, s] are the slots the new rows
+    copied (one exception, below: a row that holds no request).
+    `cache_len` is a scalar (the batch in lock-step) or [b] (per-row
+    depths). `abs_positions` [b, s] are the slots the new rows
     land in, used for masking; `start` [b] (or None) hides the left-pad
     slots of each row. `scale` multiplies the scores. `rope` is (cos,
     sin, positions) for rotary embeddings on q and k, or None for a
@@ -216,9 +261,22 @@ def cached_attention(q: jax.Array, kk: jax.Array, vv: jax.Array,
     the blocks of K and V that overlap ``[start[row], cache_len[row]]``
     (`decode_attention`; a row with ``cache_len < start``, which is how
     the engine marks a slot that holds no request, reads nothing and
-    gets zeros). Prefill chunks (s > 1), the lock-step batch (a scalar
-    `cache_len`), every other platform and the shapes `decode_block_len`
-    turns down read the layer whole and mask, below.
+    gets zeros). Where the head is a whole lane row (``hd % 128 == 0``)
+    that kernel is also the step's write (PR 48): it is handed the new
+    row, attends to it at ``cache_len[row]`` and leaves it written in
+    the block it holds, so this function issues no write of its own; a
+    row that holds no request is then not written at all (the XLA
+    writes put a row computed from a token no request owns at its
+    clamped depth, position 0: nobody reads it, and `insert_row` lays a
+    request's own rows into a slot before the slot is read again).
+    With a head under a lane row (64, the hybrid model: the kernel is
+    handed V in K's order, not the stack itself), for prefill chunks
+    (s > 1), the lock-step batch (a scalar `cache_len`), on every other
+    platform and for the shapes `decode_block_len` turns down, the rows
+    are written by `_write_rows`, one small `dynamic_update_slice` of K
+    and of V a row; the last four of those read the layer whole and
+    mask, below. Which it is follows from the shapes and the platform:
+    nothing selects it.
     Returns (attn [b, s, heads * hd], k_cache, v_cache)."""
     b, s, nh, hd = q.shape
     nkv = kk.shape[2]
@@ -228,46 +286,39 @@ def cached_attention(q: jax.Array, kk: jax.Array, vv: jax.Array,
             cos, sin, positions = rope
             q = apply_rope(q, cos, sin, positions)
             kk = apply_rope(kk, cos, sin, positions)
-    with jax.named_scope("kv_update"):
-        def write(k_cache, v_cache, kk, vv, row, at):
-            # kk, vv [rows, s, nkv, hd] -> the cache's orders, at position
-            # `at` of rows [row, row + rows) of layer li
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, kk.transpose(0, 2, 3, 1)[None], (li, row, 0, 0, at))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, vv.transpose(0, 2, 1, 3)[None], (li, row, 0, at, 0))
-            return k_cache, v_cache
-
-        if jnp.ndim(cache_len) == 0:
-            # whole batch advances together (left-padded batched decode,
-            # batch-1 prefill): one block of the carry
-            k_cache, v_cache = write(k_cache, v_cache, kk, vv, 0, cache_len)
-        else:
-            # per-row write offsets (continuous-batching slots: each row
-            # is an independent request at its own depth, vLLM-style).
-            # One small in-place write per row, the row cut out BEFORE it
-            # is transposed: as one scatter, as a vmap of
-            # dynamic_update_slice over the batch axis, or cut from the
-            # transposed batch, the compiler re-lays the carry out for
-            # the update's layout and copies the whole cache into and
-            # out of the loop (PERF.md, PR 25; tests/test_chip_compile.py
-            # holds the step to it).
-            for r in range(b):
-                k_cache, v_cache = write(k_cache, v_cache, kk[r:r + 1],
-                                         vv[r:r + 1], r, cache_len[r])
+    # A decode step with per-row depths on a TPU: the decode kernel, in
+    # blocks of `block` positions. Where the head is a whole lane row the
+    # kernel also writes the new row, into the block it holds anyway.
+    block = decode_block_len(
+        nkv, hd, v_cache.shape[3], v_cache.dtype,
+        jax.sharding.get_abstract_mesh()) if (
+            s == 1 and jnp.ndim(cache_len) == 1) else None
+    kernel_writes = block is not None and hd % 128 == 0
+    if not kernel_writes:
+        with jax.named_scope("kv_update"):
+            k_cache, v_cache = _write_rows(k_cache, v_cache, kk, vv, li,
+                                           cache_len)
     with jax.named_scope("attn"):
         # Over kv-head groups, K and V as they lie in the cache: the
         # group's query heads are rows of one matmul per kv head, so no
         # GQA repeat of K or V exists anywhere.
-        block = decode_block_len(
-            nkv, hd, v_cache.shape[3], v_cache.dtype,
-            jax.sharding.get_abstract_mesh()) if (
-                s == 1 and jnp.ndim(cache_len) == 1) else None
         if block is not None:
+            new_kv = None
+            if kernel_writes:
+                # v held as projected, as the caller holds q and k: left
+                # free, the compiler makes wv's product give it a kv head
+                # at a time for the kernel's operand and copies the layer
+                # of wv transposed for that, 4 MiB a layer
+                # (tests/test_chip_compile.py::
+                # test_serve_step_reads_weights_where_they_lie)
+                new_kv = (kk[:, 0], jax.lax.optimization_barrier(
+                    vv.reshape(b, nkv * hd)).reshape(b, nkv, hd))
             attn = decode_attention(
                 q.reshape(b, nkv, group, hd), k_cache, v_cache, li,
                 jnp.zeros_like(cache_len) if start is None else start,
-                cache_len, scale=scale, block_len=block)
+                cache_len, scale=scale, block_len=block, new_kv=new_kv)
+            if kernel_writes:
+                attn, k_cache, v_cache = attn
             return attn.reshape(b, s, nh * hd), k_cache, v_cache
         k_l = jax.lax.dynamic_index_in_dim(k_cache, li, 0, keepdims=False)
         v_l = jax.lax.dynamic_index_in_dim(v_cache, li, 0, keepdims=False)

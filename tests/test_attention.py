@@ -182,8 +182,8 @@ _ROWS = [(0, -1), (0, 0), (3, _BLOCK - 2), (0, _BLOCK - 1), (0, _BLOCK),
          (0, _MAX_LEN - 1), (2 * _BLOCK + 5, 3 * _BLOCK + 9)]
 
 
-def _decode_case(hd, group, rope, nkv=2, layers=2, seed=0):
-    b = len(_ROWS)
+def _decode_case(hd, group, rope, nkv=2, layers=2, seed=0, rows=_ROWS):
+    b = len(rows)
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     dt = jnp.bfloat16
     k_cache = jax.random.normal(ks[0], (layers, b, nkv, hd, _MAX_LEN), dt)
@@ -191,8 +191,8 @@ def _decode_case(hd, group, rope, nkv=2, layers=2, seed=0):
     q = jax.random.normal(ks[2], (b, 1, nkv * group, hd), dt)
     kk = jax.random.normal(ks[3], (b, 1, nkv, hd), dt)
     vv = jax.random.normal(ks[4], (b, 1, nkv, hd), dt)
-    start = jnp.asarray([r[0] for r in _ROWS], jnp.int32)
-    cache_len = jnp.asarray([r[1] for r in _ROWS], jnp.int32)
+    start = jnp.asarray([r[0] for r in rows], jnp.int32)
+    cache_len = jnp.asarray([r[1] for r in rows], jnp.int32)
     abs_positions = cache_len[:, None]
     if rope:
         from ray_tpu.ops.rope import rope_frequencies
@@ -211,13 +211,20 @@ def _through_kernel(monkeypatch):
                         lambda *a: _BLOCK)
 
 
+def _f32(a):
+    return np.asarray(a, np.float32)
+
+
 @pytest.mark.parametrize("rope", [True, False], ids=["rope", "nope"])
 @pytest.mark.parametrize("hd,group", [(128, 2), (64, 4)])
 def test_decode_kernel_matches_the_xla_path(monkeypatch, hd, group, rope):
     """The kernel against cached_attention's XLA path, through
     cached_attention itself: the outputs of every row that holds a
     request agree within what bf16 probabilities allow, and the caches
-    returned are equal (the writes are the XLA path's own)."""
+    returned are equal over those rows. With a head of a whole lane row
+    (128) the writes are the kernel's own, and a row that holds no
+    request is left as it went in (the XLA path writes it at its clamped
+    depth, a row nobody reads); with 64 they are the XLA path's."""
     args, kw = _decode_case(hd, group, rope)
     ref, k_ref, v_ref = jax.jit(
         lambda *a: cached_attention(*a, **kw))(*args)
@@ -227,50 +234,120 @@ def test_decode_kernel_matches_the_xla_path(monkeypatch, hd, group, rope):
     assert out.dtype == ref.dtype and out.shape == ref.shape
     live = np.asarray([d >= s for s, d in _ROWS])
     np.testing.assert_allclose(
-        np.asarray(out, np.float32)[live], np.asarray(ref, np.float32)[live],
-        atol=2e-2, rtol=2e-2)
+        _f32(out)[live], _f32(ref)[live], atol=2e-2, rtol=2e-2)
     # a row that holds no request reads nothing and gets zeros
-    assert not np.asarray(out, np.float32)[~live].any()
-    np.testing.assert_array_equal(np.asarray(k_out, np.float32),
-                                  np.asarray(k_ref, np.float32))
-    np.testing.assert_array_equal(np.asarray(v_out, np.float32),
-                                  np.asarray(v_ref, np.float32))
+    assert not _f32(out)[~live].any()
+    np.testing.assert_array_equal(_f32(k_out)[:, live], _f32(k_ref)[:, live])
+    np.testing.assert_array_equal(_f32(v_out)[:, live], _f32(v_ref)[:, live])
+    empty_as = (args[3], args[4]) if hd % 128 == 0 else (k_ref, v_ref)
+    np.testing.assert_array_equal(_f32(k_out)[:, ~live],
+                                  _f32(empty_as[0])[:, ~live])
+    np.testing.assert_array_equal(_f32(v_out)[:, ~live],
+                                  _f32(empty_as[1])[:, ~live])
+
+
+# rows (start, depth before the step) at the edges of what the kernel
+# writes: the new row alone in its block, alone in its range, in the
+# cache's last position, its first and past its end, no row at all, and
+# rows that hold no request around one that does
+_WRITE_CASES = {
+    "first-of-a-block": [(0, 2 * _BLOCK), (_BLOCK - 3, _BLOCK)],
+    "length-is-start": [(37, 37), (_BLOCK, _BLOCK), (3 * _BLOCK - 1,
+                                                     3 * _BLOCK - 1)],
+    "last-of-the-cache": [(0, _MAX_LEN - 1), (_MAX_LEN - 1, _MAX_LEN - 1),
+                          (3 * _BLOCK + 1, _MAX_LEN - 1)],
+    "length-zero": [(0, 0), (0, 0)],
+    # a depth past the end is the last position, as the XLA write clamps it
+    "past-the-end": [(0, _MAX_LEN), (_BLOCK, _MAX_LEN + 3)],
+    "every-row-empty": [(0, -1), (5, 4), (0, -1)],
+    "empty-around-live": [(0, -1), (0, -1), (40, 2 * _BLOCK + 17), (0, -1),
+                          (0, 15), (0, -1)],
+}
+
+
+@pytest.mark.parametrize("case", _WRITE_CASES)
+def test_decode_kernel_writes_the_new_row_and_nothing_else(monkeypatch,
+                                                          case):
+    """With the step's K and V handed to it the kernel attends to them
+    at `length[r]` and leaves them there: the outputs of the live rows
+    are the XLA path's, and every position of the caches other than
+    `length[r]` of layer `li` of the live rows is bit for bit what went
+    in: the other layers, the rows that hold no request, and the rest
+    of the tiles written back."""
+    rows = _WRITE_CASES[case]
+    args, kw = _decode_case(128, 2, False, rows=rows, layers=3, seed=3)
+    q, kk, vv, k_cache, v_cache, _, cache_len, *_ = args
+    li = jnp.int32(1)
+    args = args[:5] + (li,) + args[6:]
+    ref, _, _ = jax.jit(lambda *a: cached_attention(*a, **kw))(*args)
+    _through_kernel(monkeypatch)
+    out, k_out, v_out = jax.jit(
+        lambda *a: cached_attention(*a, **kw))(*args)
+    live = np.asarray([d >= s for s, d in rows])
+    np.testing.assert_allclose(
+        _f32(out)[live], _f32(ref)[live], atol=2e-2, rtol=2e-2)
+    assert not _f32(out)[~live].any()
+    k_want, v_want = _f32(k_cache), _f32(v_cache)
+    for r in np.flatnonzero(live):
+        at = min(rows[r][1], _MAX_LEN - 1)
+        k_want[1, r, :, :, at] = _f32(kk)[r, 0]
+        v_want[1, r, :, at, :] = _f32(vv)[r, 0]
+    np.testing.assert_array_equal(_f32(k_out), k_want)
+    np.testing.assert_array_equal(_f32(v_out), v_want)
 
 
 def test_decode_kernel_float32_is_exact():
     """In float32 the online softmax over blocks is the XLA path's
-    softmax to rounding: the tolerance of the flash parity tests."""
+    softmax to rounding: the tolerance of the flash parity tests. So it
+    is with the new row supplied to the kernel and absent from the
+    cache, which comes back holding it."""
     from ray_tpu.ops.pallas.decode_attention import decode_attention
 
-    (q, _, _, k_cache, v_cache, li, cache_len, _, start), kw = _decode_case(
+    (q, kk, vv, k_cache, v_cache, li, cache_len, _, start), kw = _decode_case(
         128, 2, False)
-    q, k_cache, v_cache = (a.astype(jnp.float32)
-                           for a in (q, k_cache, v_cache))
+    q, kk, vv, k_cache, v_cache = (a.astype(jnp.float32)
+                                   for a in (q, kk, vv, k_cache, v_cache))
     b, _, nh, hd = q.shape
     nkv = k_cache.shape[2]
     qg = q.reshape(b, nkv, nh // nkv, hd)
+    live = np.asarray(cache_len >= start)
+    rows = np.flatnonzero(live)
+    at = np.asarray(cache_len)[rows]
+    k_with = k_cache.at[li, rows, :, :, at].set(kk[rows, 0])
+    v_with = v_cache.at[li, rows, :, at, :].set(vv[rows, 0])
+
+    def reference(k_cache, v_cache):
+        pos = jnp.arange(_MAX_LEN)
+        mask = (pos >= start[:, None]) & (pos <= cache_len[:, None])
+        s = jnp.einsum("bngd,bndk->bngk", qg, k_cache[li]) * kw["scale"]
+        p = jax.nn.softmax(jnp.where(mask[:, None, None], s, -1e30), axis=-1)
+        return jnp.einsum("bngk,bnkd->bngd", p, v_cache[li])
+
     out = decode_attention(qg, k_cache, v_cache, li, start, cache_len,
                            scale=kw["scale"], block_len=_BLOCK)
-    pos = jnp.arange(_MAX_LEN)
-    mask = (pos >= start[:, None]) & (pos <= cache_len[:, None])
-    s = jnp.einsum("bngd,bndk->bngk", qg, k_cache[li]) * kw["scale"]
-    p = jax.nn.softmax(jnp.where(mask[:, None, None], s, -1e30), axis=-1)
-    ref = jnp.einsum("bngk,bnkd->bngd", p, v_cache[li])
-    live = np.asarray(cache_len >= start)
-    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
-                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(out)[live], np.asarray(reference(k_cache, v_cache))[live],
+        atol=2e-5, rtol=2e-5)
+    out, k_out, v_out = decode_attention(
+        qg, k_cache, v_cache, li, start, cache_len, scale=kw["scale"],
+        block_len=_BLOCK, new_kv=(kk[:, 0], vv[:, 0]))
+    np.testing.assert_allclose(
+        np.asarray(out)[live], np.asarray(reference(k_with, v_with))[live],
+        atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(k_out), np.asarray(k_with))
+    np.testing.assert_array_equal(np.asarray(v_out), np.asarray(v_with))
 
 
 def test_decode_kernel_under_a_mesh_that_splits_kv_heads(monkeypatch):
     """Under a mesh whose tensor axis splits kv_heads the kernel runs
     per shard (a Mosaic kernel cannot be partitioned by the compiler)
-    and gives what one device gives."""
+    and gives what one device gives, the caches it returns too."""
     from jax.sharding import NamedSharding
 
     from ray_tpu.parallel.mesh import build_mesh, spec_for
 
     args, kw = _decode_case(128, 2, True, nkv=4)
-    ref, _, _ = jax.jit(lambda *a: cached_attention(*a, **kw))(*args)
+    ref, k_ref, v_ref = jax.jit(lambda *a: cached_attention(*a, **kw))(*args)
     _through_kernel(monkeypatch)
     mesh = build_mesh({"data": 1, "tensor": 2}, jax.devices()[:2])
 
@@ -290,12 +367,15 @@ def test_decode_kernel_under_a_mesh_that_splits_kv_heads(monkeypatch):
         with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
             return cached_attention(*a, **kw)
 
-    out, k_out, _ = jax.jit(sharded)(*args)
+    out, k_out, v_out = jax.jit(sharded)(*args)
     assert "tensor" in str(k_out.sharding.spec)
+    assert "tensor" in str(v_out.sharding.spec)
     live = np.asarray([d >= s for s, d in _ROWS])
     np.testing.assert_allclose(
-        np.asarray(out, np.float32)[live], np.asarray(ref, np.float32)[live],
-        atol=2e-2, rtol=2e-2)
+        _f32(out)[live], _f32(ref)[live], atol=2e-2, rtol=2e-2)
+    # each shard wrote the new row of its own kv heads
+    np.testing.assert_array_equal(_f32(k_out)[:, live], _f32(k_ref)[:, live])
+    np.testing.assert_array_equal(_f32(v_out)[:, live], _f32(v_ref)[:, live])
 
 
 def test_decode_kernel_names_a_new_block_only_for_a_live_one():

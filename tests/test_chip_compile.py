@@ -127,38 +127,47 @@ def test_flash_kernel_compiles_for_the_chip(chip, compiled_not_interpreted,
     assert f"f32[{b},{h},{s},{d}]" not in text
 
 
-@pytest.mark.parametrize("layers,batch,kv_heads,group,head_dim", [
-    (24, 8, 8, 2, 128),     # InternLM2-1.8B, the chat cell's 8 slots
-    (4, 32, 8, 4, 64),      # granite-4.0-h-micro's attention layers
-], ids=["internlm2-8x4096", "granite-32x4096"])
+@pytest.mark.parametrize("layers,batch,kv_heads,group,head_dim,writes", [
+    (24, 8, 8, 2, 128, False),  # InternLM2-1.8B, the chat cell's 8 slots
+    (4, 32, 8, 4, 64, False),   # granite-4.0-h-micro's attention layers
+    (24, 8, 8, 2, 128, True),   # the chat cell's, writing the new rows
+], ids=["internlm2-8x4096", "granite-32x4096", "internlm2-8x4096-writes"])
 def test_decode_kernel_compiles_for_the_chip(chip, on_the_chip, layers,
                                              batch, kv_heads, group,
-                                             head_dim):
+                                             head_dim, writes):
     """The decode kernel alone at the serve cells' shapes, in the blocks
     `decode_block_len` gives them: it fits VMEM at both and takes the
     stacks as they lie (temporaries under a MiB). With head_dim 64 that
     is V in K's order: the compiler holds such a V with positions minor,
     and re-lays the whole stack out (1.07 GB here) for a kernel that
-    takes it as declared."""
+    takes it as declared. Handed the step's new K and V, the kernel
+    returns the stacks in the buffers they came in (PR 48)."""
     max_len = 4096
     on = SingleDeviceSharding(chip)
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=on)
 
-    args = (arg((batch, kv_heads, group, head_dim)),
-            arg((layers, batch, kv_heads, head_dim, max_len)),
-            arg((layers, batch, kv_heads, max_len, head_dim)),
+    stacks = (arg((layers, batch, kv_heads, head_dim, max_len)),
+              arg((layers, batch, kv_heads, max_len, head_dim)))
+    args = (arg((batch, kv_heads, group, head_dim)), *stacks,
             arg((), jnp.int32), arg((batch,), jnp.int32),
             arg((batch,), jnp.int32))
+    new = (arg((batch, kv_heads, head_dim)),) * 2 if writes else ()
     block = attention.decode_block_len(kv_heads, head_dim, max_len,
                                        jnp.bfloat16, jax.sharding.Mesh(
                                            [chip], ("tensor",)))
     assert block == 2 ** 20 // (kv_heads * head_dim * 2)
     compiled = jax.jit(lambda *a: attention.decode_attention(
-        *a, scale=head_dim ** -0.5, block_len=block)).lower(*args).compile()
+        *a[:6], scale=head_dim ** -0.5, block_len=block,
+        new_kv=a[6:] or None), donate_argnums=(1, 2) if writes else ()
+        ).lower(*args, *new).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
-    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 ** 20
+    if writes:
+        assert mem.alias_size_in_bytes == sum(
+            2 * math.prod(a.shape) for a in stacks)
     if head_dim < 128:
         as_declared = jax.jit(lambda *a: da.decode_attention(
             *a, scale=head_dim ** -0.5, block_len=block)).lower(
@@ -226,8 +235,11 @@ def test_decode_step_moves_no_cache(chip, request, batch, max_len, s,
     produces an array of a whole stack's shape, and no array of positions
     x head_dim is as large as a layer's K repeated over its group's query
     heads. On the chip the decode step's attention is the decode kernel
-    (PR 31), which takes the stacks as they lie: the same rule, with one
-    Mosaic call in the layer loop; a chunk keeps the XLA path."""
+    (PR 31), which takes the stacks as they lie and, since PR 48, leaves
+    the new rows written in them: one Mosaic call in the layer loop,
+    whose results are the stacks, and NO `dynamic-update-slice` of a
+    stack's shape in that program. A chunk keeps the XLA path and its
+    writes."""
     if request.node.callspec.id.endswith("on-chip"):
         request.getfixturevalue("on_the_chip")
     cfg = llama.LlamaConfig(max_seq_len=max_len, **_SERVE_CFG)
@@ -241,16 +253,24 @@ def test_decode_step_moves_no_cache(chip, request, batch, max_len, s,
     assert mem.temp_size_in_bytes < min(0.5e9, 2 * layer_bytes)
     assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * layer_bytes
     repeated = batch * max_len * cfg.n_heads * cfg.head_dim
+    may_give_a_stack = ("parameter", "get-tuple-element") + (
+        () if kernels else ("dynamic-update-slice",))
     for line in compiled.as_text().splitlines():
         m = _RESULT.match(line)
         if not m:
             continue
         dims = tuple(int(d) for d in m.group(2).split(",") if d)
         if dims in stacks:
-            assert m.group(3) in ("parameter", "get-tuple-element",
-                                  "dynamic-update-slice"), line[:200]
+            assert m.group(3) in may_give_a_stack, line[:200]
         elif max_len in dims and cfg.head_dim in dims:
             assert math.prod(dims) < repeated, line[:200]
+    if kernels:
+        # the kernel's results: the attention's rows and both stacks
+        call, = (line for line in compiled.as_text().splitlines()
+                 if "tpu_custom_call" in line and " custom-call(" in line)
+        for dims in stacks:
+            assert "[" + ",".join(map(str, dims)) + "]" in call.split(
+                " custom-call(")[0], call[:300]
 
 
 def _computations(text):

@@ -494,29 +494,40 @@ def test_restart_on_a_new_loop_drops_the_step_in_flight():
 
 
 # ------------------------------------------- live rows, live positions
-_KERNEL_BLOCK = 32
+# decode path -> (the model served, the kernel's block of positions).
+# With a head of a whole lane row the kernel also writes the step's new
+# K and V rows (PR 48); "debug" has heads of 16, whose rows the XLA
+# writes lay in
+_DECODE_PATHS = {
+    "xla": ("debug", None),
+    "kernel": ("debug", 32),
+    "kernel-writes": (llama.LlamaConfig(
+        vocab_size=256, dim=256, n_layers=2, n_heads=2, n_kv_heads=1,
+        hidden_dim=128, max_seq_len=256), 128),
+}
 
 
-@pytest.fixture(params=["xla", "kernel"])
+@pytest.fixture(params=list(_DECODE_PATHS))
 def decode_path(request, monkeypatch):
     """The decode step's attention on the XLA path, as every platform
     but a TPU takes it, and through the decode kernel (interpreted, in
-    blocks of _KERNEL_BLOCK positions), as a TPU does."""
-    if request.param == "kernel":
+    blocks of the path's positions), as a TPU does."""
+    block = _DECODE_PATHS[request.param][1]
+    if block:
         from ray_tpu.ops import attention
 
         # the engine asks the model's module (`decode_read_block`), which
         # asks ops/attention.py, as the step's own attention does
         monkeypatch.setattr(attention, "_on_tpu", lambda: True)
         monkeypatch.setattr(attention, "decode_block_len",
-                            lambda *a: _KERNEL_BLOCK)
+                            lambda *a: block)
     return request.param
 
 
-def _small_engine(max_batch=2):
-    return LLMEngine("debug", tp=1, max_batch=max_batch, max_seq_len=256,
-                     prompt_buckets=(16, 64), prefill_chunk=0,
-                     prefix_cache_entries=0)
+def _small_engine(path, max_batch=2):
+    return LLMEngine(_DECODE_PATHS[path][0], tp=1, max_batch=max_batch,
+                     max_seq_len=256, prompt_buckets=(16, 64),
+                     prefill_chunk=0, prefix_cache_entries=0)
 
 
 def test_request_in_a_left_slot_streams_as_in_a_fresh_engine(decode_path):
@@ -525,7 +536,7 @@ def test_request_in_a_left_slot_streams_as_in_a_fresh_engine(decode_path):
     the greedy tokens of a request admitted into it, beside a stream
     that is still live, are those a fresh engine gives it, whatever the
     slot's row held before (a deeper bucket's K and V here)."""
-    eng = _small_engine()
+    eng = _small_engine(decode_path)
     leaver = (list(range(1, 41)), 4)        # bucket 64, leaves first
     stayer = ([17, 4, 9], 120)
     late = ([5, 9, 11], 12)                 # bucket 16, into the left slot
@@ -541,7 +552,7 @@ def test_request_in_a_left_slot_streams_as_in_a_fresh_engine(decode_path):
         return c, await b
 
     got_late, got_stayer = asyncio.run(run())
-    fresh = _small_engine()
+    fresh = _small_engine(decode_path)
     assert got_late == _collect(fresh, late[0], max_new_tokens=late[1])
     assert got_stayer == _collect(fresh, stayer[0],
                                   max_new_tokens=stayer[1])
@@ -554,7 +565,7 @@ def test_kv_position_counters_rise_with_dispatched_steps_only(decode_path):
     positions inside the live rows' [start, length];
     `decode_kv_positions_read` those of the blocks the step's attention
     is asked to read: at least the live ones, at most the whole cache."""
-    eng = _small_engine()
+    eng = _small_engine(decode_path)
     keys = ("batches", "decode_kv_positions_live",
             "decode_kv_positions_read")
     _collect(eng, [3, 8, 1], max_new_tokens=1)     # a prefill, no step
@@ -572,9 +583,9 @@ def test_kv_position_counters_rise_with_dispatched_steps_only(decode_path):
     whole = steps * eng.max_batch * eng.cfg.max_seq_len
     read = st["decode_kv_positions_read"]
     assert st["decode_kv_positions_live"] <= read <= whole
-    if decode_path == "kernel":
+    if _DECODE_PATHS[decode_path][1]:
         # bucket 16: the row's one block, and none for the empty slot
-        assert read == steps * _KERNEL_BLOCK
+        assert read == steps * _DECODE_PATHS[decode_path][1]
     else:
         assert read == whole
     _collect(eng, [3, 8, 1], max_new_tokens=1)
