@@ -14,7 +14,7 @@ Design (idiomatic JAX, nothing torch-shaped):
   ``lax.scan`` — one trace/compile of one block regardless of depth.
 * Every parameter has a tuple of *logical axis names*
   (``param_logical_axes``); ``ray_tpu.parallel.mesh.shard_params`` maps
-  them to mesh axes, so DP/FSDP/TP/SP/EP are just different rule tables.
+  them to mesh axes, so DP/FSDP/TP/SP are just different rule tables.
 * Compute in bf16, params f32 (configurable), softmax/norm/rope in f32.
 * ``jax.checkpoint`` around each block (policy: save nothing but dots'
   inputs) trades FLOPs for HBM — the standard TPU recipe.
@@ -27,18 +27,15 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from functools import partial
 from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 # decode_read_block is this module's (models.module_for): K and V of
 # n_kv_heads x head_dim in every attention layer
 from ray_tpu.ops.attention import (cached_attention,  # noqa: F401
                                    decode_read_block, dot_product_attention)
-from ray_tpu.ops.cross_entropy import softmax_cross_entropy
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
@@ -70,24 +67,6 @@ class LlamaConfig:
     # LoRA: scale numerator for the low-rank path (scale = alpha / rank,
     # rank inferred from the adapter's shape; see models/lora.py)
     lora_alpha: float = 16.0
-    # MoE: >0 replaces every dense FFN with a mixture of this many experts
-    # (EP over the `expert` mesh axis; see ops/moe.py)
-    moe_num_experts: int = 0
-    moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
-    moe_aux_loss_weight: float = 0.01
-
-    @property
-    def moe(self) -> bool:
-        return self.moe_num_experts > 0
-
-    def moe_config(self):
-        from ray_tpu.ops.moe import MoEConfig
-
-        return MoEConfig(num_experts=self.moe_num_experts,
-                         top_k=self.moe_top_k,
-                         capacity_factor=self.moe_capacity_factor,
-                         aux_loss_weight=self.moe_aux_loss_weight)
 
     @property
     def head_dim(self) -> int:
@@ -143,21 +122,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> dict:
         "wo": dense(next(k), (L, nh * hd, d), nh * hd),
         "attn_norm": jnp.ones((L, d), pd),
         "mlp_norm": jnp.ones((L, d), pd),
+        "w_gate": dense(next(k), (L, d, h), d),
+        "w_up": dense(next(k), (L, d, h), d),
+        "w_down": dense(next(k), (L, h, d), h),
     }
-    if cfg.moe:
-        E = cfg.moe_num_experts
-        layers.update({
-            "router": dense(next(k), (L, d, E), d),
-            "w_gate": dense(next(k), (L, E, d, h), d),
-            "w_up": dense(next(k), (L, E, d, h), d),
-            "w_down": dense(next(k), (L, E, h, d), h),
-        })
-    else:
-        layers.update({
-            "w_gate": dense(next(k), (L, d, h), d),
-            "w_up": dense(next(k), (L, d, h), d),
-            "w_down": dense(next(k), (L, h, d), h),
-        })
     params = {
         "embed": dense(next(k), (cfg.vocab_size, d), d),
         "layers": layers,
@@ -178,20 +146,10 @@ def param_logical_axes(cfg: LlamaConfig) -> dict:
         "wo": ("layers", "heads", "embed"),
         "attn_norm": ("layers", None),
         "mlp_norm": ("layers", None),
+        "w_gate": ("layers", "embed", "mlp"),
+        "w_up": ("layers", "embed", "mlp"),
+        "w_down": ("layers", "mlp", "embed"),
     }
-    if cfg.moe:
-        layer_axes.update({
-            "router": ("layers", "embed", None),
-            "w_gate": ("layers", "expert", "embed", "mlp"),
-            "w_up": ("layers", "expert", "embed", "mlp"),
-            "w_down": ("layers", "expert", "mlp", "embed"),
-        })
-    else:
-        layer_axes.update({
-            "w_gate": ("layers", "embed", "mlp"),
-            "w_up": ("layers", "embed", "mlp"),
-            "w_down": ("layers", "mlp", "embed"),
-        })
     axes = {
         "embed": ("vocab", "embed"),
         "layers": layer_axes,
@@ -220,8 +178,7 @@ def _proj(cfg: LlamaConfig, layer: dict, name: str, h):
 
 
 def _block(cfg: LlamaConfig, x, layer, cos, sin, positions):
-    """One transformer block. x: [b, s, d] (cfg.dtype).
-    Returns (x, moe_aux_loss) — aux is 0 for the dense path.
+    """One transformer block. x: [b, s, d] (cfg.dtype) -> x.
 
     When the layer dict carries LoRA adapters ("<w>_a"/"<w>_b", stacked
     like the base weights — see models/lora.py), the low-rank path
@@ -255,25 +212,15 @@ def _block(cfg: LlamaConfig, x, layer, cos, sin, positions):
 
     with jax.named_scope("mlp"):
         h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        if cfg.moe:
-            from ray_tpu.ops.moe import moe_ffn
-
-            moe_params = {"router": layer["router"],
-                          "w_gate": layer["w_gate"],
-                          "w_up": layer["w_up"], "w_down": layer["w_down"]}
-            out, aux = moe_ffn(moe_params, h, cfg.moe_config())
-            return x + out, aux
         gate = jax.nn.silu(proj("w_gate", h))
         up = proj("w_up", h)
         x = x + proj("w_down", gate * up)
-    return x, jnp.zeros((), jnp.float32)
+    return x
 
 
 def backbone(params: dict, tokens: jax.Array, cfg: LlamaConfig,
-             positions: jax.Array | None = None,
-             with_aux: bool = False):
-    """tokens: [b, s] int32 -> final hidden states [b, s, d] (cfg.dtype),
-    or (hidden, moe_aux_loss) when with_aux.
+             positions: jax.Array | None = None) -> jax.Array:
+    """tokens: [b, s] int32 -> final hidden states [b, s, d] (cfg.dtype).
 
     The layer stack is one lax.scan over stacked weights; each step is
     optionally rematerialized.
@@ -288,10 +235,8 @@ def backbone(params: dict, tokens: jax.Array, cfg: LlamaConfig,
         # they ride the same scan as the base weights (models/lora.py)
         scanned_layers = {**scanned_layers, **params["lora"]["layers"]}
 
-    def step(carry, layer):
-        x, aux_sum = carry
-        x, aux = _block(cfg, x, layer, cos, sin, positions)
-        return (x, aux_sum + aux), None
+    def step(x, layer):
+        return _block(cfg, x, layer, cos, sin, positions), None
 
     if cfg.remat:
         if cfg.remat_policy == "nothing":
@@ -303,12 +248,8 @@ def backbone(params: dict, tokens: jax.Array, cfg: LlamaConfig,
             raise ValueError(
                 f"unknown remat_policy {cfg.remat_policy!r}")
         step = jax.checkpoint(step, policy=policy)
-    (x, aux_sum), _ = jax.lax.scan(
-        step, (x, jnp.zeros((), jnp.float32)), scanned_layers)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if with_aux:
-        return x, aux_sum
-    return x
+    x, _ = jax.lax.scan(step, x, scanned_layers)
+    return rms_norm(x, params["final_norm"], cfg.norm_eps)
 
 
 def _head_matrix(params: dict, cfg: LlamaConfig) -> jax.Array:
@@ -332,12 +273,11 @@ def loss_fn(params: dict, batch: dict, cfg: LlamaConfig):
     """
     from ray_tpu.ops.cross_entropy import fused_lm_head_cross_entropy
 
-    x, moe_aux = backbone(params, batch["tokens"], cfg, with_aux=True)
+    x = backbone(params, batch["tokens"], cfg)
     with jax.named_scope("ce"):   # the head's matmul is fused into it
         ce_loss, n_tok = fused_lm_head_cross_entropy(
             x, _head_matrix(params, cfg), batch["targets"])
-    loss = ce_loss + moe_aux
-    return loss, {"loss": ce_loss, "tokens": n_tok, "moe_aux": moe_aux}
+    return ce_loss, {"loss": ce_loss, "tokens": n_tok}
 
 
 # ----------------------------------------------------------------- decoding
@@ -378,8 +318,8 @@ def kv_cache_logical_axes() -> dict:
             "length": (), "start": ("batch",)}
 
 
-# The names serve/llm.py's engine calls on whichever model module serves
-# its config (models/granite_hybrid.py has the same set).
+# The names serve/llm.py's engine reads of whichever model module serves
+# its config (models.REQUIRED; module_for says what each means).
 TENSOR_PARALLEL = True
 CACHE_LEN_AXIS = KV_LEN_AXIS
 init_cache = init_kv_cache

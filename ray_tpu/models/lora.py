@@ -84,10 +84,6 @@ def init_lora_params(cfg: LlamaConfig, lora: LoraConfig,
             f"LoraConfig.alpha={lora.alpha} != LlamaConfig.lora_alpha="
             f"{cfg.lora_alpha}; set them consistently (e.g. "
             f"config_for(name, lora_alpha=...))")
-    if cfg.moe and any(t in ("w_gate", "w_up", "w_down")
-                       for t in lora.targets):
-        raise ValueError("LoRA on MoE expert FFNs is not supported; "
-                         "use attention targets")
     L, r = cfg.n_layers, lora.rank
     pd = cfg.param_dtype
     layers: dict = {}

@@ -1,152 +1,23 @@
-"""Mixture-of-Experts layer: top-k router + capacity-bounded dispatch +
-grouped expert FFN (GShard/Switch formulation).
+"""Dropless routed-expert layer for one holder of experts: the `noaux_tc`
+router over all routed experts (`route_sigmoid_topk`) and the part of
+the layer that the holder of some of them computes (`held_experts_ffn`).
 
-The reference framework has NO expert parallelism (SURVEY.md §2.4 —
-verified absent); this is TPU-native core-op territory. Design follows
-the GShard/Mesh-TF einsum recipe rather than a scatter/gather kernel:
-
-* routing produces a dispatch one-hot [tokens, E, C] and combine weights;
-* expert inputs form via one einsum, the expert FFN is a single grouped
-  matmul ("ecd,edh->ech") over a leading expert dim, outputs combine via
-  another einsum;
-* under GSPMD the expert dim carries the `expert` mesh axis, so XLA
-  lowers the dispatch/combine einsums to all_to_all over ICI and the
-  grouped matmul to per-device expert shards — no hand-written
-  collectives, static shapes throughout (capacity bounds make it
-  jit-compatible; overflow tokens are dropped, the standard trade).
+No capacity and no dropped token: the token-expert pairs are grouped by
+expert and a loop over the tiles that exist multiplies each by its
+expert's matrices. The models that route (`models/dots3_note.py`, and
+`models/kimi_k2.py` through it) hold a shard of the experts on one
+chip; the exchange between holders under a mesh with an `expert` axis
+is not here.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import math
-from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 
-@dataclasses.dataclass(frozen=True)
-class MoEConfig:
-    num_experts: int = 8
-    top_k: int = 2
-    capacity_factor: float = 1.25
-    # aux load-balancing loss weight (Switch Transformer eq. 4)
-    aux_loss_weight: float = 0.01
-
-
-def init_moe_params(key: jax.Array, dim: int, hidden_dim: int,
-                    cfg: MoEConfig, dtype=jnp.float32) -> dict:
-    """Router + per-expert SwiGLU FFN weights (stacked on a leading
-    expert axis, the EP analog of the stacked-layers scan trick)."""
-    k1, k2, k3, k4, k5 = jax.random.split(key, 5)
-    e, d, h = cfg.num_experts, dim, hidden_dim
-
-    def dense(rng, shape, fan_in):
-        return (jax.random.normal(rng, shape, jnp.float32)
-                / math.sqrt(fan_in)).astype(dtype)
-
-    return {
-        "router": dense(k1, (d, e), d),
-        "w_gate": dense(k2, (e, d, h), d),
-        "w_up": dense(k3, (e, d, h), d),
-        "w_down": dense(k4, (e, h, d), h),
-    }
-
-
-def moe_logical_axes() -> dict:
-    return {
-        "router": ("embed", "expert_logits"),
-        "w_gate": ("expert", "embed", "mlp"),
-        "w_up": ("expert", "embed", "mlp"),
-        "w_down": ("expert", "mlp", "embed"),
-    }
-
-
-def _route(router_logits: jax.Array, cfg: MoEConfig, capacity: int):
-    """router_logits [T, E] -> (dispatch [T, E, C] bool-ish f32,
-    combine [T, E, C] f32, aux_loss scalar).
-
-    Top-k routing with per-expert capacity: the c-th token routed to an
-    expert takes slot c; tokens beyond capacity are dropped (their
-    combine weight is 0 and the residual path carries them).
-    """
-    T, E = router_logits.shape
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-
-    # top-k expert choices per token
-    top_probs, top_idx = jax.lax.top_k(probs, cfg.top_k)     # [T, k]
-    # renormalize chosen gates so they sum to 1 (Mixtral convention)
-    top_probs = top_probs / jnp.maximum(
-        top_probs.sum(-1, keepdims=True), 1e-9)
-
-    # aux load-balancing loss: mean prob per expert x fraction routed
-    onehot_topk = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)  # [T,k,E]
-    routed_frac = onehot_topk.sum(axis=(0, 1)) / (T * cfg.top_k)
-    mean_prob = probs.mean(axis=0)
-    aux_loss = E * jnp.sum(routed_frac * mean_prob)
-
-    dispatch = jnp.zeros((T, E, capacity), jnp.float32)
-    combine = jnp.zeros((T, E, capacity), jnp.float32)
-    # position of each (token, choice) within its expert's queue:
-    # cumulative count of earlier assignments to the same expert
-    for k in range(cfg.top_k):
-        onehot = onehot_topk[:, k, :]                          # [T, E]
-        if k == 0:
-            prior = jnp.zeros((T, E), jnp.float32)
-        else:
-            prior = onehot_topk[:, :k, :].sum(axis=1)
-        # earlier tokens' assignments (all k slots) + this token's
-        # earlier-k assignments
-        pos_within = (jnp.cumsum(onehot_topk.sum(axis=1), axis=0)
-                      - onehot_topk.sum(axis=1)) + prior       # [T, E]
-        slot = (pos_within * onehot).sum(-1).astype(jnp.int32)  # [T]
-        keep = (pos_within * onehot).sum(-1) < capacity
-        slot_oh = jax.nn.one_hot(jnp.where(keep, slot, capacity),
-                                 capacity + 1,
-                                 dtype=jnp.float32)[:, :capacity]  # [T, C]
-        d_k = onehot[:, :, None] * slot_oh[:, None, :]          # [T, E, C]
-        dispatch = dispatch + d_k
-        combine = combine + d_k * top_probs[:, k][:, None, None]
-    return dispatch, combine, aux_loss
-
-
-def moe_ffn(params: dict, x: jax.Array, cfg: MoEConfig,
-            activation=jax.nn.silu) -> tuple[jax.Array, jax.Array]:
-    """x: [b, s, d] -> (out [b, s, d], aux_loss scalar).
-
-    Static-shape capacity dispatch; the grouped matmuls keep a leading
-    [E] dim that GSPMD shards over the `expert` mesh axis. Routing is
-    per batch row ("group" in GShard terms) so the one-hot dispatch
-    tensor is [b, s, E, C] with C ~ s/E — bounded, not O((b*s)^2/E).
-    """
-    b, s, d = x.shape
-    E = cfg.num_experts
-    capacity = max(1, int(cfg.capacity_factor * cfg.top_k * s / E))
-    router_logits = jnp.einsum(
-        "gsd,de->gse", x.astype(jnp.float32),
-        params["router"].astype(jnp.float32))
-    dispatch, combine, aux_loss = jax.vmap(
-        lambda lg: _route(lg, cfg, capacity))(router_logits)
-    aux_loss = aux_loss.mean()
-
-    dt = x.dtype
-    # dispatch: [g, s, E, C] x [g, s, d] -> expert inputs [E, g, C, d]
-    expert_in = jnp.einsum("gsec,gsd->egcd", dispatch.astype(dt), x)
-    # grouped SwiGLU FFN over the leading expert dim
-    gate = activation(jnp.einsum(
-        "egcd,edh->egch", expert_in, params["w_gate"].astype(dt)))
-    up = jnp.einsum("egcd,edh->egch", expert_in, params["w_up"].astype(dt))
-    expert_out = jnp.einsum(
-        "egch,ehd->egcd", gate * up, params["w_down"].astype(dt))
-    # combine: [g, s, E, C] x [E, g, C, d] -> [g, s, d]
-    out = jnp.einsum("gsec,egcd->gsd", combine.astype(dt), expert_out)
-    return out, aux_loss * cfg.aux_loss_weight
-
-
-# --------------------------------------------------------------------------
-# Dropless expert layer for one shard of the experts (serving)
-# --------------------------------------------------------------------------
 def route_sigmoid_topk(x: jax.Array, router: jax.Array, bias: jax.Array,
                        top_k: int, *, normalize: bool = True,
                        scaling: float = 1.0):

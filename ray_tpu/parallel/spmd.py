@@ -15,7 +15,6 @@ Usage:
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Any, Callable
 
 import jax
@@ -23,27 +22,20 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu.parallel.mesh import (AXIS_DATA, AXIS_FSDP, DEFAULT_RULES,
-                                   shard_params, spec_for)
+from ray_tpu.parallel.mesh import DEFAULT_RULES, shard_params, spec_for
 
 
-def batch_sharding(mesh: Mesh, seq_axis: bool = False) -> NamedSharding:
-    """Batch dim sharded over data×fsdp (DP); optionally seq dim over `seq`."""
-    logical = ("batch", "seq") if seq_axis else ("batch",)
-    return NamedSharding(mesh, spec_for(logical, None, mesh))
+def batch_sharding(mesh: Mesh) -> NamedSharding:
+    """Batch dim sharded over data×fsdp (DP)."""
+    return NamedSharding(mesh, spec_for(("batch",), None, mesh))
 
 
-def shard_batch(batch: Any, mesh: Mesh, seq_axis: bool = False) -> Any:
-    sh = batch_sharding(mesh, seq_axis)
+def shard_batch(batch: Any, mesh: Mesh) -> Any:
+    sh = batch_sharding(mesh)
 
     def put(x):
         x = jnp.asarray(x)
-        if x.ndim == 0:
-            return jax.device_put(x, NamedSharding(mesh, P()))
-        if seq_axis and x.ndim >= 2:
-            return jax.device_put(x, sh)
-        return jax.device_put(
-            x, NamedSharding(mesh, P(sh.spec[0])))
+        return jax.device_put(x, sh if x.ndim else NamedSharding(mesh, P()))
     return jax.tree.map(put, batch)
 
 

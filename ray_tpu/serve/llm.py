@@ -153,43 +153,29 @@ class LLMEngine:
     (`decode_overlapped` of `batches`) and what the look-ahead wasted
     (`decode_rows_discarded`).
 
-    `model` is a model config (a `llama.LlamaConfig`, a
-    `granite_hybrid.GraniteHybridConfig`) or the name of a llama preset;
-    the module that serves it (`models.module_for`) is asked for
-    everything the engine needs to know about the model. "The cache" is
-    that module's pytree: besides the bookkeeping leaves `length` and
-    `start`, every leaf has a batch axis (where its logical axes say
-    "batch"), so a request's ROW is that leaf at one batch index, and
-    `CACHE_LEN_AXIS` names the leaves that also have a position axis as
-    deep as the cache (K and V; a latent-attention model's latent rows
-    and index keys). A leaf not named there is recurrent: what the
-    whole prefix left behind, valid at the one position it was computed
-    to, and grafted whole. That is a state-space layer's state, and also
-    a sliding-window layer's RING of the last positions, whose depth
-    does not follow the bucket or `max_seq_len`: a request's prefill
-    cache holds a ring of the same shape, in which position p lies in
-    row p mod ring, and the slot's positions are the bucket's, so
-    `insert_row` copies the ring as it stands (the chunked prefill
-    needs the ring between chunks anyway; cutting "the last window" out
-    of a bucket-deep leaf would mean holding that leaf, 45 MB a layer at
-    20,480, to keep 1.4 MB of it). A third such leaf holds a WINDOW of
-    exact rows and, behind it on the same axis, one summary a chunk of
-    everything before (`models/evabyte.py`): a prefill cache's leaf has
-    fewer summaries and the same origin, so it is grafted as it lies.
-    `stats()["cache_bytes"]` files every
-    leaf under its kind: `kv`, `state`, or what the module's
-    `CACHE_KIND` names (`latent`, `index`, `window`, `summary`; two
-    kinds for one leaf where the module says how they share it). Beside `length`
-    and `start` a module may keep a third bookkeeping leaf, `aux`: the
-    counters its last step decided on the device (`STEP_AUX`).
-
-    What a decode step reads is the module's to say: `decode_read_block`
-    (the positions in a block of its attention's reads, or None where a
-    step reads a layer whole) and, where it has them, `decode_counters`
-    (further counters of `stats()`, from the live rows' ranges and the
-    rows the step has) and
-    `prefill_counters` (the same of a prefill call's attention, from
-    where its row starts and where the call's tokens lie).
+    `model` is a model config or the name of a llama preset. The module
+    that serves it is `models.module_for(cfg)`, and everything the
+    engine knows of the model it reads from that module by the names of
+    `models.REQUIRED` and `models.OPTIONAL`; `module_for`'s docstring
+    says what each means. What the engine makes of them: "the cache" is
+    the module's pytree, and a request's ROW is every leaf but the
+    bookkeeping (`length`, `start`, `aux`) at one index of its batch
+    axis. A leaf that `CACHE_LEN_AXIS` names is cut and grafted by
+    position, as deep as the bucket. A leaf it does not name is
+    recurrent: what the whole prefix left behind, valid at the one
+    position it was computed to, and grafted whole. That is a
+    state-space layer's state, and also a sliding-window layer's RING
+    of the last positions, whose depth does not follow the bucket or
+    `max_seq_len`: a request's prefill cache holds a ring of the same
+    shape, in which position p lies in row p mod ring, and the slot's
+    positions are the bucket's, so `insert_row` copies the ring as it
+    stands (the chunked prefill needs the ring between chunks anyway;
+    cutting "the last window" out of a bucket-deep leaf would mean
+    holding that leaf, 45 MB a layer at 20,480, to keep 1.4 MB of it).
+    A third such leaf holds a WINDOW of exact rows and, behind it on the
+    same axis, one summary a chunk of everything before
+    (`models/evabyte.py`): a prefill cache's leaf has fewer summaries
+    and the same origin, so it is grafted as it lies.
 
     A request's prefill cache is made when its first prefill call is
     dispatched, not when it is admitted: of the prompts admitted and
@@ -264,7 +250,7 @@ class LLMEngine:
         recurrent = set(self._batch_axis) - set(self._len_axis)
         shapes = jax.eval_shape(lambda: mod.init_cache(
             cfg, max_batch, max_len=cfg.max_seq_len))
-        kinds = getattr(mod, "CACHE_KIND", {})
+        kinds = mod.CACHE_KIND
         self._cache_bytes = {"kv": 0, "state": 0}
         for name in self._batch_axis:
             kind = kinds.get(name, "state" if name in recurrent else "kv")
@@ -277,19 +263,13 @@ class LLMEngine:
                     + nbytes
         # counters the model's step decides on the device and returns
         # in cache["aux"]: field of the emit span -> counter of stats()
-        self._aux = dict(getattr(mod, "STEP_AUX", {}))
+        self._aux = dict(mod.STEP_AUX)
         self._aux_totals = dict.fromkeys(self._aux.values(), 0)
         # what the module counts of a step's live ranges and of a
         # chunk's attention, summed
-        self._decode_counters = getattr(mod, "decode_counters", None)
-        self._prefill_counters = getattr(mod, "prefill_counters", None)
         self._model_counters = dict.fromkeys(
-            self._decode_counters(cfg, [], max_batch), 0) \
-            if self._decode_counters \
-            else {}
-        if self._prefill_counters:
-            self._model_counters.update(dict.fromkeys(
-                self._prefill_counters(cfg, 0, 0, 0, cfg.max_seq_len), 0))
+            {**mod.decode_counters(cfg, [], max_batch),
+             **mod.prefill_counters(cfg, 0, 0, 0, cfg.max_seq_len)}, 0)
 
         def step(params, cache, tokens, key, temperature):
             decode = tokens.ndim == 1
@@ -756,9 +736,8 @@ class LLMEngine:
         call is asynchronous: its device time is scope `prefill` in a
         profiler trace, not this span. Returns (sampled token, cache)."""
         bucket = prompts.shape[1]
-        counters = self._prefill_counters(
-            self.cfg, bucket - len(req.tokens), pos, chunk, bucket) \
-            if self._prefill_counters else {}
+        counters = self._model.prefill_counters(
+            self.cfg, bucket - len(req.tokens), pos, chunk, bucket)
         with _site("rayt.engine.prefill_chunk",
                    f"prefill_chunk[{chunk}@{bucket}]",
                    request_id=req.request_id,
@@ -1047,8 +1026,7 @@ class LLMEngine:
         self.decode_kv_positions_live += live
         self.decode_kv_positions_read += read
         for name, n in counters.items():
-            self._model_counters[name] = self._model_counters.get(
-                name, 0) + n
+            self._model_counters[name] += n
         return _InFlight(nxt, rows, active)
 
     def _kv_positions(self, rows: list, prev: Optional[_InFlight]):
@@ -1066,8 +1044,8 @@ class LLMEngine:
                   + int(prev is not None and prev.rows[i] is req))
                  for i, req in enumerate(rows) if req is not None]
         live = sum(last - start + 1 for start, last in spans)
-        counters = self._decode_counters(self.cfg, spans, self.max_batch) \
-            if self._decode_counters else {}
+        counters = self._model.decode_counters(
+            self.cfg, spans, self.max_batch)
         block = self._decode_block
         if not block:
             return live, self.max_batch * self.cfg.max_seq_len, counters

@@ -246,8 +246,10 @@ def test_all_tokens_to_one_expert_lose_none(choices):
     (32, 8, 4, 4, 7, None),     # a decode step's rows: tiles of 16
     (50, 8, 0, 16, 7, 8),       # all eight choices of every token held
     (200, 3, 4, 4, 7, None),    # a chunk's rows: tiles of 128
+    (50, 3, 4, 1, 7, None),     # ONE held expert: a plain SwiGLU FFN
 ], ids=["tiles-default", "tiles-2", "tiles-8", "no-pair-held",
-        "every-row-invalid", "decode-32", "all-choices-held", "chunk-200"])
+        "every-row-invalid", "decode-32", "all-choices-held", "chunk-200",
+        "one-expert-held"])
 def test_held_experts_part_equals_the_dense_sum(tokens, top_k, first, held,
                                                 every, block_rows):
     wg, wu, wd = _expert_weights(jax.random.PRNGKey(3), 16, 32, 16)

@@ -100,8 +100,7 @@ def test_pipeline_llama_blocks(cpu_mesh_devices):
         x = x.astype(cfg.dtype)
 
         def step(xx, layer):
-            y, _ = llama._block(cfg, xx, layer, cos, sin, None)
-            return y, None
+            return llama._block(cfg, xx, layer, cos, sin, None), None
 
         x, _ = jax.lax.scan(step, x, stage_layers)
         return x.astype(jnp.float32)
@@ -117,8 +116,7 @@ def test_pipeline_llama_blocks(cpu_mesh_devices):
 
     # reference: plain scan over all layers
     def step(xx, layer):
-        y, _ = llama._block(cfg, xx, layer, cos, sin, None)
-        return y, None
+        return llama._block(cfg, xx, layer, cos, sin, None), None
 
     ref, _ = jax.lax.scan(step, x0.astype(cfg.dtype), params["layers"])
     np.testing.assert_allclose(out, ref.astype(jnp.float32),

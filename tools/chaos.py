@@ -11,9 +11,6 @@ Fault primitives cover the planes this runtime can lose:
   recompile-and-resume, dag/recovery.py);
 * ``kill_worker_node`` — SIGKILL a node manager (sudden node loss:
   lineage re-execution, lease revocation, object recovery);
-* ``drain_node`` — deadline-bound graceful drain (planned preemption:
-  make-before-break actor migration, serve replica handoff, PG gang
-  rescheduling, object evacuation — the node ends DRAINED, not DEAD);
 * ``bounce_head`` — SIGKILL + same-port restart of the GCS (head HA:
   snapshot reload, client reconnect, serve controller checkpoint);
 * ``kill_serve_controller`` — the serve control plane (handles keep
@@ -97,24 +94,6 @@ class ChaosMonkey:
         handle = (self.rng.choice(nodes) if index is None
                   else nodes[index])
         self.cluster.remove_node(handle, graceful=False)
-        return handle.node_id_hex
-
-    def drain_node(self, index: Optional[int] = None, *,
-                   deadline_s: Optional[float] = None,
-                   reason: str = "chaos drain") -> str:
-        """Graceful drain (the preemption-notice path minus the notice
-        file): placement stops, workloads migrate make-before-break,
-        the node ends DRAINED — the opposite contract to
-        kill_worker_node, which tests the unplanned-loss paths."""
-        import ray_tpu as rt
-
-        if self.cluster is None or not self.cluster.worker_nodes:
-            raise RuntimeError("no worker nodes to drain")
-        nodes = self.cluster.worker_nodes
-        handle = (self.rng.choice(nodes) if index is None
-                  else nodes[index])
-        if not rt.drain_node(handle.node_id_hex, deadline_s, reason):
-            raise RuntimeError(f"drain of {handle.node_id_hex} rejected")
         return handle.node_id_hex
 
     def bounce_head(self, down_s: float = 0.5) -> str:
