@@ -30,17 +30,19 @@ OPTIONAL = {"decode_counters": _counts_nothing,
 
 def registry() -> tuple:
     """The table `module_for` walks: (config type, module), one row a
-    model file. The four beside llama are imported when the table is
+    model file. The five beside llama are imported when the table is
     first asked for: they bring `jax.experimental.pallas` with them,
     which a process that imports this package for llama alone (a train
     worker, a driver) need not pay for."""
-    from ray_tpu.models import dots3_note, evabyte, granite_hybrid, kimi_k2
+    from ray_tpu.models import (dots3_note, evabyte, granite_hybrid, kimi_k2,
+                                laguna)
 
     return ((llama.LlamaConfig, llama),
             (granite_hybrid.GraniteHybridConfig, granite_hybrid),
             (dots3_note.Dots3NoteConfig, dots3_note),
             (evabyte.EvaByteConfig, evabyte),
-            (kimi_k2.KimiK2Config, kimi_k2))
+            (kimi_k2.KimiK2Config, kimi_k2),
+            (laguna.LagunaConfig, laguna))
 
 
 def module_for(cfg):
@@ -56,7 +58,8 @@ def module_for(cfg):
     every leaf has a "batch" axis. `CACHE_LEN_AXIS`: the leaves with a
     position axis as deep as the cache, and that axis; every other leaf
     with a batch axis is grafted whole (a recurrent state, a window's
-    ring). `decode_step(params, cache, tokens, cfg)`: append tokens
+    ring: of latent rows in `dots3_note`, of K and V in
+    `laguna`). `decode_step(params, cache, tokens, cfg)`: append tokens
     [b, s], return the last position's logits and the cache, same tree,
     shapes and dtypes. `decode_read_block(cfg, mesh)`: the positions in a
     block of a decode step's cache reads, or None where it reads a layer

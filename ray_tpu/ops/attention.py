@@ -11,6 +11,15 @@ it); here it is a core op. Two paths:
 
 `impl="auto"` picks flash on TPU for long sequences, xla otherwise.
 GQA (n_kv_heads < n_heads) handled in both paths.
+
+Serving reads a stacked KV cache through `cached_attention` (shared by
+`models/llama.py`, `granite_hybrid.py`, `evabyte.py` and, for its full
+layers, `models/laguna.py`): a decode step with per-row depths on a TPU
+is `decode_attention` (ops/pallas/decode_attention.py; laguna's window
+layers call it over their ring in its `ring` mode), everything else the
+masked XLA form, which holds a layer's scores whole. A model whose
+chunks meet a cache too deep for that takes its own kernel
+(`models/laguna.py`: ops/pallas/gqa_chunk_attention.py).
 """
 
 from __future__ import annotations
@@ -150,14 +159,17 @@ def _kv_head_shards(mesh) -> int:
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      li, start: jax.Array, length: jax.Array, *,
                      scale: float, block_len: int,
-                     new_kv: tuple[jax.Array, jax.Array] | None = None):
+                     new_kv: tuple[jax.Array, jax.Array] | None = None,
+                     ring: bool = False):
     """The decode kernel on this device's kv heads: q ``[b, kv_heads,
     group, hd]`` against layer `li` of the stacked caches, row r over
     positions ``[start[r], length[r]]`` read in blocks of `block_len`.
     With `new_kv`, the step's key and value ``[b, kv_heads, hd]``, the
     kernel attends to them at ``length[r]`` and leaves them written
     there: the return is then (out, k_cache, v_cache), the stacks
-    updated in place, and the caller writes nothing.
+    updated in place, and the caller writes nothing. With `ring` the
+    caches are a window layer's ring, `block_len` deep (the kernel's
+    docstring).
     With head_dim under a lane row (64, the hybrid model) the compiler
     holds V with positions minor, ``[hd, len]`` like K, and a kernel
     that takes V as declared costs a re-laid copy of the whole stack a
@@ -179,7 +191,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         return kernel(q, k_cache, v_cache, li, start, length, scale=scale,
                       block_len=block_len,
                       v_positions_minor=v_positions_minor,
-                      new_kv=new_kv or None)
+                      new_kv=new_kv or None, ring=ring)
 
     new_kv = new_kv or ()
     mesh = jax.sharding.get_abstract_mesh()

@@ -5,9 +5,9 @@ the layer that the holder of some of them computes (`held_experts_ffn`).
 No capacity and no dropped token: the token-expert pairs are grouped by
 expert and a loop over the tiles that exist multiplies each by its
 expert's matrices. The models that route (`models/dots3_note.py`, and
-`models/kimi_k2.py` through it) hold a shard of the experts on one
-chip; the exchange between holders under a mesh with an `expert` axis
-is not here.
+`models/kimi_k2.py` and `models/laguna.py` through its `_ffn`) hold a
+shard of the experts on one chip; the exchange between holders under a
+mesh with an `expert` axis is not here.
 """
 
 from __future__ import annotations

@@ -52,6 +52,15 @@ were, and the tiles' hand-back for rows that hold nothing.) The sixteen
 `dynamic_update_slice`s a layer that wrote these rows before cost a
 decode round as much as reading every live position (0.89 ms of 7.0;
 PERF.md, PR 48).
+
+A window layer's RING (`ring`, PR 51; models/laguna.py) is the same
+call over a cache one block deep in which position p lies in row ``p
+mod block_len``: row j then holds the newest position congruent to j
+that is not past `length`, it counts where that position is not before
+`start` (every row, once the request is as deep as the ring), and the
+new row stands, and is written, at ``length mod block_len`` and not at
+the range's end. Nothing else differs: a row's one block is read, the
+tiles around the new row are cut from it and written back.
 """
 
 from __future__ import annotations
@@ -100,7 +109,7 @@ def _named_block(bi, j, start_ref, len_ref, block_len: int,
 
 def _kernel(li_ref, start_ref, len_ref, q_ref, k_ref, v_ref, *refs,
             scale: float, block_len: int, num_blocks: int,
-            v_positions_minor: bool, writes: bool):
+            v_positions_minor: bool, writes: bool, ring: bool):
     if writes:
         (kn_ref, vn_ref, o_ref, ko_ref, vo_ref, m_scratch, l_scratch,
          acc_scratch, kt_scratch, vt_scratch, sems) = refs
@@ -113,7 +122,8 @@ def _kernel(li_ref, start_ref, len_ref, q_ref, k_ref, v_ref, *refs,
     if writes:
         # where the new row stands: a depth past the cache's end is its
         # last position, as the XLA path's `dynamic_update_slice` clamps it
-        at = jnp.minimum(length, block_len * num_blocks - 1)
+        at = (jax.lax.rem(jnp.maximum(length, 0), block_len) if ring
+              else jnp.minimum(length, block_len * num_blocks - 1))
 
     @pl.when(j == 0)
     def _init():
@@ -133,8 +143,15 @@ def _kernel(li_ref, start_ref, len_ref, q_ref, k_ref, v_ref, *refs,
             jnp.int32, s.shape, 2)
         # with the new row in hand, its position is that row's and what
         # the cache holds there does not count
-        s = jnp.where((pos >= start) & (
-            pos < at if writes else pos <= length), s, NEG_INF)
+        if ring:
+            # how far behind `length` the position lies that row `pos`
+            # of the ring holds; 0 is the new row's own
+            behind = jax.lax.rem(length - pos + block_len, block_len)
+            seen = (length - behind >= start) & (
+                behind != 0 if writes else True)
+        else:
+            seen = (pos >= start) & (pos < at if writes else pos <= length)
+        s = jnp.where(seen, s, NEG_INF)
         m_prev = m_scratch[:, :, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         if writes:
@@ -216,7 +233,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      li, start: jax.Array, length: jax.Array, *,
                      scale: float, block_len: int,
                      v_positions_minor: bool = False,
-                     new_kv: tuple[jax.Array, jax.Array] | None = None):
+                     new_kv: tuple[jax.Array, jax.Array] | None = None,
+                     ring: bool = False):
     """q ``[b, kv_heads, group, hd]``, one token per row; k_cache, v_cache
     the stacked caches (with `v_positions_minor`, V given in K's order,
     ``[layers, b, kv_heads, hd, len]``); `li` the layer; row r attends
@@ -231,11 +249,17 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     (V as declared only; a depth past the cache's end is taken as its
     last position, as `dynamic_update_slice` clamps it). Returns (out,
     k_cache, v_cache), the caches aliased to the inputs: in place where
-    the caller donates them, every other position as it was."""
+    the caller donates them, every other position as it was.
+
+    With `ring` the cache is a ring one block deep (``block_len`` its
+    depth): row r attends to the newest `block_len` positions up to
+    ``length[r]`` that are not before ``start[r]``, each in row ``p mod
+    block_len``, and the new row stands at ``length[r] mod block_len``."""
     b, nkv, group, hd = q.shape
     max_len = k_cache.shape[4]
     assert max_len % block_len == 0, (max_len, block_len)
     num_blocks = max_len // block_len
+    assert not ring or num_blocks == 1, (max_len, block_len)
     writes = new_kv is not None
 
     def named(bi, j, start_ref, len_ref):
@@ -289,7 +313,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         functools.partial(_kernel, scale=scale, block_len=block_len,
                           num_blocks=num_blocks,
                           v_positions_minor=v_positions_minor,
-                          writes=writes),
+                          writes=writes, ring=ring),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,

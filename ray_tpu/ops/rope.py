@@ -1,4 +1,10 @@
-"""Rotary position embeddings (RoPE)."""
+"""Rotary position embeddings (RoPE): the table form every llama-shaped
+model reads (`rope_frequencies`, `apply_rope`), YaRN's frequencies, and
+the form for a model whose layers do not all turn alike
+(`apply_partial_rope`: a leading part of each head, at frequencies and
+under a factor the caller gives; models/laguna.py turns half a head
+under YaRN in its full layers and the whole head, plain, in its sliding
+ones)."""
 
 from __future__ import annotations
 
@@ -85,3 +91,25 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
     out = jnp.concatenate(
         [x1f * cos - x2f * sin, x2f * cos + x1f * sin], axis=-1)
     return out.astype(dtype)
+
+
+def apply_partial_rope(x: jax.Array, positions: jax.Array, inv_freq,
+                       factor: float = 1.0) -> jax.Array:
+    """Rotate the FIRST ``2 * len(inv_freq)`` numbers of each head
+    (rotated halves inside that part: its first half against its
+    second) and pass the rest as projected. x: [..., seq, heads,
+    head_dim]; positions: [..., seq]; `inv_freq` [n] float32 (plain
+    ``theta^(-2i/dim)`` or `yarn_inv_freq`). cos and sin are multiplied
+    by `factor`: YaRN's `attention_factor` where a config puts the
+    temperature there and not on the softmax scale. Angles are computed
+    from the positions in float32, so no table bounds the depth."""
+    inv_freq = jnp.asarray(inv_freq, jnp.float32)
+    half = inv_freq.shape[0]
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos = (jnp.cos(ang) * factor)[..., None, :]
+    sin = (jnp.sin(ang) * factor)[..., None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:2 * half].astype(jnp.float32)
+    turned = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    return jnp.concatenate(
+        [t.astype(x.dtype) for t in turned] + [x[..., 2 * half:]], axis=-1)

@@ -812,3 +812,132 @@ def test_kimi_k2_chunk_holds_no_array_of_every_pair(chip, on_the_chip):
                                    "bitcast")
                     or "calls=%bitcast_fusion" in line), line[:200]
     assert compiled.memory_analysis().temp_size_in_bytes < 549e6 - 139e6
+
+
+@functools.cache
+def _laguna_step(chip, batch, s, max_len):
+    """(config, cache shapes, compiled `decode_step`) of models/laguna.py
+    at the Laguna-S-2.1 cell's sizes (5 layers, 64 of 256 experts, a
+    quarter of the vocabulary), the cache donated; compiled once a shape
+    (10 s each), under the `on_the_chip` fixture of whichever test asks
+    first."""
+    from ray_tpu.models import laguna
+
+    cfg = laguna.LagunaConfig(vocab_size=25088, n_layers=5, experts_held=64,
+                              max_seq_len=24576)
+    on = SingleDeviceSharding(chip)
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=on), tree)
+
+    params = place(jax.eval_shape(
+        lambda key: laguna.init_params(cfg, key), jax.random.PRNGKey(0)))
+    cache = dict(jax.eval_shape(
+        lambda: laguna.init_cache(cfg, batch, max_len)))
+    if s == 1:
+        cache["length"] = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    tokens = jax.ShapeDtypeStruct((batch, s), jnp.int32, sharding=on)
+    compiled = jax.jit(
+        lambda p, c, t: laguna.decode_step(p, c, t, cfg),
+        donate_argnums=(1,)).lower(params, place(cache), tokens).compile()
+    return cfg, cache, compiled
+
+
+@pytest.mark.parametrize("batch,s,max_len", [
+    (32, 1, 24576),       # the engine's decode step, the cell's 32 slots
+    (1, 1024, 20480),     # one chunk of the deepest bucket's prefill
+], ids=["decode-32x24576-on-chip", "chunk-1x1024@20480"])
+def test_laguna_step_holds_no_scores_and_moves_no_cache(
+        chip, on_the_chip, batch, s, max_len):
+    """models/laguna.py at the Laguna-S-2.1 cell's sizes and published
+    widths under the rule the other models' steps are held to: weights,
+    caches and temporaries fit 15.75 GB; with the cache donated K and V
+    of the full layers (6.44 GB for the slots) and the rings are written
+    where they lie, and nothing but parameters, the in-place writes and
+    the kernels' results has a whole stack's shape. One Mosaic call a
+    layer: the decode step's two full layers through the decode kernel,
+    which writes its row, and its three rings through the same kernel's
+    ring mode, which writes its own too (no `dynamic-update-slice` of a
+    stack in that program); a chunk's five through
+    ops/pallas/gqa_chunk_attention.py, so that no array of queries by
+    the cache's depth (one head's scores, 1,024 x 20,480, let alone `[s,
+    heads, len]` in float32) exists outside VMEM."""
+    from ray_tpu.models import laguna
+
+    cfg, cache, compiled = _laguna_step(chip, batch, s, max_len)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == cfg.n_layers
+    leaves = ("k", "v", "window_k", "window_v")
+    stacks = {tuple(cache[key].shape) for key in leaves}
+    stack_bytes = sum(math.prod(cache[key].shape) * 2 for key in leaves)
+    if s == 1:
+        assert stack_bytes == 6_442_450_944 + 201_326_592
+        assert laguna.decode_read_block(cfg, jax.sharding.Mesh(
+            [chip], ("tensor",))) == 512
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75e9)
+    assert mem.temp_size_in_bytes < (0.05e9 if s == 1 else 0.3e9)
+    assert mem.alias_size_in_bytes >= stack_bytes
+    deep = {tuple(cache[key].shape) for key in ("k", "v")}
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if dims in deep or (s == 1 and dims in stacks):
+            assert m.group(3) in (
+                ("parameter", "get-tuple-element", "custom-call") if s == 1
+                else ("parameter", "get-tuple-element",
+                      "dynamic-update-slice", "bitcast")), line[:200]
+        # a chunk's scores against the deep cache, in any dtype
+        assert s == 1 or not (s in dims and max_len in dims), line[:200]
+        # wq and w_o are read where they lie: q and k are held as
+        # projected (`laguna._qkv`), or every step copies wq transposed,
+        # 56 MB a sliding layer (my chip run, PR 51: a quarter of the
+        # device time under no phase)
+        if m.group(3) == "copy":
+            assert sorted(dims) not in ([3072, 6144], [3072, 9216]), \
+                line[:200]
+
+
+def _without_locations(text: str) -> str:
+    """Compiled HLO without what a moved line changes: the tables of
+    files and frames, the instructions' metadata, and the Mosaic calls'
+    serialized bodies (whose debug locations name lines; the kernel's
+    jaxpr is held beside it)."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"backend_config=\{[^}]*\}", "", text)
+    text = re.sub(r"stack_frame_id=[0-9]*", "", text)
+    return "\n".join(line for line in text.splitlines()
+                     if not re.match(r'^[0-9]+ ["{]', line))
+
+
+def test_chat_decode_step_is_the_program_it_was(chip, on_the_chip):
+    """The chat and long-prompt cells' decode step (InternLM2-1.8B, 8
+    slots x 4096, per-row depths, as a TPU runs it) compiles to the
+    program PR 51's parent compiled it to: the decode kernel gained a
+    `ring` mode for models/laguna.py's window layers, and with the mode
+    off neither the kernel's jaxpr nor the step around it may differ.
+    (A PR that means to change this program computes both digests anew,
+    on its parent and on itself.)"""
+    import hashlib
+
+    cfg = llama.LlamaConfig(max_seq_len=4096, **_SERVE_CFG)
+    compiled, cache = _compiled_decode_step(chip, cfg, 8, 4096, 1, True)
+    digest = lambda text: hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert digest(_without_locations(compiled.as_text())) == \
+        "8a49ebb0af5efd01"
+    b, hd = 8, cfg.head_dim
+    sd = jax.ShapeDtypeStruct
+    new = sd((b, cfg.n_kv_heads, hd), jnp.bfloat16)
+    kernel = jax.make_jaxpr(
+        lambda q, k, v, li, start, length, kn, vn: da.decode_attention(
+            q, k, v, li, start, length, scale=hd ** -0.5, block_len=512,
+            new_kv=(kn, vn)))(
+        sd((b, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, hd),
+           jnp.bfloat16), cache["k"], cache["v"], sd((), jnp.int32),
+        sd((b,), jnp.int32), sd((b,), jnp.int32), new, new)
+    assert digest(re.sub(r" at 0x[0-9a-f]+", "", str(kernel))) == \
+        "d26f2496b372ed36"

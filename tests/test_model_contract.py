@@ -1,7 +1,7 @@
 """Every model module of `models.registry()` against the contract that
 `models.module_for` states and `serve/llm.py`'s engine relies on. The
 cases come from the registry and from `models.REQUIRED` / `OPTIONAL`: a
-sixth module is covered by its row there and its smallest config here."""
+seventh module is covered by its row there and its smallest config here."""
 
 import json
 import os
@@ -11,11 +11,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from benchmarks import (latent_moe_model, manifest as manifest_mod, rehearsal,
-                        sparse_moe_model)
+from benchmarks import (gqa_moe_model, latent_moe_model,
+                        manifest as manifest_mod, rehearsal, sparse_moe_model)
 from ray_tpu import models
 from ray_tpu.models import (dots3_note, evabyte, granite_hybrid, kimi_k2,
-                            llama)
+                            laguna, llama)
 
 B, N, CHUNK = 7, 32, 16     # slots, cache depth, a prefill chunk
 BOOKKEEPING = ("length", "start", "aux")
@@ -51,6 +51,8 @@ SMALLEST = {
         dtype=jnp.float32, param_dtype=jnp.float32),
     kimi_k2.KimiK2Config: lambda: _rehearsal_twin(
         "Kimi-K2.6.json", latent_moe_model.program_config),
+    laguna.LagunaConfig: lambda: _rehearsal_twin(
+        "Laguna-S-2.1.json", gqa_moe_model.program_config),
 }
 
 

@@ -5,6 +5,7 @@ forms `ops/attention.cached_attention` has for them:
 
   python3 tools/decode_attention_probe.py [--live 4,8] [--rows 8]
       [--layers 24] [--max-len 4096] [--repeat 7] [--seed 0]
+      [--ring] [--depths 100,1500]
 
 `writes+kernel`: sixteen `dynamic_update_slice`s a layer (one of K and
 one of V a slot) and then the decode kernel, which is what every decode
@@ -24,7 +25,12 @@ dispatch cost, to be taken off the others. The line `agree` compares the
 two forms after one round from the same stacks: the largest absolute
 difference of the outputs over the live rows, and whether the caches are
 equal bit for bit over the live rows and, in the empty rows, equal to
-what went in. Nothing here is the benchmark's (PERF.md, PR 48).
+what went in. With --ring the stacks are a window layer's rings,
+--max-len rows deep (models/laguna.py: `--ring --rows 32 --live 32
+--layers 3 --max-len 512 --group 9 --depths 600,20000` is the Laguna
+cell's): one block a row in the kernel's `ring` mode, the new row at
+``length mod max_len``. Nothing here is the benchmark's (PERF.md, PRs 48
+and 51).
 """
 
 from __future__ import annotations
@@ -50,6 +56,8 @@ def main():
     ap.add_argument("--head-dim", type=int, default=128)
     ap.add_argument("--repeat", type=int, default=7)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--ring", action="store_true")
+    ap.add_argument("--depths", default="100,1500")
     args = ap.parse_args()
 
     import jax
@@ -63,8 +71,8 @@ def main():
                                           args.kv_heads, args.group,
                                           args.head_dim, args.max_len)
     dev = jax.devices()[0]
-    block = attention.decode_block_len(nkv, hd, max_len, dt,
-                                       jax.sharding.get_abstract_mesh())
+    block = max_len if args.ring else attention.decode_block_len(
+        nkv, hd, max_len, dt, jax.sharding.get_abstract_mesh())
     print(json.dumps({"device": dev.device_kind, "platform": dev.platform,
                       "k": [layers, b, nkv, hd, max_len],
                       "block_len": block}), flush=True)
@@ -82,10 +90,11 @@ def main():
     def kernel(k, v, li, q, length, new_kv):
         return attention.decode_attention(
             q, k, v, li, start, length, scale=scale, block_len=block,
-            new_kv=new_kv)
+            new_kv=new_kv, ring=args.ring)
 
     def writes_then_kernel(k, v, li, q, kk, vv, length):
-        k, v = attention._write_rows(k, v, kk, vv, li, length)
+        at = jnp.maximum(length, 0) % max_len if args.ring else length
+        k, v = attention._write_rows(k, v, kk, vv, li, at)
         return kernel(k, v, li, q, length, None), k, v
 
     def kernel_writes(k, v, li, q, kk, vv, length):
@@ -125,7 +134,8 @@ def main():
     for live in (int(n) for n in args.live.split(",") if n):
         depth = np.full((b,), -1, np.int32)
         at = rng.permutation(b)[:live]
-        depth[at] = rng.integers(100, 1501, size=live)
+        lo, hi = (int(n) for n in args.depths.split(","))
+        depth[at] = rng.integers(lo, hi + 1, size=live)
         length = jnp.asarray(depth)
         holds = jnp.asarray(depth >= 0)
         case = {"live": live, "depths": depth.tolist()}
