@@ -3,8 +3,9 @@ router over all routed experts (`route_sigmoid_topk`) and the part of
 the layer that the holder of some of them computes (`held_experts_ffn`).
 
 No capacity and no dropped token: the token-expert pairs are grouped by
-expert and a loop over the tiles that exist multiplies each by its
-expert's matrices. The models that route (`models/dots3_note.py`, and
+expert and one grouped-matmul kernel (ops/pallas/held_experts.py) walks
+the tiles that exist, each against its expert's matrices as they stream
+in. The models that route (`models/dots3_note.py`, and
 `models/kimi_k2.py` and `models/laguna.py` through its `_ffn`) hold a
 shard of the experts on one chip; the exchange between holders under a
 mesh with an `expert` axis is not here.
@@ -12,10 +13,10 @@ mesh with an `expert` axis is not here.
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops.pallas.held_experts import held_experts
 
 
 def route_sigmoid_topk(x: jax.Array, router: jax.Array, bias: jax.Array,
@@ -38,8 +39,7 @@ def route_sigmoid_topk(x: jax.Array, router: jax.Array, bias: jax.Array,
 
 def held_experts_ffn(x: jax.Array, chosen: jax.Array, weights: jax.Array,
                      w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-                     first: int = 0, *, valid: jax.Array | None = None,
-                     block_rows: int | None = None):
+                     first: int = 0, *, valid: jax.Array | None = None):
     """The part of a routed expert layer that THIS holder of experts
     computes: ``sum_j weights[t, j] * SwiGLU_e(x[t])`` over the chosen
     experts e = chosen[t, j] that lie in [first, first + held), `held`
@@ -49,22 +49,24 @@ def held_experts_ffn(x: jax.Array, chosen: jax.Array, weights: jax.Array,
     is what each shard runs; the exchange between shards is not here).
 
     No capacity and no dropped token. The token-expert pairs are laid
-    out by expert, each expert's group padded to whole tiles of
-    `block_rows` rows, and a loop over the tiles THAT EXIST (its trip
-    count is decided on the device) multiplies each by its expert's
-    matrices and adds its rows, weighted in float32, into their tokens'
-    rows of y: an expert no token chose is never read, one that many
-    chose takes as many tiles as it needs, and nothing of T x k rows of
-    d is written, gathered or summed; only the layout's integers are
-    sized for the worst case (every pair on one held expert). x: [T, d];
-    chosen, weights: [T, k]; valid: [T] bool or None, rows that are no
-    token (padding, a slot that holds no request) and reach no expert.
-    Returns (y [T, d] float32, pairs computed, held experts hit, tiles
-    walked)."""
+    out by expert, each expert's group padded to whole tiles of 16 rows
+    (a decode step's T) or 128 (a chunk's), and ONE kernel
+    (ops/pallas/held_experts.py) walks the tiles THAT EXIST: it reads
+    each tile's expert where the stacks lie, the next tile's matrices in
+    flight while this one's multiply, gathers the tile's rows of x and
+    adds its results, weighted in float32, into their tokens' rows of y
+    on the chip's own memory. An expert no token chose is never read,
+    one that many chose takes as many tiles as it needs, and nothing of
+    T x k rows of d is written, gathered or summed; only the layout's
+    integers are sized for the worst case (every pair on one held
+    expert). x: [T, d]; chosen, weights: [T, k]; valid: [T] bool or
+    None, rows that are no token (padding, a slot that holds no request)
+    and reach no expert. Returns (y [T, d] float32, pairs computed, held
+    experts hit, tiles walked)."""
     T, d = x.shape
     k = chosen.shape[1]
     held = w_gate.shape[0]
-    bm = block_rows or (16 if T <= 64 else 128)
+    bm = 16 if T <= 64 else 128
     pairs = T * k
     n_slots = -(-pairs // bm) * bm + held * bm
 
@@ -81,43 +83,21 @@ def held_experts_ffn(x: jax.Array, chosen: jax.Array, weights: jax.Array,
     slot = jnp.where(mine, offsets[jnp.minimum(local, held - 1)] + rank,
                      n_slots)                                 # [pairs]
     token = jnp.repeat(jnp.arange(T, dtype=jnp.int32), k)
-    # a padding row is no token: its index lies past y and its add is
-    # dropped
-    slot_token = jnp.full((n_slots,), T, jnp.int32).at[slot].set(
+    # a padding row is no token: the kernel stops at the tile's count
+    slot_token = jnp.zeros((n_slots,), jnp.int32).at[slot].set(
         token, mode="drop")
     slot_weight = jnp.zeros((n_slots,), jnp.float32).at[slot].set(
         weights.reshape(pairs).astype(jnp.float32), mode="drop")
+    tile_start = jnp.arange(n_slots // bm) * bm
+    # compared with every end: the default's binary search is a `while`
     tile_expert = jnp.minimum(jnp.searchsorted(
-        ends, jnp.arange(n_slots // bm) * bm, side="right"), held - 1)
+        ends, tile_start, side="right", method="compare_all"), held - 1)
+    # a group fills its tiles from the first row on: a tile's tokens are
+    # its first `tile_rows` rows, none past the tiles that exist
+    tile_rows = jnp.clip(counts[tile_expert] - (
+        tile_start - offsets[tile_expert]), 0, bm)
     tiles = ends[-1] // bm
-    # the scatter pays for a padding row what it pays for a token's (0.2
-    # us of 7,168 float32; my chip run, PR 47): a tile's rows are added
-    # 16 at a time, as far as its tokens reach
-    sub = math.gcd(bm, 16)
-
-    def tile(i, y):
-        e = tile_expert[i]
-        rows = jax.lax.dynamic_slice_in_dim(slot_token, i * bm, bm)
-        g = jax.lax.dynamic_slice_in_dim(slot_weight, i * bm, bm)
-        xt = jnp.take(x, rows, axis=0, mode="clip")
-        pick = lambda w: jax.lax.dynamic_index_in_dim(
-            w, e, 0, keepdims=False).astype(x.dtype)
-        h = jax.nn.silu(xt @ pick(w_gate)) * (xt @ pick(w_up))
-        out = (h @ pick(w_down)).astype(jnp.float32) * g[:, None]
-
-        def add(j, y):
-            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, j * sub, sub)
-            return y.at[cut(rows)].add(cut(out), mode="drop")
-
-        if bm == sub:  # a decode step's tile: one add, no loop around it
-            return add(0, y)
-        return jax.lax.fori_loop(0, -(-(rows < T).sum() // sub), add, y)
-
-    # zeros that depend on x: as a bare constant the compiler merges the
-    # layers' buffers into one broadcast that carries no scope's name
-    # (84 MB a layer a chunk, a quarter of the cell's unscoped device
-    # time; my chip run, PR 32)
-    y = jax.lax.fori_loop(0, tiles, tile, jnp.broadcast_to(
-        (x[:1, :1] * jnp.zeros((), x.dtype)).astype(jnp.float32), (T, d)))
+    y = held_experts(x, w_gate, w_up, w_down, tile_expert, tile_rows, tiles,
+                     slot_token, slot_weight)
     return y, mine.sum().astype(jnp.int32), (counts > 0).sum().astype(
         jnp.int32), tiles

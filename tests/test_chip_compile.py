@@ -218,6 +218,15 @@ _RESULT = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = ([a-z0-9]+)\[([0-9,]*)\][^ ]* ([\w\-]+)\(")
 
 
+def _mosaic_calls(text, name=None):
+    """The program's Mosaic calls; with `name`, those whose instruction
+    carries it (a call is named by its innermost `jax.named_scope`)."""
+    return [line for line in text.splitlines()
+            if "tpu_custom_call" in line and " custom-call(" in line
+            and (name is None or re.match(
+                rf"\s*(?:ROOT )?%?{name}[.\d]* = ", line))]
+
+
 @pytest.mark.parametrize("batch,max_len,s,per_row,kernels", [
     (8, 4096, 1, True, 0),    # the engine's decode step, 8 slots: XLA path
     (1, 3584, 256, False, 0),  # one chunk of a long prompt's prefill
@@ -531,7 +540,7 @@ def _dots3_cell_config(max_len):
                                       max_seq_len=max_len)
 
 
-def test_dots3_decode_step_moves_no_cache(chip):
+def test_dots3_decode_step_moves_no_cache(chip, compiled_not_interpreted):
     """models/dots3_note.decode_step at the benchmark's 32 slots x 24,576
     under the rule the other two models' steps are held to: with the
     cache donated, the latent rows (2.0 GB), the index keys and the rings
@@ -651,7 +660,9 @@ def test_dots3_chunk_holds_no_block_of_scores(chip, on_the_chip):
         lambda p, c, t: dots3_note.decode_step(p, c, t, cfg),
         donate_argnums=(1,)).lower(params, cache, tokens).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == cfg.n_layers
+    # one attention kernel a layer, one expert kernel an expert layer
+    assert len(_mosaic_calls(text, "held_experts")) == cfg.n_layers - 1
+    assert len(_mosaic_calls(text)) == 2 * cfg.n_layers - 1
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < HBM_BYTES)
@@ -764,7 +775,8 @@ def test_kimi_k2_step_reads_live_blocks_and_moves_no_cache(
 
     cfg, cache, compiled = _kimi_k2_step(chip, batch, s, max_len)
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == cfg.n_layers
+    assert len(_mosaic_calls(text, "held_experts")) == cfg.n_layers - 1
+    assert len(_mosaic_calls(text)) == 2 * cfg.n_layers - 1
     stack = tuple(cache["latent"].shape)
     stack_bytes = math.prod(stack) * 2
     if s == 1:
@@ -856,9 +868,10 @@ def test_laguna_step_holds_no_scores_and_moves_no_cache(
     of the full layers (6.44 GB for the slots) and the rings are written
     where they lie, and nothing but parameters, the in-place writes and
     the kernels' results has a whole stack's shape. One Mosaic call a
-    layer: the decode step's two full layers through the decode kernel,
-    which writes its row, and its three rings through the same kernel's
-    ring mode, which writes its own too (no `dynamic-update-slice` of a
+    layer for attention (and one an expert layer,
+    ops/pallas/held_experts.py): the decode step's two full layers
+    through the decode kernel, which writes its row, and its three rings
+    through the same kernel's ring mode, which writes its own too (no `dynamic-update-slice` of a
     stack in that program); a chunk's five through
     ops/pallas/gqa_chunk_attention.py, so that no array of queries by
     the cache's depth (one head's scores, 1,024 x 20,480, let alone `[s,
@@ -867,7 +880,8 @@ def test_laguna_step_holds_no_scores_and_moves_no_cache(
 
     cfg, cache, compiled = _laguna_step(chip, batch, s, max_len)
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == cfg.n_layers
+    assert len(_mosaic_calls(text, "held_experts")) == cfg.n_layers - 1
+    assert len(_mosaic_calls(text)) == 2 * cfg.n_layers - 1
     leaves = ("k", "v", "window_k", "window_v")
     stacks = {tuple(cache[key].shape) for key in leaves}
     stack_bytes = sum(math.prod(cache[key].shape) * 2 for key in leaves)
@@ -900,6 +914,111 @@ def test_laguna_step_holds_no_scores_and_moves_no_cache(
         if m.group(3) == "copy":
             assert sorted(dims) not in ([3072, 6144], [3072, 9216]), \
                 line[:200]
+
+
+# held, d, f, top-k of the three cells whose tokens are routed
+_HELD = {"laguna": (64, 3072, 1024, 10), "kimi": (12, 7168, 2048, 8),
+         "dots3": (32, 5120, 1536, 8)}
+
+
+def _held_experts_operands(text):
+    """[[defining line of each operand] of each `held_experts` call]."""
+    out = []
+    for lines in _computations(text).values():
+        for call in _mosaic_calls("\n".join(lines), "held_experts"):
+            names = re.findall(r"%([\w.\-]+)", call.split(
+                " custom-call(", 1)[1].split("), ")[0])
+            out.append([next(d for d in lines if re.match(
+                rf"\s*(?:ROOT )?%?{re.escape(n)} = ", d)) for n in names])
+    return out
+
+
+@pytest.mark.parametrize("tokens", [32, 1024], ids=["decode-32", "chunk-1024"])
+@pytest.mark.parametrize("model", list(_HELD))
+def test_held_experts_kernel_compiles_for_the_chip(
+        chip, compiled_not_interpreted, model, tokens):
+    """ops/moe.held_experts_ffn at the three expert cells' sizes, a
+    decode step's 32 rows and a chunk's 1,024: ONE Mosaic call
+    (ops/pallas/held_experts.py) inside the VMEM it asks for, x and y
+    resident (29 MB of y at Kimi's chunk) beside the blocks of f that
+    `f_block` chose; no `while` (the layout's search of the groups' ends
+    was one) and no scatter of rows of d; the three stacks are the
+    call's operands as the program received them, neither copied nor
+    cut."""
+    from ray_tpu.ops import moe
+    from ray_tpu.ops.pallas import held_experts as he
+
+    held, d, f, k = _HELD[model]
+    bm = 16 if tokens == 32 else 128
+    fb = he.f_block(tokens, d, f, bm, jnp.bfloat16, jnp.bfloat16)
+    assert fb == {"laguna": (1024, 1024), "kimi": (1024, 512),
+                  "dots3": (1536, 768)}[model][tokens == 1024]
+    assert (he._fixed_bytes(tokens, d, bm, jnp.bfloat16)
+            + he._block_bytes(d, fb, bm, jnp.bfloat16)) <= he._VMEM_BYTES
+    on = SingleDeviceSharding(chip)
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=on)
+    compiled = jax.jit(
+        lambda x, c, w, wg, wu, wd: moe.held_experts_ffn(x, c, w, wg, wu, wd,
+                                                         held)).lower(
+        S((tokens, d), jnp.bfloat16), S((tokens, k), jnp.int32),
+        S((tokens, k), jnp.float32), S((held, d, f), jnp.bfloat16),
+        S((held, d, f), jnp.bfloat16), S((held, f, d), jnp.bfloat16)
+    ).compile()
+    text = compiled.as_text()
+    assert len(_mosaic_calls(text)) == 1
+    assert " while(" not in text
+    # the layout's integers and x's words are all that is made: 2 MB a
+    # decode step (Laguna's one-hot of 320 pairs by 64 experts), x's
+    # 14.7 MB beside y's 29.4 at Kimi's chunk
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        3e6 if tokens == 32 else 1.6 * tokens * d * 4)
+    (operands,) = _held_experts_operands(text)
+    assert all(" parameter(" in line for line in operands[-3:]), operands[-3:]
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if m and m.group(3) == "scatter":
+            assert "," not in m.group(2), line[:200]    # integers, a slot each
+
+
+@pytest.mark.parametrize("batch,s,max_len", [
+    (32, 1, 24576), (1, 1024, 20480),
+], ids=["decode-32x24576-on-chip", "chunk-1x1024@20480"])
+def test_laguna_step_walks_its_experts_in_one_kernel(chip, on_the_chip, batch,
+                                                     s, max_len):
+    """The Laguna cell's two step programs hold, under `moe_experts`, no
+    `while` (PR 51's loop over the tiles, its inner loop of adds, the
+    layout's binary search) and no scatter of rows of d (the weighted
+    add into `y`, `fusion f32[1024,3072]`, 8.6% of the cell; ledger, PR
+    51); no array of `pairs` or of `n_slots` rows of d exists in any
+    dtype but a parameter; and each of the four calls takes its layer's
+    three stacks as the program's own parameters, not as copies or
+    slices."""
+    cfg, _, compiled = _laguna_step(chip, batch, s, max_len)
+    text = compiled.as_text()
+    T = batch * s
+    pairs = T * cfg.experts_per_tok
+    bm = 16 if T <= 64 else 128
+    n_slots = -(-pairs // bm) * bm + cfg.experts_held * bm
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if "moe_experts" in line:
+            assert m.group(3) != "while", line[:200]
+            assert m.group(3) != "scatter" or len(dims) == 1, line[:200]
+        if dims[-1:] == (cfg.dim,) and math.prod(dims[:-1]) in (pairs,
+                                                                n_slots):
+            assert m.group(3) in ("parameter", "get-tuple-element",
+                                  "bitcast"), line[:200]
+    calls = _held_experts_operands(text)
+    assert len(calls) == cfg.n_layers - 1
+    stacks = set()
+    for operands in calls:
+        for line in operands[-3:]:
+            assert " parameter(" in line and "layers" in line, line[:200]
+            stacks.add(re.search(r'op_name="(.*?)"', line).group(1))
+    assert len(stacks) == 3 * (cfg.n_layers - 1)
 
 
 def _without_locations(text: str) -> str:

@@ -239,19 +239,21 @@ def test_all_tokens_to_one_expert_lose_none(choices):
     assert (int(pairs), int(hit), int(tiles)) == (n, 1, -(-n // 128))
 
 
-@pytest.mark.parametrize("tokens,top_k,first,held,every,block_rows", [
-    (50, 3, 4, 4, 7, None), (50, 3, 4, 4, 7, 2), (50, 3, 4, 4, 7, 8),
-    (50, 3, 16, 4, 7, 8),       # the router never chooses 16..19
-    (50, 3, 4, 4, 1, 8),        # no row is a token
-    (32, 8, 4, 4, 7, None),     # a decode step's rows: tiles of 16
-    (50, 8, 0, 16, 7, 8),       # all eight choices of every token held
-    (200, 3, 4, 4, 7, None),    # a chunk's rows: tiles of 128
-    (50, 3, 4, 1, 7, None),     # ONE held expert: a plain SwiGLU FFN
-], ids=["tiles-default", "tiles-2", "tiles-8", "no-pair-held",
+@pytest.mark.parametrize("tokens,top_k,first,held,every", [
+    (50, 3, 4, 4, 7),
+    (64, 3, 4, 4, 7),         # the last T whose tiles are 16 rows
+    (65, 3, 4, 4, 7),         # the first whose tiles are 128
+    (50, 3, 16, 4, 7),        # the router never chooses 16..19
+    (50, 3, 4, 4, 1),         # no row is a token
+    (32, 8, 4, 4, 7),         # a decode step's rows: tiles of 16
+    (50, 8, 0, 16, 7),        # all eight choices of every token held
+    (200, 3, 4, 4, 7),        # a chunk's rows: tiles of 128
+    (50, 3, 4, 1, 7),         # ONE held expert: a plain SwiGLU FFN
+], ids=["tiles-default", "tiles-16-at-64", "tiles-128-at-65", "no-pair-held",
         "every-row-invalid", "decode-32", "all-choices-held", "chunk-200",
         "one-expert-held"])
 def test_held_experts_part_equals_the_dense_sum(tokens, top_k, first, held,
-                                                every, block_rows):
+                                                every):
     wg, wu, wd = _expert_weights(jax.random.PRNGKey(3), 16, 32, 16)
     x = jax.random.normal(jax.random.PRNGKey(4), (tokens, 32))
     router = jax.random.normal(jax.random.PRNGKey(5), (32, 16)) / np.sqrt(32)
@@ -260,7 +262,7 @@ def test_held_experts_part_equals_the_dense_sum(tokens, top_k, first, held,
     valid = jnp.arange(tokens) % every != 0
     y, pairs, hit, tiles = moe.held_experts_ffn(
         x, chosen, weights, wg[:held], wu[:held], wd[:held], first,
-        valid=valid, block_rows=block_rows)
+        valid=valid)
     want = jnp.zeros_like(x)
     counts = []
     for e in range(held):
@@ -272,9 +274,9 @@ def test_held_experts_part_equals_the_dense_sum(tokens, top_k, first, held,
     assert float(jnp.abs(y - want).max()) < 1e-5
     assert int(pairs) == sum(counts)
     assert int(hit) == sum(c > 0 for c in counts)
-    # the loop's trip count: each expert's pairs in whole tiles, and no
-    # tile for an expert nobody chose
-    bm = block_rows or (16 if tokens <= 64 else 128)
+    # the tiles the kernel walks: each expert's pairs in whole tiles, and
+    # no tile for an expert nobody chose
+    bm = 16 if tokens <= 64 else 128
     assert int(tiles) == sum(-(-c // bm) for c in counts)
     if first == 16 or every == 1:
         assert sum(counts) == 0 and not bool(y.any())

@@ -1,9 +1,10 @@
-"""What one holder's routed experts cost at the two expert cells' sizes
-(Kimi-K2.6: 12 of 384 experts, d 7,168, f 2,048; dots3-note-prev: 32 of
-256, d 5,120, f 1,536; top-8; a chunk of 1,024 tokens and a decode step
+"""What one holder's routed experts cost at the three expert cells'
+sizes (Kimi-K2.6: 12 of 384 experts, d 7,168, f 2,048, top-8;
+dots3-note-prev: 32 of 256, d 5,120, f 1,536, top-8; Laguna-S-2.1: 64 of
+256, d 3,072, f 1,024, top-10; a chunk of 1,024 tokens and a decode step
 of 32 rows), on whatever device jax finds:
 
-  python3 tools/held_experts_probe.py [--models kimi,dots3]
+  python3 tools/held_experts_probe.py [--models kimi,dots3,laguna]
       [--tokens 1024,32] [--other path/to/another/moe.py[,...]]
       [--layers 4] [--repeat 5] [--seed 0]
 
@@ -11,10 +12,10 @@ One program a form, model, T and routing: `ops/moe.held_experts_ffn`
 called --layers times on bfloat16 x, each call's y reaching the next
 call's x so that none is dropped, as a step's expert layers follow one
 another. Two routings: `routed`, drawn by `route_sigmoid_topk` over the
-FULL router width from a random router (about T x 8 x held / width
+FULL router width from a random router (about T x k x held / width
 pairs are this holder's), and `worst`, every pair on one held expert
-(T x 8 rows in one group; a token there chooses its expert eight
-times, which no router does and the function still has to sum). One
+(T x k rows in one group; a token there chooses its expert k times,
+which no router does and the function still has to sum). One
 JSON line each: milliseconds a round of all calls (the median of
 --repeat, each ended by block_until_ready) and a call with the `loop`
 line's round taken off (the same program with no experts in it: the
@@ -22,8 +23,11 @@ chain's additions and the dispatch), the pairs held, the experts hit,
 the tiles and rows walked (from the routing, on the host; `tiles` is
 the function's own third counter where it returns one), and the
 largest absolute difference of y to the first form's. --other times
-further copies of the module (the parent commit's, a candidate's).
-Nothing here is the benchmark's: it sizes the combine (PERF.md, PR 47).
+further copies of the module (the parent commit's, a candidate's)
+beside the tree's at all three sizes: PR 52 set the kernel
+(ops/pallas/held_experts.py) against PR 51's XLA loop that way.
+Nothing here is the benchmark's and no cell runs it: it sizes the
+experts' walk (PERF.md, PRs 47 and 52).
 """
 
 from __future__ import annotations
@@ -38,14 +42,15 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# held, router width, d, f: benchmarks/configs/{Kimi-K2.6,dots3-note-prev}.json
-MODELS = {"kimi": (12, 384, 7168, 2048), "dots3": (32, 256, 5120, 1536)}
-TOP_K = 8
+# held, router width, d, f, top-k:
+# benchmarks/configs/{Kimi-K2.6,dots3-note-prev,Laguna-S-2.1}.json
+MODELS = {"kimi": (12, 384, 7168, 2048, 8), "dots3": (32, 256, 5120, 1536, 8),
+          "laguna": (64, 256, 3072, 1024, 10)}
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--models", default="kimi,dots3")
+    ap.add_argument("--models", default="kimi,dots3,laguna")
     ap.add_argument("--tokens", default="1024,32")
     ap.add_argument("--other", default="")
     ap.add_argument("--layers", type=int, default=4)
@@ -92,7 +97,7 @@ def main():
         return jax.jit(run)
 
     for model in filter(None, args.models.split(",")):
-        held, width, d, f = MODELS[model]
+        held, width, d, f, top_k = MODELS[model]
         first = width // 3
         ks = jax.random.split(jax.random.PRNGKey(args.seed), 6)
         dense = lambda key, shape: (jax.random.normal(key, shape, f32)
@@ -104,11 +109,11 @@ def main():
             bm = 16 if T <= 64 else 128
             x = jax.random.normal(ks[4], (T, d), f32).astype(bf16)
             _, chosen, weights = moe.route_sigmoid_topk(
-                x, router, jnp.zeros((width,)), TOP_K)
+                x, router, jnp.zeros((width,)), top_k)
             routings = {
                 "routed": (chosen, weights),
-                "worst": (jnp.full((T, TOP_K), first, jnp.int32),
-                          jnp.full((T, TOP_K), 1.0 / TOP_K, f32))}
+                "worst": (jnp.full((T, top_k), first, jnp.int32),
+                          jnp.full((T, top_k), 1.0 / top_k, f32))}
             _, loop_ms = timed(chain(lambda x, *_: (x.astype(f32),)), x,
                                chosen, weights, *w)
             print(json.dumps({"model": model, "T": T, "form": "loop",
