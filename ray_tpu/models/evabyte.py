@@ -36,22 +36,26 @@ origin, so the engine's `insert_row` grafts it as it lies. Nothing of it
 is as deep as the context: `CACHE_LEN_AXIS` is empty, every leaf is
 grafted whole, and the engine keeps no prefix store for this model.
 
-A decode step writes k_t, v_t, recomputes the summary of the chunk t
-lies in from that chunk's rows in the window (an overwrite with no
-branch; final when the chunk ends, and visible only once the window
-has), and attends. A prefill chunk reads the window's rows as it found
-them, attends its queries to those of their own window, to the chunk's
-own keys of their own window and to the summaries of earlier windows
-INCLUDING one that ends inside the chunk, and then lays its rows over
-the window's. Positions before cache["start"] are left padding: no bytes,
-in no window, chunk or summary.
+A decode step writes k_t, v_t; a row whose t is the last position of
+its chunk then folds the chunk, once: the summary is computed from the
+chunk's rows in the window and written at W + j, final from that step
+on and visible only once the window has ended. A row in mid-chunk reads
+and writes nothing for it: the slot of an open chunk, which no query's
+range holds, keeps what it had. Then every row attends. A prefill chunk
+reads the window's rows as it found them, attends its queries to those
+of their own window, to the chunk's own keys of their own window and to
+the summaries of earlier windows INCLUDING one that ends inside the
+chunk, and then lays its rows over the window's. Positions before
+cache["start"] are left padding: no bytes, in no window, chunk or
+summary.
 
 Scopes beside llama's `attn_qkv`, `attn_out`, `mlp`, `embed`, `lm_head`:
 `eva_window_attn` (scores and values over E, the window's rows written;
 a decode step's one kernel over the whole range; a chunk's one write of
 its layer, summaries and all), `eva_chunk_attn` (a chunk's scores and
 values over C and the merge of the two parts), `eva_summarise` (k~, v~
-computed; a decode step's written).
+computed; in a decode step the loop over the rows that close a chunk,
+their k~, v~ computed and written).
 """
 
 from __future__ import annotations
@@ -63,6 +67,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ray_tpu.ops.attention import decode_attention, decode_block_len
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -235,8 +240,11 @@ def decode_counters(cfg: EvaByteConfig, spans: list, rows: int) -> dict:
     overlap a row's range, or both parts whole for every row where
     nothing bounds the read. `windows_folded`: rows whose query is the
     first of a window, so that the window before it has just become its
-    summaries."""
+    summaries. `chunks_folded`: rows whose written position is the last
+    of its chunk, the rows the step folds (one in `chunk_size` of the
+    live rows stepped, over a long run)."""
     W, cpw, L = cfg.window_size, cfg.chunks_per_window, cfg.n_layers
+    c = cfg.chunk_size
     t = np.asarray([last - start for start, last in spans], np.int64)
     win_live, sum_live = t % W + 1, cpw * (t // W)
     block = _read_block(cfg)
@@ -249,7 +257,8 @@ def decode_counters(cfg: EvaByteConfig, spans: list, rows: int) -> dict:
             "decode_summaries_live": L * int(sum_live.sum()),
             "decode_window_positions_read": L * int(np.sum(win_read)),
             "decode_summaries_read": L * int(np.sum(sum_read)),
-            "windows_folded": int(((t > 0) & (t % W == 0)).sum())}
+            "windows_folded": int(((t > 0) & (t % W == 0)).sum()),
+            "chunks_folded": int((t % c == c - 1).sum())}
 
 
 def prefill_counters(cfg: EvaByteConfig, start: int, pos: int, chunk: int,
@@ -314,26 +323,39 @@ def _decode_attend(cfg, layer, li, q, kk, vv, kc, vc, t, live):
                 vc, vv[r:r + 1].transpose(0, 2, 1, 3)[None],
                 (li, r, 0, ring[r], 0))
     with jax.named_scope("eva_summarise"):
-        # the chunk t lies in: c rows of the window, the latest first
+        # a row whose written position is the last of its chunk folds the
+        # chunk, once: its c rows of the window, the step's own among
+        # them and the latest first, become k~, v~ at index W + j. The
+        # loop takes those rows alone: a row in mid-chunk, or one that
+        # holds no request, reads and writes nothing here
         j = t // c
         base = W - c - (j % cpw) * c
-        kch = jnp.stack([jax.lax.dynamic_slice(
-            kc, (li, r, 0, 0, base[r]), (1, 1, H, hd, c))[0, 0]
-            for r in range(b)])                          # [b, H, hd, c]
-        vch = jnp.stack([jax.lax.dynamic_slice(
-            vc, (li, r, 0, base[r], 0), (1, 1, H, c, hd))[0, 0]
-            for r in range(b)])                          # [b, H, c, hd]
-        ok = jnp.arange(c)[None, :] >= (c - 1 - t % c)[:, None]
-        k_sum, v_sum = _summarise(
-            layer, kch.transpose(0, 3, 1, 2)[:, None],
-            vch.transpose(0, 2, 1, 3)[:, None], ok[:, None], c)
-        for r in range(b):
-            kc = jax.lax.dynamic_update_slice(
-                kc, k_sum[r:r + 1].transpose(0, 2, 3, 1)[None],
-                (li, r, 0, 0, W + j[r]))
-            vc = jax.lax.dynamic_update_slice(
-                vc, v_sum[r:r + 1].transpose(0, 2, 1, 3)[None],
-                (li, r, 0, W + j[r], 0))
+        closes = live & (t % c == c - 1)
+        first = jnp.argsort(~closes, stable=True)     # the closing rows
+        whole = jnp.ones((1, 1, c), bool)
+        # the rows cut out in the stack's own order: left to choose, the
+        # compiler lays the whole stack out for the transposes below, and
+        # copies it a step (tests/test_chip_compile.py)
+        as_it_lies = Layout((0, 1, 2))
+
+        def fold(i, stacks):
+            kc, vc = stacks
+            r = first[i]
+            kch = with_layout_constraint(jax.lax.dynamic_slice(
+                kc, (li, r, 0, 0, base[r]), (1, 1, H, hd, c))[0, 0],
+                as_it_lies)                              # [H, hd, c]
+            vch = with_layout_constraint(jax.lax.dynamic_slice(
+                vc, (li, r, 0, base[r], 0), (1, 1, H, c, hd))[0, 0],
+                as_it_lies)                              # [H, c, hd]
+            k_sum, v_sum = _summarise(
+                layer, kch.transpose(2, 0, 1)[None, None],
+                vch.transpose(1, 0, 2)[None, None], whole, c)
+            return (jax.lax.dynamic_update_slice(
+                        kc, k_sum[..., None], (li, r, 0, 0, W + j[r])),
+                    jax.lax.dynamic_update_slice(
+                        vc, v_sum[:, :, :, None], (li, r, 0, W + j[r], 0)))
+
+        kc, vc = jax.lax.fori_loop(0, closes.sum(), fold, (kc, vc))
     with jax.named_scope("eva_window_attn"):
         # the window's rows and the summaries before it: one range; a
         # row that holds no request gets an empty one

@@ -685,9 +685,13 @@ def test_evabyte_step_moves_no_cache(chip, on_the_chip, batch, s, max_len):
     of a whole stack's shape but the in-place writes. The decode step is
     the decode kernel the models share, given each row's one range over
     both parts of the leaf (32 KV heads of 128, group 1), and holds
-    under 16 MB beside its arguments; a chunk reads its layer once and
-    writes it once (its largest temporaries are the float32 scores of
-    1,024 queries against the window and the chunk)."""
+    under 16 MB beside its arguments; since PR 53 its fold of a closed
+    chunk is a loop inside the layer loop over the rows that close one
+    on this step, both stacks carried through it where they lie, and
+    nothing cuts a chunk's 16 rows out of a stack outside that loop; a
+    chunk reads its layer once and writes it once (its largest
+    temporaries are the float32 scores of 1,024 queries against the
+    window and the chunk)."""
     from ray_tpu.models import evabyte
 
     cfg = evabyte.EvaByteConfig(n_layers=8)
@@ -724,6 +728,25 @@ def test_evabyte_step_moves_no_cache(chip, on_the_chip, batch, s, max_len):
             assert m.group(3) in ("parameter", "get-tuple-element",
                                   "dynamic-update-slice", "bitcast",
                                   "custom-call"), line[:200]
+    if s > 1:
+        return
+    # the layer loop and, inside it, the fold's: the stacks are carried
+    # through both and still aliased to the arguments (above), and a
+    # chunk's rows [.., H, hd, c] / [.., H, c, hd] are cut out, or
+    # copied, under `eva_summarise`'s loop alone
+    assert len(re.findall(r" while\(", text)) == 2
+    rows = {(cfg.n_heads, cfg.head_dim, cfg.chunk_size),
+            (cfg.n_heads, cfg.chunk_size, cfg.head_dim)}
+    cut = 0
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m or m.group(3) not in ("copy", "dynamic-slice"):
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if tuple(d for d in dims if d != 1) in rows:
+            assert "/eva_summarise/while/body/" in line, line[:300]
+            cut += m.group(3) == "dynamic-slice"
+    assert cut >= 2
 
 
 @functools.cache

@@ -24,16 +24,20 @@ HP = {"heads": H, "window": W, "chunk": C, "pred_heads": 8,
       "rope_theta": 100000.0, "norm_eps": 1e-5}
 
 
-@pytest.fixture(scope="module")
-def model():
+def _twin(window: int, chunk: int, max_len: int, seed: int):
     cfg = evabyte.EvaByteConfig(
         vocab_size=32, dim=H * HD, n_layers=L, n_heads=H, hidden_dim=96,
-        max_seq_len=128, window_size=W, chunk_size=C, n_pred_heads=8,
-        dtype=jnp.float32, param_dtype=jnp.float32)
-    params = evabyte.init_params(cfg, jax.random.PRNGKey(0))
+        max_seq_len=max_len, window_size=window, chunk_size=chunk,
+        n_pred_heads=8, dtype=jnp.float32, param_dtype=jnp.float32)
+    params = evabyte.init_params(cfg, jax.random.PRNGKey(seed))
     for name in ("phi", "mu"):
         params["layers"][name] = params["layers"][name] * 10.0
     return cfg, params
+
+
+@pytest.fixture(scope="module")
+def model():
+    return _twin(W, C, 128, 0)
 
 
 def _rel(a, b):
@@ -46,6 +50,7 @@ _REF = jax.jit(lambda p, toks: evabyte_ref.logits_and_summaries(p, toks, HP))
 # hashable)
 _STEP = jax.jit(evabyte.decode_step, static_argnames=("cfg", "all_heads"))
 _FORWARD = jax.jit(evabyte.forward, static_argnames=("cfg", "all_heads"))
+_SUMMARISE = jax.jit(evabyte._summarise, static_argnames=("c",))
 
 
 def _reference(params, toks):
@@ -199,6 +204,111 @@ def test_lockstep_decode_of_a_left_padded_batch_equals_forward(model):
             cfg=cfg)
 
 
+# ------------------------------------------- the fold of a closed chunk
+@pytest.fixture(scope="module")
+def chunks_of_four():
+    """A second twin, whose chunks are long enough to stand at different
+    phases: a window of 16 in chunks of 4."""
+    return _twin(16, 4, 64, 4)
+
+
+FOLD_STEPS = 14         # 2 c + 2 and four more: every row ends a window
+SET = 1e3               # what an unclosed chunk's slot is made to hold
+
+
+# (prompt lengths of the three rows, None: the row holds no request; the
+# rows that close a chunk on each of the first six steps)
+@pytest.mark.parametrize("lens,closing", [
+    ((5, 6, 7), [1, 1, 1, 0, 1, 1]),
+    ((8, 12, 4), [0, 0, 0, 3, 0, 0]),
+    ((6, None, 9), [0, 1, 1, 0, 0, 1]),
+    ((None, None, 11), [1, 0, 0, 0, 1, 0]),
+    ((7, 10, 3), [2, 1, 0, 0, 2, 1]),
+    ((13, 14, 15), [1, 1, 1, 0, 1, 1]),
+], ids=["one-row-a-step", "none-then-every-row", "a-row-holds-no-request",
+        "one-row-alone", "a-chunk-the-prefill-began",
+        "a-chunk-and-a-window-at-once"])
+def test_decode_folds_a_chunk_once_on_the_step_that_closes_it(
+        chunks_of_four, lens, closing):
+    """Rows at different phases of their chunks, stepped together. Before
+    every step each slot of a chunk not yet closed (and every slot of a
+    row that holds no request) is set to a value no summary has. After
+    it: (a) the chunk a row's written position closed holds `_summarise`
+    of its c cached rows, exactly, in every layer, and keeps it; every
+    other slot set is as it was set: a row in mid-chunk, or with no
+    request, wrote nothing; (b) the logits are `forward`'s, which is
+    also how (c) no row's range reaches a slot not yet closed: a set
+    slot read would move them."""
+    cfg, params = chunks_of_four
+    Wf, c, cpw = cfg.window_size, cfg.chunk_size, cfg.chunks_per_window
+    rng = np.random.default_rng(sum(n or 0 for n in lens))
+    seqs = [None if n is None else rng.integers(1, 32, size=48)
+            for n in lens]
+    want = [None if q is None else np.asarray(
+        _FORWARD(params, jnp.asarray(q)[None], cfg=cfg)[0]) for q in seqs]
+    cache = evabyte.init_cache(cfg, 3)
+    length, start = [-1] * 3, [0] * 3
+    for r, n in enumerate(lens):
+        if n is None:
+            continue
+        logits, small = _prefill(cfg, params, seqs[r][:n], 16, 8)
+        assert _rel(logits[0, 0], want[r][n - 1]) < 1e-5
+        for name in ("k", "v"):
+            cache[name] = jax.lax.dynamic_update_slice_in_dim(
+                cache[name], small[name], r, 1)
+        length[r], start[r] = 16, 16 - n
+    cache["start"] = jnp.asarray(start, jnp.int32)
+    cache["length"] = jnp.asarray(length, jnp.int32)
+    live = np.asarray([n is not None for n in lens])
+    layers = [{name: params["layers"][name][li] for name in ("phi", "mu")}
+              for li in range(L)]
+    folded = {}                # (row, chunk) -> (k~, v~) [L, H, HD]
+    seen = []
+    for i in range(FOLD_STEPS):
+        t = np.asarray([(n or 0) + i for n in lens])
+        first_open = np.where(live, t // c, 0)
+        for r in range(3):
+            at = Wf + int(first_open[r])
+            cache["k"] = cache["k"].at[:, r, :, :, at:].set(SET)
+            cache["v"] = cache["v"].at[:, r, :, at:].set(SET)
+        fed = np.asarray([[0 if q is None else q[n + i]]
+                          for q, n in zip(seqs, lens)], np.int32)
+        logits, cache = _STEP(params, cache, jnp.asarray(fed), cfg=cfg)
+        cache["length"] = jnp.where(live, cache["length"], -1)
+        k_now, v_now = np.asarray(cache["k"]), np.asarray(cache["v"])
+        closes = live & (t % c == c - 1)
+        seen.append(int(closes.sum()))
+        for r in range(3):
+            if live[r]:
+                assert _rel(logits[r], want[r][lens[r] + i]) < 1e-5, (r, i)
+            if closes[r]:
+                j = int(t[r]) // c
+                base = Wf - c - (j % cpw) * c
+                sums = [_SUMMARISE(
+                    layers[li],
+                    jnp.asarray(k_now[li, r, :, :, base:base + c]
+                                ).transpose(2, 0, 1)[None, None],
+                    jnp.asarray(v_now[li, r, :, base:base + c]
+                                ).transpose(1, 0, 2)[None, None],
+                    jnp.ones((1, 1, c), bool), c) for li in range(L)]
+                folded[r, j] = tuple(np.stack(
+                    [np.asarray(s[n][0, 0]) for s in sums]) for n in (0, 1))
+            # (a) closed here or on an earlier step: what the fold gave
+            for (row, j), (k_sum, v_sum) in folded.items():
+                if row == r:
+                    assert np.array_equal(k_now[:, r, :, :, Wf + j], k_sum)
+                    assert np.array_equal(v_now[:, r, :, Wf + j], v_sum)
+            # and nothing written behind the chunks closed so far
+            at = Wf + (int(t[r] + 1) // c if live[r] else 0)
+            assert (k_now[:, r, :, :, at:] == SET).all(), (r, i)
+            assert (v_now[:, r, :, at:] == SET).all(), (r, i)
+    assert seen[:6] == closing
+    assert len(folded) == sum(seen) >= 3
+    assert evabyte.decode_counters(
+        cfg, [(0, n + i) for n in lens if n is not None
+              for i in range(FOLD_STEPS)], 3)["chunks_folded"] == sum(seen)
+
+
 # -------------------------------------------------------------- the engine
 PROMPTS = (5, 21, 13, 37, 30, 60, 9)
 
@@ -296,18 +406,21 @@ def test_engine_counts_what_the_steps_read_and_saw(served):
     position the seven requests made a query of."""
     eng, prompts, outs = served
     stats = eng.stats()
-    want = dict.fromkeys(("pw", "ps", "dw", "ds", "folds"), 0)
+    want = dict.fromkeys(("pw", "ps", "dw", "ds", "folds", "chunks"), 0)
     for p, o in zip(prompts, outs):
         for t in range(len(p) + len(o) - 1):
             phase = "p" if t < len(p) else "d"
             want[phase + "w"] += L * (t % W + 1)
             want[phase + "s"] += L * (W // C) * (t // W)
             want["folds"] += t > 0 and t % W == 0
+            want["chunks"] += phase == "d" and t % C == C - 1
     assert stats["prefill_window_keys_visible"] == want["pw"]
     assert stats["prefill_summaries_visible"] == want["ps"]
     assert stats["decode_window_positions_live"] == want["dw"]
     assert stats["decode_summaries_live"] == want["ds"]
     assert stats["windows_folded"] == want["folds"]
+    # the decode steps that closed a chunk: one in C of the rows stepped
+    assert stats["chunks_folded"] == want["chunks"]
     # nothing bounds a step's read on the CPU: both parts whole, for
     # every row the step has
     assert stats["decode_window_positions_read"] == \
@@ -351,7 +464,7 @@ def test_decode_counters_from_row_ranges_and_the_kernels_blocks(
         "decode_summaries_live": L * (8 + 8 + 0),
         "decode_window_positions_read": L * 3 * W,
         "decode_summaries_read": L * 3 * 64,
-        "windows_folded": 1}
+        "windows_folded": 1, "chunks_folded": 2}   # t = 21 and 5 of C 2
     # where the kernel bounds the read: whole blocks that meet a row's
     # range, [W - 1 - t mod W, W + summaries - 1], of 2,048 + 2,048 rows
     big = evabyte.EvaByteConfig(n_layers=2)
@@ -363,6 +476,7 @@ def test_decode_counters_from_row_ranges_and_the_kernels_blocks(
     assert got["decode_window_positions_read"] == 2 * (2048 + 128 + 384)
     assert got["decode_summaries_read"] == 2 * (256 + 384 + 0)
     assert got["windows_folded"] == 1
+    assert got["chunks_folded"] == 1            # t = 6143 of chunks of 16
 
 
 def test_decode_step_on_the_kernels_path_equals_the_plain_one(model,

@@ -536,7 +536,8 @@ def test_spans_carry_what_a_window_and_its_summaries_gave_a_step(tmp_path):
     assert dispatch and len(chunks) == 4
     for d in dispatch:
         assert {"active", "live_positions", "t_host", "windows_folded",
-                *decode} <= set(d)
+                "chunks_folded", *decode} <= set(d)
+        assert 0 <= d["chunks_folded"] <= d["active"]
         # two layers: at most the window's 8 rows a live row, and on the
         # CPU both parts of every row read whole (48 summaries of 96)
         assert 2 * d["active"] <= d[decode[0]] <= 2 * 8 * d["active"]
@@ -555,6 +556,11 @@ def test_spans_carry_what_a_window_and_its_summaries_gave_a_step(tmp_path):
     assert sum(s["windows_folded"] for s in dispatch + chunks) == \
         st["windows_folded"] - before["windows_folded"] == 5
     assert sum(s["windows_folded"] for s in dispatch) == 1
+    # chunks of 2: the decode steps that wrote an odd position, 41 and 43
+    # of the long request's 40 to 44 and 3, 5 and 7 of the short's 3 to 7
+    assert "chunks_folded" not in chunks[0]
+    assert sum(s["chunks_folded"] for s in dispatch) == \
+        st["chunks_folded"] - before["chunks_folded"] == 5
 
 
 # ------------------------------------------- the process's own log (PR 40)
