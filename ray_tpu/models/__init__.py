@@ -25,7 +25,8 @@ def _counts_nothing(*args) -> dict:
 OPTIONAL = {"decode_counters": _counts_nothing,
             "prefill_counters": _counts_nothing,
             "CACHE_KIND": types.MappingProxyType({}),
-            "STEP_AUX": types.MappingProxyType({})}
+            "STEP_AUX": types.MappingProxyType({}),
+            "mixed_step": None}
 
 
 def registry() -> tuple:
@@ -76,7 +77,15 @@ def module_for(cfg):
     two kinds share one leaf's position axis (`{}`). `STEP_AUX`: the
     counters the step decides on the device and returns in
     `cache["aux"]`, field of the emit span -> counter of `stats()`
-    (`{}`)."""
+    (`{}`). `mixed_step(params, small, chunk_tokens, cache, tokens,
+    cfg)`: a prefill chunk [1, s] appended to one request's prefill
+    cache `small` AND one token a row [b, 1] appended to the slots'
+    `cache`, as two calls of `decode_step` append them, in one pass over
+    the weights; returns (the chunk's last position's logits, small, the
+    rows' logits, cache), and names its operations' phases itself
+    (`prefill`, `decode`). With it, the engine's round with a chunk due
+    is that one program; without it (None), a chunk's `decode_step` and
+    then the rows'."""
     for config_type, module in registry():
         if isinstance(cfg, config_type):
             break

@@ -31,6 +31,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 # decode_read_block is this module's (models.module_for): K and V of
 # n_kv_heads x head_dim in every attention layer
@@ -329,6 +330,41 @@ def cache_logical_axes(cfg: LlamaConfig) -> dict:
     return kv_cache_logical_axes()
 
 
+def _qkv(cfg: LlamaConfig, layer: dict, x):
+    """A block's `attn_qkv` products over x ``[..., d]``: q and k as
+    projected, ``[..., heads * hd]``, and v cut into its heads,
+    ``[..., kv_heads, hd]``."""
+    with jax.named_scope("attn_qkv"):
+        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+        q = _proj(cfg, layer, "wq", h)
+        kk = _proj(cfg, layer, "wk", h)
+        vv = _proj(cfg, layer, "wv", h)
+        vv = vv.reshape(vv.shape[:-1] + (cfg.n_kv_heads, cfg.head_dim))
+        # q and k exist as projected, [..., heads * hd], before they are
+        # cut into heads for the rope. Left free, the compiler makes the
+        # product give them heads-major, takes wq and wk transposed for
+        # that, and so slices each out of its stack and copies it into
+        # the other layout on every layer of every step (12 MB a layer,
+        # a tenth of the chat cell's decode round: PERF.md, PR 45). Held
+        # here, the products read the stacks where they lie, as wv's, wo's
+        # and the MLP's do. Not v: with it held too the chunk's V stack is
+        # re-laid around the layer loop (tests/test_chip_compile.py).
+        q, kk = jax.lax.optimization_barrier((q, kk))
+    return q, kk, vv
+
+
+def _out_mlp(cfg: LlamaConfig, layer: dict, x, attn):
+    """The rest of a block behind its attention: `attn_out` and `mlp`."""
+    with jax.named_scope("attn_out"):
+        x = x + _proj(cfg, layer, "wo", attn)
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        x = x + _proj(cfg, layer, "w_down",
+                      jax.nn.silu(_proj(cfg, layer, "w_gate", h))
+                      * _proj(cfg, layer, "w_up", h))
+    return x
+
+
 def _decode_block(cfg: LlamaConfig, x, layer, li, k_cache, v_cache, cos, sin,
                   positions, cache_len, start, abs_positions):
     """Single-step (or chunked prefill) block `li` against the KV cache.
@@ -344,34 +380,45 @@ def _decode_block(cfg: LlamaConfig, x, layer, li, k_cache, v_cache, cos, sin,
     """
     b, s, d = x.shape
     hd, nh, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
-    with jax.named_scope("attn_qkv"):
-        h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = _proj(cfg, layer, "wq", h)
-        kk = _proj(cfg, layer, "wk", h)
-        vv = _proj(cfg, layer, "wv", h).reshape(b, s, nkv, hd)
-        # q and k exist as projected, [b, s, heads * hd], before they are
-        # cut into heads for the rope. Left free, the compiler makes the
-        # product give them heads-major, takes wq and wk transposed for
-        # that, and so slices each out of its stack and copies it into
-        # the other layout on every layer of every step (12 MB a layer,
-        # a tenth of the chat cell's decode round: PERF.md, PR 45). Held
-        # here, the products read the stacks where they lie, as wv's, wo's
-        # and the MLP's do. Not v: with it held too the chunk's V stack is
-        # re-laid around the layer loop (tests/test_chip_compile.py).
-        q, kk = jax.lax.optimization_barrier((q, kk))
-        q = q.reshape(b, s, nh, hd)
-        kk = kk.reshape(b, s, nkv, hd)
+    q, kk, vv = _qkv(cfg, layer, x)
     attn, k_cache, v_cache = cached_attention(
-        q, kk, vv, k_cache, v_cache, li, cache_len, abs_positions, start,
-        scale=hd ** -0.5, rope=(cos, sin, positions))
-    with jax.named_scope("attn_out"):
-        x = x + _proj(cfg, layer, "wo", attn)
-    with jax.named_scope("mlp"):
-        h = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        x = x + _proj(cfg, layer, "w_down",
-                      jax.nn.silu(_proj(cfg, layer, "w_gate", h))
-                      * _proj(cfg, layer, "w_up", h))
-    return x, k_cache, v_cache
+        q.reshape(b, s, nh, hd), kk.reshape(b, s, nkv, hd), vv, k_cache,
+        v_cache, li, cache_len, abs_positions, start, scale=hd ** -0.5,
+        rope=(cos, sin, positions))
+    return _out_mlp(cfg, layer, x, attn), k_cache, v_cache
+
+
+def _cache_positions(cache: dict, b: int, s: int):
+    """Where s new tokens a row land in `cache`: (cache_len, a scalar or
+    [b]; the slots [b, s]; `start` [b] or None; the rope positions
+    [b, s], relative to each row's first real token)."""
+    cache_len = cache["length"]
+    if jnp.ndim(cache_len) == 0:
+        abs_positions = cache_len + jnp.arange(s)[None, :].repeat(b, 0)
+    else:
+        abs_positions = cache_len[:, None] + jnp.arange(s)[None, :]
+    start = cache.get("start")
+    if start is None:
+        return cache_len, abs_positions, None, abs_positions
+    return (cache_len, abs_positions, start,
+            jnp.maximum(abs_positions - start[:, None], 0))
+
+
+def _serve_layers(params: dict) -> dict:
+    """The stacked layers a serve-time loop indexes: with adapters, they
+    are stacked on the same [n_layers] axis and taken per layer exactly
+    like the base weights (the _proj low-rank branch fires per layer;
+    models/lora.py)."""
+    layers = params["layers"]
+    if "lora" in params:
+        layers = {**layers, **params["lora"]["layers"]}
+    return layers
+
+
+def _layer(layers: dict, li):
+    return jax.tree.map(
+        lambda w: jax.lax.dynamic_index_in_dim(w, li, 0, keepdims=False),
+        layers)
 
 
 def decode_step(params: dict, cache: dict, tokens: jax.Array,
@@ -391,32 +438,15 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array,
     writes land in the caller's buffers and no copy of a stack is made."""
     b, s = tokens.shape
     dt = cfg.dtype
-    cache_len = cache["length"]
-    if jnp.ndim(cache_len) == 0:
-        abs_positions = cache_len + jnp.arange(s)[None, :].repeat(b, 0)
-    else:
-        abs_positions = cache_len[:, None] + jnp.arange(s)[None, :]
-    start = cache.get("start")
-    if start is None:
-        positions = abs_positions
-    else:
-        # rope positions are relative to each row's first real token
-        positions = jnp.maximum(abs_positions - start[:, None], 0)
+    cache_len, abs_positions, start, positions = _cache_positions(cache, b, s)
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
-    layers = params["layers"]
-    if "lora" in params:
-        # serve-time adapters: stacked on the same [n_layers] axis, they
-        # are taken per layer exactly like the base weights (the _proj
-        # low-rank branch fires per layer; models/lora.py)
-        layers = {**layers, **params["lora"]["layers"]}
+    layers = _serve_layers(params)
 
     def step(li, carry):
         x, kc, vc = carry
-        layer = jax.tree.map(
-            lambda w: jax.lax.dynamic_index_in_dim(w, li, 0, keepdims=False),
-            layers)
+        layer = _layer(layers, li)
         return _decode_block(cfg, x, layer, li, kc, vc, cos, sin,
                              positions, cache_len, start, abs_positions)
 
@@ -429,3 +459,84 @@ def decode_step(params: dict, cache: dict, tokens: jax.Array,
     if start is not None:
         new_cache["start"] = start
     return logits, new_cache
+
+
+def mixed_step(params: dict, small: dict, chunk_tokens: jax.Array,
+               cache: dict, tokens: jax.Array, cfg: LlamaConfig
+               ) -> tuple[jax.Array, dict, jax.Array, dict]:
+    """A prefill chunk and a decode step as ONE pass over the weights
+    (models.OPTIONAL): `chunk_tokens` [1, s] appended to `small`, one
+    request's prefill cache, as `decode_step(params, small,
+    chunk_tokens, cfg)` appends them, and `tokens` [b, 1] to `cache`, the
+    slots' cache with per-row depths, as `decode_step(params, cache,
+    tokens, cfg)` does. Returns (the chunk's last position's logits [1,
+    vocab], small, the rows' logits [b, vocab], cache).
+
+    One layer loop carries both caches. The chunk's s rows and the
+    slots' b rows lie together, [s + b, d], through the loop: every
+    product of a layer (`attn_qkv`, `attn_out`, `mlp`) and the head's
+    reads its matrix once for all of them, where two programs read it
+    twice (a decode step's products over b rows cost what reading their
+    matrices costs: PERF.md, PR 57). They are cut apart only for
+    attention: the chunk's against its own cache, the rows' each against
+    its slot (on a TPU the decode kernel, which also writes the row). A
+    row computes what `decode_step` computes for it: the same
+    arithmetic, precisions and rope positions.
+
+    The phases are named here, since one program holds both: the
+    products and the chunk's attention are `prefill`'s, the slots'
+    attention (rope, kernel, write) is `decode`'s, under the block's
+    part names, so a profiler trace files every operation as it files
+    the two programs' (benchmarks/trace_spans.scope_of)."""
+    s, b = chunk_tokens.shape[1], tokens.shape[0]
+    hd, nh = cfg.head_dim, cfg.n_heads
+    chunk_at = _cache_positions(small, 1, s)
+    rows_at = _cache_positions(cache, b, 1)
+    with jax.named_scope("prefill"), jax.named_scope("embed"):
+        x = jnp.take(params["embed"], jnp.concatenate(
+            [chunk_tokens[0], tokens[:, 0]]), axis=0).astype(cfg.dtype)
+    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
+    layers = _serve_layers(params)
+
+    def attend(q, kk, vv, rows, kc, vc, li, at):
+        """`cached_attention` of `rows` rows' new tokens, q, k and v
+        as `_qkv` gives them, against layer `li` of one cache."""
+        cache_len, abs_positions, start, positions = at
+        heads = lambda a: a.reshape(rows, a.shape[0] // rows, -1, hd)
+        attn, kc, vc = cached_attention(
+            heads(q), heads(kk), heads(vv), kc, vc, li, cache_len,
+            abs_positions, start, scale=hd ** -0.5,
+            rope=(cos, sin, positions))
+        return attn.reshape(-1, nh * hd), kc, vc
+
+    def step(li, carry):
+        x, sk, sv, kc, vc = carry
+        with jax.named_scope("prefill"):
+            layer = _layer(layers, li)
+            q, kk, vv = _qkv(cfg, layer, x)
+            # the chunk's V stack held as it lies: left to choose, the
+            # compiler lays it positions-minor for v cut out of the
+            # s + b rows' product and copies the whole stack into and
+            # out of the layer loop, 176 MB each way at the long-prompt
+            # cell's bucket (tests/test_chip_compile.py)
+            sv = with_layout_constraint(sv, Layout((0, 1, 2, 3, 4)))
+            attn_chunk, sk, sv = attend(q[:s], kk[:s], vv[:s], 1, sk, sv,
+                                        li, chunk_at)
+        with jax.named_scope("decode"):
+            attn_rows, kc, vc = attend(q[s:], kk[s:], vv[s:], b, kc, vc,
+                                       li, rows_at)
+        with jax.named_scope("prefill"):
+            x = _out_mlp(cfg, layer, x,
+                         jnp.concatenate([attn_chunk, attn_rows]))
+        return x, sk, sv, kc, vc
+
+    x, sk, sv, kc, vc = jax.lax.fori_loop(
+        0, cfg.n_layers, step,
+        (x, small["k"], small["v"], cache["k"], cache["v"]))
+    with jax.named_scope("prefill"), jax.named_scope("lm_head"):
+        # the chunk's last row and the b rows: one read of the head
+        x = rms_norm(x[s - 1:], params["final_norm"], cfg.norm_eps)
+        logits = (x @ _head_matrix(params, cfg)).astype(jnp.float32)
+    small = {**small, "k": sk, "v": sv, "length": chunk_at[0] + s}
+    cache = {**cache, "k": kc, "v": vc, "length": rows_at[0] + 1}
+    return logits[:1], small, logits[1:], cache

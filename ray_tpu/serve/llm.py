@@ -17,6 +17,7 @@ prefill/decode with donated KV cache, greedy/temperature sampling in-jit.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import dataclasses
 import functools
 import os
@@ -104,7 +105,8 @@ class _Slot:
 @dataclass
 class _PendingPrefill:
     """A long prompt being prefilled one chunk per engine round, so
-    active decode streams keep emitting between chunks (vLLM-style
+    active decode streams keep emitting between chunks, or in the
+    chunk's own program where the model has the shared pass (vLLM-style
     chunked prefill; no reference analog — TPU-native static shapes:
     one trace per (chunk, bucket) pair)."""
     req: _Request
@@ -152,6 +154,15 @@ class LLMEngine:
     turn. `stats()` counts how often the pipeline was full
     (`decode_overlapped` of `batches`) and what the look-ahead wasted
     (`decode_rows_discarded`).
+
+    A prompt longer than `prefill_chunk` is prefilled a chunk a round.
+    Where the module has a `mixed_step` (models.OPTIONAL) that round is
+    ONE program: the chunk and the rows' decode step in one pass over
+    the weights (`_advance_prefill`, `_mixed_dispatch`), also when no
+    row decodes beside the chunk; `stats()` counts `mixed_steps` and the
+    `mixed_rows` they carried. Where it has none the round is the
+    chunk's program and then the decode step's. Which it is follows from
+    the module, never from an argument.
 
     `model` is a model config or the name of a llama preset. The module
     that serves it is `models.module_for(cfg)`, and everything the
@@ -271,6 +282,13 @@ class LLMEngine:
             {**mod.decode_counters(cfg, [], max_batch),
              **mod.prefill_counters(cfg, 0, 0, 0, cfg.max_seq_len)}, 0)
 
+        def sample(key, logits, temperature):
+            greedy = jnp.argmax(logits, axis=-1)
+            sampled = jax.random.categorical(
+                key, logits / jnp.maximum(temperature, 1e-4))
+            return jnp.where(temperature[:, 0] > 0, sampled,
+                             greedy).astype(jnp.int32)
+
         def step(params, cache, tokens, key, temperature):
             decode = tokens.ndim == 1
             if decode:  # device-resident [b], the last step's aux behind
@@ -293,15 +311,36 @@ class LLMEngine:
                                                 cache["length"])
                 with jax.named_scope("sample"):
                     key, sub = jax.random.split(key)
-                    greedy = jnp.argmax(logits, axis=-1)
-                    sampled = jax.random.categorical(
-                        sub, logits / jnp.maximum(temperature, 1e-4))
-                    nxt = jnp.where(temperature[:, 0] > 0, sampled,
-                                    greedy).astype(jnp.int32)
+                    nxt = sample(sub, logits, temperature)
                     if decode and self._aux:
                         # one array for the one host read of the step
                         nxt = jnp.concatenate([nxt, cache["aux"]])
                     return nxt, cache, key
+
+        def mixed(params, small, chunk, cache, tokens, key, temperature,
+                  temps):
+            """A chunk of one request's prefill and the slots' decode
+            step as the module's one pass over the weights: what `step`
+            does for `(small, chunk, temperature)` and for `(cache,
+            tokens, temps)`. The module names the phases of its
+            operations; here the rows' bookkeeping and sampling are
+            `decode`'s and the chunk's token is `prefill`'s."""
+            with jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh):
+                depth = cache["length"]
+                chunk_logits, small, logits, cache = mod.mixed_step(
+                    params, small, chunk, cache, tokens[:max_batch, None],
+                    cfg)
+                with jax.named_scope("prefill"), jax.named_scope("sample"):
+                    key, sub_chunk, sub = jax.random.split(key, 3)
+                    first = sample(sub_chunk, chunk_logits, temperature)
+                with jax.named_scope("decode"):
+                    cache["length"] = jnp.where(depth < 0, depth,
+                                                cache["length"])
+                    with jax.named_scope("sample"):
+                        nxt = sample(sub, logits, temps)
+                        if self._aux:
+                            nxt = jnp.concatenate([nxt, cache["aux"]])
+                return first, small, nxt, cache, key
 
         # one jit; prefill (s=bucket) and decode (s=1) are separate traces
         # of the same function, cached per shape. Donated: the cache (1)
@@ -315,29 +354,38 @@ class LLMEngine:
         # read afterwards; and temps (4), which decode reuses across
         # steps.
         self._step_jit = jax.jit(step, donate_argnums=(1, 3))
+        # where the module has the shared pass, the program of a round
+        # with a chunk due (_advance_prefill). Donated: both caches
+        # (1, 3) and the key (5); the tokens (4) and the slots' temps
+        # (7) are not, as in `step`
+        self._mixed_jit = None if mod.mixed_step is None else jax.jit(
+            mixed, donate_argnums=(1, 3, 5))
         self._key_seed = seed ^ 0x5EED
         self._key_reseeds = 0
+        self._stepped = False
 
-        def _step_guarded(*args):
-            # the key rides donated through every call site (incl. the
-            # prefill paths that never reach _poison_recover): a failed
-            # step may have consumed its buffer, so re-seed BEFORE
-            # re-raising or the engine would raise 'Array has been
-            # deleted' on every later step, forever
-            try:
-                return self._step_jit(*args)
-            except BaseException:
-                self._reseed_key()
-                raise
+        def guarded(program: str):
+            def call(*args):
+                if not self._stepped:
+                    # the first step of any kind, returned, ends the
+                    # process's start-up (phase `ready`)
+                    self._stepped = True
+                    with process_log().phase("ready"):
+                        return call(*args)
+                # the key rides donated through every call site (incl.
+                # the prefill paths that never reach _poison_recover): a
+                # failed step may have consumed its buffer, so re-seed
+                # BEFORE re-raising or the engine would raise 'Array has
+                # been deleted' on every later step, forever
+                try:
+                    return getattr(self, program)(*args)
+                except BaseException:
+                    self._reseed_key()
+                    raise
+            return call
 
-        def _first_step(*args):
-            # the first step of any kind, returned, ends the process's
-            # start-up (phase `ready`); every later one is _step_guarded
-            self._step = _step_guarded
-            with process_log().phase("ready"):
-                return _step_guarded(*args)
-
-        self._step = _first_step
+        self._step = guarded("_step_jit")
+        self._mixed = guarded("_mixed_jit")
         self._decode_program = f"decode_dispatch[{max_batch}]"
 
         def insert_row(cache, row, slot, length, start):
@@ -384,9 +432,11 @@ class LLMEngine:
         self._row_live = [False] * max_batch
         # device-resident between steps: re-uploading from host every
         # decode step would cost two H2D transfers per token
-        self._cur = jnp.zeros((max_batch + len(self._aux),), jnp.int32)
-        self._temps = jnp.zeros((max_batch, 1), jnp.float32)
-        self._key = jax.random.PRNGKey(seed ^ 0x5EED)
+        self._replicated = jax.sharding.NamedSharding(
+            self.mesh, jax.sharding.PartitionSpec())
+        self._cur, self._temps = self._fresh_feed()
+        self._key = jax.device_put(jax.random.PRNGKey(seed ^ 0x5EED),
+                                   self._replicated)
         # the decode step dispatched and not read yet; whenever it is
         # set, some slot still holds a request one of its rows is for
         self._inflight: Optional[_InFlight] = None
@@ -422,6 +472,10 @@ class LLMEngine:
         self.decode_kv_positions_read = 0
         self.prefills = 0
         self.prefill_chunks = 0
+        # of the chunk calls, those the mixed program made, and the
+        # decode rows they carried
+        self.mixed_steps = 0
+        self.mixed_rows = 0
         self.prefix_hits = 0
         self.prefix_misses = 0
         self.prefix_hit_tokens = 0   # prefill tokens skipped via reuse
@@ -467,9 +521,7 @@ class LLMEngine:
                 self._row_live = [False] * self.max_batch
                 self._inflight = None
                 self._decode_cache = None
-                self._cur = jnp.zeros(
-                    (self.max_batch + len(self._aux),), jnp.int32)
-                self._temps = jnp.zeros((self.max_batch, 1), jnp.float32)
+                self._cur, self._temps = self._fresh_feed()
             self._queue = asyncio.Queue()
             self._task = asyncio.ensure_future(self._engine_loop())
             self._loop = loop
@@ -598,18 +650,20 @@ class LLMEngine:
             while (not queue.empty()
                    and any(s is None for s in self._slots)):
                 await _admit(queue.get_nowait())
+            stepped = False
             if self._pending_prefills:
                 # one chunk per round: a long prompt costs active
                 # streams ~one chunk of latency per step, not the
-                # whole-prompt stall
+                # whole-prompt stall. Where the module has the shared
+                # pass the chunk's program steps the rows too
                 try:
-                    await loop.run_in_executor(
+                    stepped = await loop.run_in_executor(
                         None, self._advance_prefill, epoch)
                 except Exception:
                     if epoch != self._epoch:
                         return
-            if any(s is not None and s.emitted >= 0
-                   for s in self._slots):
+            if not stepped and any(s is not None and s.emitted >= 0
+                                   for s in self._slots):
                 try:
                     await loop.run_in_executor(
                         None, self._decode_step_all, epoch)
@@ -725,8 +779,14 @@ class LLMEngine:
         if prefix is not None:
             return self._graft_prefix(small, prefix[0], start, prefix[1])
         if pos:
-            small["length"] = jnp.int32(pos)
+            small["length"] = self._depth(pos)
         return small
+
+    def _depth(self, pos: int):
+        """A prefill cache's `length` set from the host, placed as
+        `_prefill_cache` places the one it starts from (the step's
+        program is then the same program)."""
+        return jax.device_put(np.int32(pos), self._cache_sharding["length"])
 
     def _prefill_dispatch(self, req: _Request, small, prompts, pos: int,
                           chunk: int, prefix: Optional[tuple] = None):
@@ -749,11 +809,14 @@ class LLMEngine:
             nxt, small, self._key = self._step(
                 self.params, small, jnp.asarray(prompts[:, pos:pos + chunk]),
                 self._key, jnp.asarray([[req.temperature]], np.float32))
+        self._count_prefill_call(req, counters)
+        return nxt, small
+
+    def _count_prefill_call(self, req: _Request, counters: dict):
         for name, n in counters.items():
             self._model_counters[name] += n
         if req.obs is not None:
             req.obs["prefill_chunks"] = req.obs.get("prefill_chunks", 0) + 1
-        return nxt, small
 
     # ----------------------------------------------- prefix KV reuse
     def _prefix_lookup(self, toks: list) -> tuple[Optional[dict], int]:
@@ -790,7 +853,7 @@ class LLMEngine:
                 entry["row"][key_], e_off, matched, axis)
             small[key_] = jax.lax.dynamic_update_slice_in_dim(
                 small[key_], seg, off, axis)
-        small["length"] = jnp.int32(off + matched)
+        small["length"] = self._depth(off + matched)
         return small
 
     def _prefix_put(self, tokens: list, small, bucket: int):
@@ -818,42 +881,115 @@ class LLMEngine:
                              int(h["bucket"]), int(h["start"]),
                              store=False)
 
-    def _advance_prefill(self, epoch: int):
+    def _advance_prefill(self, epoch: int) -> bool:
+        """A round's chunk: the next `prefill_chunk` tokens of the first
+        pending prompt, and after its last chunk the graft into its
+        slot. Where the module has a `mixed_step` the chunk's program
+        steps the decode rows too, and the round's read and emit follow
+        here (`_mixed_dispatch`); the return says so: the round then
+        needs no decode step of its own. A prefill pool's prompt
+        (`prefill_only`) keeps the chunk's own program."""
         with self._mutex:
             if epoch != self._epoch or not self._pending_prefills:
-                return
+                return False
             pf = self._pending_prefills[0]
+            mixed = self._mixed_jit is not None and not pf.req.handoff_out
+            finishing = False
             try:
                 chunk = min(self.prefill_chunk, pf.bucket - pf.pos)
-                nxt, pf.small = self._prefill_dispatch(
-                    pf.req, pf.small, pf.prompts, pf.pos, chunk, pf.prefix)
+                if mixed:
+                    nxt = self._mixed_round_locked(pf, chunk)
+                else:
+                    nxt, pf.small = self._prefill_dispatch(
+                        pf.req, pf.small, pf.prompts, pf.pos, chunk,
+                        pf.prefix)
                 pf.prefix = None
                 pf.pos += chunk
                 self.prefill_chunks += 1
+                self.mixed_steps += int(mixed)
                 if pf.pos < pf.bucket:
-                    return
+                    return mixed
+                finishing = True
                 self._pending_prefills.pop(0)
                 self.prefills += 1
                 self._slots[pf.slot] = None  # release the reservation
                 self._finish_prefill(
                     pf.req, pf.slot, pf.small, nxt,
                     pf.bucket, pf.bucket - len(pf.req.tokens))
+                return mixed
             except BaseException as e:
                 # a failed chunk step donated pf.small's buffers, and a
                 # failed final insert already removed pf from the lists
-                # _poison_recover notifies — either way, retrying is
-                # impossible and the consumer must hear about it
-                if self._pending_prefills and \
-                        self._pending_prefills[0] is pf:
+                # _poison_recover notifies: either way, retrying is
+                # impossible and the consumer must hear about it. A
+                # failed mixed step lost the slots' cache too, and
+                # _poison_recover has told this prompt with the rest
+                pending = bool(self._pending_prefills) and \
+                    self._pending_prefills[0] is pf
+                if pending:
                     self._pending_prefills.pop(0)
-                if self._slots[pf.slot] is not None and \
-                        self._slots[pf.slot].emitted < 0:
                     self._slots[pf.slot] = None
-                pf.req.loop.call_soon_threadsafe(
-                    pf.req.out.put_nowait,
-                    e if isinstance(e, Exception)
-                    else RuntimeError(repr(e)))
+                if pending or finishing:
+                    pf.req.loop.call_soon_threadsafe(
+                        pf.req.out.put_nowait,
+                        e if isinstance(e, Exception)
+                        else RuntimeError(repr(e)))
                 raise
+
+    def _mixed_round_locked(self, pf: _PendingPrefill, chunk: int):
+        """One round of the decode pipeline (`_decode_step_locked`)
+        whose dispatch is the mixed program: it carries `pf`'s next
+        chunk and steps the rows. Returns the chunk's sampled token."""
+        first = None
+
+        def dispatch(prev):
+            nonlocal first
+            first, rec = self._mixed_dispatch(pf, chunk, prev)
+            return rec
+
+        self._decode_step_locked(dispatch)
+        return first
+
+    def _mixed_dispatch(self, pf: _PendingPrefill, chunk: int,
+                        prev: Optional[_InFlight]):
+        """Dispatch the mixed program for `pf`'s next chunk and the rows
+        live in this step (`_step_rows`): what `_prefill_dispatch` and
+        `_dispatch_decode` do in two calls, under both their spans.
+        With no live row every row is retired, the slots' attention
+        reads nothing, and the step counts as a chunk alone. Returns
+        (the chunk's sampled token, the rows' record or None)."""
+        req, bucket, pos = pf.req, pf.bucket, pf.pos
+        rows, active = self._step_rows(prev)
+        self._retire_left(rows)
+        chunk_counters = self._model.prefill_counters(
+            self.cfg, bucket - len(req.tokens), pos, chunk, bucket)
+        live, read, counters = self._kv_positions(rows, prev) if active \
+            else (0, 0, {})
+        with _site("rayt.engine.prefill_chunk",
+                   f"prefill_chunk[{chunk}@{bucket}]",
+                   request_id=req.request_id, pos=pos, chunk=chunk,
+                   last=int(pos + chunk >= bucket), mixed=1,
+                   **chunk_counters), \
+                (_span("rayt.engine.decode_dispatch", active=active,
+                       live_positions=live, t_host=time.perf_counter(),
+                       mixed=1, **counters)
+                 if active else contextlib.nullcontext()):
+            if pf.small is None:
+                pf.small = self._prefill_cache(
+                    bucket, bucket - len(req.tokens), pos, pf.prefix)
+            (first, pf.small, nxt, self._decode_cache,
+             self._key) = self._mixed(
+                self.params, pf.small,
+                jnp.asarray(pf.prompts[:, pos:pos + chunk]),
+                self._decode_cache, self._cur, self._key,
+                jnp.asarray([[req.temperature]], np.float32), self._temps)
+        self._cur = nxt
+        self._count_prefill_call(req, chunk_counters)
+        self.mixed_rows += active
+        if not active:
+            return first, None
+        return first, self._count_decode_step(nxt, rows, active, live, read,
+                                              counters)
 
     def _row(self, small: dict) -> dict:
         """A batch-1 cache's leaves without the bookkeeping."""
@@ -919,14 +1055,22 @@ class LLMEngine:
                 self._cur, self._temps, jnp.int32(slot), jnp.int32(first),
                 jnp.float32(req.temperature))
 
+    def _fresh_feed(self):
+        """(`_cur`, `_temps`) of an engine no row decodes in, placed as
+        a step's outputs are: a program asked for with these is the
+        program asked for with those."""
+        return jax.device_put(
+            (jnp.zeros((self.max_batch + len(self._aux),), jnp.int32),
+             jnp.zeros((self.max_batch, 1), jnp.float32)), self._replicated)
+
     def _reseed_key(self):
         """Rebuild the PRNG key after a failed (donating) step consumed
         its buffer; the reseed counter keeps the stream fresh."""
         import jax as _jax
 
         self._key_reseeds += 1
-        self._key = _jax.random.PRNGKey(
-            self._key_seed ^ (self._key_reseeds << 16))
+        self._key = _jax.device_put(_jax.random.PRNGKey(
+            self._key_seed ^ (self._key_reseeds << 16)), self._replicated)
 
     def _poison_recover(self):
         """The shared decode cache was donated into a call that failed:
@@ -935,7 +1079,8 @@ class LLMEngine:
         The PRNG key is re-seeded by the _step guard at the raise site."""
         err = RuntimeError("decode cache lost to a failed engine step")
         for s in self._slots:
-            if s is not None:
+            # a reserved slot's request is told with the pending prompts
+            if s is not None and s.emitted >= 0:
                 s.req.loop.call_soon_threadsafe(s.req.out.put_nowait, err)
         for pf in self._pending_prefills:
             pf.req.loop.call_soon_threadsafe(pf.req.out.put_nowait, err)
@@ -944,8 +1089,7 @@ class LLMEngine:
         self._row_live = [False] * self.max_batch
         self._inflight = None
         self._decode_cache = None
-        self._cur = jnp.zeros((self.max_batch + len(self._aux),), jnp.int32)
-        self._temps = jnp.zeros((self.max_batch, 1), jnp.float32)
+        self._cur, self._temps = self._fresh_feed()
 
     def _decode_step_all(self, epoch: int):
         with self._mutex:
@@ -953,17 +1097,19 @@ class LLMEngine:
                 raise RuntimeError("engine restarted during decode")
             self._decode_step_locked()
 
-    def _decode_step_locked(self):
+    def _decode_step_locked(self, dispatch=None):
         """One round of the decode pipeline: dispatch step k+1, fed by
         step k's tokens where they lie on the device, THEN read step k's
         tokens and emit them. The device holds the next step queued
         while the host waits for the read, runs the emit loop and gives
         the event loop its turn. An admission (insert_row, set_slot) is
         queued behind the step in flight and applies to its outputs, so
-        the admitted request joins at the step after."""
+        the admitted request joins at the step after. `dispatch` is
+        `_dispatch_decode`, or the mixed program's (a round with a
+        chunk due: `_mixed_round_locked`)."""
         prev = self._inflight
         try:
-            self._inflight = self._dispatch_decode(prev)
+            self._inflight = (dispatch or self._dispatch_decode)(prev)
             if prev is None:
                 return
             # a device fault of the step just dispatched surfaces at a
@@ -983,11 +1129,9 @@ class LLMEngine:
             self.decode_rows_discarded += ahead.active
             self._inflight = None
 
-    def _dispatch_decode(self, prev: Optional[_InFlight]):
-        """Dispatch a decode step across all slots (free rows compute
-        masked garbage — the price of a single static-shape trace) for
-        the rows that are live in it, and return its record; None, and
-        no dispatch, when no row is. A row that gets its last token from
+    def _step_rows(self, prev: Optional[_InFlight]) -> tuple[list, int]:
+        """(per row, the request a step dispatched now is for, or None;
+        how many rows that is). A row that gets its last token from
         `prev`, the step in flight, is not live: the ends the host can
         count are known here, an eos only at the read."""
         rows: list = [None] * self.max_batch
@@ -999,16 +1143,27 @@ class LLMEngine:
                          or s.length + 1 >= self.cfg.max_seq_len - 1)):
                 continue
             rows[i] = s.req
-        active = sum(1 for r in rows if r is not None)
-        if not active:
-            return None
+        return rows, sum(1 for r in rows if r is not None)
+
+    def _retire_left(self, rows: list):
+        """Tell the device which rows were freed since the last step
+        (_finish, or `_step_rows`' rule), so that the step for `rows`
+        reads none of their cache."""
         gone = [live and r is None for live, r in zip(self._row_live, rows)]
         if any(gone):
-            # freed since the last step (_finish, or the rule above):
-            # tell the device, so the step reads none of their cache
             self._decode_cache["length"] = self._retire(
                 self._decode_cache["length"], np.asarray(gone))
             self._row_live = [r is not None for r in rows]
+
+    def _dispatch_decode(self, prev: Optional[_InFlight]):
+        """Dispatch a decode step across all slots (free rows compute
+        masked garbage — the price of a single static-shape trace) for
+        the rows that are live in it, and return its record; None, and
+        no dispatch, when no row is."""
+        rows, active = self._step_rows(prev)
+        if not active:
+            return None
+        self._retire_left(rows)
         live, read, counters = self._kv_positions(rows, prev)
         # t_host is this process's perf_counter read as the span opens:
         # the one event that carries both clocks, so request records and
@@ -1022,6 +1177,11 @@ class LLMEngine:
                 self.params, self._decode_cache, self._cur,
                 self._key, self._temps)
         self._cur = nxt  # stays on device for the next step
+        return self._count_decode_step(nxt, rows, active, live, read,
+                                       counters)
+
+    def _count_decode_step(self, nxt, rows: list, active: int, live: int,
+                           read: int, counters: dict) -> _InFlight:
         self.batches += 1
         self.decode_kv_positions_live += live
         self.decode_kv_positions_read += read
@@ -1116,6 +1276,8 @@ class LLMEngine:
                 "batches": self.batches,
                 "prefills": self.prefills,
                 "prefill_chunks": self.prefill_chunks,
+                "mixed_steps": self.mixed_steps,
+                "mixed_rows": self.mixed_rows,
                 "prefix_hits": self.prefix_hits,
                 "prefix_misses": self.prefix_misses,
                 "prefix_hit_tokens": self.prefix_hit_tokens,
@@ -1227,9 +1389,13 @@ class LlamaService:
                 "count": len(devs), "pid": os.getpid(),
                 "mesh_devices": [d.id for d in
                                  self.engine.mesh.devices.flat],
-                # distinct compiled variants of the engine's one jitted
-                # step: a prefill per bucket or chunk shape, one decode
-                "step_programs": self.engine._step_jit._cache_size(),
+                # distinct compiled variants of the engine's jitted
+                # steps: a prefill per bucket or chunk shape (the mixed
+                # program's where the module has one), one decode
+                "step_programs": sum(
+                    jitted._cache_size() for jitted in (
+                        self.engine._step_jit, self.engine._mixed_jit)
+                    if jitted is not None),
                 "memory": [d.memory_stats() or {} for d in devs]}
 
     def reference_check(self, tokens: list[int],

@@ -176,27 +176,56 @@ def test_decode_kernel_compiles_for_the_chip(chip, on_the_chip, layers,
             2 * layers * batch * kv_heads * max_len * head_dim)
 
 
-def _compiled_decode_step(chip, cfg, batch, max_len, s, per_row):
-    """llama.decode_step for `batch` rows x `s` tokens against a cache
-    `max_len` deep, cache donated, compiled for the described chip.
-    Returns (compiled, the cache's shapes)."""
+def _llama_on(chip, cfg):
+    """(place, params): `place` puts a tree of shapes on the described
+    chip; `params` are llama's for `cfg`, placed."""
     on = SingleDeviceSharding(chip)
 
     def place(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=on), tree)
 
-    params = place(jax.eval_shape(
+    return place, place(jax.eval_shape(
         lambda key: llama.init_params(cfg, key), jax.random.PRNGKey(0)))
+
+
+def _slots_cache(cfg, batch, max_len, per_row=True):
     cache = dict(jax.eval_shape(
         lambda: llama.init_kv_cache(cfg, batch, max_len)))
     if per_row:
         cache["length"] = jax.ShapeDtypeStruct((batch,), jnp.int32)
-    tokens = jax.ShapeDtypeStruct((batch, s), jnp.int32, sharding=on)
+    return cache
+
+
+def _compiled_decode_step(chip, cfg, batch, max_len, s, per_row):
+    """llama.decode_step for `batch` rows x `s` tokens against a cache
+    `max_len` deep, cache donated, compiled for the described chip.
+    Returns (compiled, the cache's shapes)."""
+    place, params = _llama_on(chip, cfg)
+    cache = _slots_cache(cfg, batch, max_len, per_row)
+    tokens = place(jax.ShapeDtypeStruct((batch, s), jnp.int32))
     compiled = jax.jit(
         lambda p, c, t: llama.decode_step(p, c, t, cfg),
         donate_argnums=(1,)).lower(params, place(cache), tokens).compile()
     return compiled, cache
+
+
+def _compiled_mixed_step(chip, cfg, batch, max_len, s, bucket):
+    """llama.mixed_step for a chunk of `s` tokens against a prefill
+    cache `bucket` deep and one token for each of `batch` slots `max_len`
+    deep (per-row depths), both caches donated, compiled for the
+    described chip. Returns (compiled, the slots' cache's shapes, the
+    prefill cache's shapes)."""
+    place, params = _llama_on(chip, cfg)
+    cache = _slots_cache(cfg, batch, max_len)
+    small = _slots_cache(cfg, 1, bucket, per_row=False)
+    tokens = lambda b, n: place(jax.ShapeDtypeStruct((b, n), jnp.int32))
+    compiled = jax.jit(
+        lambda p, sm, ch, c, t: llama.mixed_step(p, sm, ch, c, t, cfg),
+        donate_argnums=(1, 3)).lower(
+            params, place(small), tokens(1, s), place(cache),
+            tokens(batch, 1)).compile()
+    return compiled, cache, small
 
 
 def test_1b_decode_step_compiles_for_the_chip(chip):
@@ -227,15 +256,18 @@ def _mosaic_calls(text, name=None):
                 rf"\s*(?:ROOT )?%?{name}[.\d]* = ", line))]
 
 
-@pytest.mark.parametrize("batch,max_len,s,per_row,kernels", [
-    (8, 4096, 1, True, 0),    # the engine's decode step, 8 slots: XLA path
-    (1, 3584, 256, False, 0),  # one chunk of a long prompt's prefill
-    (8, 4096, 1, True, 1),    # the decode step as a TPU runs it
-    (1, 3584, 256, False, 0),  # the chunk as a TPU runs it: no kernel
+@pytest.mark.parametrize("batch,max_len,s,per_row,kernels,mixed_bucket", [
+    (8, 4096, 1, True, 0, None),    # the decode step, 8 slots: XLA path
+    (1, 3584, 256, False, 0, None),  # one chunk of a long prompt's prefill
+    (8, 4096, 1, True, 1, None),    # the decode step as a TPU runs it
+    (1, 3584, 256, False, 0, None),  # the chunk as a TPU runs it: no kernel
+    (8, 4096, 256, True, 1, 3584),  # the chunk carrying the 8 rows (PR 57)
+    (8, 4096, 256, True, 1, 2048),  # ... at the cell's other bucket
 ], ids=["decode-8x4096", "chunk-1x3584-s256", "decode-8x4096-on-chip",
-        "chunk-1x3584-s256-on-chip"])
+        "chunk-1x3584-s256-on-chip", "mixed-s256@3584+8x4096-on-chip",
+        "mixed-s256@2048+8x4096-on-chip"])
 def test_decode_step_moves_no_cache(chip, request, batch, max_len, s,
-                                    per_row, kernels):
+                                    per_row, kernels, mixed_bucket):
     """Per step every cache byte is read at most once, by attention, and
     only the new rows are written (PERF.md, PR 25): with the cache
     donated the step's temporaries hold less than ONE layer of it (seed:
@@ -248,19 +280,33 @@ def test_decode_step_moves_no_cache(chip, request, batch, max_len, s,
     the new rows written in them: one Mosaic call in the layer loop,
     whose results are the stacks, and NO `dynamic-update-slice` of a
     stack's shape in that program. A chunk keeps the XLA path and its
-    writes."""
+    writes. The mixed program (`llama.mixed_step`, PR 57: a chunk of `s`
+    in a cache `mixed_bucket` deep beside the slots) is held to both: the slots' stacks as the
+    decode step's, through its one Mosaic call a layer; the request's
+    stacks as the chunk's, with no copy of either (left free, the
+    compiler re-lays the request's V stack around the layer loop, 176
+    MB each way: `mixed_step` holds it as it lies)."""
     if request.node.callspec.id.endswith("on-chip"):
         request.getfixturevalue("on_the_chip")
     cfg = llama.LlamaConfig(max_seq_len=max_len, **_SERVE_CFG)
-    compiled, cache = _compiled_decode_step(chip, cfg, batch, max_len, s,
-                                            per_row)
+    chunk_stacks = set()
+    if mixed_bucket is None:
+        compiled, cache = _compiled_decode_step(chip, cfg, batch, max_len, s,
+                                                per_row)
+        own_bytes = 2 * cfg.n_layers * 2 * math.prod(cache["k"].shape[1:])
+    else:
+        compiled, cache, small = _compiled_mixed_step(
+            chip, cfg, batch, max_len, s, mixed_bucket)
+        chunk_stacks = {tuple(small[key].shape) for key in ("k", "v")}
+        own_bytes = 2 * cfg.n_layers * 2 * (
+            math.prod(cache["k"].shape[1:]) + math.prod(small["k"].shape[1:]))
     assert compiled.as_text().count("tpu_custom_call") == kernels
 
     stacks = {tuple(cache[key].shape) for key in ("k", "v")}
     layer_bytes = 2 * math.prod(cache["k"].shape[1:])
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < min(0.5e9, 2 * layer_bytes)
-    assert mem.alias_size_in_bytes >= 2 * cfg.n_layers * layer_bytes
+    assert mem.alias_size_in_bytes >= own_bytes
     repeated = batch * max_len * cfg.n_heads * cfg.head_dim
     may_give_a_stack = ("parameter", "get-tuple-element") + (
         () if kernels else ("dynamic-update-slice",))
@@ -271,6 +317,9 @@ def test_decode_step_moves_no_cache(chip, request, batch, max_len, s,
         dims = tuple(int(d) for d in m.group(2).split(",") if d)
         if dims in stacks:
             assert m.group(3) in may_give_a_stack, line[:200]
+        elif dims in chunk_stacks:
+            assert m.group(3) in ("parameter", "get-tuple-element",
+                                  "dynamic-update-slice"), line[:200]
         elif max_len in dims and cfg.head_dim in dims:
             assert math.prod(dims) < repeated, line[:200]
     if kernels:
@@ -365,15 +414,19 @@ def _weight_moves(compiled, params):
     return found
 
 
-@pytest.mark.parametrize("batch,max_len,s,per_row,left", [
-    (8, 4096, 1, True, []),
-    (8, 4096, 1, True, []),
+@pytest.mark.parametrize("batch,max_len,s,per_row,left,mixed_bucket", [
+    (8, 4096, 1, True, [], None),
+    (8, 4096, 1, True, [], None),
     (1, 3584, 256, False, [("copy", "wv", 4 * 2 ** 20),
-                           ("slice", "wv", 4 * 2 ** 20)]),
+                           ("slice", "wv", 4 * 2 ** 20)], None),
+    (8, 4096, 256, True, [], 3584),
+    (8, 4096, 256, True, [], 2048),
 ], ids=["decode-8x4096", "decode-8x4096-on-chip",
-        "chunk-1x3584-s256-on-chip"])
+        "chunk-1x3584-s256-on-chip", "mixed-s256@3584+8x4096-on-chip",
+        "mixed-s256@2048+8x4096-on-chip"])
 def test_serve_step_reads_weights_where_they_lie(chip, request, batch,
-                                                 max_len, s, per_row, left):
+                                                 max_len, s, per_row, left,
+                                                 mixed_bucket):
     """Every matrix of a layer is read from its stack by the product
     that uses it (PERF.md, PR 45): in the serve cells' two programs no
     instruction outside a fusion gives one layer of a stacked weight as
@@ -390,12 +443,20 @@ def test_serve_step_reads_weights_where_they_lie(chip, request, batch,
     each a layer. Held like q and k, v makes the compiler re-lay the
     whole V stack `bf16[24,1,8,3584,128]` (176 MB) into and out of the
     layer loop, which `test_decode_step_moves_no_cache` forbids; so does
-    writing V a kv head at a time (PR 45: forms tried, CHANGES.md)."""
+    writing V a kv head at a time (PR 45: forms tried, CHANGES.md). The
+    mixed program (PR 57; the chunk in a cache `mixed_bucket` deep), whose
+    products run over the chunk's rows and the slots' together, has
+    none, wv's included: v comes of one product for both, and the
+    request's V stack is held as it lies."""
     if request.node.callspec.id.endswith("on-chip"):
         request.getfixturevalue("on_the_chip")
     cfg = llama.LlamaConfig(max_seq_len=max_len, **_SERVE_CFG)
-    compiled, _ = _compiled_decode_step(chip, cfg, batch, max_len, s,
-                                        per_row)
+    if mixed_bucket is None:
+        compiled, _ = _compiled_decode_step(chip, cfg, batch, max_len, s,
+                                            per_row)
+    else:
+        compiled, _, _ = _compiled_mixed_step(chip, cfg, batch, max_len, s,
+                                              mixed_bucket)
     params = jax.eval_shape(lambda key: llama.init_params(cfg, key),
                             jax.random.PRNGKey(0))
     assert sorted(_weight_moves(compiled, params)) == left

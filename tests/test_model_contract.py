@@ -152,6 +152,74 @@ def test_counters_name_what_stats_will_carry(served):
     assert module.prefill_counters(cfg, 4, 0, CHUNK, N).keys() == none.keys()
 
 
+def test_mixed_step_is_optional_and_llama_alone_has_it(served):
+    """`mixed_step` (PR 57): None unless the module has the shared pass
+    of a chunk and the rows' step. llama's gives both programs' logits
+    and both caches as they went in."""
+    module, cfg = served
+    assert models.OPTIONAL["mixed_step"] is None
+    if module is not llama:
+        assert module.mixed_step is None
+        return
+    params = jax.eval_shape(
+        lambda: module.init_params(cfg, jax.random.PRNGKey(0)))
+
+    def run(params):
+        small = module.init_cache(cfg, 1, max_len=N)
+        cache = module.init_cache(cfg, B, max_len=N)
+        cache["length"] = jnp.full((B,), 5, jnp.int32)
+        return (small, cache), module.mixed_step(
+            params, small, jnp.ones((1, CHUNK), jnp.int32), cache,
+            jnp.ones((B, 1), jnp.int32), cfg)
+
+    (small, cache), (chunk_logits, new_small, logits, new_cache) = \
+        jax.eval_shape(run, params)
+    assert chunk_logits.shape == (1, cfg.vocab_size)
+    assert logits.shape == (B, cfg.vocab_size)
+    assert chunk_logits.dtype == logits.dtype == jnp.float32
+    assert _shapes(new_small) == _shapes(small)
+    assert _shapes(new_cache) == _shapes(cache)
+
+
+def test_an_engine_over_a_module_without_mixed_step_runs_two_programs():
+    """A round with a chunk due, over a module that has no `mixed_step`
+    (the byte model's smallest config): the chunk's call of `step` and
+    then the rows' call, as before PR 57, and nothing counted as
+    mixed."""
+    import asyncio
+
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg = SMALLEST[evabyte.EvaByteConfig]()
+    eng = LLMEngine(cfg, tp=1, max_batch=2, prompt_buckets=(32, 64),
+                    prefill_chunk=8)
+    assert eng._mixed_jit is None
+    calls, step_jit = [], eng._step_jit
+    eng._step_jit = lambda *a: (calls.append(a[2].shape), step_jit(*a))[1]
+
+    async def stream(prompt, n):
+        return [t async for t in eng.generate(prompt, max_new_tokens=n)]
+
+    async def run():
+        row = asyncio.ensure_future(stream([1, 2, 3], 60))
+        while eng.batches < 3:
+            await asyncio.sleep(0.01)
+        late = await stream(list(range(1, 31)) * 2, 3)
+        return await row, late
+
+    row, late = asyncio.run(run())
+    assert (len(row), len(late)) == (60, 3)
+    st = eng.stats()
+    assert (st["mixed_steps"], st["mixed_rows"]) == (0, 0)
+    # the short prompt's one chunk that holds a token, the long one's 8
+    assert st["prefill_chunks"] == 1 + 8
+    # each chunk's program and, beside a live row, the rows' decode step
+    # behind it
+    chunks = [i for i, shape in enumerate(calls) if shape == (1, 8)]
+    assert len(chunks) == 9
+    assert all(calls[i + 1] == (eng.max_batch,) for i in chunks[1:])
+
+
 def test_a_module_lacking_a_required_name_is_refused(monkeypatch):
     lacking = types.SimpleNamespace(**vars(llama))
     del lacking.decode_read_block

@@ -243,6 +243,32 @@ def test_engine_step_names_its_phase_and_parts(phase, tokens):
         (phase, "attn", False)
 
 
+def test_mixed_program_names_both_phases_and_files_each_part_once():
+    """(b) lowered: the engine's mixed program (a chunk carrying the
+    decode rows, PR 57) names its phases in the model: no path lies
+    under both, the products (`attn_out`, `mlp`, `lm_head`, `embed`)
+    are `prefill`'s alone, and `attn_qkv` (the rope), `attn` and the
+    writes are under each: `scope_of` files an operation as it
+    files the two programs'."""
+    eng = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=64,
+                    prompt_buckets=(16, 32), prefill_chunk=8)
+    eng._ensure_decode_cache()
+    small = llama.init_kv_cache(eng.cfg, 1, max_len=32)
+    paths = _scope_paths(eng._mixed_jit.lower(
+        eng.params, small, jnp.zeros((1, 8), jnp.int32), eng._decode_cache,
+        eng._cur, eng._key, jnp.zeros((1, 1), jnp.float32), eng._temps))
+    filed = {}
+    for p in paths:
+        phase, part, _ = trace_spans.scope_of(p + "/op")
+        assert {"prefill", "decode"} - set(p.split("/")), p
+        filed.setdefault(part, set()).add(phase)
+    for part in ("embed", "lm_head", "attn_out", "mlp"):
+        assert filed[part] == {"prefill"}, (part, filed[part])
+    # (here the rows' K and V are written by XLA; on a TPU by the kernel)
+    for part in ("attn_qkv", "attn", "kv_update", "sample"):
+        assert filed[part] == {"prefill", "decode"}, (part, filed[part])
+
+
 def test_lora_train_step_names_loss_optimizer_and_kernels():
     """(b) the LoRA step with the flash kernels (interpret mode here) and
     full remat: loss, optimizer, the parts, the kernels, the adapters'
@@ -604,12 +630,11 @@ def test_engine_names_every_program_it_asks_for_by_its_site(fresh_log):
     assert _stages_of(recs, "finish_prefill[16]", "set_slot") == once
     assert _stages_of(recs, "admit[16]", "<lambda>") == once   # retire
     # `step` is one function behind both phases: the site tells them
-    # apart. Each is asked for once, and once more when its arguments
-    # stop being the arrays the host made for the very first call
-    # (ROADMAP S7: the same shape, compiled or loaded again)
+    # apart. Each is asked for once: the arrays the host makes for the
+    # very first call (the key, the token vector, the temperatures) are
+    # placed as a step's outputs are (PR 57; ROADMAP S7)
     for site in ("prefill_chunk[16@16]", "decode_dispatch[2]"):
-        got = _stages_of(recs, site, "step")
-        assert got in (once, sorted(once * 2)), (site, got)
+        assert _stages_of(recs, site, "step") == once, site
     assert not [r for r in recs if r["program"] == "unlabelled"]
     assert {r["stage"] for r in recs} == set(once)   # no cache here
     assert all(r["t"] >= log.t0 and r["seconds"] >= 0 for r in recs)
@@ -628,7 +653,12 @@ def test_engine_names_every_program_it_asks_for_by_its_site(fresh_log):
         r["program"] for r in new} <= {
         "admit[64]", "prefill_chunk[16@64]", "finish_prefill[64]"}
     assert _stages_of(new, "finish_prefill[64]", "insert_row") == once
-    assert "compile" in _stages_of(new, "prefill_chunk[16@64]", "step")
+    # a chunked prompt's program is the mixed one (PR 57), asked for
+    # once (the second chunk's call, whose caches are the first's
+    # outputs, misses jit's fast path and finds the trace it has)
+    got = _stages_of(new, "prefill_chunk[16@64]", "mixed")
+    assert got.count("compile") == got.count("lower") == 1, got
+    assert not _stages_of(new, "prefill_chunk[16@64]", "step")
     assert not _stages_of(new, "finish_prefill[64]", "set_slot")
 
     programs = eng.stats()["programs"]

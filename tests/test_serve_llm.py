@@ -233,9 +233,28 @@ def test_chunked_prefill_with_a_lora_adapter_matches_unbatched_decode():
     what a plain unbatched decode with the same base and adapter gives,
     and the adapter is no no-op (PR 45 holds q and k as projected, the
     low-rank path summed in; the decode step alone was covered)."""
+    cfg = llama.config_for("debug", max_seq_len=1024)
+    params = _lora_params(cfg)
+    base = {k: v for k, v in params.items() if k != "lora"}
+    eng = LLMEngine("debug", tp=2, max_batch=2, max_seq_len=1024,
+                    prompt_buckets=(32, 512), prefill_chunk=64,
+                    params=params, seed=0)
+    prompt = [5, 9, 11, 42, 7] * 30  # 150 tokens -> bucket 512
+    got = _collect(eng, prompt, max_new_tokens=6)
+    assert eng.prefill_chunks >= 3
+    assert got == _greedy_reference(eng, prompt, 6)
+    eng.params = base   # the reference reads the engine's: now without
+    assert got != _greedy_reference(eng, prompt, 6)
+
+
+# --------------------------------------------------------------------------
+# The mixed program: a prefill chunk carries the decode rows (PR 57)
+# --------------------------------------------------------------------------
+def _lora_params(cfg):
+    """Base parameters with a trained adapter (B random, not the zeros
+    it starts from) under "lora"."""
     from ray_tpu.models import lora as lora_mod
 
-    cfg = llama.config_for("debug", max_seq_len=1024)
     adapter = lora_mod.init_lora_params(
         cfg, lora_mod.LoraConfig(rank=4, alpha=cfg.lora_alpha),
         jax.random.PRNGKey(7))
@@ -243,16 +262,187 @@ def test_chunked_prefill_with_a_lora_adapter_matches_unbatched_decode():
         k: (0.3 * jax.random.normal(jax.random.PRNGKey(i), v.shape, v.dtype)
             if k.endswith("_b") else v)
         for i, (k, v) in enumerate(sorted(adapter["layers"].items()))}}
-    base = llama.init_params(cfg, jax.random.PRNGKey(0))
-    eng = LLMEngine("debug", tp=2, max_batch=2, max_seq_len=1024,
+    return {**llama.init_params(cfg, jax.random.PRNGKey(0)), "lora": adapter}
+
+
+@pytest.mark.parametrize("lora", [False, True], ids=["base", "lora"])
+def test_mixed_step_equals_a_chunk_step_and_a_decode_step(lora):
+    """`llama.mixed_step` from one state against `decode_step` called
+    twice from it, the chunk on the request's cache and one token a row
+    on the slots': both logits to float32 round-off with the same
+    argmax, both caches leaf by leaf. The rows stand at their own
+    depths, one holds no request (depth < 0), and the chunk is a
+    left-padded prompt's first."""
+    import jax.numpy as jnp
+
+    cfg = llama.config_for("debug", max_seq_len=128)
+    params = _lora_params(cfg) if lora else llama.init_params(
+        cfg, jax.random.PRNGKey(0))
+    b, s, bucket = 4, 16, 64
+    rng = np.random.default_rng(0)
+    fill = lambda key, a: jax.random.normal(
+        jax.random.PRNGKey(key), a.shape, jnp.float32).astype(a.dtype)
+    cache = llama.init_kv_cache(cfg, b, max_len=128)
+    cache.update(k=fill(1, cache["k"]), v=fill(2, cache["v"]),
+                 length=jnp.asarray([40, -1, 17, 90], jnp.int32),
+                 start=jnp.asarray([3, 0, 0, 30], jnp.int32))
+    # 41 real tokens in a bucket of 64: the first chunk that holds one
+    # starts at 16, its first seven positions padding
+    small = llama.init_kv_cache(cfg, 1, max_len=bucket)
+    small.update(start=jnp.asarray([23], jnp.int32), length=jnp.int32(16))
+    chunk = jnp.asarray(rng.integers(1, 255, (1, s)), jnp.int32)
+    tokens = jnp.asarray(rng.integers(1, 255, (b, 1)), jnp.int32)
+    want_chunk, want_small = _reference_step(params, small, chunk, cfg)
+    want_rows, want_cache = _reference_step(params, cache, tokens, cfg)
+    got_chunk, got_small, got_rows, got_cache = jax.jit(
+        llama.mixed_step, static_argnums=5)(
+            params, small, chunk, cache, tokens, cfg)
+    if lora:  # the adapter is no no-op
+        bare, _ = _reference_step({k: v for k, v in params.items()
+                                   if k != "lora"}, small, chunk, cfg)
+        assert np.abs(np.asarray(bare - want_chunk)).max() > 1e-2
+    for got, want in ((got_chunk, want_chunk), (got_rows, want_rows)):
+        assert got.dtype == jnp.float32 and got.shape == want.shape
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        assert (np.argmax(got, -1) == np.argmax(want, -1)).all()
+    for got, want in ((got_small, want_small), (got_cache, want_cache)):
+        assert sorted(got) == sorted(want)
+        for leaf in want:
+            np.testing.assert_array_equal(
+                np.asarray(got[leaf], np.float32),
+                np.asarray(want[leaf], np.float32), err_msg=leaf)
+
+
+def _long_prompts_beside_decoding_rows(eng):
+    """Two rows decode; two long prompts (5 and 4 chunks of 64 in the
+    bucket of 512) and a short one arrive while they do. Returns the
+    five streams in the order sent."""
+    reqs = [([1, 2, 3], 40), ([7, 7], 30),
+            ([(7 * i) % 251 + 1 for i in range(300)], 6),
+            ([(5 * i) % 241 + 1 for i in range(200)], 5),
+            ([9, 4, 9], 8)]
+
+    async def run():
+        first = [asyncio.ensure_future(_agen_list(
+            eng.generate(p, max_new_tokens=m))) for p, m in reqs[:2]]
+        await _when(lambda: eng.batches >= 3)
+        late = await asyncio.gather(*[
+            _agen_list(eng.generate(p, max_new_tokens=m))
+            for p, m in reqs[2:]])
+        assert not all(f.done() for f in first)
+        return [await f for f in first] + late
+    return reqs, asyncio.run(run())
+
+
+def _mixed_engine():
+    """(engine, calls): every call of the engine's two jitted programs
+    is noted in `calls` as (program, the shape of its prompt tokens or
+    of its chunk)."""
+    eng = LLMEngine("debug", tp=1, max_batch=4, max_seq_len=1024,
                     prompt_buckets=(32, 512), prefill_chunk=64,
-                    params={**base, "lora": adapter}, seed=0)
-    prompt = [5, 9, 11, 42, 7] * 30  # 150 tokens -> bucket 512
-    got = _collect(eng, prompt, max_new_tokens=6)
-    assert eng.prefill_chunks >= 3
-    assert got == _greedy_reference(eng, prompt, 6)
-    eng.params = base   # the reference reads the engine's: now without
-    assert got != _greedy_reference(eng, prompt, 6)
+                    prefix_cache_entries=0)
+    calls = []
+    for program in ("_step_jit", "_mixed_jit"):
+        jitted = getattr(eng, program)
+        if jitted is not None:
+            setattr(eng, program, lambda *a, _p=program, _j=jitted: (
+                calls.append((_p, a[2].shape)), _j(*a))[1])
+    return eng, calls
+
+
+def test_mixed_rounds_stream_what_two_programs_stream(monkeypatch):
+    """Long prompts arriving while rows decode: the engine's round with
+    a chunk due is the mixed program (every chunk call, and the rows
+    that decode beside it), and every greedy stream is the one the
+    two-program path gives (the same engine over the module with its
+    `mixed_step` taken away), with the chunks and decode steps counted
+    as that path counts them."""
+    eng, calls = _mixed_engine()
+    reqs, got = _long_prompts_beside_decoding_rows(eng)
+    st = eng.stats()
+    assert st["mixed_steps"] == st["prefill_chunks"] == 5 + 4
+    # beside a chunk: the two rows, the short prompt's, a finished long's
+    assert 2 * 5 <= st["mixed_rows"] <= 3 * 9
+    # a chunk goes through the mixed program, never the chunk's own
+    assert calls.count(("_mixed_jit", (1, 64))) == 9
+    assert ("_step_jit", (1, 64)) not in calls
+    monkeypatch.setattr(llama, "mixed_step", None)
+    two, calls = _mixed_engine()
+    assert two._mixed_jit is None
+    _, want = _long_prompts_beside_decoding_rows(two)
+    assert got == want and calls.count(("_step_jit", (1, 64))) == 9
+    for (prompt, max_new), stream in zip(reqs, got):
+        assert stream == _greedy_reference(eng, prompt, max_new), prompt
+    st2 = two.stats()
+    assert (st2["mixed_steps"], st2["mixed_rows"]) == (0, 0)
+    assert st["prefill_chunks"] == st2["prefill_chunks"]
+    assert st["prefills"] == st2["prefills"] == 5
+    # every stream's tokens but its first come from a decode step: the
+    # steps are counted, whichever program made them
+    assert st["generated_tokens"] == st2["generated_tokens"]
+    assert abs(st["batches"] - st2["batches"]) <= 2
+    assert eng._inflight is None and st["active_slots"] == 0
+
+
+def test_a_chunk_alone_goes_through_the_mixed_program():
+    """A chunk of a chunked prompt with no row decoding beside it: the
+    mixed program still, over retired rows; no decode step is counted
+    and no record kept of one."""
+    eng, _ = _mixed_engine()
+    prompt = [(7 * i) % 251 + 1 for i in range(300)]
+    got = _collect(eng, prompt, max_new_tokens=3)
+    st = eng.stats()
+    assert st["mixed_steps"] == st["prefill_chunks"] == 5
+    assert st["mixed_rows"] == 0 and st["batches"] == 2
+    assert got == _greedy_reference(eng, prompt, 3)
+
+
+def test_a_failed_mixed_step_fails_the_prompt_and_the_rows_once():
+    """The mixed program takes both caches donated: when it raises, the
+    pending prompt and the live rows each hear the engine's error once,
+    nothing is left pending or in flight, and the next request is
+    served."""
+    eng, _ = _mixed_engine()
+    mixed_jit = eng._mixed_jit
+
+    def boom(*a):
+        raise RuntimeError("boom at the mixed dispatch")
+
+    async def consume(prompt):
+        got = []
+        try:
+            async for t in eng.generate(prompt, max_new_tokens=200):
+                got.append(t)
+        except RuntimeError as e:
+            return got, e
+        return got, None
+
+    async def run():
+        rows = [asyncio.ensure_future(consume(p))
+                for p in ([1, 2, 3], [7, 7])]
+        await _when(lambda: eng.batches >= 4
+                    and sum(s is not None for s in eng._slots) == 2)
+        with eng._mutex:
+            live = [s.req for s in eng._slots if s is not None]
+            eng._mixed_jit = boom
+        long = asyncio.ensure_future(consume(
+            [(7 * i) % 251 + 1 for i in range(300)]))
+        results = await asyncio.wait_for(asyncio.gather(*rows, long), 60)
+        with eng._mutex:
+            eng._mixed_jit = mixed_jit
+            assert eng._inflight is None and not eng._pending_prefills
+            assert eng._slots == [None] * 4
+        assert all(r.out.empty() for r in live)
+        after = await _agen_list(eng.generate([5, 9, 11], max_new_tokens=6))
+        return results, after
+
+    results, after = asyncio.run(run())
+    for got, err in results:
+        assert "decode cache lost to a failed engine step" in str(err)
+    assert all(len(got) >= 4 for got, _ in results[:2])
+    assert results[2][0] == []
+    assert after == _greedy_reference(eng, [5, 9, 11], 6)
+    assert eng.stats()["mixed_steps"] == 0
 
 
 # --------------------------------------------------------------------------
