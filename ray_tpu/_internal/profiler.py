@@ -24,13 +24,15 @@ Beside it, the process's own log (:class:`ProcessLog`, one a process,
 program the process asks XLA for, by the name of the site that asked
 (:func:`site_type`). It is written when the process starts and when jax
 traces, lowers, compiles or loads a program; a warm step writes nothing.
-`LLMEngine.stats()` and the train step records show it.
+It also sums the pauses of the process's garbage collector (`gc_s`, by
+`gc.callbacks`). `LLMEngine.stats()` and the train step records show it.
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import sys
 import threading
 import time
@@ -108,6 +110,8 @@ class ProcessLog:
         self.chip_wait_s = 0.0
         self.callbacks = 0               # listener calls, of any event
         self.appended = 0                # records written, dropped or not
+        self.gc_s = 0.0                  # in the collector, all generations
+        self._gc_start = None
         self._lock = threading.Lock()
         self._records: collections.deque = collections.deque(
             maxlen=_MAX_RECORDS)
@@ -194,6 +198,27 @@ class ProcessLog:
         prev = getattr(self._thread, "label", None)
         self._thread.label = program
         return prev
+
+    @contextlib.contextmanager
+    def labelled(self, program):
+        """This thread's label for the block: a site with no span, for
+        programs asked for outside any traced stretch (a recipe's
+        set-up). Yields the label it replaces."""
+        outer = self.label(program)
+        try:
+            yield outer
+        finally:
+            self.label(outer)
+
+    def on_gc(self, phase: str):
+        """`gc.callbacks`: a collection runs in the thread that caused
+        it, under the interpreter's lock, and stops every other."""
+        now = time.perf_counter()
+        if phase == "start":
+            self._gc_start = now
+        elif self._gc_start is not None:
+            self.gc_s += now - self._gc_start
+            self._gc_start = None
 
     def chose(self, what: str, **choice):
         """A choice the code being traced made from its shapes, which
@@ -323,10 +348,15 @@ def _on_duration(event, seconds, fun_name=None, **kw):
     _LOG.on_duration(event, seconds, fun_name)
 
 
+def _on_gc(phase, info):
+    _LOG.on_gc(phase)
+
+
 def listen():
-    """Registers the two listeners with jax.monitoring, once a process:
-    where it first touches the backend (`ProcessLog.backend_up`), or on
-    first use in a process that never does."""
+    """Registers the two listeners with jax.monitoring and the one with
+    the garbage collector, once a process: where it first touches the
+    backend (`ProcessLog.backend_up`), or on first use in a process that
+    never does."""
     global _listening
     with _listen_lock:
         if _listening:
@@ -335,6 +365,7 @@ def listen():
 
         jax.monitoring.register_event_listener(_on_event)
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        gc.callbacks.append(_on_gc)
         _listening = True
 
 

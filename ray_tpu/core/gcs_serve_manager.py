@@ -264,18 +264,16 @@ class GcsServeManager:
         dep = m.get("deployment") or ""
         rep = m.get("replica") or ""
         cur = {k: int(m.get(k) or 0)
-               for k in ("prefills", "prefill_chunks", "decode_steps")}
+               for k in ("prefills", "prefill_chunks", "decode_steps",
+                         "loop_stalls", "loop_stall_us", "host_us_wait",
+                         "prompt_tokens")}
         key = (app, dep, rep)
         last = self._engine_last.get(key) or {}
         deltas = {k: (v - last.get(k, 0) if v >= last.get(k, 0) else v)
                   for k, v in cur.items()}
         self._engine_last[key] = cur
         self._metric_buf.extend(serve_engine_metric_records(
-            app, dep, rep,
-            prefills=deltas["prefills"],
-            prefill_chunks=deltas["prefill_chunks"],
-            decode_steps=deltas["decode_steps"],
-            occupancy=m.get("occupancy"),
+            app, dep, rep, **deltas, occupancy=m.get("occupancy"),
             ts=float(m.get("ts") or time.time())))
 
     def drain_metric_records(self) -> list[dict]:

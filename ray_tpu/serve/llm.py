@@ -17,6 +17,7 @@ prefill/decode with donated KV cache, greedy/temperature sampling in-jit.
 from __future__ import annotations
 
 import asyncio
+import collections
 import contextlib
 import dataclasses
 import functools
@@ -38,12 +39,200 @@ from ray_tpu.serve.multiplex import multiplexed
 
 # Host spans of the engine's units of work (`rayt.engine.*`), written
 # into the JAX profiler's trace when one is being taken of this process
-# and a flag check otherwise (_internal/profiler.py).
+# and a flag check otherwise (_internal/profiler.py). A unit opens its
+# own through `LLMEngine._unit`, which also counts its time.
 _span = span_type()
 # The same span, where a jitted call may be made under it: it also names,
 # for its thread, the programs asked for inside it (the site and the
 # static key the engine holds there), for the process's log of them.
 _site = site_type()
+
+
+ENGINE = "rayt.engine."
+# the engine's units of work, by their spans' names: what the loop's
+# executor hops are made of
+UNITS = ("admit", "prefill_chunk", "finish_prefill", "decode_dispatch",
+         "token_sync", "emit")
+# a hop of the engine's loop longer than this is a stall: over twice the
+# longest sound hop of any cell (a dots3 round with its chunk and the
+# first-token read behind it, about 0.1 s) and an eighth of the shortest
+# stall on record (2.08 s; PERF.md section 7)
+STALL_S = 0.25
+STALLS_KEPT = 16
+
+
+class _LoopClock:
+    """Where the engine loop's time goes, always on, in seconds of
+    `time.perf_counter()`: the clock of `t_host`, of the request records
+    and of a client's stamps on the same host.
+
+    The loop's side marks its turns (`turn`): it is waiting for work,
+    inside an executor hop, or between the two, and the time since the
+    last mark goes to `wait_s`, or to `handoff_s` (a hop's wall time
+    less the units' self time inside it, and all of the time between).
+    The executor's side marks the units (`enter`, `leave`): a unit's
+    SELF time, its own interval less the units nested in it, goes to
+    `units[name]`. So `loop_s` is tiled by `wait_s`, the six units and
+    `handoff_s`, also at a read in mid-wait or mid-hop (`read` counts
+    the open interval on both sides). A hop longer than STALL_S is
+    counted and kept, with what it was made of, in `stalls`.
+
+    One lock for both sides and the reader: the units run one at a time
+    (the engine's mutex), a turn and a unit's mark cost two uncontended
+    acquisitions and two clock reads."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.units = dict.fromkeys(UNITS, 0.0)
+        self._open: list = []       # the units open now, outermost first
+        self._since = 0.0           # when the innermost began or resumed
+        self.loop_s = self.wait_s = self.handoff_s = 0.0
+        self._state = None          # None (no loop), "between", "wait", "hop"
+        self._mark = 0.0            # the loop's last turn
+        # as the open hop began: the units' sum, and what a stall's
+        # record is the difference from
+        self._hop_units_s = 0.0
+        self._hop: dict = {}
+        self.hops = 0
+        self.stall_count = 0
+        self.stall_s = 0.0
+        self.stalls: collections.deque = collections.deque(
+            maxlen=STALLS_KEPT)
+        # inside `prefill_chunk`, not part of the tiling: a request's
+        # prefill cache made and placed (`LLMEngine._prefill_cache`)
+        self.prefill_cache_s = 0.0
+
+    # ------------------------------------------------ the executor's side
+    def enter(self, name: str):
+        with self._lock:
+            now = time.perf_counter()
+            if self._open:
+                self.units[self._open[-1]] += now - self._since
+            self._open.append(name)
+            self._since = now
+
+    def leave(self):
+        with self._lock:
+            now = time.perf_counter()
+            self.units[self._open.pop()] += now - self._since
+            self._since = now
+
+    # ---------------------------------------------------- the loop's side
+    def _as_of(self, now: float) -> tuple:
+        """(loop_s, wait_s, handoff_s, open_unit_s) with the open
+        interval of the loop's state counted up to `now`, and how long
+        the innermost open unit has run since it began or resumed
+        (callers hold the lock)."""
+        open_unit_s = now - self._since if self._open else 0.0
+        wait, handoff = self.wait_s, self.handoff_s
+        if self._state is None:
+            return self.loop_s, wait, handoff, open_unit_s
+        open_s = now - self._mark
+        if self._state == "wait":
+            wait += open_s
+        elif self._state == "hop":
+            handoff += open_s - (sum(self.units.values()) + open_unit_s
+                                 - self._hop_units_s)
+        else:
+            handoff += open_s
+        return self.loop_s + open_s, wait, handoff, open_unit_s
+
+    def turn(self, state):
+        """The loop's side enters `state`; -> when."""
+        with self._lock:
+            return self._turn(state)
+
+    def _turn(self, state) -> float:
+        now = time.perf_counter()
+        self.loop_s, self.wait_s, self.handoff_s, _ = self._as_of(now)
+        self._mark, self._state = now, state
+        return now
+
+    def start(self):
+        """A loop begins. One that never ended (its event loop was
+        dropped, not closed) stopped counting when it last turned."""
+        with self._lock:
+            self._state = None
+            self._turn("between")
+
+    def _levels(self) -> dict:
+        """What a stall's record holds the growth of over its hop."""
+        log = process_log()
+        return {"units": dict(self.units), "handoff_s": self.handoff_s,
+                "prefill_cache_s": self.prefill_cache_s, "gc_s": log.gc_s,
+                "programs_asked": log.appended}
+
+    def begin_hop(self, kind: str, request_id: str, active_slots: int):
+        with self._lock:
+            t = self._turn("hop")
+            self.hops += 1
+            self._hop_units_s = sum(self.units.values())
+            self._hop = {"t": t, "hop": kind, "active_slots": active_slots,
+                         **({"request_id": request_id} if request_id
+                            else {}), "before": self._levels()}
+
+    def end_hop(self):
+        with self._lock:
+            seconds = self._turn("between") - self._hop["t"]
+            if seconds <= STALL_S:
+                return
+            self.stall_count += 1
+            self.stall_s += seconds
+            before, now = self._hop.pop("before"), self._levels()
+            units = {name: s - before["units"][name]
+                     for name, s in now.pop("units").items()
+                     if s > before["units"][name]}
+            self.stalls.append({
+                **self._hop, "seconds": seconds, "units": units,
+                **{key: now[key] - before[key] for key in now}})
+
+    # --------------------------------------------------------- the reader
+    def kept_stalls(self) -> list:
+        with self._lock:
+            return [dict(rec, units=dict(rec["units"]))
+                    for rec in self.stalls]
+
+    def read(self) -> dict:
+        """The flat whole-number counters of `LLMEngine.stats()`, in
+        microseconds, as of now."""
+        with self._lock:
+            loop_s, wait_s, handoff_s, open_unit_s = self._as_of(
+                time.perf_counter())
+            units = dict(self.units)
+            if self._open:
+                units[self._open[-1]] += open_unit_s
+            us = lambda seconds: int(seconds * 1e6)
+            return {"loop_us": us(loop_s), "loop_hops": self.hops,
+                    "host_us_wait": us(wait_s),
+                    **{"host_us_" + name: us(s)
+                       for name, s in units.items()},
+                    "host_us_handoff": us(handoff_s),
+                    "host_us_prefill_cache": us(self.prefill_cache_s),
+                    "loop_stalls": self.stall_count,
+                    "loop_stall_us": us(self.stall_s)}
+
+
+class _Unit:
+    """One unit of the engine's work: its span as before (a site where
+    `program` names the programs asked for inside it), and its self
+    time on the engine's clock, profiler session or none."""
+    __slots__ = ("_clock", "_name", "_span")
+
+    def __init__(self, clock: _LoopClock, name: str, program=None,
+                 **fields):
+        self._clock, self._name = clock, name
+        self._span = (_span(ENGINE + name, **fields) if program is None
+                      else _site(ENGINE + name, program, **fields))
+
+    def __enter__(self):
+        self._clock.enter(self._name)
+        return self._span.__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            return self._span.__exit__(*exc)
+        finally:
+            self._clock.leave()
 
 
 def _engine_build_phase(init):
@@ -163,6 +352,14 @@ class LLMEngine:
     `mixed_rows` they carried. Where it has none the round is the
     chunk's program and then the decode step's. Which it is follows from
     the module, never from an argument.
+
+    The loop accounts for its own time, always on (`_LoopClock`,
+    `host_time`): the wait for work with every slot free, which is also
+    span `rayt.engine.wait`, each unit's self time and the hand-off
+    around the units tile the loop's elapsed time in `stats()`, and an
+    executor hop longer than STALL_S is counted and kept with the unit
+    it fell in (`stats()["stalls"]`): the record of a stall in a run no
+    profiler watched.
 
     `model` is a model config or the name of a llama preset. The module
     that serves it is `models.module_for(cfg)`, and everything the
@@ -480,6 +677,12 @@ class LLMEngine:
         self.prefix_misses = 0
         self.prefix_hit_tokens = 0   # prefill tokens skipped via reuse
         self.kv_handoffs = 0         # disagg rows admitted via channel
+        # prompt tokens of the requests this engine finished a prefill
+        # for, stored prefixes included (a row prefilled in another
+        # pool is that pool's)
+        self.prompt_tokens = 0
+        # the loop's own account of its time, always on
+        self._clock = _LoopClock()
 
     # ------------------------------------------------------------ serving
     async def ensure_started(self):
@@ -636,45 +839,78 @@ class LLMEngine:
         # the NEW loop's queue; a stale loop reading it would steal and
         # fail the new loop's requests
 
+        clock = self._clock
+
+        async def hop(kind: str, fn, *args, request_id: str = ""):
+            """One executor hop, measured from this side: before the
+            call to after its return."""
+            clock.begin_hop(kind, request_id,
+                            sum(1 for s in self._slots if s is not None))
+            try:
+                return await loop.run_in_executor(None, fn, *args)
+            finally:
+                clock.end_hop()
+
         async def _admit(req: _Request):
             try:
-                await loop.run_in_executor(None, self._admit, req, epoch)
+                await hop("admit", self._admit, req, epoch,
+                          request_id=req.request_id)
             except Exception as e:
                 req.loop.call_soon_threadsafe(req.out.put_nowait, e)
 
-        while epoch == self._epoch:
-            if not any(s is not None for s in self._slots):
-                # idle: block until work arrives (no spinning)
-                await _admit(await queue.get())
-            # opportunistic refill of every free slot, no waiting
-            while (not queue.empty()
-                   and any(s is None for s in self._slots)):
-                await _admit(queue.get_nowait())
-            stepped = False
-            if self._pending_prefills:
-                # one chunk per round: a long prompt costs active
-                # streams ~one chunk of latency per step, not the
-                # whole-prompt stall. Where the module has the shared
-                # pass the chunk's program steps the rows too
-                try:
-                    stepped = await loop.run_in_executor(
-                        None, self._advance_prefill, epoch)
-                except Exception:
-                    if epoch != self._epoch:
-                        return
-            if not stepped and any(s is not None and s.emitted >= 0
-                                   for s in self._slots):
-                try:
-                    await loop.run_in_executor(
-                        None, self._decode_step_all, epoch)
-                except Exception:
-                    # _poison_recover already failed the active requests
-                    # and reset the (donated, now-dead) cache; an epoch
-                    # mismatch means a newer loop owns the engine — stop
-                    if epoch != self._epoch:
-                        return
+        clock.start()
+        try:
+            while epoch == self._epoch:
+                if not any(s is not None for s in self._slots):
+                    # idle: block until work arrives (no spinning), under
+                    # a span of its own: the device idle beneath it is
+                    # the offered load's, not the program's
+                    with _span(ENGINE + "wait"):
+                        clock.turn("wait")
+                        try:
+                            req = await queue.get()
+                        finally:
+                            clock.turn("between")
+                    await _admit(req)
+                # opportunistic refill of every free slot, no waiting
+                while (not queue.empty()
+                       and any(s is None for s in self._slots)):
+                    await _admit(queue.get_nowait())
+                stepped = False
+                if self._pending_prefills:
+                    # one chunk per round: a long prompt costs active
+                    # streams ~one chunk of latency per step, not the
+                    # whole-prompt stall. Where the module has the shared
+                    # pass the chunk's program steps the rows too
+                    try:
+                        stepped = await hop(
+                            "prefill", self._advance_prefill, epoch,
+                            request_id=self._pending_prefills[0]
+                            .req.request_id)
+                    except Exception:
+                        if epoch != self._epoch:
+                            return
+                if not stepped and any(s is not None and s.emitted >= 0
+                                       for s in self._slots):
+                    try:
+                        await hop("decode", self._decode_step_all, epoch)
+                    except Exception:
+                        # _poison_recover already failed the active
+                        # requests and reset the (donated, now-dead)
+                        # cache; an epoch mismatch means a newer loop
+                        # owns the engine — stop
+                        if epoch != self._epoch:
+                            return
+        finally:
+            clock.turn(None)
 
     # ------------------------------------------------------- the hot path
+    def _unit(self, name: str, program=None, **fields) -> _Unit:
+        """`with self._unit("emit", active=3):` is the unit's span
+        `rayt.engine.emit` (a site where `program` is given) and its
+        self time in `stats()["host_us_emit"]`."""
+        return _Unit(self._clock, name, program, **fields)
+
     def _ensure_decode_cache(self):
         if self._decode_cache is None:
             cache = self._model.init_cache(self.cfg, self.max_batch,
@@ -708,9 +944,10 @@ class LLMEngine:
         slot = next(i for i, s in enumerate(self._slots) if s is None)
         bucket = (int(req.prefilled["bucket"]) if req.prefilled is not None
                   else _bucket(len(req.tokens), self.prompt_buckets))
-        with _site("rayt.engine.admit", f"admit[{bucket}]",
-                   request_id=req.request_id,
-                   prompt_len=len(req.tokens), bucket=bucket, slot=slot):
+        with self._unit("admit", f"admit[{bucket}]",
+                        request_id=req.request_id,
+                        prompt_len=len(req.tokens), bucket=bucket,
+                        slot=slot):
             try:
                 self._ensure_decode_cache()
             except Exception:
@@ -773,14 +1010,19 @@ class LLMEngine:
         """A request's prefill cache as its first call finds it: empty
         and `pos` deep (what lies before is left padding, skipped), or
         with the stored prefix `(entry, matched)` grafted at `start`."""
-        small = self._model.init_cache(self.cfg, 1, max_len=bucket)
-        small["start"] = jnp.asarray([start], jnp.int32)
-        small = jax.device_put(small, self._cache_sharding)
-        if prefix is not None:
-            return self._graft_prefix(small, prefix[0], start, prefix[1])
-        if pos:
-            small["length"] = self._depth(pos)
-        return small
+        t = time.perf_counter()
+        try:
+            small = self._model.init_cache(self.cfg, 1, max_len=bucket)
+            small["start"] = jnp.asarray([start], jnp.int32)
+            small = jax.device_put(small, self._cache_sharding)
+            if prefix is not None:
+                return self._graft_prefix(small, prefix[0], start,
+                                          prefix[1])
+            if pos:
+                small["length"] = self._depth(pos)
+            return small
+        finally:
+            self._clock.prefill_cache_s += time.perf_counter() - t
 
     def _depth(self, pos: int):
         """A prefill cache's `length` set from the host, placed as
@@ -798,11 +1040,10 @@ class LLMEngine:
         bucket = prompts.shape[1]
         counters = self._model.prefill_counters(
             self.cfg, bucket - len(req.tokens), pos, chunk, bucket)
-        with _site("rayt.engine.prefill_chunk",
-                   f"prefill_chunk[{chunk}@{bucket}]",
-                   request_id=req.request_id,
-                   pos=pos, chunk=chunk, last=int(pos + chunk >= bucket),
-                   **counters):
+        with self._unit("prefill_chunk", f"prefill_chunk[{chunk}@{bucket}]",
+                        request_id=req.request_id,
+                        pos=pos, chunk=chunk,
+                        last=int(pos + chunk >= bucket), **counters):
             if small is None:
                 small = self._prefill_cache(
                     bucket, bucket - len(req.tokens), pos, prefix)
@@ -965,14 +1206,13 @@ class LLMEngine:
             self.cfg, bucket - len(req.tokens), pos, chunk, bucket)
         live, read, counters = self._kv_positions(rows, prev) if active \
             else (0, 0, {})
-        with _site("rayt.engine.prefill_chunk",
-                   f"prefill_chunk[{chunk}@{bucket}]",
-                   request_id=req.request_id, pos=pos, chunk=chunk,
-                   last=int(pos + chunk >= bucket), mixed=1,
-                   **chunk_counters), \
-                (_span("rayt.engine.decode_dispatch", active=active,
-                       live_positions=live, t_host=time.perf_counter(),
-                       mixed=1, **counters)
+        with self._unit("prefill_chunk", f"prefill_chunk[{chunk}@{bucket}]",
+                        request_id=req.request_id, pos=pos, chunk=chunk,
+                        last=int(pos + chunk >= bucket), mixed=1,
+                        **chunk_counters), \
+                (self._unit("decode_dispatch", active=active,
+                            live_positions=live,
+                            t_host=time.perf_counter(), mixed=1, **counters)
                  if active else contextlib.nullcontext()):
             if pf.small is None:
                 pf.small = self._prefill_cache(
@@ -1006,9 +1246,11 @@ class LLMEngine:
         apply to its outputs (`_decode_cache`, `_cur`), so device order
         makes the graft safe and the request joins at the step after."""
         row = self._row(small)
-        with _site("rayt.engine.finish_prefill", f"finish_prefill[{bucket}]",
-                   request_id=req.request_id,
-                   slot=slot, row_bytes=sum(a.nbytes for a in row.values())):
+        if req.prefilled is None:
+            self.prompt_tokens += len(req.tokens)
+        with self._unit("finish_prefill", f"finish_prefill[{bucket}]",
+                        request_id=req.request_id, slot=slot,
+                        row_bytes=sum(a.nbytes for a in row.values())):
             if not isinstance(first, int):
                 first = int(np.asarray(first)[0])
             if store:
@@ -1114,7 +1356,7 @@ class LLMEngine:
                 return
             # a device fault of the step just dispatched surfaces at a
             # later read: this one, or _finish_prefill's
-            with _span("rayt.engine.token_sync", active=prev.active):
+            with self._unit("token_sync", active=prev.active):
                 toks = np.asarray(prev.tokens)  # host sync: step k's tokens
         except BaseException:
             self._poison_recover()
@@ -1169,10 +1411,9 @@ class LLMEngine:
         # the one event that carries both clocks, so request records and
         # a client's stamps (CLOCK_MONOTONIC, one clock for the host)
         # can be laid on the profiler's time axis
-        with _site("rayt.engine.decode_dispatch", self._decode_program,
-                   active=active,
-                   live_positions=live, t_host=time.perf_counter(),
-                   **counters):
+        with self._unit("decode_dispatch", self._decode_program,
+                        active=active, live_positions=live,
+                        t_host=time.perf_counter(), **counters):
             nxt, self._decode_cache, self._key = self._step(
                 self.params, self._decode_cache, self._cur,
                 self._key, self._temps)
@@ -1228,7 +1469,7 @@ class LLMEngine:
         # occupancy of THIS step, stamped into each participant's obs:
         # mean over a request's steps = how full its decode batches ran
         occupancy = rec.active / self.max_batch
-        with _span("rayt.engine.emit", active=rec.active) as span:
+        with self._unit("emit", active=rec.active) as span:
             now = time.perf_counter()
             finished = 0
             owned = self._owned_rows(rec)
@@ -1265,8 +1506,30 @@ class LLMEngine:
                     finished += 1
             span.set_metadata(finished=finished, **aux)
 
+    def host_time(self) -> dict:
+        """The loop's account of its own time, flat whole numbers (the
+        times in microseconds): `loop_us` since the loop started, tiled
+        by `host_us_wait` (blocked on an empty queue with no slot
+        taken), the six units' self time `host_us_<unit>` and
+        `host_us_handoff` (a hop's wall time on the loop's side less
+        the units inside it: the executor both ways, the wait for the
+        mutex, the loop's own Python between hops); `loop_hops`; not
+        part of the tiling, `host_us_prefill_cache` (inside
+        `prefill_chunk`) and `host_us_gc` (the process's collector,
+        inside anything); `loop_stalls` and `loop_stall_us`, the hops
+        longer than STALL_S; `prompt_tokens`."""
+        return {**self._clock.read(),
+                "host_us_gc": int(process_log().gc_s * 1e6),
+                "prompt_tokens": self.prompt_tokens}
+
     def stats(self) -> dict:
-        """The engine's counters, and the process's own log
+        """The engine's counters, the loop's account of its time
+        (`host_time`) with the newest stalled hops whole under `stalls`
+        ({"t": perf_counter at the hop's start, "seconds", "hop",
+        "units": {unit: self seconds inside it}, "handoff_s",
+        "prefill_cache_s", "gc_s", "programs_asked": records the log
+        grew by, "active_slots", "request_id" where the hop has one}),
+        and the process's own log
         (_internal/profiler.ProcessLog): `programs`, every program the
         process asked XLA for, by the site that asked (`last` is the
         answer to "which step recompiled, and when"), and `startup`, the
@@ -1293,6 +1556,8 @@ class LLMEngine:
                 "active_slots": sum(1 for s in self._slots
                                     if s is not None),
                 "tp": self.mesh.shape.get("tensor", 1),
+                **self.host_time(),
+                "stalls": self._clock.kept_stalls(),
                 "programs": log.programs(), "startup": log.startup()}
 
 

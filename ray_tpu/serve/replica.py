@@ -24,6 +24,9 @@ from ray_tpu._internal.profiler import process_log
 # cumulative engine reports piggyback on the request-recording path at
 # most this often (differenced into rates GCS-side)
 _ENGINE_REPORT_INTERVAL_S = 2.0
+# of `LLMEngine.host_time()`, what the engine report carries to the GCS
+_ENGINE_HOST_TIME = ("loop_stalls", "loop_stall_us", "host_us_wait",
+                     "prompt_tokens")
 
 
 class _HandleMarker:
@@ -200,12 +203,15 @@ class ReplicaActor:
     def _engine_stats(self) -> Optional[dict]:
         """Summed engine counters across every resident engine (one for
         LlamaService, one per resident adapter for the multiplexed
-        service), plus instantaneous decode-slot occupancy."""
+        service), plus instantaneous decode-slot occupancy, and of the
+        engine loop's account of its time (`LLMEngine.host_time`) the
+        stalled hops, the wait for work and the prompt tokens."""
         engines = self._engines()
         if not engines:
             return None
         out = {"batches": 0, "prefills": 0, "prefill_chunks": 0,
-               "active_slots": 0, "max_batch": 0}
+               "active_slots": 0, "max_batch": 0,
+               **dict.fromkeys(_ENGINE_HOST_TIME, 0)}
         for e in engines:
             out["batches"] += int(e.batches)
             out["prefills"] += int(e.prefills)
@@ -214,6 +220,9 @@ class ReplicaActor:
                 out["active_slots"] += sum(
                     1 for s in e._slots if s is not None)
                 out["max_batch"] += int(e.max_batch)
+                host = e.host_time()
+                for key in _ENGINE_HOST_TIME:
+                    out[key] += int(host[key])
             except Exception:
                 pass
         return out
@@ -238,6 +247,7 @@ class ReplicaActor:
                    "prefills": st["prefills"],
                    "prefill_chunks": st["prefill_chunks"],
                    "decode_steps": st["batches"],
+                   **{key: st[key] for key in _ENGINE_HOST_TIME},
                    "ts": time.time()}
             if st["max_batch"]:
                 rec["occupancy"] = st["active_slots"] / st["max_batch"]

@@ -9,7 +9,7 @@ implementation.
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any, Callable, Optional
 
 
 def corpus_pretrain_loop(config: dict):
@@ -142,10 +142,17 @@ def build_lora_step(config: dict, mesh):
     fresh adapters placed on `mesh`, and a jitted step that trains ONLY
     the adapters (build_train_step(trainable_keys=("lora",)) — the
     backward computes no base-weight gradients and the optimizer holds
-    moments only for A/B). Returns (step, state, cfg)."""
+    moments only for A/B). Returns (step, state, cfg).
+
+    The programs it asks XLA for (the adapters' and the optimizer
+    state's initialisation, and the weights' where they are its own
+    random ones) are named `build_lora_step` in the process's log
+    (_internal/profiler.ProcessLog); a caller's `init_params_fn` runs
+    under the caller's label: its programs are the caller's to name."""
     import jax
     import optax
 
+    from ray_tpu._internal.profiler import process_log
     from ray_tpu.models import llama, lora
     from ray_tpu.parallel.spmd import build_train_step
 
@@ -159,20 +166,27 @@ def build_lora_step(config: dict, mesh):
         alpha=cfg.lora_alpha,
         targets=tuple(config.get("lora_targets", lora.DEFAULT_TARGETS)))
 
-    key = jax.random.PRNGKey(config.get("seed", 0))
-    init_fn: Callable[[Any], Any] = config.get("init_params_fn") \
-        or (lambda c: llama.init_params(c, key))
-    base = init_fn(cfg)
-    adapters = lora.init_lora_params(cfg, lcfg, jax.random.fold_in(key, 1))
-    params = {**base, "lora": adapters}
-    axes = {**llama.param_logical_axes(cfg),
-            "lora": lora.lora_logical_axes(cfg, lcfg)}
+    log = process_log()
+    with log.labelled("build_lora_step") as outer:
+        key = jax.random.PRNGKey(config.get("seed", 0))
+        init_fn: Optional[Callable[[Any], Any]] = config.get(
+            "init_params_fn")
+        if init_fn is None:
+            base = llama.init_params(cfg, key)
+        else:
+            with log.labelled(outer):
+                base = init_fn(cfg)
+        adapters = lora.init_lora_params(cfg, lcfg,
+                                         jax.random.fold_in(key, 1))
+        params = {**base, "lora": adapters}
+        axes = {**llama.param_logical_axes(cfg),
+                "lora": lora.lora_logical_axes(cfg, lcfg)}
 
-    loss = lambda p, b: llama.loss_fn(p, b, cfg)
-    step, state = build_train_step(
-        loss, optax.adamw(config.get("lr", 1e-3)), params, axes, mesh,
-        grad_accum=config.get("grad_accum", 1),
-        trainable_keys=("lora",))
+        loss = lambda p, b: llama.loss_fn(p, b, cfg)
+        step, state = build_train_step(
+            loss, optax.adamw(config.get("lr", 1e-3)), params, axes, mesh,
+            grad_accum=config.get("grad_accum", 1),
+            trainable_keys=("lora",))
     return step, state, cfg
 
 
@@ -202,7 +216,7 @@ def lora_finetune_loop(config: dict):
     import jax.numpy as jnp
 
     from ray_tpu import train
-    from ray_tpu._internal.profiler import span_type
+    from ray_tpu._internal.profiler import process_log, span_type
     from ray_tpu.parallel.spmd import shard_batch
     from ray_tpu.train.checkpoint import Checkpoint, save_pytree
 
@@ -255,6 +269,9 @@ def lora_finetune_loop(config: dict):
     report_every = config.get("report_every", 10)
     steps = config.get("steps", 50)
 
+    # the batch's programs (a jitted generator's, on the first call) by
+    # name in the process's log, like the step's
+    site = process_log().labelled
     # same waterfall as corpus_pretrain_loop (h2d = shard_batch, step =
     # block-until-ready update, ckpt_block stamped inside report)
     rec = ctx.recorder
@@ -264,13 +281,14 @@ def lora_finetune_loop(config: dict):
     last_loss = first_loss = None
     for i in range(start_step, steps):
         if rec is not None:
-            with rec.phase("h2d"):
+            with rec.phase("h2d"), site("make_batch"):
                 batch = shard_batch(make_batch(i, rank), mesh)
             with rec.phase("step"):
                 state, aux = step(state, batch)
                 jax.block_until_ready(aux["loss"])
         else:
-            batch = shard_batch(make_batch(i, rank), mesh)
+            with site("make_batch"):
+                batch = shard_batch(make_batch(i, rank), mesh)
             state, aux = step(state, batch)
         if (i + 1) % report_every == 0 or i == steps - 1:
             last_loss = float(aux["loss"])

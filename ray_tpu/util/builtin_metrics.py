@@ -327,13 +327,20 @@ def serve_request_metric_records(app: str, *, queue_wait_s=None,
 def serve_engine_metric_records(app: str, deployment: str, replica: str,
                                 *, prefills: int = 0,
                                 prefill_chunks: int = 0,
-                                decode_steps: int = 0, occupancy=None,
+                                decode_steps: int = 0,
+                                loop_stalls: int = 0,
+                                loop_stall_us: int = 0,
+                                host_us_wait: int = 0,
+                                prompt_tokens: int = 0, occupancy=None,
                                 ts: float = 0.0) -> list:
     """Engine health metrics, derived by the GCS serve manager from the
     DELTAS between consecutive cumulative replica engine reports
     (counter records carry deltas; the store sums them). One counter
     series per (app, deployment); the occupancy gauge adds the replica
-    tag so a lopsided decode batch is attributable."""
+    tag so a lopsided decode batch is attributable. The last four are
+    the engine loop's own account (`LLMEngine.host_time`): hops longer
+    than `serve/llm.STALL_S` and their seconds, the seconds the loop
+    waited for work with every slot free, the prompt tokens prefilled."""
     tags = {"app": app, "deployment": deployment}
     recs = []
 
@@ -349,6 +356,17 @@ def serve_engine_metric_records(app: str, deployment: str, replica: str,
     if decode_steps:
         rec("rayt_serve_engine_decode_steps_total", "counter",
             decode_steps, tags)
+    if loop_stalls:
+        rec("rayt_serve_engine_stalls_total", "counter", loop_stalls, tags)
+    if loop_stall_us:
+        rec("rayt_serve_engine_stall_seconds_total", "counter",
+            loop_stall_us / 1e6, tags)
+    if host_us_wait:
+        rec("rayt_serve_engine_wait_seconds_total", "counter",
+            host_us_wait / 1e6, tags)
+    if prompt_tokens:
+        rec("rayt_serve_engine_prompt_tokens_total", "counter",
+            prompt_tokens, tags)
     if occupancy is not None:
         rec("rayt_serve_decode_batch_occupancy", "gauge", occupancy,
             {**tags, "replica": replica})

@@ -23,7 +23,7 @@ from ray_tpu.serve.llm import LLMEngine
 from ray_tpu.train import telemetry
 
 ENGINE_SPANS = ("admit", "prefill_chunk", "finish_prefill",
-                "decode_dispatch", "token_sync", "emit")
+                "decode_dispatch", "token_sync", "emit", "wait")
 
 
 def _host_spans(trace_dir) -> list:
@@ -139,6 +139,56 @@ def test_engine_writes_every_span_into_the_profilers_trace(tmp_path):
              and st.get("request_id") == "short"]
     assert set(inner) == {"rayt.engine.admit", "rayt.engine.prefill_chunk",
                           "rayt.engine.finish_prefill"}
+
+
+def test_the_wait_for_work_is_a_span_between_two_requests(tmp_path):
+    """Two requests 0.2 s apart, the first long done when the second
+    comes: the loop's wait on its empty queue is one `rayt.engine.wait`
+    between them, on the event loop's own line, that holds no other
+    engine span and has ended when the second admission starts (the
+    reducer files what lies between the two as the hand-off)."""
+    eng = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=64,
+                    prompt_buckets=(16,), prefill_chunk=0,
+                    prefix_cache_entries=0)
+
+    async def one(rid, tokens):
+        token = request_context._set_request_obs({"request_id": rid})
+        try:
+            return [t async for t in eng.generate(tokens, max_new_tokens=3)]
+        finally:
+            request_context._reset_request_obs(token)
+
+    async def run():
+        first = await one("first", [5, 9, 11])
+        await asyncio.sleep(0.2)
+        return first, await one("second", [5, 9, 12])
+
+    asyncio.run(run())          # compile outside the session
+    waited = eng.host_time()["host_us_wait"]
+    jax.profiler.start_trace(str(tmp_path), profiler_options=_trace_options())
+    try:
+        asyncio.run(run())
+    finally:
+        jax.profiler.stop_trace()
+    assert eng.host_time()["host_us_wait"] - waited >= 200_000
+    spans = [s for s in _host_spans(tmp_path)
+             if s[1].startswith(trace_spans.ENGINE_PREFIX)]
+    admit = {s[4]["request_id"]: s for s in spans
+             if s[1] == "rayt.engine.admit"}
+    last_of_first = max(s[3] for s in spans if s[2] < admit["second"][2])
+    waits = [s for s in spans if s[1] == "rayt.engine.wait"]
+    # (the loop also waits before the first request, a moment, and
+    # after the second, until its event loop closes)
+    (between,) = [w for w in waits
+                  if admit["first"][3] <= w[2] < admit["second"][2]]
+    line, _, start, end, stats = between
+    assert end - start >= 0.19e9 and stats == {}
+    assert last_of_first == end <= admit["second"][2]
+    assert admit["second"][2] - end < trace_spans.HANDOFF_MAX_NS
+    assert not [s for s in spans if s is not between
+                and s[2] < end and s[3] > start]
+    assert {s[0] for s in waits} == {line} and line not in {
+        s[0] for s in spans if s[1] != "rayt.engine.wait"}
 
 
 def test_token_sync_of_a_step_follows_the_dispatch_of_the_next(tmp_path):
@@ -874,6 +924,34 @@ def test_step_recorder_puts_the_log_on_its_step_records(fresh_log):
     assert kept[1]["startup"] == first["startup"]
     assert kept[1]["programs"] == first["programs"]
     assert kept[3]["programs"] == delta and "startup" not in kept[3]
+
+    # the recipe's own programs have a name too (the adapters', the
+    # optimizer state's, its random weights'; ROADMAP B13), and a
+    # caller's `init_params_fn` keeps the caller's: none here
+    from ray_tpu.parallel.mesh import build_mesh
+    from ray_tpu.train.recipes import build_lora_step
+
+    mesh = build_mesh({"data": 1, "fsdp": 1, "tensor": 1}, jax.devices()[:1])
+    config = {"preset": "debug", "lora_rank": 4,
+              "model_overrides": {"max_seq_len": 64}}
+    at = log.appended
+    _, state, cfg = build_lora_step(config, mesh)
+    own = log.records()[at:]
+    assert {r["program"] for r in own} == {"build_lora_step"}
+    assert {"compile", "lower", "trace"} <= {r["stage"] for r in own}
+
+    def init_params_fn(cfg):
+        return jax.jit(lambda k: llama.init_params(cfg, k))(
+            jax.random.PRNGKey(7))
+
+    at = log.appended
+    build_lora_step({**config, "init_params_fn": init_params_fn,
+                     "lora_rank": 2}, mesh)
+    by_name = {r["fun_name"]: r["program"] for r in log.records()[at:]
+               if r["stage"] == "compile"}
+    assert by_name.pop("jit(<lambda>)") == "unlabelled"
+    assert by_name and set(by_name.values()) == {"build_lora_step"}
+    assert log.programs()["by_program"]["build_lora_step"]["count"] >= 2
 
 
 @pytest.mark.parametrize("path", ["fused", "split"])

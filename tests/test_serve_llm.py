@@ -4,6 +4,7 @@ serve/_private/replica.py:750 + response streaming; the engine itself is
 TPU-native, no reference equivalent)."""
 
 import asyncio
+import time
 
 import jax
 import numpy as np
@@ -12,6 +13,7 @@ import pytest
 import ray_tpu as rt
 from ray_tpu import serve
 from ray_tpu.models import llama
+from ray_tpu.serve import llm as llm_mod
 from ray_tpu.serve.llm import LLMEngine
 
 
@@ -780,3 +782,273 @@ def test_kv_position_counters_rise_with_dispatched_steps_only(decode_path):
         assert read == whole
     _collect(eng, [3, 8, 1], max_new_tokens=1)
     assert [eng.stats()[k] for k in keys] == [st[k] for k in keys]
+
+
+# ------------------------------- the loop's own account of its time (PR 58)
+TILING = ("host_us_wait", "host_us_admit", "host_us_prefill_chunk",
+          "host_us_finish_prefill", "host_us_decode_dispatch",
+          "host_us_token_sync", "host_us_emit", "host_us_handoff")
+HOST_TIME = TILING + ("loop_us", "loop_hops", "host_us_prefill_cache",
+                      "host_us_gc", "loop_stalls", "loop_stall_us",
+                      "prompt_tokens")
+
+
+def _tiles(read: dict) -> bool:
+    """The eight counters sum to `loop_us` within 1% (they are whole
+    microseconds of one sum of seconds)."""
+    return abs(sum(read[k] for k in TILING) - read["loop_us"]) <= \
+        0.01 * read["loop_us"] + len(TILING)
+
+
+def test_host_time_tiles_the_loop_at_any_read():
+    """A run with one-shot prompts, chunked ones and mixed rounds: the
+    wait, the six units' self time and the hand-off sum to the loop's
+    elapsed time at the end, at reads taken from the event loop while a
+    hop is out (the engine's loop is then in mid-hop) and in mid-wait."""
+    eng, _ = _mixed_engine()
+    assert set(HOST_TIME) <= set(eng.stats()) and eng.stats()["loop_us"] == 0
+    reads = []
+
+    async def run():
+        work = asyncio.ensure_future(asyncio.to_thread(
+            _long_prompts_beside_decoding_rows, eng))
+        while not work.done():
+            reads.append(eng.host_time())
+            await asyncio.sleep(0.005)
+        return await work
+
+    asyncio.run(run())
+    # the run's own event loop is closed, and with it the engine's loop
+    end = eng.host_time()
+    assert end == eng.host_time() and _tiles(end)
+    assert len(reads) > 10 and all(_tiles(r) for r in reads if r["loop_us"])
+    for a, b in zip(reads, reads[1:] + [end]):
+        assert all(b[k] >= a[k] for k in HOST_TIME), (a, b)
+    assert all(end[k] > 0 for k in TILING)
+    st = eng.stats()
+    # an admission, a chunk's round or a decode round each; a decode
+    # round dispatches a step or reads the last one of a stream
+    rounds = end["loop_hops"] - st["prefills"] - st["prefill_chunks"]
+    assert 0 < rounds <= st["batches"] + st["prefills"]
+    assert 0 < end["host_us_prefill_cache"] < end["host_us_prefill_chunk"] \
+        + end["host_us_decode_dispatch"]
+    # in mid-wait: the engine's loop blocks on its queue with no slot taken
+    async def idle():
+        await _agen_list(eng.generate([1, 2, 3], max_new_tokens=2))
+        a = eng.host_time()
+        await asyncio.sleep(0.05)
+        return a, eng.host_time()
+
+    a, b = asyncio.run(idle())
+    assert _tiles(a) and _tiles(b)
+    assert b["host_us_wait"] - a["host_us_wait"] >= 45_000
+    assert b["loop_hops"] == a["loop_hops"]
+
+
+class _Ticks:
+    """`time` for serve/llm.py with a clock the test sets."""
+    def __init__(self):
+        self.now = 100.0
+
+    def perf_counter(self):
+        return self.now
+
+
+def test_a_units_self_time_is_its_interval_less_the_units_inside_it(
+        monkeypatch):
+    """By a clock the test sets: a one-shot admission (prefill_chunk and
+    finish_prefill inside admit), a mixed round (decode_dispatch inside
+    prefill_chunk), with reads in mid-wait and in mid-hop."""
+    from ray_tpu.serve import llm
+
+    ticks = _Ticks()
+    monkeypatch.setattr(llm, "time", ticks)
+    clock = llm._LoopClock()
+    assert clock.read()["loop_us"] == 0
+
+    def at(t, call, *args):
+        ticks.now = 100.0 + t
+        return call(*args)
+
+    at(0, clock.start)
+    at(1, clock.turn, "wait")
+    mid_wait = at(3, clock.read)
+    assert (mid_wait["loop_us"], mid_wait["host_us_wait"],
+            mid_wait["host_us_handoff"]) == (3_000_000, 2_000_000, 1_000_000)
+    at(4, clock.turn, "between")
+    at(5, clock.begin_hop, "admit", "r1", 0)
+    at(6, clock.enter, "admit")
+    at(8, clock.enter, "prefill_chunk")
+    mid_hop = at(9, clock.read)
+    assert (mid_hop["host_us_admit"], mid_hop["host_us_prefill_chunk"],
+            mid_hop["host_us_handoff"]) == (2_000_000, 1_000_000, 3_000_000)
+    assert _tiles(mid_hop) and mid_hop["loop_us"] == 9_000_000
+    at(11, clock.leave)
+    at(11, clock.enter, "finish_prefill")
+    at(12, clock.leave)
+    at(15, clock.leave)
+    at(16, clock.end_hop)
+    at(16, clock.begin_hop, "prefill", "r2", 1)
+    at(16, clock.enter, "prefill_chunk")
+    at(17, clock.enter, "decode_dispatch")
+    at(21, clock.leave)
+    at(21.5, clock.leave)
+    at(21.5, clock.enter, "token_sync")
+    at(22, clock.leave)
+    at(22.25, clock.end_hop)
+    at(23, clock.turn, None)
+    end = at(40, clock.read)
+    assert end == {
+        "loop_us": 23_000_000, "loop_hops": 2, "host_us_wait": 3_000_000,
+        "host_us_admit": 5_000_000, "host_us_prefill_chunk": 4_500_000,
+        "host_us_finish_prefill": 1_000_000,
+        "host_us_decode_dispatch": 4_000_000, "host_us_token_sync": 500_000,
+        "host_us_emit": 0, "host_us_handoff": 5_000_000,
+        "host_us_prefill_cache": 0, "loop_stalls": 2,
+        "loop_stall_us": 17_250_000}
+    first, second = clock.kept_stalls()
+    assert first == {"t": 105.0, "seconds": 11.0, "hop": "admit",
+                     "units": {"admit": 5.0, "prefill_chunk": 3.0,
+                               "finish_prefill": 1.0},
+                     "handoff_s": 2.0, "prefill_cache_s": 0.0, "gc_s": 0.0,
+                     "programs_asked": 0, "active_slots": 0,
+                     "request_id": "r1"}
+    assert second["units"] == {"prefill_chunk": 1.5, "decode_dispatch": 4.0,
+                               "token_sync": 0.5}
+    assert (second["hop"], second["handoff_s"], second["active_slots"]) == (
+        "prefill", 0.25, 1)
+    # a loop that starts afterwards goes on counting; the newest stalls
+    # are kept, every one is counted
+    at(50, clock.start)
+    for k in range(20):
+        at(51 + k, clock.begin_hop, "decode", "", 2)
+        at(51.5 + k, clock.end_hop)
+    at(71, clock.begin_hop, "decode", "", 2)
+    at(71.25, clock.end_hop)       # STALL_S itself is no stall
+    read = at(72, clock.read)
+    assert (read["loop_stalls"], read["loop_stall_us"]) == (22, 27_250_000)
+    assert read["loop_us"] == 45_000_000 and _tiles(read)
+    kept = clock.kept_stalls()
+    assert len(kept) == llm.STALLS_KEPT == 16
+    assert [r["t"] for r in kept] == [100.0 + 55 + k for k in range(16)]
+    assert "request_id" not in kept[0]
+
+
+def test_admit_excludes_the_prefill_and_the_finish_inside_it(monkeypatch):
+    """Through the engine: a one-shot admission whose prefill call is
+    made to take 0.2 s. The time lands in `prefill_chunk`, not also in
+    `admit`, which holds both; the hop's sum is the loop's."""
+    eng = _small_engine("xla")
+    _collect(eng, [5, 9, 11], max_new_tokens=2)        # every program
+    before = eng.host_time()
+    step = eng._step
+
+    def slow_prefill(params, cache, tokens, *rest):
+        if tokens.ndim == 2:
+            time.sleep(0.2)
+        return step(params, cache, tokens, *rest)
+
+    monkeypatch.setattr(eng, "_step", slow_prefill)
+    _collect(eng, [5, 9, 12], max_new_tokens=2)
+    after = eng.host_time()
+    took = {k: after[k] - before[k] for k in HOST_TIME}
+    assert took["host_us_prefill_chunk"] >= 200_000
+    assert took["host_us_admit"] + took["host_us_finish_prefill"] < \
+        took["host_us_prefill_chunk"]
+    assert took["prompt_tokens"] == 3 and _tiles(after)
+
+
+def test_a_stalled_hop_is_counted_and_kept_with_the_unit_it_fell_in(
+        monkeypatch):
+    """One decode step made to sleep 0.3 s: one stall, its record names
+    the hop and the unit, `t` lies in the sleep (the clock of a client's
+    stamps), nothing was asked of XLA in it, and the hops around it are
+    none."""
+    eng = _small_engine("xla")
+    _collect(eng, [5, 9, 11], max_new_tokens=6)        # every program
+    before = eng.stats()
+    step, slept = eng._step, []
+
+    def stalling(params, cache, tokens, *rest):
+        if tokens.ndim == 1 and eng.batches - before["batches"] == 2 \
+                and not slept:
+            slept.append(time.perf_counter())
+            time.sleep(0.3)
+            slept.append(time.perf_counter())
+        return step(params, cache, tokens, *rest)
+
+    monkeypatch.setattr(eng, "_step", stalling)
+    assert len(_collect(eng, [5, 9, 12], max_new_tokens=6)) == 6
+    after = eng.stats()
+    assert after["loop_stalls"] - before["loop_stalls"] == 1
+    assert after["loop_hops"] - before["loop_hops"] >= 5
+    (rec,) = after["stalls"][len(before["stalls"]):]
+    assert rec["hop"] == "decode" and rec["seconds"] >= 0.3 > llm_mod.STALL_S
+    assert max(rec["units"], key=rec["units"].get) == "decode_dispatch"
+    assert rec["units"]["decode_dispatch"] >= 0.3
+    assert rec["t"] <= slept[0] and slept[1] <= rec["t"] + rec["seconds"]
+    assert rec["programs_asked"] == 0 and rec["active_slots"] == 1
+    assert rec["prefill_cache_s"] == 0.0 and "request_id" not in rec
+    assert abs(after["loop_stall_us"] - before["loop_stall_us"]
+               - rec["seconds"] * 1e6) <= 1
+    # what `serve_host_stall_share` makes of it in a 30 s window
+    from benchmarks.readers import loop
+
+    obs = {"before": {"stats": before}, "after": {"stats": after},
+           "window": (100.0, 130.0)}
+    assert loop.counter_share_of_window(obs, "loop_stall_us") == \
+        pytest.approx(100 * rec["seconds"] / 30, rel=1e-4)
+
+
+def test_prompt_tokens_counts_every_prefilled_prompt_whole():
+    """`prompt_tokens`: the prompts' lengths, the part a stored prefix
+    covered included, one-shot or in chunks."""
+    eng = LLMEngine("debug", tp=1, max_batch=2, max_seq_len=256,
+                    prompt_buckets=(16, 64), prefill_chunk=32,
+                    prefix_cache_entries=4)
+    shared = list(range(3, 40))
+    prompts = [[5, 9, 11], shared + [77, 78], shared + [88] * 9]
+    for p in prompts:
+        assert len(_collect(eng, p, max_new_tokens=2)) == 2
+    st = eng.stats()
+    assert st["prefix_hits"] == 1 and st["prefix_hit_tokens"] == 32
+    assert st["prompt_tokens"] == sum(map(len, prompts)) == 3 + 39 + 46
+
+
+def test_the_window_holds_every_counter_of_the_loop():
+    """What a serve cell's info line prints (`stats_in_window`: the
+    difference of every whole number of `stats()` between the window's
+    two ends) holds the loop's account, each a count and none negative;
+    the stalls' records are no number and stay out."""
+    from benchmarks import serve_cell
+
+    eng = _small_engine("xla")
+    _collect(eng, [5, 9, 11], max_new_tokens=3)
+    before = eng.stats()
+    _collect(eng, [5, 9, 12, 4], max_new_tokens=5)
+    after = eng.stats()
+    window = serve_cell.stats_in_window(
+        {"before": {"stats": before}, "after": {"stats": after}})
+    assert set(HOST_TIME) <= set(window) and "stalls" not in window
+    assert all(type(window[k]) is int and window[k] >= 0 for k in HOST_TIME)
+    assert window["prompt_tokens"] == 4 and window["loop_hops"] >= 5
+    assert abs(sum(window[k] for k in TILING) - window["loop_us"]) <= \
+        0.01 * window["loop_us"] + len(TILING)
+
+
+def test_counter_share_of_window_by_hand():
+    """benchmarks/readers/loop.py: 3 s of `loop_stall_us` in a window of
+    30 s read 10.0; no counter at either end, nothing."""
+    from benchmarks.readers import loop
+
+    ends = lambda a, b: {"before": {"stats": a}, "after": {"stats": b},
+                         "window": (100.0, 130.0)}
+    obs = ends({"loop_stall_us": 250_000}, {"loop_stall_us": 3_250_000})
+    assert loop.counter_share_of_window(obs, "loop_stall_us") == 10.0
+    assert loop.counter_share_of_window(obs, "host_us_wait") is None
+    for a, b in (({}, {"loop_stall_us": 1}), ({"loop_stall_us": 1}, {}),
+                 ({}, {})):
+        assert loop.counter_share_of_window(
+            ends(a, b), "loop_stall_us") is None
+    sound = ends({"loop_stall_us": 0}, {"loop_stall_us": 0})
+    assert loop.counter_share_of_window(sound, "loop_stall_us") == 0.0

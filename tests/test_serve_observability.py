@@ -225,6 +225,40 @@ def test_manager_engine_counter_deltas_and_restart():
     assert c["rayt_serve_engine_decode_steps_total"] == 20
 
 
+def test_engine_loop_counters_become_operator_series():
+    """The engine loop's own account (LLMEngine.host_time: stalled hops,
+    the wait for work, prompt tokens) rides the same report: counts as
+    they are, microseconds as seconds, differences of consecutive
+    reports, and no record where nothing was counted."""
+    from ray_tpu.util.builtin_metrics import serve_engine_metric_records
+
+    recs = serve_engine_metric_records(
+        "a", "D", "pid-7", loop_stalls=2, loop_stall_us=5_900_000,
+        host_us_wait=250_000, prompt_tokens=7000, ts=1.0)
+    assert {r["name"]: (r["kind"], r["value"], r["tags"]) for r in recs} == {
+        name: ("counter", value, {"app": "a", "deployment": "D"})
+        for name, value in (
+            ("rayt_serve_engine_stalls_total", 2.0),
+            ("rayt_serve_engine_stall_seconds_total", 5.9),
+            ("rayt_serve_engine_wait_seconds_total", 0.25),
+            ("rayt_serve_engine_prompt_tokens_total", 7000.0))}
+    assert serve_engine_metric_records("a", "D", "pid-7") == []
+
+    m = _mgr()
+    report = {"kind": "engine", "app": "a", "deployment": "D",
+              "replica": "pid-7", "prefills": 1, "prefill_chunks": 1,
+              "decode_steps": 1, "ts": 1.0}
+    m.ingest(dict(report, loop_stalls=1, loop_stall_us=2_950_000,
+                  host_us_wait=100_000, prompt_tokens=3000))
+    m.drain_metric_records()
+    m.ingest(dict(report, loop_stalls=1, loop_stall_us=2_950_000,
+                  host_us_wait=400_000, prompt_tokens=6500))
+    got = {r["name"]: r["value"] for r in m.drain_metric_records()}
+    assert got == {"rayt_serve_engine_wait_seconds_total":
+                   pytest.approx(0.3),
+                   "rayt_serve_engine_prompt_tokens_total": 3500.0}
+
+
 def test_manager_derives_histograms_before_sampling():
     """Prometheus series must be unskewed by retention: a sampled-out
     record still contributes its ttft/tpot/queue-wait observations."""
