@@ -49,13 +49,30 @@ chunk, and then lays its rows over the window's. Positions before
 cache["start"] are left padding: no bytes, in no window, chunk or
 summary.
 
+On a TPU, for shapes whole in its tiles, a chunk's attention is two
+calls a layer of ops/pallas/gqa_chunk_attention.py, which keeps a tile
+of scores in VMEM and walks only the tiles that hold a pair that counts:
+one over the leaf AS FOUND, read where it lies in the stack (the
+window's rows, each seen to its window's end; the summaries of chunks
+that ended before the call, each seen from its window's end on), one
+over the call's OWN rows followed by the summaries it has just made
+(`_seen_by` says from where to where each column is seen; `_chunk_plan`
+makes the tables once a call, for every layer). Each returns its part
+of the one softmax (values, running max, sum) and `_merged` divides.
+Elsewhere (every CPU run) the same two parts are plain jax.numpy over
+the window and the chunk whole and over every summary row. Which it is
+follows from the platform and the shapes: nothing selects it.
+
 Scopes beside llama's `attn_qkv`, `attn_out`, `mlp`, `embed`, `lm_head`:
-`eva_window_attn` (scores and values over E, the window's rows written;
-a decode step's one kernel over the whole range; a chunk's one write of
-its layer, summaries and all), `eva_chunk_attn` (a chunk's scores and
-values over C and the merge of the two parts), `eva_summarise` (k~, v~
-computed; in a decode step the loop over the rows that close a chunk,
-their k~, v~ computed and written).
+`eva_window_attn` (a decode step's one kernel over the whole range, the
+window's rows written; of a chunk the kernel call over its own rows and
+the summaries it made, and the write of the window's rows; in the plain
+form the scores and values over E), `eva_chunk_attn` (of a chunk the
+kernel call over the leaf as found and the merge of the two parts; in
+the plain form the scores and values over C and the merge),
+`eva_summarise` (k~, v~ computed, and of a chunk written, the columns
+of the chunks it holds bytes of and no other; in a decode step the loop
+over the rows that close a chunk, their k~, v~ computed and written).
 """
 
 from __future__ import annotations
@@ -69,7 +86,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.layout import Layout, with_layout_constraint
 
+from ray_tpu.ops import attention as _attention
 from ray_tpu.ops.attention import decode_attention, decode_block_len
+from ray_tpu.ops.pallas import gqa_chunk_attention as _chunk
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 
 NEG = -1e30
@@ -261,6 +280,64 @@ def decode_counters(cfg: EvaByteConfig, spans: list, rows: int) -> dict:
             "chunks_folded": int((t % c == c - 1).sum())}
 
 
+def _groups(cfg: EvaByteConfig, s: int) -> int:
+    """The chunks a call of `s` positions can lie in, wherever it
+    starts."""
+    return (s + cfg.chunk_size - 2) // cfg.chunk_size + 1
+
+
+def _chunk_tiles(cfg: EvaByteConfig, s: int, n: int):
+    """The chunk kernel's tiles for a call of `s` queries: (those against
+    a leaf of `n` columns as found; those against the call's own rows
+    followed by the summaries it makes; the columns those summaries are
+    padded to, whole key tiles), or None where the plain form stays: off
+    a TPU, and for shapes not whole in any tile."""
+    if not _attention._on_tpu():
+        return None
+    found = _chunk.tiles(1, cfg.head_dim, s, n)
+    own = _chunk.tiles(1, cfg.head_dim, s, s)
+    if found is None or own is None:
+        return None
+    return found, own, -(-_groups(cfg, s) // own.k) * own.k
+
+
+def _seen_by(xp, cfg: EvaByteConfig, t0, s: int, S: int, made: int):
+    """From which own positions to which each key column of a call's
+    two kernel calls is seen, as ops/pallas/gqa_chunk_attention.py takes
+    it (first > last: by none). `t0` [...] is the own position of the
+    call's first query (negative: left padding), `s` its queries, `S`
+    the leaf's summary columns, `made` the columns of the summaries the
+    call makes (chunk ``first // c + g`` in column g). Returns (the leaf
+    as found [..., 2, W + S]: a row of the first query's window from
+    before the call to that window's end, the summary of a chunk that
+    ended before the call from its window's end on; the call's own [...,
+    2, s + made]: a byte's key from itself to its window's end, a
+    summary the call makes of bytes it holds from its window's end on).
+    `xp` is numpy or jax.numpy."""
+    W, c, cpw = cfg.window_size, cfg.chunk_size, cfg.chunks_per_window
+    never = ever = _chunk.NO_WINDOW
+    t0 = xp.asarray(t0)[..., None]
+    first = xp.maximum(t0, 0)
+    j0 = first // c
+
+    def cols(seen_from, to):
+        return xp.stack(xp.broadcast_arrays(seen_from, xp.asarray(to)), -2)
+
+    res = W - 1 - xp.arange(W)           # index i holds residue W - 1 - i
+    w0 = W * (first // W)
+    j = xp.arange(S)
+    p = t0 + xp.arange(s)
+    jm = j0 + xp.arange(made)
+    found = xp.concatenate([
+        cols(xp.where(res < first % W, w0 + res, never), w0 + W - 1),
+        cols(xp.where(j < j0, W * (j // cpw + 1), never), ever)], -1)
+    own = xp.concatenate([
+        cols(xp.where(p >= 0, p, never), W * (p // W + 1) - 1),
+        cols(xp.where(c * jm < t0 + s, W * (jm // cpw + 1), never), ever)],
+        -1)
+    return found, own
+
+
 def prefill_counters(cfg: EvaByteConfig, start: int, pos: int, chunk: int,
                      depth: int) -> dict:
     """What the attention of one prefill call does: `chunk` queries at
@@ -268,16 +345,28 @@ def prefill_counters(cfg: EvaByteConfig, start: int, pos: int, chunk: int,
     `start`, in a cache made for `depth` positions. Pairs of query and
     key summed over the layers: `_visible`, those a query attends to (its
     window's rows up to itself, the summaries of the windows before),
-    and `_visited`, those whose scores are computed: the window as the
-    call found it and the call's own keys, and every summary row, for
-    every query."""
+    and `_visited`, those whose scores are computed: under the kernel
+    the live tiles' (`_chunk_tiles`, by the rule the device's tables
+    follow), filed by what the key column holds, a window's row or a
+    summary; in the plain form the window as the call found it and the
+    call's own keys, and every summary row, for every query."""
     W, cpw, L = cfg.window_size, cfg.chunks_per_window, cfg.n_layers
+    S = cfg.summaries(depth)
     t = np.arange(max(pos, start), pos + chunk, dtype=np.int64) - start
+    tl = _chunk_tiles(cfg, chunk, W + S)
+    if tl is None:
+        window, summaries = chunk * (W + chunk), chunk * S
+    else:
+        found, own = _seen_by(np, cfg, pos - start, chunk, S, tl[2])
+        found = _chunk.keys_visited(found, pos - start, chunk, tl[0])
+        own = _chunk.keys_visited(own, pos - start, chunk, tl[1])
+        window = int(found[:W].sum() + own[:chunk].sum())
+        summaries = int(found[W:].sum() + own[chunk:].sum())
     return {
         "prefill_window_keys_visible": L * int((t % W + 1).sum()),
-        "prefill_window_keys_visited": L * chunk * (W + chunk),
+        "prefill_window_keys_visited": L * window,
         "prefill_summaries_visible": L * int((cpw * (t // W)).sum()),
-        "prefill_summaries_visited": L * chunk * cfg.summaries(depth),
+        "prefill_summaries_visited": L * summaries,
         "windows_folded": int(((t > 0) & (t % W == 0)).sum())}
 
 
@@ -382,12 +471,12 @@ def _decode_attend(cfg, layer, li, q, kk, vv, kc, vc, t, live):
 
 def _part(z, ok, v_of):
     """One part of the softmax: scores z [b, H, q, k] of which `ok` [b,
-    1, q, k] count -> (running max, sum, values [b, q, H, hd]) for the
+    1, q, k] count -> (values [b, H, q, hd], running max, sum) for the
     merge; `v_of(p)` multiplies the weights into the part's values."""
     z = jnp.where(ok, z, NEG)
     m = z.max(-1)
     p = jnp.exp(z - m[..., None]) * ok
-    return m, p.sum(-1), v_of(p)
+    return v_of(p), m, p.sum(-1)
 
 
 def _rows_at(a, first, n: int):
@@ -406,13 +495,41 @@ def _laid_at(new, first, n: int):
         x, y, i, 0))(buf, new, first)[:, :n]
 
 
-def _chunk_attend(cfg, layer, li, q, kk, vv, kc, vc, t):
+def _chunk_plan(cfg: EvaByteConfig, t, n: int):
+    """What the chunk kernel's two calls a layer take, the same for every
+    layer of a call: (tiles, the leaf's columns as found, their tables,
+    the call's own columns, their tables), or None where the plain form
+    stays. `t` [b, s] as `_chunk_attend` takes it, `n` the leaf's
+    columns."""
+    s = t.shape[1]
+    tl = _chunk_tiles(cfg, s, n)
+    if tl is None:
+        return None
+    found, own = _seen_by(jnp, cfg, t[:, 0], s, n - cfg.window_size, tl[2])
+    return (tl, found, _chunk.tile_tables(found, t[:, 0], s, tl[0]),
+            own, _chunk.tile_tables(own, t[:, 0], s, tl[1]))
+
+
+def _merged(part1, part2):
+    """Two parts of one softmax, each (values not yet divided [..., q,
+    hd], running max [..., q], sum [..., q]) -> the quotient, zeros for
+    a query with nothing in either."""
+    (acc1, m1, l1), (acc2, m2, l2) = part1, part2
+    m = jnp.maximum(m1, m2)
+    a1, a2 = jnp.exp(m1 - m), jnp.exp(m2 - m)
+    total = l1 * a1 + l2 * a2
+    return (acc1 * a1[..., None] + acc2 * a2[..., None]) / jnp.where(
+        total == 0, 1.0, total)[..., None]
+
+
+def _chunk_attend(cfg, layer, li, q, kk, vv, kc, vc, t, plan=None):
     """`s` new positions of a batch in lock-step against layer `li`: q,
     kk, vv [b, s, H, hd], `t` [b, s] each position's own place in its
-    request (negative: left padding, no byte). Returns (attn [b, s,
-    H * hd], kc, vc). The layer is read once, before anything of it is
-    written, and written once, so that the stack is updated where it
-    lies."""
+    request (negative: left padding, no byte); `plan` from `_chunk_plan`.
+    Returns (attn [b, s, H * hd], kc, vc). The layer is read before
+    anything of it is written, and of it only the window's rows and the
+    summaries the call makes are written, each once, so that the stack
+    is updated where it lies."""
     b, s, H, hd = q.shape
     W, c, cpw = cfg.window_size, cfg.chunk_size, cfg.chunks_per_window
     n = kc.shape[4]
@@ -423,36 +540,17 @@ def _chunk_attend(cfg, layer, li, q, kk, vv, kc, vc, t):
     t0 = t[:, 0]
     first = jnp.maximum(t0, 0)      # the call's first byte, were it one
     f32 = dict(preferred_element_type=jnp.float32)
-    k_l = jax.lax.dynamic_slice(kc, (li, 0, 0, 0, 0), (1, b, H, hd, n))[0]
-    v_l = jax.lax.dynamic_slice(vc, (li, 0, 0, 0, 0), (1, b, H, n, hd))[0]
-    ring_k, ring_v = k_l[..., :W], v_l[:, :, :W]
+    ring_k = jax.lax.dynamic_slice(kc, (li, 0, 0, 0, 0), (1, b, H, hd, W))[0]
+    ring_v = jax.lax.dynamic_slice(vc, (li, 0, 0, 0, 0), (1, b, H, W, hd))[0]
     at = jnp.arange(W)               # index i holds residue W - 1 - i
-
-    with jax.named_scope("eva_window_attn"):
-        # the window as the call found it: a byte of the first query's
-        # window where its residue lies before the call's first byte
-        held = (W - 1 - at)[None, :] < (first % W)[:, None]
-        ring_ok = (real & (win == (first // W)[:, None]))[:, :, None] \
-            & held[:, None, :]
-        own_ok = (real[:, :, None] & real[:, None, :]
-                  & (win[:, :, None] == win[:, None, :])
-                  & (jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]))
-        z = jnp.concatenate([
-            jnp.einsum("bqhd,bhdk->bhqk", q, ring_k, **f32),
-            jnp.einsum("bqhd,bkhd->bhqk", q, kk, **f32)], -1) * scale
-        m1, l1, acc1 = _part(
-            z, jnp.concatenate([ring_ok, own_ok], -1)[:, None],
-            lambda p: jnp.einsum("bhqk,bhkd->bqhd", p[..., :W].astype(dt),
-                                 ring_v, **f32)
-            + jnp.einsum("bhqk,bkhd->bqhd", p[..., W:].astype(dt), vv,
-                         **f32))
+    by_head = lambda x: x.transpose(0, 2, 1, 3)      # [b, s, H, hd] <->
 
     with jax.named_scope("eva_summarise"):
         # every chunk the call's bytes lie in, whole: G groups of c from
         # chunk `j0`; of the first, what lies before the call is in the
         # window: the c positions before the call's first, picked out as
         # found by a product with their one-hot rows (exact)
-        G = (s + c - 2) // c + 1
+        G = _groups(cfg, s)
         before = W - 1 - (t0[:, None] - c + jnp.arange(c)[None, :]) % W
         pick = (before[:, :, None] == at[None, None, :]).astype(dt)
         prev_k = jnp.einsum("bew,bhdw->behd", pick, ring_k)
@@ -468,30 +566,70 @@ def _chunk_attend(cfg, layer, li, q, kk, vv, kc, vc, t):
         ok = place < (t0 + s)[:, None, None]
         k_sum, v_sum = _summarise(layer, grp(prev_k, kk), grp(prev_v, vv),
                                   ok, c)
-        hit = _laid_at(ok.any(-1), j0, S)               # [b, S]
-        sum_k = jnp.where(hit[:, None, None, :],
-                          _laid_at(k_sum, j0, S).transpose(0, 2, 3, 1),
-                          k_l[..., W:])
-        sum_v = jnp.where(hit[:, None, :, None],
-                          _laid_at(v_sum, j0, S).transpose(0, 2, 1, 3),
-                          v_l[:, :, W:])
+        hit = ok.any(-1)           # [b, G]: the call holds bytes of it
 
-    with jax.named_scope("eva_chunk_attn"):
-        # the summaries of the windows before each query's own, one that
-        # ended inside this call among them
-        ok2 = real[:, :, None] & (jnp.arange(S)[None, None, :]
-                                  < (cpw * win)[:, :, None])
-        m2, l2, acc2 = _part(
-            jnp.einsum("bqhd,bhdj->bhqj", q, sum_k, **f32) * scale,
-            ok2[:, None],
-            lambda p: jnp.einsum("bhqj,bhjd->bqhd", p.astype(dt), sum_v,
-                                 **f32))
-        m = jnp.maximum(m1, m2)
-        a1, a2 = jnp.exp(m1 - m), jnp.exp(m2 - m)
-        total = l1 * a1 + l2 * a2                       # [b, H, s]
-        by_q = lambda x: x.transpose(0, 2, 1)[..., None]
-        attn = (acc1 * by_q(a1) + acc2 * by_q(a2)) / by_q(
-            jnp.where(total == 0, 1.0, total))
+    if plan is not None:
+        (found_t, own_t, made), found, found_tables, own, own_tables = plan
+        q_h = by_head(q)[:, :, None]                  # [b, H, 1, s, hd]
+        with jax.named_scope("eva_chunk_attn"):
+            # the leaf as the call found it, where it lies in the stack:
+            # the window's rows and the summaries of the chunks before
+            part1 = _chunk.gqa_chunk_attention(
+                q_h, kc, vc, li, found, t0, scale=scale, t=found_t,
+                tables=found_tables, parts=True)
+        with jax.named_scope("eva_window_attn"):
+            # the call's own keys, then the summaries it has just made
+            none = jnp.zeros((b, made - G, H, hd), dt)
+            k_own = jnp.concatenate([kk, k_sum, none], 1).transpose(
+                0, 2, 3, 1)                            # [b, H, hd, s + made]
+            v_own = by_head(jnp.concatenate([vv, v_sum, none], 1))
+            part2 = _chunk.gqa_chunk_attention(
+                q_h, k_own[None], v_own[None], 0, own, t0, scale=scale,
+                t=own_t, tables=own_tables, parts=True)
+        with jax.named_scope("eva_chunk_attn"):
+            attn = by_head(_merged(part1, part2)[:, :, 0])
+    else:
+        with jax.named_scope("eva_window_attn"):
+            # the window as the call found it: a byte of the first
+            # query's window where its residue lies before the call's
+            # first byte
+            held = (W - 1 - at)[None, :] < (first % W)[:, None]
+            ring_ok = (real & (win == (first // W)[:, None]))[:, :, None] \
+                & held[:, None, :]
+            own_ok = (real[:, :, None] & real[:, None, :]
+                      & (win[:, :, None] == win[:, None, :])
+                      & (jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]))
+            z = jnp.concatenate([
+                jnp.einsum("bqhd,bhdk->bhqk", q, ring_k, **f32),
+                jnp.einsum("bqhd,bkhd->bhqk", q, kk, **f32)], -1) * scale
+            part1 = _part(
+                z, jnp.concatenate([ring_ok, own_ok], -1)[:, None],
+                lambda p: jnp.einsum("bhqk,bhkd->bhqd",
+                                     p[..., :W].astype(dt), ring_v, **f32)
+                + jnp.einsum("bhqk,bkhd->bhqd", p[..., W:].astype(dt), vv,
+                             **f32))
+        with jax.named_scope("eva_chunk_attn"):
+            # the summaries of the windows before each query's own, one
+            # that ended inside this call among them
+            laid = _laid_at(hit, j0, S)                 # [b, S]
+            sum_k = jnp.where(
+                laid[:, None, None, :],
+                _laid_at(k_sum, j0, S).transpose(0, 2, 3, 1),
+                jax.lax.dynamic_slice(kc, (li, 0, 0, 0, W),
+                                      (1, b, H, hd, S))[0])
+            sum_v = jnp.where(
+                laid[:, None, :, None],
+                _laid_at(v_sum, j0, S).transpose(0, 2, 1, 3),
+                jax.lax.dynamic_slice(vc, (li, 0, 0, W, 0),
+                                      (1, b, H, S, hd))[0])
+            ok2 = real[:, :, None] & (jnp.arange(S)[None, None, :]
+                                      < (cpw * win)[:, :, None])
+            part2 = _part(
+                jnp.einsum("bqhd,bhdj->bhqj", q, sum_k, **f32) * scale,
+                ok2[:, None],
+                lambda p: jnp.einsum("bhqj,bhjd->bhqd", p.astype(dt), sum_v,
+                                     **f32))
+            attn = by_head(_merged(part1, part2))
 
     with jax.named_scope("eva_window_attn"):
         # the call's last W rows over the window's: index i takes the
@@ -509,15 +647,38 @@ def _chunk_attend(cfg, layer, li, q, kk, vv, kc, vc, t):
             return jax.vmap(lambda x, by: jnp.roll(x, by, 0))(
                 turned, -tail0 % W)
 
-        k_l = jnp.concatenate([
-            jnp.where(put[:, None, None, :], laid(kk).transpose(0, 2, 3, 1),
-                      ring_k), sum_k], -1)
-        v_l = jnp.concatenate([
-            jnp.where(put[:, None, :, None], laid(vv).transpose(0, 2, 1, 3),
-                      ring_v), sum_v], 2)
-        # the layer's one write: the window's rows and the summaries
-        kc = jax.lax.dynamic_update_slice(kc, k_l[None], (li, 0, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, v_l[None], (li, 0, 0, 0, 0))
+        # the layer's two writes: the window's rows, whole
+        kc = jax.lax.dynamic_update_slice(kc, jnp.where(
+            put[:, None, None, :], laid(kk).transpose(0, 2, 3, 1),
+            ring_k)[None], (li, 0, 0, 0, 0))
+        vc = jax.lax.dynamic_update_slice(vc, jnp.where(
+            put[:, None, :, None], laid(vv).transpose(0, 2, 1, 3),
+            ring_v)[None], (li, 0, 0, 0, 0))
+    with jax.named_scope("eva_summarise"):
+        # and the summaries made, a row's `cols` columns from the first
+        # chunk it holds bytes of (held back where they would pass the
+        # leaf's end: a column then takes the group that falls on it);
+        # a column whose chunk the call holds no byte of keeps what it had
+        cols = min(G, S)
+        col0 = jnp.minimum(j0, S - cols)                      # [b]
+        g_of = (col0 - j0)[:, None] + jnp.arange(cols)[None, :]
+        takes = (g_of >= 0) & jnp.take_along_axis(
+            hit, jnp.maximum(g_of, 0), 1)                     # [b, cols]
+        pick_g = lambda x: jnp.take_along_axis(
+            x, jnp.maximum(g_of, 0)[:, :, None, None], 1)
+        new_k = pick_g(k_sum).transpose(0, 2, 3, 1)       # [b, H, hd, cols]
+        new_v = pick_g(v_sum).transpose(0, 2, 1, 3)       # [b, H, cols, hd]
+        # cut out and put back in the stack's own order, as the decode
+        # step's fold does and for its reason
+        lies = lambda x: with_layout_constraint(x, Layout((0, 1, 2)))
+        for r in range(b):
+            k_at, v_at = (li, r, 0, 0, W + col0[r]), (li, r, 0, W + col0[r], 0)
+            kc = jax.lax.dynamic_update_slice(kc, lies(jnp.where(
+                takes[r][None, None, :], new_k[r], lies(jax.lax.dynamic_slice(
+                    kc, k_at, (1, 1, H, hd, cols))[0, 0])))[None, None], k_at)
+            vc = jax.lax.dynamic_update_slice(vc, lies(jnp.where(
+                takes[r][None, :, None], new_v[r], lies(jax.lax.dynamic_slice(
+                    vc, v_at, (1, 1, H, cols, hd))[0, 0])))[None, None], v_at)
     return attn.astype(dt).reshape(b, s, H * hd), kc, vc
 
 
@@ -543,6 +704,7 @@ def _hidden(params: dict, cache: dict, tokens: jax.Array,
         x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
     cos, sin = rope_frequencies(hd, cfg.max_seq_len, cfg.rope_theta)
     f32 = dict(preferred_element_type=jnp.float32)
+    plan = None if per_row else _chunk_plan(cfg, t, cache["k"].shape[4])
 
     def block(li, carry):
         x, kc, vc = carry
@@ -560,7 +722,7 @@ def _hidden(params: dict, cache: dict, tokens: jax.Array,
                                           t[:, 0], cache_len >= 0)
         else:
             attn, kc, vc = _chunk_attend(cfg, layer, li, q, kk, vv, kc, vc,
-                                         t)
+                                         t, plan)
         with jax.named_scope("attn_out"):
             x = x + jnp.dot(attn, layer["wo"].astype(dt), **f32)
         with jax.named_scope("mlp"):

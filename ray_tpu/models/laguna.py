@@ -390,7 +390,8 @@ def _full_layer(cfg, ki: int, q, kk, vv, k_cache, v_cache, cache_len,
         k_pos = jnp.broadcast_to(jnp.arange(depth, dtype=jnp.int32),
                                  (b, depth))
         out = _chunk.gqa_chunk_attention(
-            _by_group(q, nkv), k_cache, v_cache, ki, k_pos, start, cache_len,
+            _by_group(q, nkv), k_cache, v_cache, ki,
+            _chunk.seen_by_position(jnp, k_pos, start), cache_len,
             scale=scale, t=t)
         return _by_head(out), k_cache, v_cache
 
@@ -440,8 +441,10 @@ def _sliding_layer(cfg, ki: int, q, kk, vv, ring_k, ring_v, cache_len,
         t = _chunk_tiles(H // nkv, hd, s, ring + s)
         if t is not None:
             attn = _by_head(_chunk.gqa_chunk_attention(
-                _by_group(q, nkv), k_all[None], v_all[None], 0, k_pos, start,
-                cache_len, scale=scale, t=t, window=cfg.sliding_window))
+                _by_group(q, nkv), k_all[None], v_all[None], 0,
+                _chunk.seen_by_position(jnp, k_pos, start,
+                                        cfg.sliding_window),
+                cache_len, scale=scale, t=t))
         else:
             dist = abs_pos[:, :, None] - k_pos[:, None, :]
             mask = ((dist >= 0) & (dist < cfg.sliding_window)
@@ -577,8 +580,9 @@ def prefill_counters(cfg: LagunaConfig, start: int, pos: int, chunk: int,
     depths = np.arange(max(pos, start), pos + chunk) - start + 1
     ring, nkv = cfg.ring_len, cfg.n_kv_heads
     t = _chunk_tiles(cfg.heads_full // nkv, cfg.head_dim, chunk, depth)
-    full = chunk * depth if t is None else _chunk.keys_visited(
-        np.arange(depth), start, pos, chunk, t, _chunk.NO_WINDOW)
+    full = chunk * depth if t is None else int(_chunk.keys_visited(
+        _chunk.seen_by_position(np, np.arange(depth), start), pos, chunk,
+        t).sum())
     t = _chunk_tiles(cfg.heads_sliding // nkv, cfg.head_dim, chunk,
                      ring + chunk)
     if t is None:
@@ -588,8 +592,9 @@ def prefill_counters(cfg: LagunaConfig, start: int, pos: int, chunk: int,
         k_pos = np.concatenate([
             pos - 1 - (pos - 1 - np.arange(ring)) % ring,
             pos + np.arange(chunk)])
-        window = _chunk.keys_visited(k_pos, start, pos, chunk, t,
-                                     cfg.sliding_window)
+        window = int(_chunk.keys_visited(
+            _chunk.seen_by_position(np, k_pos, start, cfg.sliding_window),
+            pos, chunk, t).sum())
     nf, ns = cfg.count(KINDS[0]), cfg.count(KINDS[1])
     return {"prefill_full_keys_visited": nf * full,
             "prefill_full_keys_visible": nf * int(depths.sum()),

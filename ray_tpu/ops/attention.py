@@ -18,8 +18,14 @@ layers, `models/laguna.py`): a decode step with per-row depths on a TPU
 is `decode_attention` (ops/pallas/decode_attention.py; laguna's window
 layers call it over their ring in its `ring` mode), everything else the
 masked XLA form, which holds a layer's scores whole. A model whose
-chunks meet a cache too deep for that takes its own kernel
-(`models/laguna.py`: ops/pallas/gqa_chunk_attention.py).
+chunks meet a cache too deep for that, or too much of it that no query
+sees, calls ops/pallas/gqa_chunk_attention.py itself, which decides from
+what its input says about each key column: `models/laguna.py` (a full
+layer by position, a sliding one over its ring and the chunk) and
+`models/evabyte.py` (the leaf as found, then the chunk's own rows and
+the summaries it made: two calls and a merge). The latent models' chunks
+take ops/pallas/latent_attention.py (`models/dots3_note.py`,
+`models/kimi_k2.py`), which takes a mask.
 """
 
 from __future__ import annotations

@@ -508,6 +508,136 @@ def test_decode_step_on_the_kernels_path_equals_the_plain_one(model,
     assert _rel(kernel[1], want) < 1e-5
 
 
+# the chunk kernel's twin: windows of 32 in chunks of 4, a leaf for 128
+# positions (32 rows of the window, 32 summaries), heads of 128
+KW, KC, KS = 32, 4, 32
+# first own positions of the rows of one call, its queries, key tile
+CHUNK_CASES = {
+    "inside-a-window": ([8], 16, 8),
+    "a-window-ends-inside": ([24], 16, 8),
+    "a-window-ends-inside-a-call-of-its-length": ([16], 32, 8),
+    "left-padded-first-chunk": ([-5], 16, 8),
+    "deep-last-tile-partly-seen": ([3 * KW + 8], 16, 16),
+    "two-rows-two-phases": ([8, 59], 16, 8),
+}
+
+
+@pytest.fixture
+def chunk_kernel(monkeypatch):
+    """(config, tiles of 8 queries x `tk` keys chosen as on a TPU) for
+    the chunk kernel interpreted."""
+    from ray_tpu.ops import attention
+    from ray_tpu.ops.pallas import gqa_chunk_attention as gqa
+
+    cfg = evabyte.EvaByteConfig(
+        vocab_size=32, dim=256, n_layers=2, n_heads=2, hidden_dim=64,
+        max_seq_len=4 * KW, window_size=KW, chunk_size=KC, n_pred_heads=8,
+        dtype=jnp.float32, param_dtype=jnp.float32)
+
+    def tiles_of(tk):
+        monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+        monkeypatch.setattr(gqa, "_Q_TILES", (8,))
+        monkeypatch.setattr(gqa, "_K_TILES", (tk,))
+        return cfg
+
+    return tiles_of
+
+
+def _sees(cfg, t0: int, s: int, S: int, made: int):
+    """[s, W + S] and [s, s + made] bool by the module's docstring, each
+    pair on its own: whether the query at own position t0 + i attends to
+    the leaf's column as the call found it, and to the call's own."""
+    W, c, cpw = cfg.window_size, cfg.chunk_size, cfg.chunks_per_window
+    first = max(t0, 0)
+    found = np.zeros((s, W + S), bool)
+    own = np.zeros((s, s + made), bool)
+    for i in range(s):
+        t = t0 + i
+        if t < 0:
+            continue
+        for col in range(W):            # the position the column holds
+            p = W * (first // W) + W - 1 - col
+            found[i, col] = p < first and p // W == t // W
+        for j in range(S):              # final before the call began
+            found[i, W + j] = c * (j + 1) <= first and j // cpw < t // W
+        for k in range(s):
+            p = t0 + k
+            own[i, k] = 0 <= p <= t and p // W == t // W
+        for g in range(made):           # a chunk the call holds bytes of
+            j = first // c + g
+            own[i, s + g] = (c * (j + 1) > first and c * j < t0 + s
+                             and j // cpw < t // W)
+    return found, own
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_chunk_kernel_against_the_plain_form(chunk_kernel, case):
+    """ops/pallas/gqa_chunk_attention.py interpreted, as `_chunk_attend`
+    calls it twice a layer (the leaf as found, where it lies in the
+    stack; the call's own rows and the summaries it makes), against the
+    plain form over a leaf of noise: a column one form sees and the other
+    does not shows. The layer written is the plain form's."""
+    t0, s, tk = CHUNK_CASES[case]
+    cfg = chunk_kernel(tk)
+    b, H, hd, n = len(t0), cfg.n_heads, cfg.head_dim, KW + KS
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 7)
+    q, kk, vv = (jax.random.normal(k, (b, s, H, hd)) for k in ks[:3])
+    kc = jax.random.normal(ks[3], (2, b, H, hd, n))
+    vc = jax.random.normal(ks[4], (2, b, H, n, hd))
+    layer = {"phi": jax.random.normal(ks[5], (H, hd)) * 0.2,
+             "mu": jax.random.normal(ks[6], (H, hd)) * 0.2}
+    t = jnp.asarray(t0)[:, None] + jnp.arange(s)[None, :]
+    plan = evabyte._chunk_plan(cfg, t, n)
+    assert plan[0][:2] == ((8, tk), (8, tk))
+    want = evabyte._chunk_attend(cfg, layer, 1, q, kk, vv, kc, vc, t)
+    got = evabyte._chunk_attend(cfg, layer, 1, q, kk, vv, kc, vc, t, plan)
+    for a, b_ in zip(got, want):
+        assert float(jnp.abs(a - b_).max()) < 2e-5
+    assert float(jnp.abs(want[0]).max()) > 0.1
+    # a left-padding query gives zeros; the layer not asked for is left
+    pad = np.asarray(t) < 0
+    assert float(jnp.abs(got[0])[pad].sum()) == 0.0
+    assert np.array_equal(got[1][0], kc[0])
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_chunk_kernels_tables_and_what_the_counters_call_visited(
+        chunk_kernel, case):
+    """A tile is live iff it holds a pair of query and key that counts,
+    and `prefill_counters` counts the live tiles' pairs by what their
+    columns hold, by the rule the device's tables follow."""
+    t0, s, tk = CHUNK_CASES[case]
+    cfg = chunk_kernel(tk)
+    t = jnp.asarray(t0)[:, None] + jnp.arange(s)[None, :]
+    (_, _, made), _, (found_live, found_named), _, (own_live, own_named) = \
+        evabyte._chunk_plan(cfg, t, KW + KS)
+    for r, first in enumerate(t0):
+        found, own = _sees(cfg, first, s, KS, made)
+        visited = [0, 0]
+        for sees, live, named in ((found, found_live, found_named),
+                                  (own, own_live, own_named)):
+            by_tile = sees.reshape(s // 8, 8, -1, tk).any((1, 3))
+            assert np.array_equal(np.asarray(live[r]) > 0, by_tile)
+            # a step that computes nothing names a tile that is live
+            if by_tile.any():
+                assert np.take_along_axis(
+                    by_tile, np.asarray(named[r]), 1)[by_tile.any(1)].all()
+            edge = KW if sees is found else s   # rows | summaries
+            pairs = 8 * np.repeat(by_tile.sum(0), tk)
+            visited[0] += int(pairs[:edge].sum())
+            visited[1] += int(pairs[edge:].sum())
+        got = evabyte.prefill_counters(cfg, 7, 7 + first, s, 4 * KW)
+        L = cfg.n_layers
+        assert got["prefill_window_keys_visited"] == L * visited[0]
+        assert got["prefill_summaries_visited"] == L * visited[1]
+        assert got["prefill_window_keys_visible"] == L * int(
+            found[:, :KW].sum() + own[:, :s].sum())
+        assert got["prefill_summaries_visible"] == L * int(
+            found[:, KW:].sum() + own[:, s:].sum())
+        assert visited[0] * L >= got["prefill_window_keys_visible"]
+        assert visited[1] * L >= got["prefill_summaries_visible"]
+
+
 @pytest.mark.parametrize("model_name", ["evabyte", "llama"])
 def test_one_prefill_cache_is_alive_among_the_prompts_admitted(model,
                                                               model_name):

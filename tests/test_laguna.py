@@ -453,9 +453,10 @@ def test_chunk_kernel_against_the_plain_form():
     k_pos = jnp.broadcast_to(jnp.arange(n), (b, n))
     for window in (None, 10):
         t = gqa.Tiles(16, 32)
+        seen = gqa.seen_by_position(jnp, k_pos, start, window)
         got = m._by_head(gqa.gqa_chunk_attention(
-            m._by_group(q, nkv), k, v, 0, k_pos, start, pos0,
-            scale=hd ** -0.5, t=t, window=window))
+            m._by_group(q, nkv), k, v, 0, seen, pos0, scale=hd ** -0.5,
+            t=t))
         q_pos = pos0 + jnp.arange(s)
         dist = q_pos[None, :, None] - k_pos[:, None, :]
         mask = ((dist >= 0) & (dist < (window or n + s))
@@ -464,8 +465,7 @@ def test_chunk_kernel_against_the_plain_form():
         assert float(jnp.abs(got - want).max()) < 2e-5
         # row 1's queries before its start attend to nothing: zeros
         assert float(jnp.abs(got[1, :5]).max()) == 0.0
-        live, named = gqa.tile_tables(k_pos, start, pos0, s, t,
-                                      window or gqa.NO_WINDOW)
+        live, named = gqa.tile_tables(seen, jnp.full((b,), pos0), s, t)
         # keys past the chunk's end are never live; with the window, nor
         # are those more than 10 behind the second query tile's first
         assert int(live[:, :, 2].sum()) == 0
