@@ -16,13 +16,29 @@ from __future__ import annotations
 import functools
 import time
 
-from benchmarks import model
+from benchmarks import eva_ops, model
 from benchmarks.sparse_moe_model import _placements
+from benchmarks.yardsticks import Yardsticks, per_shapes
 
 PROGRAM_MODULE = "ray_tpu.models.evabyte"
 # the limits `correct` holds every check to, beside `finite`
 LIMITS = ("logits_rel_rms", "summary_rel_rms", "token_margin_logits",
           "token_margin_program")
+
+# what benchmarks/readers/model.py reads for this model: a window of
+# exact keys and one summary a chunk before it, in one pair of scopes
+_ATTN = ["eva_window_attn", "eva_chunk_attn"]
+YARDSTICKS = Yardsticks(
+    flops_per_token=per_shapes(eva_ops.flops_per_token),
+    attn_scopes={"decode": _ATTN, "prefill": _ATTN},
+    decode_attended=(("decode_window_positions_live",
+                      "decode_summaries_live"),),
+    decode_attn_work=eva_ops.decode_attn_work,
+    prefill_visible=(("prefill_window_keys_visible",
+                      "prefill_summaries_visible"),),
+    prefill_attn_flops=eva_ops.attn_flops,
+    cache_read=(["decode_window_positions_read", "decode_summaries_read"],
+                ["decode_window_positions_live", "decode_summaries_live"]))
 
 
 def program_config(config: dict, role: str, **overrides):

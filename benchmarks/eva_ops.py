@@ -1,7 +1,8 @@
 """The bytes and operations that EVA's attention requires (a window of
 exact keys, one summary a chunk of everything before it), from shapes:
-the yardsticks of `evabyte_decode_attn_roofline_share`,
-`evabyte_prefill_attn_roofline_share` and `evabyte_mfu`, kept beside
+the byte cell's yardsticks of `serve_decode_attn_roofline_share`,
+`serve_prefill_attn_roofline_share` and `serve_mfu` (named to the one
+reader by eva_model.YARDSTICKS), kept beside
 peaks.py so that no PR that claims a gain can change what 100% means.
 Each is written for the WORK, not for how the program does it: a decode
 query must read the key and the value of every window position and
@@ -46,6 +47,13 @@ def attn_flops(config: dict, pairs: int) -> float:
     return 4.0 * _heads_dim(config) * pairs
 
 
+def decode_attn_work(config: dict, rows: int) -> tuple:
+    """(bytes, operations) of the decode rounds' attention over `rows`
+    window positions and summaries (2 operations a byte read: the bytes
+    bound)."""
+    return decode_attn_bytes(config, rows), attn_flops(config, rows)
+
+
 def attended(config: dict, t):
     """What a query at position `t` of its request (an int or an array
     of them) attends to: its window's positions up to itself and one
@@ -65,3 +73,10 @@ def attn_flops_per_token(config: dict, shapes: list) -> float:
                 for p, o in shapes)
     tokens = sum(p + o for p, o in shapes)
     return attn_flops(config, config["num_hidden_layers"] * pairs) / tokens
+
+
+def flops_per_token(config: dict, shapes: list) -> float:
+    """Required operations a byte served: two a matmul weight, and the
+    attention of one cycle of the traffic's shapes."""
+    return (2.0 * matmul_params(config)
+            + attn_flops_per_token(config, shapes))

@@ -16,15 +16,33 @@ from __future__ import annotations
 
 import functools
 
-from benchmarks import model
+from benchmarks import gqa_moe_ops, model
 from benchmarks.eva_model import take_slots
 from benchmarks.sparse_moe_model import _margin, _placements
+from benchmarks.yardsticks import Yardsticks, per_shapes
 
 PROGRAM_MODULE = "ray_tpu.models.laguna"
 # the limits `correct` holds every check to, beside `finite`
 LIMITS = ("logits_rel_rms_forced", "logits_rel_rms_forced_step",
           "router_margin", "logits_rel_rms", "token_margin_logits",
           "token_margin_program")
+
+# what benchmarks/readers/model.py reads for this model: grouped-query
+# attention of two kinds, each counted apart (another count of heads)
+_ATTN = ["full_attn", "window_attn"]
+YARDSTICKS = Yardsticks(
+    flops_per_token=per_shapes(gqa_moe_ops.flops_per_token),
+    attn_scopes={"decode": _ATTN, "prefill": _ATTN},
+    decode_attended=(("decode_full_positions_attended",),
+                     ("decode_window_positions_attended",)),
+    decode_attn_work=gqa_moe_ops.decode_attn_work,
+    prefill_visible=(("prefill_full_keys_visible",),
+                     ("prefill_window_keys_visible",)),
+    prefill_attn_flops=gqa_moe_ops.attn_flops,
+    cache_read=(["decode_full_positions_read",
+                 "decode_window_positions_read"],
+                ["decode_full_positions_attended",
+                 "decode_window_positions_attended"]))
 
 
 def program_config(config: dict, role: str, **overrides):

@@ -1,16 +1,17 @@
 """The bytes and operations that a decoder of the `laguna` family
 requires (grouped-query attention of two kinds, each with its own count
 of query heads: full depth and a window; routed experts of which this
-holder has a share), from shapes: the yardsticks of `laguna_mfu` and of
-`laguna_decode_attn_` and `laguna_prefill_attn_roofline_share`, kept
-beside peaks.py so that no PR that claims a gain can change what 100%
-means. Each is written for the WORK, not for how the program does it: a
-decode query must read the key and the value of every position it
-attends to, over all kv heads, once (4,096 B a position and layer), and
-no other: not the rest of a block, not a ring row that holds a position
-outside the window or before the row's first; a query must score and
-weigh every visible key for each of its layer's query heads (4 x 128
-operations a head and key).
+holder has a share), from shapes: the Laguna cell's yardsticks of
+`serve_mfu` and of `serve_decode_attn_` and
+`serve_prefill_attn_roofline_share` (named to the one reader by
+gqa_moe_model.YARDSTICKS), kept beside peaks.py so that no PR that
+claims a gain can change what 100% means. Each is written for the
+WORK, not for how the program does it: a decode query must read the key
+and the value of every position it attends to, over all kv heads, once
+(4,096 B a position and layer), and no other: not the rest of a block,
+not a ring row that holds a position outside the window or before the
+row's first; a query must score and weigh every visible key for each of
+its layer's query heads (4 x 128 operations a head and key).
 """
 
 from __future__ import annotations
@@ -96,6 +97,14 @@ def attn_flops(config: dict, full_pairs: int, window_pairs: int) -> float:
     return 4.0 * config["head_dim"] * (
         heads(config, FULL) * full_pairs
         + heads(config, SLIDING) * window_pairs)
+
+
+def decode_attn_work(config: dict, full: int, window: int) -> tuple:
+    """(bytes, operations) of the decode rounds' attention over `full`
+    positions of the full layers and `window` visible ring rows of the
+    sliding ones (6 or 9 query heads a kv head: the bytes bound)."""
+    return (decode_attn_bytes(config, full + window),
+            attn_flops(config, full, window))
 
 
 def flops_per_token(config: dict, shapes: list) -> float:

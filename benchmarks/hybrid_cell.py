@@ -19,13 +19,21 @@ import time
 
 import numpy as np
 
-from benchmarks import traffic as traffic_mod
+from benchmarks import ssm_ops, traffic as traffic_mod
 from benchmarks.client import ClosedLoop, Stream, post_stream
 from benchmarks.manifest import Cell
 from benchmarks import serve_cell
 from benchmarks.serve_cell import (attempted_failed, info,  # noqa: F401
                                    start_cluster)
 from benchmarks.traffic import Request
+from benchmarks.yardsticks import Yardsticks
+
+# what benchmarks/readers/model.py reads for the hybrid cell (its
+# configuration names no helper): the whole step's operations. The
+# state update's roofline is its own entry (readers/ssm.py)
+YARDSTICKS = Yardsticks(
+    flops_per_token=ssm_ops.flops_per_token,
+    attn_scopes={"decode": ["attn"], "prefill": ["attn"]})
 
 
 def correct(obs: dict, tol: dict) -> bool:

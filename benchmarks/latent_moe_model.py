@@ -15,14 +15,28 @@ from __future__ import annotations
 
 import functools
 
-from benchmarks import model
+from benchmarks import latent_moe_ops, model
 from benchmarks.eva_model import take_slots
 from benchmarks.sparse_moe_model import _margin, _placements
+from benchmarks.yardsticks import Yardsticks, per_shapes
 
 PROGRAM_MODULE = "ray_tpu.models.kimi_k2"
 # the limits `correct` holds every check to, beside `finite`
 LIMITS = ("logits_rel_rms_forced", "router_margin", "logits_rel_rms",
           "token_margin_logits", "token_margin_program")
+
+# what benchmarks/readers/model.py reads for this model: dense latent
+# attention, a scope a phase; every layer attends to every live position
+YARDSTICKS = Yardsticks(
+    flops_per_token=per_shapes(latent_moe_ops.flops_per_token),
+    attn_scopes={"decode": ["mla_decode_attn"],
+                 "prefill": ["mla_prefill_attn"]},
+    decode_attended=(("live_positions",),),
+    decode_attn_work=latent_moe_ops.decode_attn_work,
+    prefill_visible=(("prefill_latent_keys_visible",),),
+    prefill_attn_flops=latent_moe_ops.attn_flops,
+    cache_read=(["decode_latent_positions_read"],
+                ["decode_latent_positions_live"]))
 
 
 def program_config(config: dict, role: str, **overrides):

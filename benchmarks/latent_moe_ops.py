@@ -1,17 +1,17 @@
-"""The bytes and operations that a decoder of the `kimi_k2` family
-requires (dense latent attention, routed experts of which this holder
-has a share), from shapes: the yardsticks of `kimik2_mfu` and of
-`kimik2_mla_decode_attn_` and `kimik2_mla_prefill_attn_roofline_share`,
-kept beside peaks.py so that no PR that claims a gain can change what
-100% means. Each is written for
-the WORK, not for how the program does it: a decode query must read the
-one cached row ``[c_kv | k_rope]`` of every position it attends to,
-once, without the row's filling to whole lanes, and no other; a query
-must score and weigh every key from its row's first to itself, in
-whichever form of latent attention costs less (the expanded one: 2 x
-(192 + 128) operations a head and key, the key's and the value's
-up-projection counted once a token among the matrices, against 2 x (576
-+ 512) absorbed).
+"""The bytes and operations that a decoder of the `kimi_k2` family requires
+(dense latent attention, routed experts of which this holder has a
+share), from shapes: the Kimi cell's yardsticks of `serve_mfu` and of
+`serve_decode_attn_` and `serve_prefill_attn_roofline_share` (named to
+the one reader by latent_moe_model.YARDSTICKS), kept beside peaks.py so
+that no PR that claims a gain can change what 100% means. Each is
+written for the WORK, not for how the program does it: a decode query
+must read the one cached row ``[c_kv | k_rope]`` of every position it
+attends to, once, without the row's filling to whole lanes, and no
+other; a query must score and weigh every key from its row's first to
+itself, in whichever form of latent attention costs less (the expanded
+one: 2 x (192 + 128) operations a head and key, the key's and the
+value's up-projection counted once a token among the matrices, against 2
+x (576 + 512) absorbed).
 """
 
 from __future__ import annotations
@@ -84,6 +84,15 @@ def decode_attn_flops(config: dict, positions: int) -> float:
     return (config["num_attention_heads"] * 2.0
             * (2 * config["kv_lora_rank"] + config["qk_rope_head_dim"])
             * positions)
+
+
+def decode_attn_work(config: dict, live: int) -> tuple:
+    """(bytes, operations) of the decode rounds' attention over `live`
+    positions a layer: every layer attends densely, so each counts once
+    a layer."""
+    positions = live * config["num_hidden_layers"]
+    return (decode_attn_bytes(config, positions),
+            decode_attn_flops(config, positions))
 
 
 def attn_flops(config: dict, pairs: int) -> float:

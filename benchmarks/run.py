@@ -56,9 +56,11 @@ def end_to_end(cell, obs: dict) -> dict:
 
 def per_layer(cell, obs: dict) -> dict:
     """Every per-layer metric of the cell that finds something to read.
-    A reader learns whose run it reads from `obs["cell"]`, not from its
-    metric file."""
-    obs = {**obs, "cell": cell.name}
+    A reader learns whose run it reads from `obs["cell"]`, and the
+    cell's files from `obs["config"]` and `obs["traffic"]`, not from
+    its metric file and whatever the driver put into `obs`."""
+    obs = {**obs, "cell": cell.name, "config": cell.config,
+           "traffic": cell.traffic}
     out = {}
     for m in cell.per_layer:
         spec = m["file"]

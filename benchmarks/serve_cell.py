@@ -16,10 +16,18 @@ import time
 
 import numpy as np
 
-from benchmarks import traffic as traffic_mod
+from benchmarks import attention_ops, traffic as traffic_mod
 from benchmarks.client import ClosedLoop, OpenLoop, Stream, post_stream
 from benchmarks.manifest import Cell
 from benchmarks.traffic import Request
+from benchmarks.yardsticks import Yardsticks
+
+# what benchmarks/readers/model.py reads for the cells this driver runs
+# (the `llama` family; their configurations name no helper): the whole
+# step's operations, of a closed loop's cycle
+YARDSTICKS = Yardsticks(
+    flops_per_token=attention_ops.serve_flops_per_token,
+    attn_scopes={"decode": ["attn"], "prefill": ["attn"]})
 
 
 class NoAccelerator(RuntimeError):

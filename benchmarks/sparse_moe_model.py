@@ -15,13 +15,22 @@ from __future__ import annotations
 
 import functools
 
-from benchmarks import model
+from benchmarks import model, sparse_moe_ops
+from benchmarks.yardsticks import Yardsticks, per_shapes
 
 PROGRAM_MODULE = "ray_tpu.models.dots3_note"
 # the limits `correct` holds every check to, beside `finite`
 LIMITS = ("logits_rel_rms_forced", "index_score_rel_rms", "selection_margin",
           "router_margin", "logits_rel_rms", "token_margin_logits",
           "token_margin_program")
+
+# what benchmarks/readers/model.py reads for this model: the whole
+# step's operations. Its attention's rooflines are its own entries
+# (readers/sparse_moe.py: the indexer and the chosen rows, each apart)
+_ATTN = ["sparse_attn", "window_attn"]
+YARDSTICKS = Yardsticks(
+    flops_per_token=per_shapes(sparse_moe_ops.flops_per_token),
+    attn_scopes={"decode": _ATTN, "prefill": _ATTN})
 
 
 def program_config(config: dict, role: str, **overrides):

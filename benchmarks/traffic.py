@@ -123,6 +123,15 @@ def closed_loop(spec: dict, vocab: int, seed: int):
         index += n
 
 
+def cycle_lengths(spec: dict) -> tuple:
+    """(prompt lengths, output lengths) of one cycle of a closed loop:
+    what every cycle holds, whatever the seed. The pairing of the two is
+    shuffled anew in each cycle."""
+    n = int(spec["cycle"])
+    return (quantile_lengths(spec["prompt_len"], n),
+            quantile_lengths(spec["output_len"], n))
+
+
 def bucket_of(n: int, buckets) -> int:
     for b in buckets:
         if n <= b:

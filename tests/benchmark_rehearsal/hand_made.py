@@ -2,11 +2,15 @@
 would return and a profiler trace in trace_spans' plain form, built from
 the cell's own files and nothing else. The trace holds every scope, the
 spans every field and `stats()` every counter that a metric file of the
-cell names in its `args`, and what the reader modules those files name
+cell names in its `args`, what the reader modules those files name
 read by their own arithmetic, which hand_made/<module>.json lists:
 
     {"scopes": ["decode/ssm_update", ...],
      "fields": {"rayt.engine.emit": ["experts_hit", ...]}}
+
+and, in the same form, what readers/model.py reads for the cell's model:
+the scopes and fields its YARDSTICKS names (`Yardsticks.reads`), so a
+model's helper is the one place that lists them.
 
 test_manifest_entries.py holds every (entry, cell) pair to it: a number
 from `obs(cell)`, None from `bare(cell)`, the same run of a program that
@@ -23,6 +27,7 @@ import os
 from benchmarks import trace_spans
 from benchmarks.client import Stream
 from benchmarks.manifest import Cell
+from benchmarks.readers import model as model_reader
 from benchmarks.readers import spans
 from benchmarks.traffic import Request
 
@@ -63,8 +68,10 @@ def needs(cell: Cell) -> tuple:
                 + args.get("fields", []))
         if "counter" in args:
             counters.append(args["counter"])
-    for name in sorted(modules):
-        made = module_reads(name)
+    own = model_reader.yardsticks({"config": cell.config,
+                                   "traffic": cell.traffic})
+    for made in [module_reads(name) for name in sorted(modules)] + \
+            [own.reads() if own and "model" in modules else {}]:
         scopes += made.get("scopes", [])
         for span, names in made.get("fields", {}).items():
             fields.setdefault(span, []).extend(names)

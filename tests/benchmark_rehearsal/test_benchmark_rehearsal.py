@@ -438,6 +438,42 @@ def test_peaks_and_required_operations():
     assert 7.3e9 < per_token < 7.6e9
 
 
+def test_llama_serve_operations_a_token_against_hand_counts():
+    """`serve_mfu`'s yardstick in the long-prompt cell: one cycle of the
+    closed loop, the attention in expectation over the pairing."""
+    import itertools
+
+    from benchmarks import attention_ops
+
+    cell = manifest_mod.resolve(MANIFEST, "internlm2-1.8b.longprompt")
+    prompts, outputs = traffic_mod.cycle_lengths(cell.traffic)
+    assert prompts == [1625 + 250 * i for i in range(8)]
+    assert outputs == [19 + 6 * i for i in range(8)]
+    c = attention_ops.cycle_sums(cell.traffic)
+    assert (c["tokens"], c["passed"], c["outputs"]) == (20320, 20312, 320)
+    # the mean over a sample of pairings is the mean over all pairs
+    by_pair = {(p, o): (p + o - 1) * (p + o) // 2
+               for p in prompts for o in outputs}
+    assert c["pairs"] == sum(by_pair.values()) / 8
+    turns = [outputs[k:] + outputs[:k] for k in range(8)]     # 8 matchings
+    assert sum(sum(by_pair[p, o] for p, o in zip(prompts, t))
+               for t in turns) / 8 == c["pairs"]
+    assert attention_ops.causal_pairs(3) == 6
+    # 24 x 62.9 M block weights a position, the head of 189.5 M for each
+    # output token, 4 x 128 x 16 heads x 24 layers a pair
+    block, head = 24 * 62_914_560, 2048 * 92544
+    assert attention_ops.serve_flops_per_token(
+        cell.config, cell.traffic) == pytest.approx(
+        (2 * block * 20312 + 2 * head * 320 + 196_608 * c["pairs"]) / 20320)
+    assert 3.2e9 < attention_ops.serve_flops_per_token(
+        cell.config, cell.traffic) < 3.4e9
+    # a seed's cycle holds these lengths, in another pairing
+    reqs = list(itertools.islice(
+        traffic_mod.closed_loop(cell.traffic, 1000, 3_000_000_019), 8))
+    assert sorted(len(r.tokens) for r in reqs) == prompts
+    assert sorted(r.max_new_tokens for r in reqs) == outputs
+
+
 # ------------------------------------------- reference against the program
 def test_reference_imports_nothing_from_the_program():
     ref_dir = os.path.join(ROOT, "benchmarks", "reference")

@@ -17,7 +17,7 @@ from benchmarks import eva_model, eva_ops
 from benchmarks import manifest as manifest_mod
 from benchmarks import model_cell
 from benchmarks import traffic as traffic_mod
-from benchmarks.readers import eva as reader
+from benchmarks.readers import model as reader
 from benchmarks.readers import spans as spans_reader
 
 ROOT = manifest_mod.ROOT
@@ -215,13 +215,8 @@ def test_readers_on_a_hand_made_trace(monkeypatch, full):
         100 * (14000 * 16384 / 819e9) / 300e-9)
     assert reader.prefill_attn_roofline_share(obs) == pytest.approx(
         100 * (5_000_000 * 16384 / 197e12) / 600e-9)
-    # the cache's read excess, by the fields its metric file names
-    with open(os.path.join(ROOT, "benchmarks", "metrics",
-                           "evabyte_cache_read_excess.json")) as f:
-        excess = json.load(f)
-    assert excess["reader"] == "spans.field_ratio"
-    assert spans_reader.field_ratio(obs, **excess["args"]) == \
-        pytest.approx(15500 / 14000)
+    # the cache's read excess, by the fields the helper names
+    assert reader.cache_read_excess(obs) == pytest.approx(15500 / 14000)
     # the share of the whole: the window's rate against the peak
     monkeypatch.setattr(reader, "serve_tokens_per_s", lambda obs: 12000.0)
     obs["traffic"] = {"shapes": SHAPES}
@@ -241,7 +236,7 @@ def test_readers_on_a_hand_made_trace(monkeypatch, full):
     monkeypatch.setattr(trace_spans, "newest_xplane", lambda d: None)
     spans_reader._reduced.clear()
     assert share(["decode/mlp"]) is None and phases(["prefill"]) is None
-    assert spans_reader.field_ratio(obs, **excess["args"]) is None
+    assert reader.cache_read_excess(obs) is None
     assert reader.mfu(obs) is None
 
 
@@ -251,21 +246,24 @@ def test_every_metric_of_the_cell_names_it_and_a_reader_that_is_there():
     cell = manifest_mod.resolve(MANIFEST, CELL)
     own = {m["name"]: m for m in cell.per_layer
            if m["name"].startswith("evabyte_")}
-    assert {"evabyte_window_attn_share", "evabyte_chunk_attn_share",
-            "evabyte_summarise_share", "evabyte_cache_read_excess"} <= \
-        set(own)
+    assert set(own) == {"evabyte_window_attn_share",
+                        "evabyte_chunk_attn_share",
+                        "evabyte_summarise_share"}
     for m in own.values():
         assert m["workloads"] == [CELL], m["name"]
         assert m["moves"] == "serve_tokens_per_s"
         assert m["file"]["what"] and "cell" not in m["file"]["args"]
-        module, fn = m["file"]["reader"].split(".")
-        assert module in ("eva", "spans"), m["name"]
-        if module == "eva":
-            assert callable(getattr(reader, fn))
-    peaks = {n for n in own if n.endswith(("roofline_share", "_mfu"))}
-    assert peaks == {"evabyte_decode_attn_roofline_share",
-                     "evabyte_prefill_attn_roofline_share", "evabyte_mfu"}
-    assert all(own[n]["unit"] == "%" for n in peaks)
+        assert m["file"]["reader"] == "spans.path_share", m["name"]
+    # its yardsticks are the shared entries', read through its helper
+    shared = {m["name"]: m for m in cell.per_layer
+              if m["file"]["reader"].startswith("model.")}
+    assert set(shared) == {"serve_decode_attn_roofline_share",
+                           "serve_prefill_attn_roofline_share",
+                           "serve_mfu", "serve_cache_read_excess"}
+    for m in shared.values():
+        assert callable(getattr(reader, m["file"]["reader"].split(".")[1]))
+    peaks = {n for n in shared if n.endswith(("roofline_share", "_mfu"))}
+    assert all(shared[n]["unit"] == "%" for n in peaks) and len(peaks) == 3
     assert CELL in next(m for m in MANIFEST["end_to_end"]
                         if m["name"] == "serve_tokens_per_s")["workloads"]
     assert {m["name"] for m in cell.end_to_end} == {"serve_tokens_per_s",

@@ -18,7 +18,7 @@ from benchmarks import gqa_moe_model, gqa_moe_ops
 from benchmarks import manifest as manifest_mod
 from benchmarks import model_cell, rehearsal
 from benchmarks import traffic as traffic_mod
-from benchmarks.readers import gqa_moe as reader
+from benchmarks.readers import model as reader
 from benchmarks.readers import spans as spans_reader
 from benchmarks.readers import sparse_moe as experts_reader
 
@@ -31,10 +31,11 @@ REDUCED = ["num_hidden_layers", "layer_types", "mlp_layer_types",
            "gating_types", "num_attention_heads_per_layer", "num_experts",
            "vocab_size"]
 PER_LAYER = REDUCED[1:5]
-OWN = {"laguna_mfu", "laguna_full_attn_share", "laguna_window_attn_share",
-       "laguna_attn_proj_share", "laguna_decode_attn_roofline_share",
-       "laguna_prefill_attn_roofline_share", "laguna_cache_read_excess",
-       "laguna_moe_tile_fill"}
+OWN = {"laguna_full_attn_share", "laguna_moe_tile_fill"}
+# its yardsticks and the scopes it shares, under the shared names
+MODEL = {"serve_mfu", "serve_decode_attn_roofline_share",
+         "serve_prefill_attn_roofline_share", "serve_cache_read_excess"}
+SCOPES = {"serve_window_attn_share", "serve_attn_proj_share"}
 
 
 @pytest.fixture(scope="module")
@@ -263,16 +264,14 @@ def test_readers_on_a_hand_made_trace(monkeypatch, full):
         100 * (46 * 18_874_368 / 819e9) / 100e-9)
     # what the entries that are data name
     ratio = lambda name: spans_reader.field_ratio(obs, **_metric(name)["args"])
-    assert _metric("laguna_cache_read_excess")["reader"] == \
-        _metric("laguna_moe_tile_fill")["reader"] == "spans.field_ratio"
-    assert ratio("laguna_cache_read_excess") == pytest.approx(
-        676_080 / 646_080)
+    assert _metric("laguna_moe_tile_fill")["reader"] == "spans.field_ratio"
+    assert reader.cache_read_excess(obs) == pytest.approx(676_080 / 646_080)
     assert ratio("laguna_moe_tile_fill") == pytest.approx(80 / 47)
     share = lambda name: spans_reader.path_share(
         obs, **_metric(name)["args"])
     assert share("laguna_full_attn_share") == pytest.approx(100 * 1500 / 2400)
-    assert share("laguna_window_attn_share") == pytest.approx(100 * 200 / 2400)
-    assert share("laguna_attn_proj_share") == pytest.approx(100 * 500 / 2400)
+    assert share("serve_window_attn_share") == pytest.approx(100 * 200 / 2400)
+    assert share("serve_attn_proj_share") == pytest.approx(100 * 500 / 2400)
     assert share("serve_moe_experts_share") == \
         pytest.approx(100 * 100 / 2400)
     assert share("serve_mlp_share") is None               # not in it
@@ -284,7 +283,7 @@ def test_readers_on_a_hand_made_trace(monkeypatch, full):
     monkeypatch.setattr(trace_spans, "newest_xplane", lambda d: None)
     spans_reader._reduced.clear()
     for fn in (reader.decode_attn_roofline_share, reader.mfu,
-               reader.prefill_attn_roofline_share,
+               reader.prefill_attn_roofline_share, reader.cache_read_excess,
                experts_reader.experts_roofline_share):
         assert fn(obs) is None
 
@@ -300,19 +299,21 @@ def test_every_metric_of_the_cell_names_it_and_a_reader_that_is_there():
         assert m["workloads"] == [CELL], m["name"]
         assert m["moves"] == "serve_tokens_per_s" and m["file"]["what"]
         assert "cell" not in m["file"]["args"], m["name"]
-    peaks = {n: m for n, m in own.items()
-             if n.endswith(("roofline_share", "_mfu"))}
-    assert len(peaks) == 3
+    by_name = {m["name"]: m for m in cell.per_layer}
+    peaks = {n: by_name[n] for n in MODEL - {"serve_cache_read_excess"}}
     assert all(m["unit"] == "%" and m["better"] == "higher"
+               and m["file"]["reader"].startswith("model.")
                for m in peaks.values())
-    assert own["laguna_mfu"]["layer"] == "the whole"
-    assert {m["layer"] for n, m in peaks.items() if n != "laguna_mfu"} == \
+    assert peaks["serve_mfu"]["layer"] == "the whole"
+    assert {m["layer"] for n, m in peaks.items() if n != "serve_mfu"} == \
         {"kernels"}
     # the shared readings the cell reports beside its own: the sixteen
-    # serve_*, the three of the held experts, the eight under setup_s
-    names = {m["name"] for m in cell.per_layer}
+    # serve_*, the three of the held experts, the eight under setup_s,
+    # its model's four yardsticks and the two scopes other models write
+    names = set(by_name)
     shared = names - OWN
-    assert len(shared) == 16 + 3 + 8
+    assert shared >= MODEL | SCOPES
+    assert len(shared) == 16 + 3 + 8 + len(MODEL | SCOPES)
     assert {"serve_device_idle_share", "serve_batch_occupancy",
             "serve_prefill_ms_per_ktok", "serve_mlp_share",
             "serve_lm_head_share", "serve_peak_hbm_gb",
@@ -321,7 +322,7 @@ def test_every_metric_of_the_cell_names_it_and_a_reader_that_is_there():
             "programs_in_window"} <= shared
     assert {m["name"] for m in cell.end_to_end} == {"serve_tokens_per_s",
                                                    "setup_s"}
-    assert len(MANIFEST["workloads"]) == 9 and len(MANIFEST["configs"]) == 7
+    # no count of cells or configurations: a later PR appends its own
     assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 1
 
 

@@ -147,6 +147,31 @@ def _hand_made_trace(with_ssm: bool):
         {"name": "/host:CPU", "lines": [{"name": "t", "events": host}]}]}
 
 
+def test_operations_a_token_against_hand_counts(full):
+    """`serve_mfu`'s yardstick in this cell (ssm_ops.flops_per_token)."""
+    from benchmarks import attention_ops
+
+    tr = manifest_mod.resolve(MANIFEST, CELL).traffic
+    c = attention_ops.cycle_sums(tr)
+    assert (c["tokens"], c["passed"], c["outputs"]) == (
+        50 + 136 + 293 + 795 + 153 + 222 + 295 + 430, 2374 - 4, 1100)
+    # a Mamba layer: in_z 2048 x 4096, in_xbc 2048 x 4352, in_dt 2048 x 64,
+    # out_proj 4096 x 2048; an attention layer: q and o 2048 x 2048, k and
+    # v 2048 x 512; the MLP behind either 3 x 2048 x 8192
+    mamba = 2048 * (4096 + 4352 + 64) + 4096 * 2048
+    attention = 2 * 2048 * 2048 + 2 * 2048 * 512
+    matrices = 36 * mamba + 4 * attention + 40 * 3 * 2048 * 8192
+    assert 2.9e9 < matrices < 3.0e9      # of the model's 3.19 G parameters
+    # the recurrence: 5 operations an element of 64 x 64 x 128, 36 layers;
+    # the convolution: 4 products and sums a channel of 4352
+    a_token = 2 * matrices + 36 * 5 * 64 * 64 * 128 + 36 * 2 * 4 * 4352
+    want = (a_token * c["passed"] + 2 * 2048 * 100352 * c["outputs"]
+            + 4 * 64 * 32 * 4 * c["pairs"]) / c["tokens"]
+    assert ssm_ops.flops_per_token(full, tr) == pytest.approx(want)
+    assert 6.1e9 < want < 6.4e9
+    assert hybrid_cell.YARDSTICKS.flops_per_token is ssm_ops.flops_per_token
+
+
 def test_ssm_reader_sums_self_time_by_any_scope_name(monkeypatch, full):
     from benchmarks import trace_spans
 

@@ -16,7 +16,7 @@ from benchmarks import latent_moe_model, latent_moe_ops
 from benchmarks import manifest as manifest_mod
 from benchmarks import model_cell
 from benchmarks import traffic as traffic_mod
-from benchmarks.readers import latent_moe as reader
+from benchmarks.readers import model as reader
 from benchmarks.readers import spans as spans_reader
 from benchmarks.readers import sparse_moe as experts_reader
 
@@ -236,14 +236,12 @@ def test_readers_on_a_hand_made_trace(monkeypatch, full):
     assert experts_reader.experts_roofline_share(obs) == pytest.approx(
         100 * (7 * 88_080_384 / 819e9) / 100e-9)
     # what the entries that are data name: the read excess, the shares
-    excess = _metric("kimik2_cache_read_excess")
-    assert excess["reader"] == "spans.field_ratio"
-    assert spans_reader.field_ratio(obs, **excess["args"]) == \
+    assert reader.cache_read_excess(obs) == \
         pytest.approx(3_350_000 / 3_050_000)
     share = lambda name: spans_reader.path_share(
         obs, **_metric(name)["args"])
     assert share("kimik2_mla_attn_share") == pytest.approx(100 * 1700 / 2300)
-    assert share("kimik2_mla_proj_share") == pytest.approx(100 * 400 / 2300)
+    assert share("serve_attn_proj_share") == pytest.approx(100 * 400 / 2300)
     assert share("serve_moe_experts_share") == \
         pytest.approx(100 * 100 / 2300)
     assert share("serve_moe_shared_router_share") is None   # not in it
@@ -255,7 +253,7 @@ def test_readers_on_a_hand_made_trace(monkeypatch, full):
     monkeypatch.setattr(trace_spans, "newest_xplane", lambda d: None)
     spans_reader._reduced.clear()
     for fn in (reader.decode_attn_roofline_share, reader.mfu,
-               reader.prefill_attn_roofline_share,
+               reader.prefill_attn_roofline_share, reader.cache_read_excess,
                experts_reader.experts_roofline_share):
         assert fn(obs) is None
 
@@ -283,20 +281,23 @@ def test_every_metric_of_the_cell_names_it_and_a_reader_that_is_there():
     cell = manifest_mod.resolve(MANIFEST, CELL)
     own = {m["name"]: m for m in cell.per_layer
            if m["name"].startswith("kimik2_")}
-    assert {"kimik2_mfu", "kimik2_mla_decode_attn_roofline_share",
-            "kimik2_mla_prefill_attn_roofline_share",
-            "kimik2_cache_read_excess", "kimik2_moe_expert_tiles",
-            "kimik2_mla_attn_share", "kimik2_mla_proj_share"} <= set(own)
+    assert set(own) == {"kimik2_moe_expert_tiles", "kimik2_mla_attn_share"}
     for m in own.values():
         assert m["workloads"] == [CELL], m["name"]
         assert m["moves"] == "serve_tokens_per_s" and m["file"]["what"]
         assert "cell" not in m["file"]["args"], m["name"]
-    peaks = {n: m for n, m in own.items()
-             if n.endswith(("roofline_share", "_mfu"))}
+    # its yardsticks are the shared entries', read through its helper
+    by_name = {m["name"]: m for m in cell.per_layer}
+    assert {"serve_cache_read_excess", "serve_attn_proj_share"} <= \
+        set(by_name)
+    peaks = {n: by_name[n] for n in (
+        "serve_mfu", "serve_decode_attn_roofline_share",
+        "serve_prefill_attn_roofline_share")}
     assert all(m["unit"] == "%" and m["better"] == "higher"
+               and m["file"]["reader"].startswith("model.")
                for m in peaks.values())
-    assert own["kimik2_mfu"]["layer"] == "the whole"
-    assert {m["layer"] for n, m in peaks.items() if n != "kimik2_mfu"} == \
+    assert peaks["serve_mfu"]["layer"] == "the whole"
+    assert {m["layer"] for n, m in peaks.items() if n != "serve_mfu"} == \
         {"kernels"}
     # the shared readings the cell reports beside its own
     assert {"serve_device_idle_share", "serve_batch_occupancy",

@@ -15,8 +15,10 @@ import types
 import pytest
 
 import hand_made
+import retired_readers
 from benchmarks import manifest as manifest_mod
 from benchmarks import run
+from benchmarks.readers import model as model_reader
 from benchmarks.readers import spans
 
 ROOT = manifest_mod.ROOT
@@ -38,6 +40,10 @@ SERVE = {"serve_device_idle_share", "serve_idle_admission_share",
 # the held experts' readings, for the cells whose model has them
 EXPERTS = {"serve_moe_experts_share", "serve_moe_shared_router_share",
            "serve_moe_experts_roofline_share"}
+# a model's own yardsticks, read through its configuration's helper
+# (readers/model.py); every serve_tokens_per_s cell has the first
+MODEL = {"serve_mfu", "serve_decode_attn_roofline_share",
+         "serve_prefill_attn_roofline_share", "serve_cache_read_excess"}
 TRAIN = {"train_mfu", "train_host_share", "train_device_idle_share",
          "train_peak_hbm_gb", "train_idle_unowned_share",
          "train_recompute_share", "train_flash_roofline_share"}
@@ -57,6 +63,7 @@ MUST_HAVE = {
         "chat_idle_unowned_share", "chat_prefill_device_share",
         "chat_decode_attention_share", "chat_device_unscoped_share"},
     "internlm2-1.8b.longprompt": SERVE | STARTUP | {
+        "serve_mfu", "serve_attn_proj_share",
         "longprompt_decode_attention_share",
         "longprompt_ttft_decode_interleave_share"},
     "internlm2-1.8b.lora-ft": TRAIN | STARTUP | {
@@ -67,33 +74,57 @@ MUST_HAVE = {
     # its decode-only granite_mlp_share stands for serve_mlp_share
     "granite-4.0-h-micro.chat-closed": (SERVE - {"serve_mlp_share"}) | \
     STARTUP | {
+        "serve_mfu", "serve_attn_proj_share",
         "granite_decode_attention_share", "granite_mlp_share",
         "granite_ssm_update_share", "granite_ssm_scan_share",
         "granite_ssm_mixer_rest_share",
         "granite_ssm_update_roofline_share"},
     "dots3-note-prev.longdoc-closed": SERVE | STARTUP | EXPERTS | {
+        "serve_mfu", "serve_window_attn_share", "serve_attn_proj_share",
         "dots3_index_score_share", "dots3_index_topk_share",
-        "dots3_sparse_attn_share", "dots3_window_attn_share",
-        "dots3_mla_proj_share",
+        "dots3_sparse_attn_share",
         "dots3_index_score_roofline_share",
         "dots3_sparse_attn_roofline_share"},
-    "EvaByte.bytes-longdoc-closed": SERVE | STARTUP | {
+    "EvaByte.bytes-longdoc-closed": SERVE | STARTUP | MODEL | {
         "evabyte_window_attn_share", "evabyte_chunk_attn_share",
-        "evabyte_summarise_share", "evabyte_attn_proj_share",
-        "evabyte_cache_read_excess",
-        "evabyte_decode_attn_roofline_share",
-        "evabyte_prefill_attn_roofline_share", "evabyte_mfu"},
-    "Kimi-K2.6.docqa-closed": SERVE | STARTUP | EXPERTS | {
-        "kimik2_mfu", "kimik2_mla_decode_attn_roofline_share",
-        "kimik2_mla_prefill_attn_roofline_share",
-        "kimik2_cache_read_excess", "kimik2_moe_expert_tiles",
-        "kimik2_mla_attn_share", "kimik2_mla_proj_share"},
+        "evabyte_summarise_share", "serve_attn_proj_share"},
+    "Kimi-K2.6.docqa-closed": SERVE | STARTUP | EXPERTS | MODEL | {
+        "kimik2_moe_expert_tiles", "kimik2_mla_attn_share",
+        "serve_attn_proj_share"},
+    "Laguna-S-2.1.codectx-closed": SERVE | STARTUP | EXPERTS | MODEL | {
+        "laguna_full_attn_share", "laguna_moe_tile_fill",
+        "serve_window_attn_share", "serve_attn_proj_share"},
 }
 # what PR 50 retired: each read 0.0 on both sides of every line, and has
 # a twin from inside the program or a test that holds what it guarded
 RETIRED = {"chat_compiles_in_window", "longprompt_compiles_in_window",
            "granite_compiles_in_window", "dots3_compiles_in_window",
            "evabyte_programs_in_window", "chat_decode_kv_update_share"}
+# what PR 60 merged, each into the shared entry that reads the same
+# number in its cell (old name -> new name)
+MERGED = {
+    "evabyte_mfu": "serve_mfu", "kimik2_mfu": "serve_mfu",
+    "laguna_mfu": "serve_mfu",
+    "evabyte_decode_attn_roofline_share": "serve_decode_attn_roofline_share",
+    "kimik2_mla_decode_attn_roofline_share":
+        "serve_decode_attn_roofline_share",
+    "laguna_decode_attn_roofline_share": "serve_decode_attn_roofline_share",
+    "evabyte_prefill_attn_roofline_share":
+        "serve_prefill_attn_roofline_share",
+    "kimik2_mla_prefill_attn_roofline_share":
+        "serve_prefill_attn_roofline_share",
+    "laguna_prefill_attn_roofline_share":
+        "serve_prefill_attn_roofline_share",
+    "evabyte_cache_read_excess": "serve_cache_read_excess",
+    "kimik2_cache_read_excess": "serve_cache_read_excess",
+    "laguna_cache_read_excess": "serve_cache_read_excess",
+    "dots3_window_attn_share": "serve_window_attn_share",
+    "laguna_window_attn_share": "serve_window_attn_share",
+    "evabyte_attn_proj_share": "serve_attn_proj_share",
+    "laguna_attn_proj_share": "serve_attn_proj_share",
+    "kimik2_mla_proj_share": "serve_attn_proj_share",
+    "dots3_mla_proj_share": "serve_attn_proj_share"}
+RETIRED |= set(MERGED)
 
 
 @pytest.fixture(scope="module")
@@ -163,6 +194,99 @@ def test_the_manifest_leaves_room_and_no_file_is_left_over():
         seen[key] = m["name"]
 
 
+OLD_READERS = [(module, fn) for module in retired_readers.CELLS
+               for fn in retired_readers.FUNCTIONS]
+
+
+@pytest.mark.parametrize("module,fn", OLD_READERS,
+                         ids=[f"{m}.{f}" for m, f in OLD_READERS])
+def test_the_one_reader_reads_what_the_models_own_reader_read(
+        module, fn, runs, monkeypatch):
+    """readers/<module>.<fn>, as it stood before PR 60 merged it
+    (retired_readers.py), and readers/model.<fn>, which finds the same
+    arithmetic through the configuration's helper: the same number to
+    the last digit on that model's hand-made run, and both nothing where
+    there is nothing to read."""
+    cell, obs, both = runs(retired_readers.CELLS[module])
+    old = getattr(retired_readers, module + "_" + fn)
+    new = getattr(model_reader, fn)
+    monkeypatch.setattr(spans, "_both", lambda name: both)
+    value = old(obs)
+    assert isinstance(value, float) and math.isfinite(value) and value > 0
+    assert new(obs) == value
+    monkeypatch.setattr(spans, "_both", lambda name: (None, None))
+    assert old(hand_made.bare(cell)) is None
+    assert new(hand_made.bare(cell)) is None
+
+
+NO_YARDSTICK = [
+    # a helper that names the operations a token and no attention work
+    ("dots3-note-prev.longdoc-closed", "decode_attn_roofline_share"),
+    ("dots3-note-prev.longdoc-closed", "prefill_attn_roofline_share"),
+    ("dots3-note-prev.longdoc-closed", "cache_read_excess"),
+    # configurations with no "model" key, found by their driver
+    ("internlm2-1.8b.longprompt", "decode_attn_roofline_share"),
+    ("internlm2-1.8b.longprompt", "cache_read_excess"),
+    ("granite-4.0-h-micro.chat-closed", "prefill_attn_roofline_share"),
+    # a driver that names no yardsticks at all
+    ("internlm2-1.8b.lora-ft", "mfu"),
+]
+
+
+@pytest.mark.parametrize("cell_name,fn", NO_YARDSTICK,
+                         ids=[f"{f}@{c}" for c, f in NO_YARDSTICK])
+def test_a_yardstick_a_model_does_not_name_reads_nothing(
+        cell_name, fn, runs, monkeypatch):
+    """The generic readers give None, and no error, for a cell whose
+    helper or driver names no yardstick of that kind, on a run in which
+    every other reader of the cell finds something to read."""
+    _, obs, both = runs(cell_name)
+    monkeypatch.setattr(spans, "_both", lambda name: both)
+    assert getattr(model_reader, fn)(obs) is None
+
+
+@pytest.mark.parametrize("fn", retired_readers.FUNCTIONS)
+@pytest.mark.parametrize("config,traffic", [
+    ({}, {"driver": "no_such_driver"}),        # no model key, unknown driver
+    ({"model": "no_such_model"}, {"driver": "serve_cell"}),
+    ({}, {}), ({"model": "manifest"}, {})],    # a module with no YARDSTICKS
+    ids=["unknown-driver", "unknown-model", "neither", "no-table"])
+def test_a_configuration_the_readers_cannot_place_reads_nothing(
+        fn, config, traffic, runs, monkeypatch):
+    _, obs, both = runs("Kimi-K2.6.docqa-closed")
+    monkeypatch.setattr(spans, "_both", lambda name: both)
+    assert getattr(model_reader, fn)(obs) is not None     # placed: a number
+    lost = {**obs, "config": config, "traffic": traffic}
+    assert model_reader.yardsticks(lost) is None
+    assert getattr(model_reader, fn)(lost) is None
+
+
+def test_a_models_yardsticks_name_what_its_hand_made_run_holds(runs):
+    """Every helper or driver that holds a YARDSTICKS names scopes as
+    "<phase>/<scope>" and span fields that the hand-made trace of its
+    cell then carries, so the pairs above read a number with no
+    hand_made/<module>.json for readers/model.py."""
+    seen = 0
+    for name in CELLS:
+        cell, obs, _ = runs(name)
+        own = model_reader.yardsticks(obs)
+        if own is None or not any(m["file"]["reader"].startswith("model.")
+                                  for m in cell.per_layer):
+            continue
+        seen += 1
+        reads = own.reads()
+        scopes, fields, _ = hand_made.needs(cell)
+        assert reads["scopes"] and set(reads["scopes"]) <= set(scopes)
+        assert all(s.split("/")[0] in ("decode", "prefill")
+                   for s in reads["scopes"])
+        for span, names in reads["fields"].items():
+            assert span.startswith("rayt.engine.") and names
+            assert set(names) <= set(fields[span])
+    assert seen >= 6       # every serve_tokens_per_s cell, and any later
+    # and every name PR 60 merged lives on as an entry of the manifest
+    assert set(MERGED.values()) <= {m["name"] for m in MANIFEST["per_layer"]}
+
+
 def test_what_a_hand_made_trace_must_hold_for_a_reader_module():
     """hand_made/<module>.json, where a reader module has one, names
     scopes and span fields in the forms hand_made.py builds a trace
@@ -179,14 +303,17 @@ def test_what_a_hand_made_trace_must_hold_for_a_reader_module():
 
 
 def test_the_harness_names_the_cell_to_the_readers(runs, monkeypatch):
-    """run.per_layer puts the cell's name into `obs`; every reader that
-    reads the trace asks for that cell's, and the line holds every
-    entry of the cell."""
+    """run.per_layer puts the cell's name, configuration and traffic
+    into `obs` (serve_cell's own `obs` holds no configuration: the
+    long-prompt cell's first traced run of PR 60 failed on it); every
+    reader that reads the trace asks for that cell's, and the line holds
+    every entry of the cell."""
     cell, obs, both = runs("Kimi-K2.6.docqa-closed")
     asked = set()
     monkeypatch.setattr(spans, "_both",
                         lambda name: asked.add(name) or both)
-    line = run.per_layer(cell, {k: v for k, v in obs.items() if k != "cell"})
+    line = run.per_layer(cell, {k: v for k, v in obs.items()
+                                if k not in ("cell", "config", "traffic")})
     assert asked == {cell.name}
     assert set(line) == {m["name"] for m in cell.per_layer}
     assert all(isinstance(v["value"], float) and v["unit"]

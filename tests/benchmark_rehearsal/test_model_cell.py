@@ -212,6 +212,47 @@ def _hand_made_trace(with_moe: bool):
         {"name": "/host:CPU", "lines": [{"name": "t", "events": host}]}]}
 
 
+def test_operations_a_token_against_hand_counts(full):
+    """`serve_mfu`'s yardstick in this cell
+    (sparse_moe_ops.flops_per_token), on two requests small enough to
+    count by hand and at the cell's shapes."""
+    ops = sparse_moe_ops
+    # a full layer: q_a 5120 x 1024, q_b 1024 x 128 x 192, kv_a 5120 x
+    # 576, the up-projections 512 x 128 x 256, gate 5120 x 128, o 16384 x
+    # 5120, the indexer's wq_b 1024 x 64 x 128, key 5120 x 128, weights
+    # 5120 x 64
+    full_layer = (5120 * 1024 + 1024 * 128 * 192 + 5120 * 576
+                  + 512 * 128 * 256 + 5120 * 128 + 128 * 128 * 5120
+                  + 1024 * 64 * 128 + 5120 * 128 + 5120 * 64)
+    sliding = (5120 * 1024 + 1024 * 64 * 256 + 5120 * 1088
+               + 1024 * 64 * 320 + 5120 * 64 + 64 * 128 * 5120)
+    assert ops.attention_params(full, "full_attention") == full_layer
+    assert ops.attention_params(full, "sliding_attention") == sliding
+    assert ops.held_pairs_per_token(full) == 1.0       # 8 x 32 / 256
+    expert = 3 * 5120 * 1536
+    matrices = (2 * full_layer + 3 * sliding + 3 * 5120 * 13824
+                + 4 * (5120 * 256 + expert * (1 + 1.0)))
+    assert ops.matmul_params(full) == matrices
+    assert 0.95e9 < matrices < 1.05e9
+    # [3, 2]: 4 queries that see 1, 2, 3, 4 keys; [3000, 1]: 3000 queries
+    # of which the last 952 choose 2,048 and the last 2,487 see 513
+    assert ops._first(4, 2048) == 10 and ops._first(4, 3) == 9
+    shapes = [[3, 2], [3000, 1]]
+    scored = 10 + 3000 * 3001 // 2
+    chosen = 10 + 2048 * 2049 // 2 + 952 * 2048
+    window = 10 + 513 * 514 // 2 + 2487 * 513
+    attention = (2 * (64 * (2 * 128 + 3) * scored + 2 * 128 * 320 * chosen)
+                 + 3 * 2 * 64 * 384 * window)
+    want = (2 * matrices * 3004 + 2 * 5120 * 19008 * 3 + attention) / 3006
+    assert ops.flops_per_token(full, shapes) == pytest.approx(want)
+    cell = manifest_mod.resolve(MANIFEST, CELL).traffic["shapes"]
+    assert 2.4e9 < ops.flops_per_token(full, cell) < 2.7e9
+    own = sparse_moe_model.YARDSTICKS
+    assert own.flops_per_token(full, {"shapes": shapes}) == \
+        ops.flops_per_token(full, shapes)
+    assert own.decode_attn_work is None and own.cache_read is None
+
+
 def test_readers_on_a_hand_made_trace(monkeypatch, full):
     from benchmarks import trace_spans
 
@@ -267,7 +308,9 @@ def test_every_metric_of_the_cell_names_it_and_a_reader_that_is_there():
     own = {m["name"]: m for m in cell.per_layer
            if m["name"].startswith("dots3_")}
     assert {"dots3_index_score_share", "dots3_index_topk_share",
-            "dots3_sparse_attn_share", "dots3_window_attn_share"} <= set(own)
+            "dots3_sparse_attn_share"} <= set(own)
+    assert {"serve_window_attn_share", "serve_attn_proj_share",
+            "serve_mfu"} <= {m["name"] for m in cell.per_layer}
     for m in cell.per_layer:
         assert m["moves"] in ("serve_tokens_per_s", "setup_s"), m["name"]
     for m in own.values():
